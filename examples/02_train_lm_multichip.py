@@ -1,5 +1,6 @@
-"""Multi-chip SPMD LM training on a dp x tp mesh. Off-TPU this simulates
-8 devices (run: python examples/02_train_lm_multichip.py)."""
+"""Multi-chip SPMD LM training on a dp x tp mesh, always on 8 virtual CPU
+devices — it never opens a chip (run: python
+examples/02_train_lm_multichip.py). chip_smoke.py trains on real ones."""
 import os
 
 if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
@@ -8,8 +9,7 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
 
 import jax
 
-# The config knob (not the env var) wins over site-installed TPU plugins —
-# this demo always simulates a slice with 8 virtual CPU devices.
+# Whatever JAX_PLATFORMS says: the mesh below is 8 virtual CPU devices.
 jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy as np

@@ -25,6 +25,7 @@ go straight to shared memory without holding it.
 
 from __future__ import annotations
 
+import hashlib
 import mmap
 import os
 import socket
@@ -51,9 +52,9 @@ def _default_inline_max() -> int:
     from ray_tpu import config
     return int(config.get("max_inline_object_bytes"))
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "_native")
-SHMSTORED = os.path.join(_NATIVE_DIR, "shmstored")
+_REPO_DIR = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+_NATIVE_DIR = os.path.join(_REPO_DIR, "ray_tpu", "_native")
 
 
 class ObjectStoreError(Exception):
@@ -65,20 +66,25 @@ class ObjectStoreFullError(ObjectStoreError):
 
 
 def ensure_built() -> str:
-    """Build the daemon from source if the binary is missing."""
-    if not os.path.exists(SHMSTORED):
-        src_dir = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))), "native")
-        subprocess.run(["make", "-C", src_dir], check=True,
-                       capture_output=True)
-    return SHMSTORED
+    """Path of the store daemon built from the committed source, which it
+    is named after (shmstored-<hash of shmstore.cc>): a binary of other
+    sources — stale, or copied along with the tree — is never picked up,
+    whatever its timestamp."""
+    src_dir = os.path.join(_REPO_DIR, "native")
+    with open(os.path.join(src_dir, "shmstore", "shmstore.cc"), "rb") as f:
+        digest = hashlib.sha1(f.read()).hexdigest()[:12]
+    binary = os.path.join(_NATIVE_DIR, f"shmstored-{digest}")
+    if not os.path.exists(binary):
+        subprocess.run(
+            ["make", "-C", src_dir, os.path.relpath(binary, src_dir)],
+            check=True, capture_output=True)
+    return binary
 
 
 def start_store(sock_path: str, capacity: int, prefix: str,
                 spill_dir: Optional[str] = None) -> subprocess.Popen:
     """Launch shmstored; waits for its READY line."""
-    ensure_built()
-    args = [SHMSTORED, sock_path, str(capacity), prefix]
+    args = [ensure_built(), sock_path, str(capacity), prefix]
     if spill_dir:
         args.append(spill_dir)
     proc = subprocess.Popen(args, stdout=subprocess.PIPE,

@@ -351,12 +351,14 @@ define("serve_drain_timeout_s", float, 10.0,
        "serve graceful_shutdown_timeout_s).")
 
 # TPU
-define("tpu_force_host_platform", bool, False,
-       "Treat CPU devices as the TPU plane (for tests on a virtual mesh).")
-define("tpu_chips_per_host_override", int, 0, "0 = autodetect from jax.")
-define("tpu_probe_timeout_s", float, 20.0,
-       "Hard deadline for the subprocess device-count probe; a wedged PJRT "
-       "backend degrades to 0 chips instead of hanging init().")
+define("tpu_chips_per_host_override", int, 0,
+       "0 = ask the device probe. >0 fakes that many chips (ids 0..n-1) "
+       "with no probe and no slice identity, for tests on a host without "
+       "a TPU.")
+define("tpu_probe_timeout_s", float, 120.0,
+       "Hard deadline for the subprocess device probe (a cold libtpu start "
+       "took 16-20 s on a v5e host); on expiry init() raises with the "
+       "probe's stderr instead of hanging.")
 
 # Observability
 define("task_event_buffer_size", int, 100_000,

@@ -58,18 +58,11 @@ def init(address: Optional[str] = None, *,
             from ray_tpu.client.runtime import ClientRuntime
             _runtime = ClientRuntime(address, namespace=namespace)
         else:
-            try:
-                from ray_tpu.core.runtime_cluster import ClusterRuntime
-            except ModuleNotFoundError:
-                # Cluster runtime not built yet; default to in-process.
-                from ray_tpu.core.runtime_local import LocalRuntime
-                _runtime = LocalRuntime(num_cpus=num_cpus, num_tpus=num_tpus,
-                                        resources=resources)
-            else:
-                _runtime = ClusterRuntime(address=address, num_cpus=num_cpus,
-                                          num_tpus=num_tpus,
-                                          resources=resources,
-                                          namespace=namespace)
+            from ray_tpu.core.runtime_cluster import ClusterRuntime
+            _runtime = ClusterRuntime(address=address, num_cpus=num_cpus,
+                                      num_tpus=num_tpus,
+                                      resources=resources,
+                                      namespace=namespace)
         return _runtime
 
 

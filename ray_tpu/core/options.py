@@ -51,9 +51,9 @@ _ACTOR_KEYS = set(ActorOptions.__dataclass_fields__)
 def _check_resources(opts) -> None:
     if opts.num_cpus < 0 or opts.num_tpus < 0:
         raise ValueError("num_cpus / num_tpus must be >= 0")
-    if opts.num_tpus != int(opts.num_tpus) and opts.num_tpus > 1:
-        raise ValueError("fractional num_tpus > 1 is not allowed (chips are "
-                         "indivisible above one)")
+    if opts.num_tpus != int(opts.num_tpus):
+        raise ValueError("fractional num_tpus is not allowed: a chip "
+                         "belongs to one process at a time")
     for k, v in opts.resources.items():
         if not isinstance(k, str) or (isinstance(v, (int, float)) and v < 0):
             raise ValueError(f"bad custom resource {k!r}: {v!r}")

@@ -349,7 +349,8 @@ class TaskSubmitter:
                                            task["strategy"],
                                            task.get("runtime_env"),
                                            count=want)
-            except RuntimeEnvSetupError as e:
+            except (RuntimeEnvSetupError, ValueError) as e:
+                # Deterministic: no node will ever grant this shape.
                 self._fail_queued(st, e)
                 return
         finally:
@@ -922,15 +923,21 @@ class ClusterRuntime:
 
     @staticmethod
     def _default_resources(num_cpus, num_tpus, resources):
+        """Chips come from the probe subprocess (tpu/topology.py), never
+        from a backend opened here: the driver must not hold a chip its
+        workers need. A probe that fails raises — asked for or not, zero
+        chips is never assumed."""
         import multiprocessing
+        from ray_tpu.tpu.topology import local_chip_count
         total = {"CPU": float(num_cpus if num_cpus is not None
                               else multiprocessing.cpu_count())}
         if num_tpus is None:
-            try:
-                from ray_tpu.tpu.topology import local_chip_count
-                num_tpus = local_chip_count()
-            except Exception:
-                num_tpus = 0
+            num_tpus = local_chip_count()
+        elif num_tpus:
+            have = local_chip_count(required=True)
+            if have < num_tpus:
+                raise ValueError(
+                    f"init(num_tpus={num_tpus}) but this host has {have}")
         if num_tpus:
             total["TPU"] = float(num_tpus)
         total.update(resources or {})
@@ -1054,7 +1061,9 @@ class ClusterRuntime:
         lease_policy.cc + spillback replies of HandleRequestWorkerLease).
         Returns up to ``count`` grants from the FIRST daemon that grants at
         all (multi-grant extras never spill: they only exist to drain a
-        deep local queue); empty list when nothing granted anywhere."""
+        deep local queue); empty list when nothing granted anywhere.
+        Raises where no node can ever grant (a broken runtime_env, a TPU
+        count no host gives one process)."""
         targets: List[str] = []
         if isinstance(strategy, dict) and strategy.get("type") == "pg":
             pg = self.conductor.call("pg_ready", pg_id=strategy["pg_id"],
@@ -1102,6 +1111,7 @@ class ClusterRuntime:
                                    for k in ("CPU", "TPU")))
             targets += [n["address"] for n in nodes]
         t0 = time.monotonic()
+        refusal, grantable = None, False
         for addr in targets:
             try:
                 # _timeout bounds the client read: a daemon stuck spawning
@@ -1132,6 +1142,15 @@ class ClusterRuntime:
                 # another node re-runs the same broken spec. Fail fast.
                 from ray_tpu.core.exceptions import RuntimeEnvSetupError
                 raise RuntimeEnvSetupError(resp["env_error"])
+            if resp.get("lease_error"):
+                refusal = resp["lease_error"]
+            elif not resp.get("infeasible"):
+                grantable = True    # busy now, but it could serve this
+        if refusal and not grantable:
+            # A shape its node can never serve (a TPU count that is
+            # neither one chip nor a whole host), and no other node that
+            # could: waiting changes nothing.
+            raise ValueError(refusal)
         return []
 
     def _release_lease(self, w: _LeasedWorker) -> None:

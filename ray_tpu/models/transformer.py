@@ -28,6 +28,7 @@ from jax import lax
 from ray_tpu.ops.attention import mha
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
+from ray_tpu.parallel.sharding import DEFAULT_RULES, LogicalRules
 
 
 @dataclasses.dataclass(frozen=True)
@@ -194,17 +195,19 @@ def _rope(x, positions, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _attention(cfg: TransformerConfig, q, k, v, mesh):
+def _attention(cfg: TransformerConfig, q, k, v, mesh, rules):
     impl = cfg.attn_impl
     if impl == "ring":
         return ring_attention(q, k, v, mesh, causal=cfg.causal)
     if impl == "ulysses":
         return ulysses_attention(q, k, v, mesh, causal=cfg.causal)
-    return mha(q, k, v, causal=cfg.causal, impl=impl)
+    return mha(q, k, v, causal=cfg.causal, impl=impl, mesh=mesh,
+               rules=rules)
 
 
 def _layer_apply(cfg: TransformerConfig, mesh, layer, x, positions,
-                 return_kv: bool = False):
+                 return_kv: bool = False,
+                 rules: LogicalRules = DEFAULT_RULES):
     dt = cfg.dtype
     h = _rmsnorm(x, layer["ln1"])
     a = layer["attn"]
@@ -213,7 +216,7 @@ def _layer_apply(cfg: TransformerConfig, mesh, layer, x, positions,
     v = jnp.einsum("bse,ehd->bshd", h, a["wv"].astype(dt))
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
-    o = _attention(cfg, q, k, v, mesh)
+    o = _attention(cfg, q, k, v, mesh, rules)
     o = jnp.einsum("bshd,hde->bse", o, a["wo"].astype(dt))
     x = x + o
     h = _rmsnorm(x, layer["ln2"])
@@ -232,9 +235,11 @@ def _layer_apply(cfg: TransformerConfig, mesh, layer, x, positions,
     return x + y
 
 
-def _stage_apply(cfg: TransformerConfig, mesh, stage_layers, x, positions):
-    """Apply a stack of layers (leading dim = layers) with lax.scan."""
-    body = partial(_layer_apply, cfg, mesh)
+def _stage_apply(cfg: TransformerConfig, mesh, stage_layers, x, positions,
+                 rules: LogicalRules = DEFAULT_RULES):
+    """Apply a stack of layers (leading dim = layers) with lax.scan.
+    ``rules``: what the caller sharded params and batch by over ``mesh``."""
+    body = partial(_layer_apply, cfg, mesh, rules=rules)
     if cfg.remat:
         body = jax.checkpoint(body)
 
@@ -246,7 +251,8 @@ def _stage_apply(cfg: TransformerConfig, mesh, stage_layers, x, positions):
 
 
 def transformer_apply(params, tokens, cfg: TransformerConfig, *,
-                      mesh=None, positions=None):
+                      mesh=None, positions=None,
+                      rules: LogicalRules = DEFAULT_RULES):
     """tokens: [B, S] int32 -> logits [B, S, vocab] (compute in cfg.dtype,
     logits float32)."""
     b, s = tokens.shape
@@ -266,23 +272,24 @@ def transformer_apply(params, tokens, cfg: TransformerConfig, *,
         pos_s = positions[:1]
 
         def stage_fn(stage_layers, act):
-            return _stage_apply(cfg, mesh, stage_layers, act, pos_s)
+            return _stage_apply(cfg, mesh, stage_layers, act, pos_s, rules)
 
         x = pipeline_apply(stage_fn, params["layers"], xs, mesh,
                            num_microbatches=m)
         x = x.reshape(b, s, cfg.d_model)
     else:
-        x = _stage_apply(cfg, mesh, params["layers"], x, positions)
+        x = _stage_apply(cfg, mesh, params["layers"], x, positions, rules)
     x = _rmsnorm(x, params["final_norm"])
     head = (params["embed"].T if cfg.tied_embeddings else params["lm_head"])
     return (x @ head.astype(cfg.dtype)).astype(jnp.float32)
 
 
-def transformer_loss(params, batch, cfg: TransformerConfig, *, mesh=None):
+def transformer_loss(params, batch, cfg: TransformerConfig, *, mesh=None,
+                     rules: LogicalRules = DEFAULT_RULES):
     """batch: {"tokens": [B, S]} next-token cross-entropy (mean over
     non-final positions)."""
     tokens = batch["tokens"]
-    logits = transformer_apply(params, tokens, cfg, mesh=mesh)
+    logits = transformer_apply(params, tokens, cfg, mesh=mesh, rules=rules)
     targets = tokens[:, 1:]
     logits = logits[:, :-1]
     logp = jax.nn.log_softmax(logits, axis=-1)

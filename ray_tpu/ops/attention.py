@@ -13,12 +13,14 @@ ops/flash.py are the TPU fast path with the same signature.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ray_tpu.ops.flash import _on_tpu, flash_attention
+from ray_tpu.parallel.sharding import DEFAULT_RULES, LogicalRules
 
 NEG_INF = -1e30
 
@@ -111,11 +113,15 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
 
 def mha(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
-        block_size: int = 512, impl: str = "auto"):
+        block_size: int = 512, impl: str = "auto", mesh=None,
+        rules: LogicalRules = DEFAULT_RULES):
     """Dispatch: 'reference' | 'blockwise' | 'flash' (Pallas) | 'auto'.
 
     auto = flash on TPU when shapes are tile-aligned, else blockwise for long
-    sequences, else reference.
+    sequences, else reference. 'flash' is the kernel or an error, never a
+    stand-in. ``mesh``/``rules``: the mesh q/k/v are sharded over and the
+    rules that laid them out (flash runs per shard; the jnp forms are
+    partitioned by XLA).
     """
     if impl == "reference":
         return attention_reference(q, k, v, causal=causal, scale=scale)
@@ -123,23 +129,14 @@ def mha(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
         return blockwise_attention(q, k, v, causal=causal, scale=scale,
                                    block_size=block_size)
     if impl == "flash":
-        from ray_tpu.ops.flash import flash_attention
-        return flash_attention(q, k, v, causal=causal, scale=scale)
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               mesh=mesh, rules=rules)
     # auto
     sq, d = q.shape[1], q.shape[3]
     if _on_tpu() and sq % 128 == 0 and k.shape[1] % 128 == 0 and d % 128 == 0:
-        from ray_tpu.ops.flash import flash_attention
-        return flash_attention(q, k, v, causal=causal, scale=scale)
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               mesh=mesh, rules=rules)
     if sq >= 2048:
         return blockwise_attention(q, k, v, causal=causal, scale=scale,
                                    block_size=block_size)
     return attention_reference(q, k, v, causal=causal, scale=scale)
-
-
-@functools.cache
-def _on_tpu() -> bool:
-    import jax
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops._compat import axis_size, shard_map
 
 
 def pipeline_apply_local(stage_fn: Callable, stage_params: Any, x,
@@ -35,7 +34,7 @@ def pipeline_apply_local(stage_fn: Callable, stage_params: Any, x,
     Returns [num_microbatches, mb, ...] outputs, replicated (materialized on
     the last rank, broadcast at the end).
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     m = num_microbatches
     perm = [(i, (i + 1) % n) for i in range(n)]  # rank r -> r+1
@@ -90,5 +89,5 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x, mesh: Mesh, *,
         return pipeline_apply_local(stage_fn, sp, xx, axis=axis,
                                     num_microbatches=num_microbatches)
 
-    return shard_map(body, mesh=mesh, in_specs=(p_spec, x_spec),
+    return jax.shard_map(body, mesh=mesh, in_specs=(p_spec, x_spec),
                      out_specs=x_spec, check_vma=False)(stage_params, x)

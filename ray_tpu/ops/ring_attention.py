@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu.ops._compat import axis_size, shard_map
 from ray_tpu.ops.attention import NEG_INF, _block_step
 
 
@@ -38,7 +37,7 @@ def ring_attention_local(q, k, v, *, axis: str = "sp", causal: bool = True,
     q,k,v: local chunks [B, S_local, H, D]; sequence dim sharded over `axis`.
     Returns the local output chunk [B, S_local, H, D].
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     me = lax.axis_index(axis)
     b, s, h, d = q.shape
     _, sk, hk, _ = k.shape
@@ -87,5 +86,5 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis: str = "sp",
              axis, None, None)
     fn = functools.partial(ring_attention_local, axis=axis, causal=causal,
                            scale=scale)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)(q, k, v)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops._compat import axis_size, shard_map
 from ray_tpu.ops.attention import attention_reference, blockwise_attention
 
 
@@ -30,7 +29,7 @@ def ulysses_attention_local(q, k, v, *, axis: str = "sp",
                             block_size: int = 1024):
     """Call inside shard_map; q,k,v local chunks [B, S_local, H, D] with the
     sequence dim sharded over `axis`. H must be divisible by axis size."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     h = q.shape[2]
     if h % n:
         raise ValueError(f"heads={h} not divisible by sp axis size {n}")
@@ -61,5 +60,5 @@ def ulysses_attention(q, k, v, mesh: Mesh, *, axis: str = "sp",
              axis, None, None)
     fn = functools.partial(ulysses_attention_local, axis=axis, causal=causal,
                           scale=scale)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)(q, k, v)

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops import _compat
 
 AxisName = Union[str, Sequence[str]]
 
@@ -54,7 +53,7 @@ def alltoall(x, axis: AxisName, *, split_dim: int, concat_dim: int):
 def ring_permute(x, axis: str, *, shift: int = 1):
     """Send to (i+shift) mod n along `axis` — the ICI-neighbor hop used by
     ring attention and pipeline stages."""
-    n = _compat.axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name=axis, perm=perm)
 
@@ -72,7 +71,7 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str):
-    return _compat.axis_size(axis)
+    return lax.axis_size(axis)
 
 
 def broadcast_rounds(n: int, *, fanout: int = 2, root: int = 0):

@@ -122,10 +122,10 @@ def _topology_ordered(devs: Sequence) -> Optional[List]:
     a full box — then the caller keeps jax's own ordering rather than
     guessing adjacency it cannot verify.
 
-    Fixes the VERDICT round-1 finding that `np.reshape` row-major over
-    `jax.devices()` puts the latency-bound tp axis on non-adjacent chips of
-    a 3D torus (the reference has no analog: torch process groups have no
-    topology model at all, reference python/ray/train/torch/config.py:113).
+    Without it, `np.reshape` row-major over `jax.devices()` puts the
+    latency-bound tp axis on non-adjacent chips of a 3D torus (the
+    reference has no analog: torch process groups have no topology model
+    at all, reference python/ray/train/torch/config.py:113).
     """
     recs = []
     for d in devs:
@@ -239,11 +239,6 @@ def build_mesh(spec: MeshSpec, devices: Optional[Sequence] = None, *,
 def local_mesh(**axis_sizes):
     """Convenience: build_mesh(MeshSpec(**axis_sizes)) on all local devices."""
     return build_mesh(MeshSpec(**axis_sizes))
-
-
-def data_axes() -> Tuple[str, ...]:
-    """Mesh axes a per-example batch dimension is sharded over."""
-    return ("dcn_dp", "dp", "fsdp")
 
 
 def best_dp_fsdp_split(num_devices: int, params_bytes: int,

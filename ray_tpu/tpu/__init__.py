@@ -9,19 +9,19 @@ compute plane maps slices onto `jax.sharding.Mesh` axes.
 """
 
 from ray_tpu.tpu.topology import (
-    TpuTopology,
     SliceSpec,
-    detect_topology,
-    device_kind,
+    TpuProbeError,
+    generation_of,
     local_chip_count,
+    probe_chips,
     slice_mesh_shape,
 )
 
 __all__ = [
-    "TpuTopology",
     "SliceSpec",
-    "detect_topology",
-    "device_kind",
+    "TpuProbeError",
+    "generation_of",
     "local_chip_count",
+    "probe_chips",
     "slice_mesh_shape",
 ]

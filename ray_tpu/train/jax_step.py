@@ -43,6 +43,17 @@ jax.tree_util.register_pytree_node(
 )
 
 
+def _init_opt_state(tx: optax.GradientTransformation, params, mesh: Mesh):
+    """tx.init under jit, every moment placed like its parameter and the
+    rest replicated (ZeRO under fsdp). Left to itself jit puts these zeros
+    — they depend on no input — on the default device: the whole optimizer
+    state sat on chip 0 until the first step moved it."""
+    shardings = optax.tree_map_params(
+        tx, lambda _, p: p.sharding, jax.eval_shape(tx.init, params), params,
+        transform_non_params=lambda _: replicated(mesh))
+    return jax.jit(tx.init, out_shardings=shardings)(params)
+
+
 def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
                        tx: Optional[optax.GradientTransformation] = None,
                        rules: LogicalRules = DEFAULT_RULES,
@@ -54,17 +65,18 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
     axes = transformer_logical_axes(cfg)
 
     def init_fn(key) -> TrainState:
-        params = transformer_init(key, cfg)
-        params = shard_pytree(params, mesh, axes, rules)
-        # jit(tx.init): zeros_like(p) inherits p's sharding, so optimizer
-        # moments land sharded exactly like their params (ZeRO under fsdp).
-        opt_state = jax.jit(tx.init)(params)
-        return TrainState(params, opt_state,
+        # Initialised under jit straight into its shardings: every device
+        # makes only its own shard, instead of the whole f32 tree landing
+        # on the default device before being spread out.
+        init = partial(transformer_init, cfg=cfg)
+        params = jax.jit(init, out_shardings=pytree_shardings(
+            jax.eval_shape(init, key), mesh, axes, rules))(key)
+        return TrainState(params, _init_opt_state(tx, params, mesh),
                           jax.device_put(jnp.zeros((), jnp.int32),
                                          replicated(mesh)))
 
     def loss_fn(params, batch):
-        return transformer_loss(params, batch, cfg, mesh=mesh)
+        return transformer_loss(params, batch, cfg, mesh=mesh, rules=rules)
 
     @partial(jax.jit, donate_argnums=(0,))
     def step_fn(state: TrainState, batch) -> Tuple[TrainState, dict]:
@@ -99,7 +111,7 @@ def make_resnet_train_step(mesh: Mesh, *, num_classes: int = 1000,
             key, jnp.zeros((1, image_size, image_size, 3), jnp.float32),
             train=True)
         variables = jax.device_put(variables, replicated(mesh))
-        opt_state = jax.jit(tx.init)(variables["params"])
+        opt_state = _init_opt_state(tx, variables["params"], mesh)
         return TrainState(variables, opt_state,
                           jax.device_put(jnp.zeros((), jnp.int32),
                                          replicated(mesh)))
@@ -158,8 +170,7 @@ def make_vit_train_step(cfg, mesh: Mesh, *,
             "head": ("embed", None),
         }
         params = shard_pytree(params, mesh, axes, rules)
-        opt_state = jax.jit(tx.init)(params)
-        return TrainState(params, opt_state,
+        return TrainState(params, _init_opt_state(tx, params, mesh),
                           jax.device_put(jnp.zeros((), jnp.int32),
                                          replicated(mesh)))
 

@@ -1,0 +1,135 @@
+"""Pallas flash kernels without a chip: their numbers, and their compilation.
+
+The kernels only run on a TPU (chip_smoke.py checks them there at the
+flagship head shape). Here the same kernel bodies run through the Pallas
+interpreter on the CPU against attention_reference, reached only by the
+explicit ``interpret=True`` that no platform check ever selects; and libtpu
+compiles them ahead of time for a v5e 2x2 topology, which needs no device
+and applies the chip's real VMEM and HBM limits.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops.attention import attention_reference
+from ray_tpu.ops.flash import _flash_bwd, _flash_fwd, flash_attention
+
+
+def _bhsd(x):  # [B, S, H, D] -> [B*H, S, D]
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+@pytest.mark.parametrize("causal,sq,sk", [(True, 256, 256),
+                                          (False, 256, 256),
+                                          (True, 128, 256)])
+def test_flash_kernels_match_reference(causal, sq, sk):
+    rng = np.random.default_rng(0)
+    b, h, d = 1, 2, 128
+    q = jnp.asarray(rng.normal(size=(b, sq, h, d)), jnp.float32) * 0.5
+    k = jnp.asarray(rng.normal(size=(b, sk, h, d)), jnp.float32) * 0.5
+    v = jnp.asarray(rng.normal(size=(b, sk, h, d)), jnp.float32) * 0.5
+    g = jnp.asarray(rng.normal(size=(b, sq, h, d)), jnp.float32)
+    kw = dict(causal=causal, scale=d ** -0.5, block_q=128, block_k=128,
+              interpret=True)
+
+    ref, vjp = jax.vjp(
+        lambda q, k, v: attention_reference(q, k, v, causal=causal), q, k, v)
+    out, lse = _flash_fwd(_bhsd(q), _bhsd(k), _bhsd(v), **kw)
+    np.testing.assert_allclose(out, _bhsd(ref), atol=2e-5)
+
+    grads = _flash_bwd((_bhsd(q), _bhsd(k), _bhsd(v), out, lse), _bhsd(g),
+                       **kw)
+    for got, want in zip(grads, vjp(g)):
+        np.testing.assert_allclose(got, _bhsd(want), atol=2e-4)
+
+
+def test_flash_attention_raises_off_tpu():
+    q = jnp.zeros((1, 128, 2, 128), jnp.float32)
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        flash_attention(q, q, q)
+    from ray_tpu.ops.attention import mha
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        mha(q, q, q, impl="flash")
+
+
+def test_flash_compiles_for_v5e_per_shard(monkeypatch):
+    """Mosaic accepts fwd + bwd at the flagship head shape with the default
+    block table, and under a 4-chip data mesh each chip runs the kernel on
+    its own batch shard: GSPMD cannot partition a Mosaic call, so without
+    the shard_map in flash_attention this does not even lower."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ray_tpu.ops import flash
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu in this install
+        pytest.skip(f"no TPU compiler here: {e!r}")
+    # Lowering targets the topology, not this process's CPU backend.
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash, "generation", lambda: "v5e")
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4, 1),
+                ("dcn_dp", "dp", "tp"))
+    q = jax.ShapeDtypeStruct((8, 2048, 16, 128), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, mesh=mesh)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile().as_text()
+    calls = [ln for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 3                    # fwd, dkv, dq
+    # [B*H, S, D] operands carry the shard's batch (2), not the global 8
+    assert all("bf16[32,2048,128]" in ln for ln in calls)
+    assert "bf16[128,2048,128]" not in hlo and "all-gather" not in hlo
+
+    # The split is the caller's rules', not flash's own: batch over tp and
+    # heads over dp here, 4 sequences x 4 heads -> 2 x 2 per chip.
+    from ray_tpu.parallel.sharding import DEFAULT_RULES
+    rules = DEFAULT_RULES.extend({"batch": "tp", "heads": "dp"})
+    mesh = Mesh(np.array(topo.devices).reshape(1, 2, 2),
+                ("dcn_dp", "dp", "tp"))
+    q = jax.ShapeDtypeStruct((4, 256, 4, 128), jnp.bfloat16,
+                             sharding=NamedSharding(
+                                 mesh, P("tp", None, "dp")))
+    hlo = jax.jit(lambda q: flash_attention(
+        q, q, q, mesh=mesh, rules=rules)).lower(q).compile().as_text()
+    call, = [ln for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert "bf16[4,256,128]" in call
+    assert "all-gather" not in hlo and "all-to-all" not in hlo
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2, 1])
+def test_sharded_flash_splits_gqa_heads_like_the_reference(monkeypatch,
+                                                           kv_heads):
+    """The shard_map around the kernel, with the kernel itself replaced by
+    the reference: kv heads that split over tp stay grouped with their q
+    heads, and fewer kv heads than tp shards (kv_heads=1, tp=4) still
+    work."""
+    from jax.sharding import Mesh
+
+    from ray_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash, "generation", lambda: "v5e")
+    monkeypatch.setattr(
+        flash, "_flash_bshd",
+        lambda q, k, v, *, causal, scale, block_q, block_k:
+        attention_reference(q, k, v, causal=causal, scale=scale))
+    mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("dp", "tp"))
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(2, 128, 8, 128)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, 128, kv_heads, 128)),
+                        jnp.float32) for _ in range(2))
+    out = jax.jit(lambda q, k, v: flash_attention(q, k, v, mesh=mesh))(
+        q, k, v)
+    np.testing.assert_allclose(out, attention_reference(q, k, v), atol=1e-5)

@@ -130,39 +130,54 @@ def test_mesh_spec_validation_still_raises():
         build_mesh(MeshSpec(dp=128), devices=_fake_torus((2, 2, 2)))
 
 
-# -- chip-count probe (topology.py) ---------------------------------------
+# -- device probe (topology.py) -------------------------------------------
+
+_FAKE_V5E = ("import json, sys; json.dump({'platform': 'tpu', "
+             "'device_kind': 'TPU v5 lite', 'count': %d}, sys.stdout)")
 
 
 def test_chip_probe_counts_devices(monkeypatch):
     from ray_tpu.tpu import topology
 
     monkeypatch.setattr(topology, "platform_pinned_off_tpu", lambda: False)
-    monkeypatch.setattr(topology, "_chip_count_cache", None)
-    monkeypatch.setattr(topology, "_PROBE_SRC",
-                        "import sys; sys.stdout.write('4')")
+    monkeypatch.setattr(topology, "_probe_cache", None)
+    monkeypatch.setattr(topology, "_PROBE_SRC", _FAKE_V5E % 4)
     assert topology.local_chip_count() == 4
-    # cached: a changed probe source is NOT re-run
-    monkeypatch.setattr(topology, "_PROBE_SRC",
-                        "import sys; sys.stdout.write('8')")
+    # cached: a changed probe source is NOT re-run (one probe per process)
+    monkeypatch.setattr(topology, "_PROBE_SRC", _FAKE_V5E % 8)
     assert topology.local_chip_count() == 4
+    # the daemon's slice identity comes from the same probe, not from jax
+    monkeypatch.delenv("TPU_ACCELERATOR_TYPE", raising=False)
+    sl = topology.detect_slice()
+    assert (sl["generation"], sl["accelerator_type"],
+            sl["device_kind"]) == ("v5e", "v5e-4", "TPU v5 lite")
+    # an env that names a one-host slice of another size than the probe
+    # found loses to the probe; one that agrees with it is kept
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-8")
+    sl = topology.detect_slice()
+    assert (sl["accelerator_type"], sl["slice_id"], sl["num_hosts"]) == (
+        "v5e-4", "local-v5e-4", 1)
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    assert topology.detect_slice()["accelerator_type"] == "v5litepod-4"
 
 
-def test_chip_probe_wedged_backend_degrades_within_deadline(monkeypatch):
+def test_chip_probe_wedged_backend_raises_within_deadline(monkeypatch):
     # A wedged PJRT plugin blocks the first backend touch forever; the
-    # probe is a sacrificial subprocess, so init degrades to 0 chips
-    # after tpu_probe_timeout_s instead of hanging.
+    # probe is a sacrificial subprocess, so init fails after
+    # tpu_probe_timeout_s instead of hanging — and never advertises 0.
     import time
 
     from ray_tpu import config
     from ray_tpu.tpu import topology
 
     monkeypatch.setattr(topology, "platform_pinned_off_tpu", lambda: False)
-    monkeypatch.setattr(topology, "_chip_count_cache", None)
+    monkeypatch.setattr(topology, "_probe_cache", None)
     monkeypatch.setattr(topology, "_PROBE_SRC", "import time; time.sleep(60)")
     config.set_override("tpu_probe_timeout_s", 0.5)
     try:
         t0 = time.monotonic()
-        assert topology.local_chip_count() == 0
+        with pytest.raises(topology.TpuProbeError, match="timed out"):
+            topology.local_chip_count()
         assert time.monotonic() - t0 < 5.0
     finally:
         config.clear_override("tpu_probe_timeout_s")
@@ -173,8 +188,40 @@ def test_chip_probe_skipped_when_pinned_off_tpu(monkeypatch):
     # even through the sacrificial subprocess.
     from ray_tpu.tpu import topology
 
-    monkeypatch.setattr(topology, "_chip_count_cache", None)
+    monkeypatch.setattr(topology, "_probe_cache", None)
     monkeypatch.setattr(
-        topology, "_probe_chip_count",
-        lambda *_: (_ for _ in ()).throw(AssertionError("probed!")))
+        topology, "probe_chips",
+        lambda *_, **__: (_ for _ in ()).throw(AssertionError("probed!")))
     assert topology.local_chip_count() == 0
+
+
+def test_init_raises_when_asked_for_tpus_and_probe_fails(monkeypatch):
+    # Asked for a chip that will not open: init() raises with the probe's
+    # stderr; it does not advertise zero chips and carry on.
+    import ray_tpu
+    from ray_tpu.tpu import topology
+
+    monkeypatch.setattr(topology, "_probe_cache", None)
+    monkeypatch.setattr(
+        topology, "_PROBE_SRC",
+        "import sys; sys.stderr.write('no chip to open'); sys.exit(3)")
+    with pytest.raises(topology.TpuProbeError, match="no chip to open"):
+        ray_tpu.init(num_tpus=1)
+    assert not ray_tpu.is_initialized()
+
+
+def test_generation_table_and_chip_visibility():
+    from ray_tpu.tpu import topology
+
+    assert topology.generation_of("TPU v5 lite") == "v5e"
+    assert topology.SliceSpec.parse("v5litepod-4").generation == "v5e"
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        topology.generation_of("TPU v9")   # never a default generation
+    one = topology.chip_visibility_env([2], 4)
+    assert one["TPU_VISIBLE_CHIPS"] == "2"
+    assert one["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    whole = topology.chip_visibility_env([0, 1, 2, 3], 4)
+    assert whole["TPU_VISIBLE_CHIPS"] == "0,1,2,3"
+    assert whole["TPU_CHIPS_PER_PROCESS_BOUNDS"] is None
+    with pytest.raises(ValueError, match="one chip or all"):
+        topology.chip_visibility_env([0, 1], 4)

@@ -1,4 +1,4 @@
-"""Multi-IP integration (round-1 ask #9 / VERDICT weak #6 analog): the
+"""Multi-IP integration: the
 conductor and each node bind DISTINCT loopback addresses (127.0.0.x —
 real separate interfaces as far as every socket is concerned), so all
 cross-component paths (registration, leases, worker callbacks, chunked
