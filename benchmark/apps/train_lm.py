@@ -1,0 +1,226 @@
+"""The training application: the loop a user writes, under ``JaxTrainer``.
+
+``drive`` runs in the benchmark's process and never touches JAX: it brings
+the runtime up, leases the cell's chips to one worker through
+``JaxTrainer(...).fit()`` and reads the worker's record from its last
+report. ``train_loop`` is what runs in that worker: weights and optimizer
+state made on the device from the seed, the plain reference's loss on the
+first batch, the step compiled once, two warm-up steps on that batch (the
+second loss, after one update, has to fall), then the window: every step a
+fresh seeded batch made on the host and placed while the previous step
+runs, the loss fetched and ``session.report``ed.
+"""
+
+from __future__ import annotations
+
+import time
+
+from benchmark.apps import lm
+
+TRACE_FROM_STEP = 4        # traced run: profile TRACE_STEPS steps from here
+TRACE_STEPS = 4            # 4 executions in the trace = 3 whole periods
+WARMUP_STEPS = 2
+# |system loss - reference loss| on the first batch, the reference at the
+# published RMSNorm epsilon (1e-5) and the program at its fixed 1e-6. The
+# system computes in bfloat16 with float32 parameters and accumulations; its
+# error on one logit is ~1e-2 but the loss averages >16,000 positions. Read
+# on the chip (PR 24): 2.6e-5 .. 2.5e-4 against a reference at the
+# program's own epsilon (9 seeds on one chip, 6 on four), and the two
+# epsilons move the reference's loss by 7e-5 .. 9.7e-4 (6 runs; 8.8e-4 on
+# the CPU with 2 rows); against the published reference, as judged here,
+# 1.8e-5 .. 9.5e-4 (6 runs, both models). 3e-3 leaves 2.5x room over the
+# two worst added up, and is 1/30 of what a wrong mask, a missing layer or
+# a shifted target moves the loss by (> 0.1 at these sizes). Once the
+# program takes the configuration's epsilon the next benchmark PR can go
+# back to 2e-3.
+LOSS_TOLERANCE = 3e-3
+# The second warm-up step runs on the first batch again: after one AdamW
+# update the loss on the same tokens has to fall by at least the traffic
+# file's ``min_first_update_fall`` (read on the chip beside it). A backward
+# pass or an optimizer that does not do its work leaves it where it was;
+# losses on fresh random batches differ by ~0.01 whatever the update did.
+
+
+def train_loop(spec: dict) -> None:
+    stamps = {"entry": time.time()}
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import trace as trace_mod
+    from ray_tpu.air import session
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.train import make_lm_train_step
+
+    compiles = lm.CompileCounter()
+    devs = jax.devices()
+    stamps["devices"] = time.time()
+    facts = lm.device_facts()
+    lm.require_chips(facts, spec["chips"], spec["rehearse"])
+    chips = spec["chips"]
+    cfg = lm.transformer_config(spec["model"], remat=spec["remat"])
+    mesh = build_mesh(MeshSpec(**{spec["mesh_axis"]: chips}))
+    init_fn, step_fn, place_batch = make_lm_train_step(cfg, mesh)
+    seed = lm.fold_seed(spec["seed"])
+    state = init_fn(jax.random.PRNGKey(seed))
+    jax.block_until_ready(state)
+    stamps["init"] = time.time()
+
+    rows, seq = spec["rows_per_chip"] * chips, spec["seq"]
+    rng = np.random.default_rng(seed)
+
+    def make_batch() -> dict:
+        return {"tokens": rng.integers(0, cfg.vocab_size, (rows, seq),
+                                       dtype=np.int32)}
+
+    first = make_batch()
+    # Before the first step: the step donates the state it is given.
+    reference = lm.reference_module(spec["config"])
+    reference_loss = reference.loss(
+        lm.reference_weights(state.params, spec["config"]),
+        jnp.asarray(first["tokens"]), spec["config"],
+        rows_per_pass=spec["reference_rows_per_pass"])
+    stamps["reference"] = time.time()
+
+    batch = place_batch(first)
+    compiled = step_fn.lower(state, batch).compile()
+    step_memory = lm.compiled_peak(compiled)
+    stamps["compiled"] = time.time()
+    warmup_losses = []
+    for _ in range(WARMUP_STEPS):         # the window's own path, report
+        state, metrics = compiled(state, batch)       # included; all on
+        warmup_losses.append(float(metrics["loss"]))  # the first batch
+        session.report({"warmup": len(warmup_losses),
+                        "loss": warmup_losses[-1]})
+    batch = place_batch(make_batch())
+    params = jax.tree.leaves(state.params)
+    checks = {
+        "system_loss": warmup_losses[0], "reference_loss": reference_loss,
+        "loss_tolerance": LOSS_TOLERANCE, "warmup_losses": warmup_losses,
+        "rms_norm_eps": {"published": float(spec["config"]["rms_norm_eps"]),
+                         "program": lm.program_rms_norm_eps(cfg)},
+        "first_update_fall": warmup_losses[0] - warmup_losses[1],
+        "min_first_update_fall": spec["min_first_update_fall"],
+        "n_params": int(sum(x.size for x in params)),
+        "param_dtypes": sorted({str(x.dtype) for x in params}),
+        "state_device_sets": sorted({len(x.sharding.device_set)
+                                     for x in jax.tree.leaves(state)}),
+    }
+
+    trace_dir = spec["trace_dir"] if spec["trace"] else None
+    profiler, steps = [], []
+    compiles_before = compiles.count
+    stamps["window_start"] = time.time()
+    t0 = time.perf_counter()
+    i = 0
+    while True:
+        if trace_dir and i == TRACE_FROM_STEP:
+            a = time.perf_counter()
+            trace_mod.start(trace_dir)
+            profiler.append([a - t0, time.perf_counter() - t0])
+        with jax.profiler.TraceAnnotation("bench.step"):
+            dispatched = time.perf_counter()
+            state, metrics = compiled(state, batch)
+            with jax.profiler.TraceAnnotation("bench.place"):
+                batch = place_batch(make_batch())
+            loss = float(metrics["loss"])          # waits for the step
+            ready = time.perf_counter()
+        with jax.profiler.TraceAnnotation("bench.report"):
+            session.report({"step": i, "loss": loss})
+        steps.append([dispatched - t0, ready - t0, loss])
+        i += 1
+        if trace_dir and i == TRACE_FROM_STEP + TRACE_STEPS:
+            a = time.perf_counter()
+            jax.profiler.stop_trace()
+            profiler.append([a - t0, time.perf_counter() - t0])
+        if ready - t0 >= spec["seconds"]:
+            break
+    window = {"steps": steps, "profiler": profiler,
+              "compiles_in_window": compiles.count - compiles_before,
+              "tokens_per_step": rows * seq}
+    reduced = {}
+    if trace_dir:
+        reduced = trace_mod.reduce_file(trace_mod.find_xplane(trace_dir))
+    session.report({"step": i, "loss": steps[-1][2], "record": {
+        "stamps": stamps, "facts": facts, "checks": checks, "window": window,
+        "trace": reduced,
+        "memory": lm.memory_report(devs, step_memory, "the train step")}})
+
+
+def judge(record: dict) -> list:
+    """-> reasons this run is not correct (empty: correct)."""
+    import math
+    checks, window = record["checks"], record["window"]
+    why = []
+    gap = abs(checks["system_loss"] - checks["reference_loss"])
+    if not gap <= checks["loss_tolerance"]:
+        why.append(f"system loss {checks['system_loss']} vs the plain "
+                   f"reference's {checks['reference_loss']} on the first "
+                   f"batch: off by {gap:.2e} > {checks['loss_tolerance']}")
+    if not checks["first_update_fall"] >= checks["min_first_update_fall"]:
+        why.append(f"after one update the loss on the same batch fell by "
+                   f"{checks['first_update_fall']:.4f}, under "
+                   f"{checks['min_first_update_fall']}: the backward pass "
+                   "or the optimizer is not doing its work")
+    losses = checks["warmup_losses"] + [s[2] for s in window["steps"]]
+    if not all(math.isfinite(x) for x in losses):
+        why.append("a loss in the run is not finite")
+    if checks["param_dtypes"] != [record["param_dtype"]]:
+        why.append(f"parameters are {checks['param_dtypes']}, the "
+                   f"configuration says {record['param_dtype']}")
+    if checks["state_device_sets"] != [record["facts"]["count"]]:
+        why.append("parameters or optimizer state are not spread over "
+                   f"every chip: {checks['state_device_sets']}")
+    return why
+
+
+def drive(run) -> dict:
+    """``run`` is ``benchmark.run.RunContext``. -> the run's record."""
+    import ray_tpu as rt
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+
+    run.phase("configure")
+    cell = run.cell
+    config = lm.effective_config(cell["config_data"], run.rehearse)
+    traffic = lm.effective_traffic(cell["traffic_data"], run.rehearse)
+    chips = cell["chips"]
+    spec = {
+        "seed": run.seed, "seconds": run.seconds, "trace": run.trace,
+        "trace_dir": run.path("trace"), "rehearse": run.rehearse,
+        "chips": chips, "config": config,
+        "model": lm.model_kwargs(config, traffic["seq"],
+                                 "auto" if run.rehearse else "flash"),
+        "remat": traffic["remat"], "mesh_axis": traffic["mesh_axis"],
+        "seq": traffic["seq"], "rows_per_chip": traffic["rows_per_chip"],
+        "reference_rows_per_pass": traffic["reference_rows_per_pass"],
+        "min_first_update_fall": traffic["min_first_update_fall"],
+    }
+    run.phase("rt.init")
+    run.init_runtime(rt, chips)
+    scaling = ScalingConfig(num_workers=1) if run.rehearse else \
+        ScalingConfig(num_workers=1, use_tpu=True, tpus_per_worker=chips)
+    run.phase("lease+train")
+    called = time.time()
+    result = JaxTrainer(
+        train_loop, train_loop_config=spec, scaling_config=scaling,
+        run_config=RunConfig(name="bench", storage_path=run.path("trial"))
+    ).fit()
+    history = result.metrics_history or []
+    record = next((m["record"] for m in reversed(history)
+                   if "record" in m), None)
+    if result.error is not None or record is None:
+        # No step report yet: the lease, the worker's start or the set-up
+        # failed, and the window was never entered. One more try is allowed.
+        before_window = not any("step" in m for m in history)
+        raise run.failure(f"JaxTrainer failed: {result.error}",
+                          before_window=before_window)
+    record["stamps"]["called"] = called
+    record["window_start"] = record["stamps"].pop("window_start")
+    record["compiles_in_window"] = record["window"]["compiles_in_window"]
+    record["param_dtype"] = config["param_dtype"]
+    steps = record["window"]["steps"]
+    record["attempted"] = len(steps)
+    record["failed"] = 0
+    record["why_not_correct"] = judge(record)
+    run.phase("shutdown")
+    return record

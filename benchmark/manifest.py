@@ -1,0 +1,96 @@
+"""``BENCHMARK.json`` and the files it names.
+
+Whatever belongs to one configuration, one traffic mix or one metric is a
+file of its own, found by the name in the manifest:
+
+    <paths[0]>/configs/<config>.json     sizes, as run (the manifest's
+                                         ``file`` says the same path)
+    <paths[0]>/traffic/<traffic>.json    parameters of the mix; ``app`` names
+                                         ``benchmark/apps/<app>.py``
+    <paths[0]>/metrics/<metric>.json     definition, unit, layer, moves
+    <paths[0]>/metrics/<metric>.py       ``read(record, cell) -> number|None``
+    benchmark/reference/<family>.py      the plain reference of the
+                                         configuration's ``family``
+
+so a later PR adds a cell, a mix, a configuration or a layer metric by
+adding files and one entry each. Nothing here knows a cell by name.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+import sys
+
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+END_TO_END_SOURCES = ("host_clock", "device_trace")
+
+
+class ManifestError(Exception):
+    pass
+
+
+def _json(path: str) -> dict:
+    try:
+        with open(path) as f:
+            return json.load(f)
+    except (OSError, ValueError) as e:
+        raise ManifestError(f"{path}: {e}") from None
+
+
+class Manifest:
+    def __init__(self, path: str = ""):
+        self.path = os.path.abspath(
+            path or os.path.join(CHECKOUT, "BENCHMARK.json"))
+        self.root = os.path.dirname(self.path)
+        self.data = _json(self.path)
+        self.home = os.path.join(self.root, self.data["paths"][0])
+
+    def cell(self, name: str) -> dict:
+        """One entry of ``workloads`` with its configuration's and its
+        traffic's files read in."""
+        cells = {w["name"]: w for w in self.data["workloads"]}
+        if name not in cells:
+            raise ManifestError(f"no workload {name!r} in {self.path} "
+                                f"(have: {sorted(cells)})")
+        cell = dict(cells[name])
+        configs = {c["name"]: c for c in self.data["configs"]}
+        if cell["config"] not in configs:
+            raise ManifestError(f"workload {name!r} names configuration "
+                                f"{cell['config']!r}, which is not listed")
+        cell["config_entry"] = configs[cell["config"]]
+        cell["config_data"] = _json(
+            os.path.join(self.root, cell["config_entry"]["file"]))
+        cell["traffic_data"] = _json(os.path.join(
+            self.home, "traffic", cell["traffic"] + ".json"))
+        return cell
+
+    def metrics(self, kind: str, cell_name: str) -> list:
+        """The ``end_to_end`` or ``per_layer`` entries this cell reports."""
+        return [m for m in self.data[kind]
+                if "workloads" not in m or cell_name in m["workloads"]]
+
+    def reader(self, metric: str):
+        path = os.path.join(self.home, "metrics", metric + ".py")
+        spec = importlib.util.spec_from_file_location(
+            "bench_metric_" + metric.replace(".", "_").replace("-", "_"),
+            path)
+        if spec is None or not os.path.exists(path):
+            raise ManifestError(f"metric {metric!r} has no reader {path}")
+        module = importlib.util.module_from_spec(spec)
+        metrics_dir = os.path.dirname(path)
+        if metrics_dir not in sys.path:      # readers share ``_common.py``
+            sys.path.insert(0, metrics_dir)
+        spec.loader.exec_module(module)
+        return module.read
+
+    def read_metrics(self, kind: str, cell: dict, record: dict) -> dict:
+        """name -> {"value", "unit"}; a reader that finds nothing to read
+        returns None and its metric is left out of the line."""
+        out = {}
+        for m in self.metrics(kind, cell["name"]):
+            value = self.reader(m["name"])(record, cell)
+            if value is not None:
+                out[m["name"]] = {"value": float(value), "unit": m["unit"]}
+        return out
