@@ -1,0 +1,5 @@
+from benchmark import spans as spans_mod
+
+
+def read(record, cell):
+    return spans_mod.median_ms(record, cell, "serve.batch.wait")

@@ -1,0 +1,6 @@
+from benchmark import spans as spans_mod
+
+
+def read(record, cell):
+    return spans_mod.median_ms(record, cell, "serve.proxy.admit",
+                               per_request=True)

@@ -1,0 +1,6 @@
+from benchmark import spans as spans_mod
+
+
+def read(record, cell):
+    return spans_mod.median_ms(record, cell, "serve.handle.slot_wait",
+                               per_request=True)

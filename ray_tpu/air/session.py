@@ -10,9 +10,11 @@ trial driver, rank-0 checkpoints persist. Plus rank/world introspection
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Dict, Optional
 
 from ray_tpu.air.checkpoint import Checkpoint
+from ray_tpu.util import events
 
 _local = threading.local()
 
@@ -37,11 +39,13 @@ class _Session:
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None) -> None:
         self.iteration += 1
-        with self.report_event:
-            self.reports.append({"metrics": dict(metrics),
-                                 "checkpoint": checkpoint,
-                                 "iteration": self.iteration})
-            self.report_event.notify_all()
+        with events.span("train.report", iteration=self.iteration):
+            with self.report_event:
+                self.reports.append({"metrics": dict(metrics),
+                                     "checkpoint": checkpoint,
+                                     "iteration": self.iteration,
+                                     "ts": time.time()})
+                self.report_event.notify_all()
         if self.stop_requested:
             raise StopIteration("trial stop requested")
 

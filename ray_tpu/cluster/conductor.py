@@ -1497,23 +1497,6 @@ class Conductor:
         with self._lock:
             return list(self._task_events)
 
-    # Span ring (util/tracing.py sink; parity role:
-    # util/tracing/tracing_helper.py -> OTLP collector).
-    def rpc_push_spans(self, spans: List[dict]) -> None:
-        with self._lock:
-            if not hasattr(self, "_spans"):
-                self._spans: List[dict] = []
-            self._spans.extend(spans)
-            if len(self._spans) > 65536:
-                del self._spans[:len(self._spans) - 65536]
-
-    def rpc_get_spans(self, trace_id: Optional[str] = None) -> List[dict]:
-        with self._lock:
-            spans = list(getattr(self, "_spans", ()))
-        if trace_id:
-            spans = [s for s in spans if s["trace_id"] == trace_id]
-        return spans
-
     # Flight-recorder event store (util/events.py sink; GcsTaskManager's
     # bounded-store role for the compact ring events every plane emits).
     def rpc_push_ring_events(self, node_id: str, pid: int, events,
@@ -1529,12 +1512,21 @@ class Conductor:
         return {"ok": True}
 
     def rpc_get_ring_events(self, limit: int = 0,
-                            kind: Optional[str] = None) -> List[dict]:
+                            kind: Optional[str] = None,
+                            spans_only: bool = False,
+                            ident: Optional[str] = None) -> List[dict]:
+        """``spans_only``: the records that are spans (util/events.py:
+        their attrs carry the span's id); ``ident``: one request's, one
+        lease's or one fit()'s records."""
         with self._ring_lock:
             evs = list(self._ring_events)
         if kind:
             evs = [e for e in evs
                    if e["kind"] == kind or e["kind"].startswith(kind + ".")]
+        if spans_only:
+            evs = [e for e in evs if e["attrs"] and "span" in e["attrs"]]
+        if ident:
+            evs = [e for e in evs if e["ident"] == ident]
         return evs[-limit:] if limit else evs
 
     def rpc_debug_state(self) -> dict:
@@ -1564,7 +1556,6 @@ class Conductor:
                 "ref_tombstones": len(self._ref_tombstones),
                 "placement_groups": len(self._pgs),
                 "task_events": len(self._task_events),
-                "spans": len(getattr(self, "_spans", ())),
             }
         with self._free_cv:
             out["free_queue"] = len(self._free_q)

@@ -126,7 +126,8 @@ class _ForkedProc:
 
 class _Worker:
     def __init__(self, proc, token: str, env_key: str,
-                 chips: Tuple[int, ...] = ()):
+                 chips: Tuple[int, ...] = (),
+                 began: Optional[Tuple[float, float]] = None):
         self.proc = proc
         self.token = token
         self.env_key = env_key
@@ -134,6 +135,12 @@ class _Worker:
         # for one lease or actor, never pooled, and dies with it.
         self.chips = chips
         self.started_at = time.monotonic()
+        # ``worker.spawn`` span: ``began`` (time.time(), perf_counter()
+        # before the Popen) -> registered; child of the lease or actor
+        # creation this process was spawned for, if any.
+        self.spawn_ts, self.spawn_t0 = began or (time.time(),
+                                                 time.perf_counter())
+        self.trace_ctx = _events.current()
         self.worker_id: Optional[bytes] = None
         self.address: Optional[str] = None
         self.pid = proc.pid
@@ -660,6 +667,7 @@ class NodeDaemon:
             # with an unhandled FileNotFoundError in the start thread.
             raise _DaemonStopping("node daemon is stopping")
         fault_plane.fire("daemon.worker.spawn", env_key=env_key)
+        began = time.time(), time.perf_counter()
         token = uuid.uuid4().hex
         if env_key == "" and not runtime_env and not chips:
             # Default-env workers fork from the zygote when possible.
@@ -677,7 +685,7 @@ class NodeDaemon:
             proc = self._fork_worker(argv, config.propagation_env(),
                                      log_path)
             if proc is not None:
-                w = _Worker(proc, token, env_key)
+                w = _Worker(proc, token, env_key, began=began)
                 with self._lock:
                     self._workers[token] = w
                 return w
@@ -743,7 +751,7 @@ class NodeDaemon:
             env=env, cwd=cwd,
             stdout=out,
             stderr=subprocess.STDOUT)
-        w = _Worker(proc, token, env_key, chips)
+        w = _Worker(proc, token, env_key, chips, began)
         with self._lock:
             self._workers[token] = w
         return w
@@ -758,7 +766,15 @@ class NodeDaemon:
             w.address = address
             w.registered.set()
             self._cv.notify_all()
-        return {"ok": True, "node_id": self.node_id}
+        # The spawn token names the span, so that the worker can name it
+        # as its ``worker.boot``'s parent.
+        ctx = w.trace_ctx or {}
+        ident = ctx.get("ident") or token[:16]
+        _events.span_record("worker.spawn", w.spawn_ts,
+                            time.perf_counter() - w.spawn_t0, ident=ident,
+                            parent=ctx.get("span"), span=token[:16],
+                            chips=len(w.chips))
+        return {"ok": True, "node_id": self.node_id, "span_ident": ident}
 
     def _checkout_worker(self, env_key: str, runtime_env: Optional[dict],
                          timeout: float = 30.0,
@@ -1069,9 +1085,12 @@ class NodeDaemon:
                           runtime_env: Optional[dict] = None,
                           strategy: Any = None,
                           wait_timeout: float = 5.0,
-                          idle_only: bool = False) -> dict:
+                          idle_only: bool = False,
+                          trace_ctx: Optional[dict] = None) -> dict:
         """Grant a worker lease, queue until resources free (bounded wait),
-        or reply infeasible so the caller spills to another node."""
+        or reply infeasible so the caller spills to another node.
+        ``trace_ctx``: the caller's ``lease.grant`` span, parent of the
+        ``worker.spawn`` this lease may cost."""
         fault_plane.fire("daemon.lease.grant", idle_only=idle_only)
         resources = {k: v for k, v in resources.items() if v > 0}
         refusal = self._tpu_refusal(resources)
@@ -1121,8 +1140,9 @@ class NodeDaemon:
         from ray_tpu.core.exceptions import RuntimeEnvSetupError
         w = None
         try:
-            w = self._checkout_worker(env_key, runtime_env, timeout=10.0,
-                                      idle_only=idle_only, chips=chips)
+            with _events.adopt(trace_ctx):
+                w = self._checkout_worker(env_key, runtime_env, timeout=10.0,
+                                          idle_only=idle_only, chips=chips)
         except RuntimeEnvSetupError as e:
             return {"granted": False, "env_error": str(e)}
         finally:
@@ -1147,14 +1167,15 @@ class NodeDaemon:
                            count: int = 1,
                            runtime_env: Optional[dict] = None,
                            strategy: Any = None,
-                           wait_timeout: float = 5.0) -> dict:
+                           wait_timeout: float = 5.0,
+                           trace_ctx: Optional[dict] = None) -> dict:
         """Multi-grant lease request: one round-trip for up to ``count``
         leases of the same shape. The first grant may wait the full
         ``wait_timeout``; extras come only from immediately free resources
         plus already-warm (pooled/recycled) workers, so the reply never
         serializes fresh process boots inside one RPC."""
         first = self.rpc_request_lease(resources, runtime_env, strategy,
-                                       wait_timeout)
+                                       wait_timeout, trace_ctx=trace_ctx)
         if not first.get("granted"):
             return dict(first, leases=[])
         leases = [first]
@@ -1323,9 +1344,10 @@ class NodeDaemon:
                 return
         from ray_tpu.core.exceptions import RuntimeEnvSetupError
         try:
-            w = self._checkout_worker(
-                self._env_key_of(opts.get("runtime_env")),
-                opts.get("runtime_env"), chips=chips)
+            with _events.adopt(spec.get("trace_ctx")):
+                w = self._checkout_worker(
+                    self._env_key_of(opts.get("runtime_env")),
+                    opts.get("runtime_env"), chips=chips)
         except RuntimeEnvSetupError as e:
             # Deterministic env failure: free the reservation and fail the
             # actor's creation.

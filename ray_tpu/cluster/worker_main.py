@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextlib
 import inspect
 import os
 import threading
@@ -135,6 +136,18 @@ class TaskEventLog:
                 self._cli.call("push_task_events", events=events)
             except Exception:
                 pass
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _execution(ctx: Optional[dict], name: str, task_id: bytes):
+    """The caller's span came with the spec (``trace_ctx``): the execution
+    is its child span, and current for whatever the body records."""
+    if ctx is None:
+        return _NO_SPAN
+    return _events.span("task.execute", ctx=ctx, task=name,
+                        task_id=task_id.hex())
 
 
 class WorkerService:
@@ -387,9 +400,10 @@ class WorkerService:
             # between dequeue and result-store — the window where only
             # lineage reconstruction (or task retries) can save the caller.
             fault_plane.fire("worker.task.exec", name=name)
-            fn = self._load_fn(function_id, function_blob)
-            args, kwargs = self._resolve(args_blob, inline_args)
-            result = fn(*args, **kwargs)
+            with _execution(trace_ctx, name, task_id):
+                fn = self._load_fn(function_id, function_blob)
+                args, kwargs = self._resolve(args_blob, inline_args)
+                result = fn(*args, **kwargs)
             self._store_returns(task_id, num_returns, result, collect)
         except BaseException as e:  # noqa: BLE001 - delivered via refs
             error = repr(e)
@@ -407,13 +421,6 @@ class WorkerService:
         _events.emit("task.exec", task_id.hex(), value=end - start,
                      attrs={"task": name, "error": error} if error
                      else {"task": name})
-        if trace_ctx is not None:
-            from ray_tpu.util import tracing
-            ctx = tracing.new_context(parent=trace_ctx)
-            attrs = {"task": name, "worker_pid": os.getpid()}
-            if error:
-                attrs["error"] = error
-            tracing.record("task.execute", start, end, ctx, attrs)
 
     def rpc_push_task(self, task_id: bytes, function_id: str,
                       function_blob: Optional[bytes], args_blob: bytes,
@@ -444,9 +451,6 @@ class WorkerService:
                 returns[t["task_id"]] = entries
         self._flush_refs()
         self._queue_seals(returns.values())
-        # Traced spans ship via the background event flusher (events.py) —
-        # the old synchronous tracing.flush here put a conductor RPC on
-        # every traced batch ack.
         return {"ok": True, "node_id": self.node_id, "returns": returns}
 
     def rpc_cancel_task(self, task_id: bytes) -> None:
@@ -518,7 +522,8 @@ class WorkerService:
                             num_returns: int,
                             arg_pins: Optional[list] = None,
                             actor_id: Optional[bytes] = None,
-                            inline_args: Optional[dict] = None) -> dict:
+                            inline_args: Optional[dict] = None,
+                            trace_ctx: Optional[dict] = None) -> dict:
         """Ordered actor call (per-caller seqno; see class docstring).
         ``actor_id`` guards against a stale address: a recycled worker may
         host a DIFFERENT actor at the address a slow caller cached, and a
@@ -533,7 +538,8 @@ class WorkerService:
         try:
             return self._push_actor_task(task_id, caller_id, seqno,
                                          method_name, args_blob,
-                                         num_returns, arg_pins, inline_args)
+                                         num_returns, arg_pins, inline_args,
+                                         trace_ctx)
         finally:
             with self._seq_lock:
                 self._active_calls -= 1
@@ -542,7 +548,8 @@ class WorkerService:
                          seqno: int, method_name: str, args_blob: bytes,
                          num_returns: int,
                          arg_pins: Optional[list] = None,
-                         inline_args: Optional[dict] = None) -> dict:
+                         inline_args: Optional[dict] = None,
+                         trace_ctx: Optional[dict] = None) -> dict:
         name = f"{self.actor_class_name}.{method_name}"
         start = time.time()
         error = ""
@@ -580,9 +587,10 @@ class WorkerService:
                 # unwieldy for match filters).
                 fault_plane.fire("worker.actor.exec", name=name,
                                  method=method_name)
-                args, kwargs = self._resolve(args_blob, inline_args)
-                m = getattr(self.actor_instance, method_name)
-                result = m(*args, **kwargs)
+                with _events.adopt(trace_ctx):   # callee's spans: children
+                    args, kwargs = self._resolve(args_blob, inline_args)
+                    m = getattr(self.actor_instance, method_name)
+                    result = m(*args, **kwargs)
                 self._store_returns(task_id, num_returns, result, collect)
             except BaseException as e:  # noqa: BLE001
                 err = repr(e)
@@ -625,12 +633,14 @@ class WorkerService:
                     return "cancelled"
                 try:
                     loop = asyncio.get_running_loop()
-                    args, kwargs = await loop.run_in_executor(
-                        None, lambda: self._resolve(args_blob, inline_args))
-                    m = getattr(self.actor_instance, method_name)
-                    result = m(*args, **kwargs)
-                    if inspect.isawaitable(result):
-                        result = await result
+                    with _events.adopt(trace_ctx):
+                        args, kwargs = await loop.run_in_executor(
+                            None,
+                            lambda: self._resolve(args_blob, inline_args))
+                        m = getattr(self.actor_instance, method_name)
+                        result = m(*args, **kwargs)
+                        if inspect.isawaitable(result):
+                            result = await result
                     self._store_returns(task_id, num_returns, result)
                 except BaseException as e:  # noqa: BLE001
                     err = repr(e)
@@ -724,6 +734,10 @@ class WorkerService:
             # process now could take down an innocent new tenant.
             return {"ok": True, "stale": True}
         self.events.flush()
+        try:
+            _events.flush_now()     # the ring's tail would die with us
+        except Exception:
+            pass
         self._stop_cgraph_loops()
         self._release_taken_pins()
         recycled = False
@@ -838,6 +852,7 @@ class WorkerService:
 
 
 def main() -> None:
+    boot_ts, boot_t0 = time.time(), time.perf_counter()
     ap = argparse.ArgumentParser()
     ap.add_argument("--conductor", required=True)
     ap.add_argument("--daemon", required=True)
@@ -870,11 +885,17 @@ def main() -> None:
         conductor_address=args.conductor, daemon_address=args.daemon,
         store=svc.store, plane=svc.plane, node_id=node_id)
     marks.append(("for_worker", time.perf_counter()))
-    get_client(args.daemon).call(
+    ack = get_client(args.daemon).call(
         "register_worker", token=args.token,
         worker_id=svc.worker_id.binary(), address=server.address,
         pid=os.getpid())
     marks.append(("registered", time.perf_counter()))
+    # The daemon's ``worker.spawn`` span is named by the spawn token; the
+    # ack carries the ident of the lease or actor creation it belongs to.
+    _events.span_record("worker.boot", boot_ts,
+                        time.perf_counter() - boot_t0,
+                        ident=(ack or {}).get("span_ident"),
+                        parent=args.token[:16])
     if prof:
         base = marks[0][1]
         print("STARTUP " + " ".join(

@@ -364,9 +364,6 @@ define("tpu_probe_timeout_s", float, 120.0,
 define("task_event_buffer_size", int, 100_000,
        "Task lifecycle events the conductor retains (oldest dropped "
        "first; state.list_tasks / dashboard timeline source).")
-define("tracing_enabled", bool, False,
-       "Record OTel-style spans around task submit/execute "
-       "(util/tracing.py; read via state.list_spans).")
 define("metrics_export_period_s", float, 5.0, "Metrics flush period.")
 define("events_enabled", bool, True,
        "Flight-recorder event ring (util/events.py): per-process "
@@ -377,8 +374,7 @@ define("event_ring_size", int, 16384,
        "Flight-recorder ring capacity per process; overwrites oldest "
        "(dropped counts ship with the next batch).")
 define("event_flush_period_s", float, 0.5,
-       "Background flush period for the event ring (and buffered "
-       "tracing spans) to the conductor.")
+       "Background flush period for the event ring to the conductor.")
 define("slow_op_threshold_s", float, 30.0,
        "Slow-op watchdog: a task/pull/RPC in flight longer than this "
        "emits a SLOW_OPERATION cluster event carrying the surrounding "

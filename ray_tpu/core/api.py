@@ -49,20 +49,22 @@ def init(address: Optional[str] = None, *,
                                "ignore_reinit_error=True to ignore.")
         if _system_config:
             config.set_system_config(_system_config)
-        if local_mode or address == "local":
-            from ray_tpu.core.runtime_local import LocalRuntime
-            _runtime = LocalRuntime(num_cpus=num_cpus, num_tpus=num_tpus,
-                                    resources=resources)
-        elif address and address.startswith("client://"):
-            # Thin client over an in-cluster proxy (parity: ray://).
-            from ray_tpu.client.runtime import ClientRuntime
-            _runtime = ClientRuntime(address, namespace=namespace)
-        else:
-            from ray_tpu.core.runtime_cluster import ClusterRuntime
-            _runtime = ClusterRuntime(address=address, num_cpus=num_cpus,
-                                      num_tpus=num_tpus,
-                                      resources=resources,
-                                      namespace=namespace)
+        from ray_tpu.util import events as _events
+        with _events.span("init"):
+            if local_mode or address == "local":
+                from ray_tpu.core.runtime_local import LocalRuntime
+                _runtime = LocalRuntime(num_cpus=num_cpus, num_tpus=num_tpus,
+                                        resources=resources)
+            elif address and address.startswith("client://"):
+                # Thin client over an in-cluster proxy (parity: ray://).
+                from ray_tpu.client.runtime import ClientRuntime
+                _runtime = ClientRuntime(address, namespace=namespace)
+            else:
+                from ray_tpu.core.runtime_cluster import ClusterRuntime
+                _runtime = ClusterRuntime(address=address, num_cpus=num_cpus,
+                                          num_tpus=num_tpus,
+                                          resources=resources,
+                                          namespace=namespace)
         return _runtime
 
 
@@ -249,10 +251,16 @@ def available_resources() -> Dict[str, float]:
 
 
 def timeline(filename: Optional[str] = None):
-    """Dump a chrome://tracing timeline of task events (parity:
-    python/ray/_private/state.py chrome_tracing_dump)."""
-    rt = _global_runtime()
-    events = getattr(rt, "timeline_events", lambda: [])()
+    """Dump a chrome://tracing timeline of task events and spans (parity:
+    python/ray/_private/state.py chrome_tracing_dump). With no runtime up
+    it draws the spans of the session this process last shut down, the
+    post-mortem of a finished job, and starts nothing."""
+    if _runtime is None:
+        from ray_tpu.core.runtime_cluster import ring_timeline
+        from ray_tpu.util import events as _events
+        events = ring_timeline(_events.last_session())
+    else:
+        events = getattr(_runtime, "timeline_events", lambda: [])()
     if filename:
         import json
         with open(filename, "w") as f:

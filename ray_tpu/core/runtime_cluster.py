@@ -673,6 +673,33 @@ class _ActorResolver:
                         r["ev"].set()
 
 
+class _GrantSpan:
+    """A ``lease.grant`` span: from the request for a worker (a lease, an
+    actor's creation) to the grant (the actor alive). Its id is minted at
+    the request and travels with it, so that the daemon's ``worker.spawn``
+    names it as its parent before it has ended."""
+
+    __slots__ = ("requested", "_p0", "parent", "ctx", "tpu")
+
+    @classmethod
+    def begin(cls, resources: Dict[str, float]) -> Optional["_GrantSpan"]:
+        return cls(resources) if _events.enabled() else None
+
+    def __init__(self, resources: Dict[str, float]):
+        self.requested, self._p0 = time.time(), time.perf_counter()
+        outer = _events.current() or {}
+        self.parent = outer.get("span")
+        sid = _events.new_span_id()
+        self.ctx = {"ident": outer.get("ident") or sid, "span": sid}
+        self.tpu = resources.get("TPU", 0)
+
+    def granted(self, **attrs) -> None:
+        _events.span_record(
+            "lease.grant", self.requested, time.perf_counter() - self._p0,
+            ident=self.ctx["ident"], parent=self.parent,
+            span=self.ctx["span"], TPU=self.tpu, **attrs)
+
+
 class _ActorClient:
     """Ordered pusher for one actor (direct_actor_task_submitter.h:67)."""
 
@@ -750,6 +777,10 @@ class _ActorClient:
                 self.incarnation = info["incarnation"]
                 self.seqno = 0
             self.address = info["address"]
+            grant = self.rt._actor_meta.get(self.actor_id, {}).pop(
+                "grant", None)
+            if grant:
+                grant.granted(actor=self.class_name)
             return True
         if info["state"] == "DEAD":
             err = info.get("creation_error")
@@ -818,7 +849,9 @@ class _ActorClient:
                         num_returns=task["num_returns"],
                         arg_pins=task.get("pin_keys") or [],
                         inline_args=task.get("inline_args"),
-                        actor_id=self.actor_id)
+                        actor_id=self.actor_id,
+                        **({"trace_ctx": task["trace_ctx"]}
+                           if "trace_ctx" in task else {}))
                     f.add_done_callback(
                         lambda f, t=task: self._ack_one(t, f))
                     futs.append(f)
@@ -1008,9 +1041,9 @@ class ClusterRuntime:
         self._ref_tracker.on_zero = self.plane.drop_inline
         _refs_mod._tracker = self._ref_tracker
         # Flight recorder: bind this process's event ring to the cluster
-        # and start the background flusher — from here on ring deltas AND
-        # buffered tracing spans ship asynchronously (nothing on the
-        # submit/execute path performs a synchronous conductor RPC).
+        # and start the background flusher — from here on ring deltas ship
+        # asynchronously (nothing on the submit/execute path performs a
+        # synchronous conductor RPC).
         _events.configure(self.node_id, self.conductor_address)
         _events.register_probe("object_plane", self.plane.metrics_probe)
         # inline-arg flag cache (config.get walks os.environ; hot path)
@@ -1110,7 +1143,8 @@ class ClusterRuntime:
                 key=lambda n: -sum(n["resources_available"].get(k, 0.0)
                                    for k in ("CPU", "TPU")))
             targets += [n["address"] for n in nodes]
-        t0 = time.monotonic()
+        grant = _GrantSpan.begin(resources)
+        ctx = grant.ctx if grant else None
         refusal, grantable = None, False
         for addr in targets:
             try:
@@ -1123,18 +1157,20 @@ class ClusterRuntime:
                     resp = get_client(addr).call(
                         "request_leases", resources=resources, count=count,
                         runtime_env=runtime_env, strategy=strategy,
-                        wait_timeout=wait, _timeout=wait + 15.0)
+                        wait_timeout=wait, trace_ctx=ctx,
+                        _timeout=wait + 15.0)
                 else:
                     resp = get_client(addr).call(
                         "request_lease", resources=resources,
                         runtime_env=runtime_env, strategy=strategy,
-                        wait_timeout=wait, _timeout=wait + 15.0)
+                        wait_timeout=wait, trace_ctx=ctx,
+                        _timeout=wait + 15.0)
             except Exception:
                 continue
             if resp.get("granted"):
                 grants = resp.get("leases") or [resp]
-                _events.emit("lease.grant", value=time.monotonic() - t0,
-                             attrs={"count": len(grants)})
+                if grant:
+                    grant.granted(count=len(grants))
                 return [_LeasedWorker(g["lease_id"], g["worker_address"],
                                       addr) for g in grants]
             if resp.get("env_error"):
@@ -1497,19 +1533,11 @@ class ClusterRuntime:
         self.plane.add_pending([store_key(r.binary()) for r in rets])
         _events.emit("task.submit", task_id.hex(),
                      attrs={"task": task["name"]})
-        from ray_tpu.util import tracing
-        if tracing.enabled():
-            # Submit span (instant) + context propagated in the spec so
-            # the worker's execute span joins the same trace
-            # (tracing_helper.py role). Spans buffer locally and ship via
-            # the flight recorder's background flusher — the synchronous
-            # tracing.flush that used to sit here put a conductor round
-            # trip on EVERY submission and halved the task fast path.
-            ctx = tracing.new_context()
-            now = time.time()
-            tracing.record("task.submit", now, now, ctx,
-                           {"task": task["name"],
-                            "task_id": task_id.hex()})
+        # The submitting thread's open span rides the spec, so that the
+        # worker's ``task.execute`` span is its child (tracing_helper.py
+        # role); a plain task pays one context-variable read and no bytes.
+        ctx = _events.current()
+        if ctx is not None:
             task["trace_ctx"] = ctx
         # Return refs are constructed BEFORE the push: the reply can beat
         # this function's tail (inline dispatch + a fast worker), and
@@ -1619,6 +1647,9 @@ class ClusterRuntime:
                 "runtime_env": opts.runtime_env,
             },
         }
+        grant = _GrantSpan.begin(resources)
+        if grant:
+            spec["trace_ctx"] = grant.ctx
         if (not opts.name and not opts.get_if_exists
                 and config.get("control_plane_batching")):
             # Unnamed actor: the id is client-generated and collisions are
@@ -1635,6 +1666,9 @@ class ClusterRuntime:
                 "methods": methods, "is_async": is_async,
                 "class_name": desc.repr_name(),
                 "max_task_retries": opts.max_task_retries,
+                # recorded by the actor's client when it first sees the
+                # actor alive
+                "grant": grant,
             }
         return ActorHandle(actor_id, desc.repr_name(), methods, is_async)
 
@@ -1726,6 +1760,9 @@ class ClusterRuntime:
         }
         if inline_args:
             task["inline_args"] = inline_args
+        ctx = _events.current()
+        if ctx is not None:
+            task["trace_ctx"] = ctx
         self.plane.add_pending([store_key(ob) for ob in return_oids])
         refs = [ObjectRef(task_id.object_id_for_return(i), owner=self.address)
                 for i in range(opts.num_returns)]
@@ -1862,7 +1899,6 @@ class ClusterRuntime:
         except Exception:
             pass
         out: List[dict] = []
-        exec_ts: Dict[str, float] = {}
         for e in self.conductor.call("get_task_events"):
             tid = e.get("task_id", "")
             out.append({
@@ -1878,74 +1914,11 @@ class ClusterRuntime:
                             "id": tid, "ts": e["start"] * 1e6, "dur": 0,
                             "bp": "e", "pid": e["node_id"][:8],
                             "tid": e["pid"]})
-                exec_ts[tid] = e["start"]
         try:
             ring = self.conductor.call("get_ring_events")
         except Exception:
             ring = []
-        for e in ring:
-            kind, ident = e["kind"], e["ident"]
-            pid_, tid_ = e["node_id"][:8], e["pid"]
-            ts_us = e["ts"] * 1e6
-            if kind == "task.submit" and ident:
-                out.append({"cat": "task", "name": "task.submit", "ph": "X",
-                            "ts": ts_us, "dur": 0, "pid": pid_, "tid": tid_,
-                            "args": {"task_id": ident,
-                                     **(e["attrs"] or {})}})
-                out.append({"cat": "task_flow", "name": "task", "ph": "s",
-                            "id": ident, "ts": ts_us, "dur": 0,
-                            "pid": pid_, "tid": tid_})
-            elif kind == "task.reply" and ident:
-                out.append({"cat": "task", "name": "task.reply", "ph": "X",
-                            "ts": ts_us, "dur": 0, "pid": pid_, "tid": tid_,
-                            "args": {"task_id": ident,
-                                     "roundtrip_s": e["value"]}})
-                out.append({"cat": "task_flow", "name": "task", "ph": "f",
-                            "bp": "e", "id": ident, "ts": ts_us, "dur": 0,
-                            "pid": pid_, "tid": tid_})
-            elif kind == "pipeline.stage.op":
-                # Per-stage pipeline lanes: one pid per compiled pipeline,
-                # one tid per stage, plus flow arrows joining microbatch m
-                # across stages (F chain opens the flow on partition 0, B
-                # chain closes it back there).
-                a = e["attrs"] or {}
-                dur = (e["value"] or 0.0) * 1e6
-                p_pid = "pipe-" + ident[:8]
-                p_tid = "stage%s" % a.get("stage", "?")
-                name = "%s p%s mb%s" % (a.get("kind", "?"),
-                                        a.get("part", "?"),
-                                        a.get("mb", "?"))
-                out.append({"cat": "pipeline", "name": name, "ph": "X",
-                            "ts": ts_us - dur, "dur": dur,
-                            "pid": p_pid, "tid": p_tid,
-                            "args": {**a, "busy_s": e["value"]}})
-                flow = a.get("flow")
-                if flow in ("s", "t", "f"):
-                    fid = "%s:%s:%s" % (ident, a.get("step", 0),
-                                        a.get("mb", 0))
-                    fev = {"cat": "pipeline_flow", "name": "mb", "ph": flow,
-                           "id": fid, "ts": ts_us - (dur if flow == "s"
-                                                     else 0), "dur": 0,
-                           "pid": p_pid, "tid": p_tid}
-                    if flow in ("t", "f"):
-                        fev["bp"] = "e"
-                    out.append(fev)
-            elif kind == "pipeline.step":
-                a = e["attrs"] or {}
-                dur = (e["value"] or 0.0) * 1e6
-                out.append({"cat": "pipeline", "name": "pipeline.step",
-                            "ph": "X", "ts": ts_us - dur, "dur": dur,
-                            "pid": "pipe-" + ident[:8], "tid": "driver",
-                            "args": {**a, "wall_s": e["value"]}})
-            elif kind.startswith(("pull.", "push.")):
-                # object-transfer view (ray.timeline's transfer rows)
-                dur = e["value"] * 1e6 if kind == "pull.done" else 0
-                out.append({"cat": "object_transfer", "name": kind,
-                            "ph": "X", "ts": ts_us - dur, "dur": dur,
-                            "pid": pid_, "tid": tid_,
-                            "args": {"object_id": ident, "value": e["value"],
-                                     **(e["attrs"] or {})}})
-        return out
+        return out + ring_timeline(ring)
 
     def debug_state(self) -> dict:
         """Driver-side slice of the cluster debug dump (the conductor and
@@ -1980,6 +1953,15 @@ class ClusterRuntime:
             _events.stop()   # final async flush; flusher thread retires
         except Exception:
             pass
+        if not getattr(self, "_is_worker", False):
+            # The run's spans outlive the runtime: the post-mortem an
+            # operator reads after the job (events.last_session(),
+            # rt.timeline() with no runtime up).
+            try:
+                _events.keep_session(self.conductor.call(
+                    "get_ring_events", spans_only=True))
+            except Exception:
+                pass
         try:
             self._flush_registrations(timeout=5.0)
             with self._reg_cv:
@@ -2014,3 +1996,81 @@ class ClusterRuntime:
         if sd is not None:
             import shutil
             shutil.rmtree(sd, ignore_errors=True)
+
+
+def ring_timeline(ring: List[dict]) -> List[dict]:
+    """Chrome-trace events from flight-recorder records (the conductor's
+    dicts): spans as nested X slices per process, task submit/reply
+    instants and their flow arrows, pipeline lanes, object transfers."""
+    out: List[dict] = []
+    for e in ring:
+        kind, ident = e["kind"], e["ident"]
+        pid_, tid_ = e["node_id"][:8], e["pid"]
+        ts_us = e["ts"] * 1e6
+        attrs = e["attrs"] or {}
+        if "span" in attrs:
+            # a span: ts is its start, value its seconds; slices of one
+            # process nest as their parents do
+            out.append({"cat": "span", "name": kind, "ph": "X",
+                        "ts": ts_us, "dur": (e["value"] or 0.0) * 1e6,
+                        "pid": pid_, "tid": tid_,
+                        "args": {"ident": ident, **attrs}})
+        elif kind == "task.submit" and ident:
+            out.append({"cat": "task", "name": "task.submit", "ph": "X",
+                        "ts": ts_us, "dur": 0, "pid": pid_, "tid": tid_,
+                        "args": {"task_id": ident,
+                                 **(e["attrs"] or {})}})
+            out.append({"cat": "task_flow", "name": "task", "ph": "s",
+                        "id": ident, "ts": ts_us, "dur": 0,
+                        "pid": pid_, "tid": tid_})
+        elif kind == "task.reply" and ident:
+            out.append({"cat": "task", "name": "task.reply", "ph": "X",
+                        "ts": ts_us, "dur": 0, "pid": pid_, "tid": tid_,
+                        "args": {"task_id": ident,
+                                 "roundtrip_s": e["value"]}})
+            out.append({"cat": "task_flow", "name": "task", "ph": "f",
+                        "bp": "e", "id": ident, "ts": ts_us, "dur": 0,
+                        "pid": pid_, "tid": tid_})
+        elif kind == "pipeline.stage.op":
+            # Per-stage pipeline lanes: one pid per compiled pipeline,
+            # one tid per stage, plus flow arrows joining microbatch m
+            # across stages (F chain opens the flow on partition 0, B
+            # chain closes it back there).
+            a = e["attrs"] or {}
+            dur = (e["value"] or 0.0) * 1e6
+            p_pid = "pipe-" + ident[:8]
+            p_tid = "stage%s" % a.get("stage", "?")
+            name = "%s p%s mb%s" % (a.get("kind", "?"),
+                                    a.get("part", "?"),
+                                    a.get("mb", "?"))
+            out.append({"cat": "pipeline", "name": name, "ph": "X",
+                        "ts": ts_us - dur, "dur": dur,
+                        "pid": p_pid, "tid": p_tid,
+                        "args": {**a, "busy_s": e["value"]}})
+            flow = a.get("flow")
+            if flow in ("s", "t", "f"):
+                fid = "%s:%s:%s" % (ident, a.get("step", 0),
+                                    a.get("mb", 0))
+                fev = {"cat": "pipeline_flow", "name": "mb", "ph": flow,
+                       "id": fid, "ts": ts_us - (dur if flow == "s"
+                                                 else 0), "dur": 0,
+                       "pid": p_pid, "tid": p_tid}
+                if flow in ("t", "f"):
+                    fev["bp"] = "e"
+                out.append(fev)
+        elif kind == "pipeline.step":
+            a = e["attrs"] or {}
+            dur = (e["value"] or 0.0) * 1e6
+            out.append({"cat": "pipeline", "name": "pipeline.step",
+                        "ph": "X", "ts": ts_us - dur, "dur": dur,
+                        "pid": "pipe-" + ident[:8], "tid": "driver",
+                        "args": {**a, "wall_s": e["value"]}})
+        elif kind.startswith(("pull.", "push.")):
+            # object-transfer view (ray.timeline's transfer rows)
+            dur = e["value"] * 1e6 if kind == "pull.done" else 0
+            out.append({"cat": "object_transfer", "name": kind,
+                        "ph": "X", "ts": ts_us - dur, "dur": dur,
+                        "pid": pid_, "tid": tid_,
+                        "args": {"object_id": ident, "value": e["value"],
+                                 **(e["attrs"] or {})}})
+    return out

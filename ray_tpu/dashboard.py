@@ -205,7 +205,7 @@ class Dashboard:
             _events.flush_now()
         except Exception:
             pass
-        return self._cli.call("get_spans")
+        return self._cli.call("get_ring_events", spans_only=True)
 
     def profile(self, pid: int, duration_s: float = 2.0,
                 node_hex: Optional[str] = None) -> str:

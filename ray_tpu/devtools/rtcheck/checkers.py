@@ -175,8 +175,9 @@ class FaultSites(Checker):
 # ----------------------------------------------------------------------
 class NameDrift(Checker):
     """Every ``rt_*`` metric-name literal outside util/metrics.py must be
-    minted in ``metrics.METRICS``; every ``emit("kind")`` literal must be
-    minted in ``events.EVENT_KINDS``. Registered names nobody references
+    minted in ``metrics.METRICS``; every ``emit("kind")``, ``span("kind")``
+    and ``span_record("kind")`` literal must be minted in
+    ``events.EVENT_KINDS``. Registered names nobody references
     are dead."""
 
     name = "name-drift"
@@ -200,7 +201,8 @@ class NameDrift(Checker):
                              f"metric name {node.value!r} is not minted in "
                              f"util/metrics.METRICS")
             if isinstance(node, ast.Call) and node.args and \
-                    _call_name(node) in ("emit", "_emit"):
+                    _call_name(node) in ("emit", "_emit", "span",
+                                         "span_record"):
                 kind = _literal_str(node.args[0])
                 if kind is None:
                     continue

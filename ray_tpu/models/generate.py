@@ -168,7 +168,8 @@ def generate(params, prompt, cfg: TransformerConfig, *,
     cfg = _inference_cfg(cfg)
     b, s = prompt.shape
     max_len = s + max_new_tokens
-    logits, cache = prefill(params, prompt, cfg, max_len, mesh=mesh)
+    with jax.named_scope("rt.generate.prefill"):
+        logits, cache = prefill(params, prompt, cfg, max_len, mesh=mesh)
     key = jax.random.PRNGKey(seed)
     key, sub = jax.random.split(key)
     first = _sample(logits, sub, temperature, top_k)
@@ -180,7 +181,8 @@ def generate(params, prompt, cfg: TransformerConfig, *,
         nxt = _sample(logits, sub, temperature, top_k)
         return (nxt, pos + 1, cache, key), token
 
-    (_, _, _, _), tokens = lax.scan(
-        step, (first, jnp.asarray(s, jnp.int32), cache, key),
-        None, length=max_new_tokens)
+    with jax.named_scope("rt.generate.decode"):
+        (_, _, _, _), tokens = lax.scan(
+            step, (first, jnp.asarray(s, jnp.int32), cache, key),
+            None, length=max_new_tokens)
     return jnp.transpose(tokens, (1, 0))   # [B, max_new_tokens]

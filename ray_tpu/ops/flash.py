@@ -116,6 +116,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k,
         q_offset=sk - sq)
     out, lse = pl.pallas_call(
         kern,
+        name="rt_flash_fwd",   # rtcheck: allow-unminted-metric(a kernel's name in the device trace, not a metric)
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -236,6 +237,7 @@ def _flash_bwd(res, g, *, causal, scale, block_q, block_k,
     dkv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, q_offset=q_offset),
+        name="rt_flash_dkv",   # rtcheck: allow-unminted-metric(a kernel's name in the device trace, not a metric)
         grid=(bh, sk // bk, sq // bq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),   # q
@@ -264,6 +266,7 @@ def _flash_bwd(res, g, *, causal, scale, block_q, block_k,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, q_offset=q_offset),
+        name="rt_flash_dq",   # rtcheck: allow-unminted-metric(a kernel's name in the device trace, not a metric)
         grid=(bh, sq // bq, sk // bk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),

@@ -18,7 +18,7 @@ from typing import Any, Callable, Dict, List, Optional
 import cloudpickle
 
 from ray_tpu.core.refs import ChannelResolvedRef
-from ray_tpu.util import lockcheck
+from ray_tpu.util import events, lockcheck
 
 
 def _get_controller(create: bool = True):
@@ -56,7 +56,6 @@ def _retryable(exc: BaseException) -> bool:
 
 def _emit(kind: str, ident: str, value: float = 1.0, **attrs) -> None:
     try:
-        from ray_tpu.util import events
         events.emit(kind, ident, value=value,
                     attrs=attrs if attrs else None)
     except Exception:
@@ -304,17 +303,22 @@ class DeploymentHandle:
             timeout = float(config.get("serve_request_timeout_s"))
         deadline = time.monotonic() + timeout
         args_blob = cloudpickle.dumps((args, kwargs))
-        while True:
+        with events.span("serve.handle.slot_wait"):
+            while True:
+                try:
+                    replica = self._pick(enforce_cap=True)
+                    break
+                except ReplicaBusyError:
+                    if time.monotonic() >= deadline:
+                        raise
+                    time.sleep(0.005)
+        with events.span("serve.handle.call") as call:
+            ref, key = self._submit(replica, args_blob)
+            sref = ServeCallRef(self, ref, key, self.method, args_blob)
             try:
-                replica = self._pick(enforce_cap=True)
-                break
-            except ReplicaBusyError:
-                if time.monotonic() >= deadline:
-                    raise
-                time.sleep(0.005)
-        ref, key = self._submit(replica, args_blob)
-        sref = ServeCallRef(self, ref, key, self.method, args_blob)
-        return sref._resolve(max(0.0, deadline - time.monotonic()))
+                return sref._resolve(max(0.0, deadline - time.monotonic()))
+            finally:
+                call.set(retries=int(sref._retried))
 
     def _remote_compiled(self, replica, key, args_blob):
         """Submit through the replica's compiled graph; None means the
@@ -611,25 +615,41 @@ def _batch_state(key: str, window_s: float) -> dict:
         return st
 
 
-def _adapt_window(st: dict, target_p99_ms: float, base_window_s: float,
-                  batch_size: int) -> None:
+def _adapt_window(st: dict, target_p99_ms: float,
+                  base_window_s: float) -> Optional[float]:
     """AIMD-flavored window law keyed off observed request p99: grow the
     flush window multiplicatively while comfortably under the SLO target
     (bigger batches amortize one forward over more requests), halve it the
     moment p99 breaches (latency recovers within a flush or two). Bounds
     keep a misconfigured target from freezing (window->0 busy-flush) or
-    stalling (window >> SLO) the pipeline."""
+    stalling (window >> SLO) the pipeline. -> the p99 it read, in ms."""
     lat = sorted(st["lat"])
     if not lat:
-        return
+        return None
     p99_ms = lat[min(len(lat) - 1, int(0.99 * len(lat)))] * 1000.0
     lo, hi = base_window_s / 10.0, base_window_s * 10.0
     if p99_ms > target_p99_ms:
         st["window"] = max(lo, st["window"] * 0.5)
     elif p99_ms < 0.8 * target_p99_ms:
         st["window"] = min(hi, st["window"] * 1.25)
-    _emit("serve.batch.flush", "batch", value=float(batch_size),
-          window_ms=st["window"] * 1000.0, p99_ms=p99_ms)
+    return p99_ms
+
+
+class _BatchWait:
+    """One caller's ``serve.batch.wait``: begun on the caller's thread at
+    enqueue, recorded by the flush that took it when that starts ``fn``."""
+
+    __slots__ = ("ts", "started", "caller")
+
+    def __init__(self):
+        self.ts, self.started = time.time(), time.perf_counter()
+        self.caller = events.current() or {}
+
+    def record(self, fn_start: float, flush_id: str) -> None:
+        events.span_record(
+            "serve.batch.wait", self.ts, fn_start - self.started,
+            ident=self.caller.get("ident"), parent=self.caller.get("span"),
+            flush=flush_id)
 
 
 def batch(_fn=None, *, max_batch_size: int = 8,
@@ -653,33 +673,55 @@ def batch(_fn=None, *, max_batch_size: int = 8,
             with st["lock"]:
                 batch_items = st["pending"][:]
                 st["pending"].clear()
+                window = st["window"]
             if not batch_items:
                 return
             items = [it[0] for it in batch_items]
             self_obj = batch_items[0][2]
-            try:
-                outs = fn(self_obj, items) if self_obj is not None \
-                    else fn(items)
-                if len(outs) != len(items):
-                    raise ValueError(
-                        f"@serve.batch fn returned {len(outs)} results "
-                        f"for {len(items)} inputs")
-                for (_, slot, _, _), out in zip(batch_items, outs):
-                    slot["result"] = out
-                    slot["event"].set()
-            except BaseException as e:  # noqa: BLE001
-                for _, slot, _, _ in batch_items:
-                    slot["error"] = e
-                    slot["event"].set()
-            finally:
-                if target_p99_ms is not None:
-                    done = time.monotonic()
-                    with st["lock"]:
-                        st["lat"].extend(done - it[3]
-                                         for it in batch_items)
-                        _adapt_window(st, target_p99_ms,
-                                      batch_wait_timeout_s,
-                                      len(batch_items))
+            waits = [it[4] for it in batch_items if it[4] is not None]
+            error = outs = None
+            # A flush serves many requests and belongs to none of them: a
+            # tree of its own, whichever thread runs it. Each request's
+            # ``serve.batch.wait`` names it (``flush``).
+            with events.span(
+                    "serve.batch.flush", ctx=events.ROOT, rows=len(items),
+                    max_batch_size=max_batch_size,
+                    window_s=window) as flushed:
+                fn_start = time.perf_counter()
+                if waits:
+                    flushed.set(oldest_wait_s=fn_start - min(
+                        w.started for w in waits))
+                try:
+                    outs = fn(self_obj, items) if self_obj is not None \
+                        else fn(items)
+                except BaseException as e:  # noqa: BLE001
+                    error = e
+                    flushed.set(error=repr(e))
+            returned, r0 = time.time(), time.perf_counter()
+            if error is None and len(outs) != len(items):
+                error = ValueError(
+                    f"@serve.batch fn returned {len(outs)} results "
+                    f"for {len(items)} inputs")
+            for i, (_, slot, _, _, _) in enumerate(batch_items):
+                if error is None:
+                    slot["result"] = outs[i]
+                else:
+                    slot["error"] = error
+                slot["event"].set()
+            p99_ms = None
+            if target_p99_ms is not None:
+                done = time.monotonic()
+                with st["lock"]:
+                    st["lat"].extend(done - it[3] for it in batch_items)
+                    p99_ms = _adapt_window(st, target_p99_ms,
+                                           batch_wait_timeout_s)
+            if flushed.id is not None:      # off every waiter's path
+                events.span_record(
+                    "serve.batch.reply", returned,
+                    time.perf_counter() - r0, ident=flushed.ident,
+                    parent=flushed.id, p99_ms=p99_ms)
+                for w in waits:
+                    w.record(fn_start, flushed.id)
 
         @functools.wraps(fn)
         def wrapper(*call_args):
@@ -693,7 +735,9 @@ def batch(_fn=None, *, max_batch_size: int = 8,
             do_flush = False
             with st["lock"]:
                 st["pending"].append((item, slot, self_obj,
-                                      time.monotonic()))
+                                      time.monotonic(),
+                                      _BatchWait() if events.enabled()
+                                      else None))
                 if len(st["pending"]) >= max_batch_size:
                     do_flush = True
                 window = st["window"]

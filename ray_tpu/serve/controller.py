@@ -16,7 +16,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 from ray_tpu.cluster import fault_plane
-from ray_tpu.util import lockcheck
+from ray_tpu.util import events, lockcheck
 
 
 class ReplicaBusyError(Exception):
@@ -55,6 +55,10 @@ class Replica:
             pass
 
     def handle_request(self, method: str, args_blob: bytes):
+        with events.span("serve.replica.call") as call:
+            return self._handle_request(method, args_blob, call)
+
+    def _handle_request(self, method: str, args_blob: bytes, call):
         import cloudpickle
         fault_plane.fire("serve.replica.call", deployment=self._deployment,
                          method=method)
@@ -68,6 +72,7 @@ class Replica:
                     f"replica of {self._deployment!r} at in-flight cap "
                     f"({self._max_ongoing})")
             self._inflight += 1
+            call.set(inflight=self._inflight)
         self._set_gauge()
         args, kwargs = cloudpickle.loads(args_blob)
         try:

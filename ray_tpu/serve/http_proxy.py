@@ -26,6 +26,8 @@ import time
 import weakref
 from typing import Iterable, Optional
 
+from ray_tpu.util import events
+
 # Proxies constructed in THIS process (in-process protocol tests; the
 # production path runs one per proxy actor process). The conftest hygiene
 # fixture asserts these are closed — a live proxy is a leaked event-loop
@@ -67,7 +69,6 @@ def _http_error(code: int, msg: str,
 
 def _emit(kind: str, ident: str, value: float = 1.0, **attrs) -> None:
     try:
-        from ray_tpu.util import events
         events.emit(kind, ident, value=value,
                     attrs=attrs if attrs else None)
     except Exception:
@@ -103,11 +104,7 @@ class HTTPProxy:
             raise self._boot_error or RuntimeError(
                 "serve proxy failed to start within 10s")
         _live_proxies.add(self)
-        try:
-            from ray_tpu.util import events
-            events.register_probe("serve.proxy", self._probe)
-        except Exception:
-            pass
+        events.register_probe("serve.proxy", self._probe)
 
     def _probe(self) -> dict:
         queued = sum(st["queued"] for st in self._adm.values())
@@ -213,17 +210,15 @@ class HTTPProxy:
         n = len(h._replicas)
         return max(1, max(1, n) * max(1, cap))
 
-    def _reject(self, writer, name: str, code: int, msg: str,
-                t0: float) -> None:
+    def _reject(self, writer, name: str, code: int, msg: str) -> int:
         kind = "shed" if code == 503 else \
             "timeouts" if code == 504 else "errors"
         self._counts[kind] += 1
         if code == 503:
             _emit("serve.shed", name)
-        _emit("serve.request", name, value=time.monotonic() - t0,
-              code=code, deployment=name)
         writer.write(_http_error(
             code, msg, retry_after=1 if code == 503 else None))
+        return code
 
     async def _dispatch(self, method: str, target: str, body: bytes,
                         writer: asyncio.StreamWriter) -> None:
@@ -263,7 +258,20 @@ class HTTPProxy:
                     args = (payload,)
             except json.JSONDecodeError:
                 args = (body,)
+        # The request's span: body parsed -> last byte written. It mints
+        # the ident that the handle's, the replica's and the batcher's
+        # spans of this request share.
+        with events.span("serve.request", deployment=name) as request:
+            code = await self._admit_and_call(name, path, args, kwargs,
+                                              writer)
+            request.set(code=code)
+            await writer.drain()
 
+    async def _admit_and_call(self, name: str, path: str, args: tuple,
+                              kwargs: dict,
+                              writer: asyncio.StreamWriter) -> int:
+        """Admission, the deployment call on a pool thread, the reply.
+        -> the HTTP code written."""
         from ray_tpu import config
         t0 = time.monotonic()
         try:
@@ -271,35 +279,47 @@ class HTTPProxy:
             fault_plane.fire("serve.proxy.admit", deployment=name,
                              path=path)
         except Exception:
-            self._reject(writer, name, 503, "admission rejected", t0)
-            return
+            return self._reject(writer, name, 503, "admission rejected")
         st = self._adm_state(name)
         if st["queued"] >= int(config.get("serve_max_queued_requests")):
-            self._reject(writer, name, 503,
-                         f"queue full for {name!r}", t0)
-            return
+            return self._reject(writer, name, 503,
+                                f"queue full for {name!r}")
         deadline = t0 + float(config.get("serve_request_timeout_s"))
         # Queue for an ongoing slot. The loop is single-threaded, so the
         # counters need no lock; check-then-act is atomic between awaits.
         st["queued"] += 1
         try:
-            while st["ongoing"] >= self._budget(name):
-                if time.monotonic() >= deadline:
-                    _emit("serve.timeout", name)
-                    self._reject(writer, name, 504,
-                                 "timed out waiting for capacity", t0)
-                    return
-                await asyncio.sleep(0.005)
-            st["ongoing"] += 1
+            with events.span("serve.proxy.admit"):
+                while st["ongoing"] >= self._budget(name):
+                    if time.monotonic() >= deadline:
+                        _emit("serve.timeout", name)
+                        return self._reject(
+                            writer, name, 504,
+                            "timed out waiting for capacity")
+                    await asyncio.sleep(0.005)
+                st["ongoing"] += 1
         finally:
             st["queued"] -= 1
 
+        # run_in_executor carries no context variables: the request's span
+        # crosses to the pool thread by hand, and the wait for that thread
+        # (run_in_executor called -> call_blocking's first line) is a span
+        # that begins here and ends there.
+        request = events.current()
+        queued, q0 = time.time(), time.perf_counter()
+
         def call_blocking():
             from ray_tpu.serve.api import _handle_for
-            return _handle_for(name).call(
-                *args,
-                timeout=max(0.05, deadline - time.monotonic()),
-                **kwargs)
+            with events.adopt(request):
+                events.span_record(
+                    "serve.proxy.thread_wait", queued,
+                    time.perf_counter() - q0,
+                    ident=request and request["ident"],
+                    parent=request and request["span"])
+                return _handle_for(name).call(
+                    *args,
+                    timeout=max(0.05, deadline - time.monotonic()),
+                    **kwargs)
 
         try:
             # executor offload: slow model calls never stall the loop —
@@ -311,23 +331,19 @@ class HTTPProxy:
             from ray_tpu.serve.controller import ReplicaBusyError
             if isinstance(e, GetTimeoutError):
                 # the in-flight call was cancelled by ServeCallRef
-                self._reject(writer, name, 504,
-                             "deployment call timed out", t0)
-            elif isinstance(e, (ReplicaBusyError, RuntimeError)) \
+                return self._reject(writer, name, 504,
+                                    "deployment call timed out")
+            if isinstance(e, (ReplicaBusyError, RuntimeError)) \
                     or _retryable(e):
                 # _retryable covers the call that burned its one retry on
                 # a SECOND dying replica: the failure is the cluster's,
                 # not the request's — the client may retry (503), this is
                 # not a 500.
-                self._reject(writer, name, 503, repr(e), t0)
-            else:
-                self._reject(writer, name, 500, repr(e), t0)
-            return
+                return self._reject(writer, name, 503, repr(e))
+            return self._reject(writer, name, 500, repr(e))
         finally:
             st["ongoing"] -= 1
         self._counts["served"] += 1
-        _emit("serve.request", name, value=time.monotonic() - t0,
-              code=200, deployment=name)
         if isinstance(out, StreamingResponse):
             writer.write((
                 "HTTP/1.1 200 OK\r\n"
@@ -341,11 +357,12 @@ class HTTPProxy:
                 writer.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
                 await writer.drain()
             writer.write(b"0\r\n\r\n")
-            return
+            return 200
         data = json.dumps(out, default=str).encode()
         writer.write((
             "HTTP/1.1 200 OK\r\nContent-Type: application/json\r\n"
             f"Content-Length: {len(data)}\r\n\r\n").encode() + data)
+        return 200
 
     # -- control ----------------------------------------------------------
     def _routes(self) -> dict:

@@ -3,7 +3,7 @@
 Role parity: python/ray/experimental/state/api.py (list_actors, list_tasks,
 list_nodes, list_objects, list_placement_groups, summarize_tasks) backed by
 the conductor's tables (the role of GCS + dashboard/state_aggregator.py),
-plus span listing (util/tracing) and on-demand worker profiling
+plus span listing (util/events) and on-demand worker profiling
 (util/profiler; the reporter module's py-spy role).
 """
 

@@ -90,11 +90,14 @@ def list_cluster_events(limit: int = 1000, source: Optional[str] = None,
                              severity=severity, event_type=event_type)
 
 
-def list_spans(trace_id: Optional[str] = None) -> List[dict]:
-    """Task-path spans (util/tracing.py; enable with
-    _system_config={"tracing_enabled": True}). Parity role:
-    util/tracing/tracing_helper.py span export."""
-    return _conductor().call("get_spans", trace_id=trace_id)
+def list_spans(ident: Optional[str] = None) -> List[dict]:
+    """Span records of the flight-recorder ring (util/events.py ``span``):
+    dicts with node_id, pid, ts (start), kind, ident, value (seconds) and
+    attrs holding the span's id and its parent's. ``ident`` narrows to one
+    request, one lease or one fit(). Parity role:
+    the reference's tracing_helper.py span export."""
+    return _conductor().call("get_ring_events", spans_only=True,
+                             ident=ident)
 
 
 def profile_worker(pid: int, duration_s: float = 1.0,

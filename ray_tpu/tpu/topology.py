@@ -185,18 +185,23 @@ def probe_chips(require_tpu: bool = False) -> dict:
         env = dict(os.environ)
         if require_tpu:
             env["JAX_PLATFORMS"] = "tpu"
-        try:
-            out = subprocess.run([sys.executable, "-c", _PROBE_SRC], env=env,
-                                 capture_output=True, text=True,
-                                 timeout=timeout_s)
-        except subprocess.TimeoutExpired as e:
-            raise TpuProbeError(
-                f"TPU probe timed out after {timeout_s}s; stderr:\n"
-                f"{e.stderr or ''}") from None
-        if out.returncode != 0:
-            raise TpuProbeError(
-                f"TPU probe exited {out.returncode}; stderr:\n{out.stderr}")
-        _probe_cache = json.loads(out.stdout)
+        from ray_tpu.util import events
+        with events.span("init.probe") as sp:
+            try:
+                out = subprocess.run([sys.executable, "-c", _PROBE_SRC],
+                                     env=env, capture_output=True, text=True,
+                                     timeout=timeout_s)
+            except subprocess.TimeoutExpired as e:
+                raise TpuProbeError(
+                    f"TPU probe timed out after {timeout_s}s; stderr:\n"
+                    f"{e.stderr or ''}") from None
+            if out.returncode != 0:
+                raise TpuProbeError(
+                    f"TPU probe exited {out.returncode}; stderr:\n"
+                    f"{out.stderr}")
+            _probe_cache = json.loads(out.stdout)
+            sp.set(chips=_probe_cache["count"],
+                   platform=_probe_cache["platform"])
     if require_tpu and _probe_cache["platform"] != "tpu":
         raise TpuProbeError(
             f"TPUs were asked for but jax reports {_probe_cache}")

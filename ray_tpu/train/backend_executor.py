@@ -19,6 +19,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import events
 
 
 class Backend:
@@ -140,11 +141,15 @@ class BackendExecutor:
 
     def start(self, ready_timeout: float = 120.0) -> None:
         try:
-            self.worker_group = WorkerGroup(
-                self.num_workers, self.resources_per_worker,
-                self.placement_strategy, slice_topology=self.slice_topology,
-                ready_timeout=ready_timeout)
-            self.backend.on_start(self.worker_group)
+            with events.span("train.backend.start",
+                             workers=self.num_workers):
+                with events.span("train.gang.start"):
+                    self.worker_group = WorkerGroup(
+                        self.num_workers, self.resources_per_worker,
+                        self.placement_strategy,
+                        slice_topology=self.slice_topology,
+                        ready_timeout=ready_timeout)
+                self.backend.on_start(self.worker_group)
         except Exception as e:  # noqa: BLE001 - retryable via FailureConfig
             raise TrainingFailedError(f"gang formation failed: {e!r}") from e
 
@@ -185,47 +190,16 @@ class BackendExecutor:
         while not finished:
             # One synchronized round: wait for report[index] on every rank
             # (session.report is a barrier in the reference's semantics).
-            round_reports: List[Optional[dict]] = [None] * len(wg.workers)
-            pending = set(range(len(wg.workers)))
-            while pending:
-                # Poll the whole round concurrently under ONE shared
-                # deadline: submit every rank's long-poll up front, then
-                # collect. Serial per-rank polling with a fresh 120s get
-                # each meant one hung rank delayed dead-rank detection on
-                # every rank queued behind it by up to 120s apiece. A rank
-                # still training answers "pending" within its 30s
-                # long-poll, re-arming the next wave's deadline — only a
-                # rank that cannot answer at all eats the full window.
-                wave = {rank: wg.workers[rank].next_report.remote(index, 30.0)
-                        for rank in sorted(pending)}
-                wave_deadline = time.monotonic() + 120.0
-                for rank, ref in wave.items():
-                    try:
-                        r = rt.get(ref, timeout=max(
-                            5.0, wave_deadline - time.monotonic()))
-                    except TrainingFailedError:
-                        raise
-                    except Exception as e:  # noqa: BLE001 - rank died
-                        # A dead rank (node loss, OOM kill) fails the whole
-                        # gang: an SPMD program cannot continue minus one
-                        # process — the trainer re-forms the gang (possibly
-                        # smaller, FailureConfig.elastic) from the last
-                        # checkpoint.
-                        raise TrainingFailedError(
-                            f"rank {rank} failed: {e!r}") from e
-                    if r["status"] == "report":
-                        round_reports[rank] = r
-                        pending.discard(rank)
-                    elif r["status"] == "finished":
-                        round_reports[rank] = None
-                        pending.discard(rank)
-                        finished = True
-                    elif r["status"] == "error":
-                        raise TrainingFailedError(r["traceback"])
-                    # "pending": poll again
+            with events.span("train.pump") as pump:
+                round_reports, done = self._round(wg, index)
+                finished = finished or done
+                rank0 = round_reports[0]
+                if rank0 is not None:
+                    # lag: this round's end over rank 0's report() start
+                    pump.set(iteration=rank0["iteration"],
+                             lag_s=time.time() - rank0["ts"])
             if all(r is None for r in round_reports):
                 break
-            rank0 = round_reports[0]
             if rank0 is not None:
                 merged = {"metrics": rank0["metrics"],
                           "checkpoint": rank0["checkpoint"],
@@ -237,6 +211,52 @@ class BackendExecutor:
                     finished = True
             index += 1
         return history
+
+    @staticmethod
+    def _round(wg: WorkerGroup, index: int):
+        """Wait for report[index] on every rank. -> (the ranks' reports,
+        None for a rank that finished; whether any rank finished)."""
+        import ray_tpu as rt
+        finished = False
+        round_reports: List[Optional[dict]] = [None] * len(wg.workers)
+        pending = set(range(len(wg.workers)))
+        while pending:
+            # Poll the whole round concurrently under ONE shared
+            # deadline: submit every rank's long-poll up front, then
+            # collect. Serial per-rank polling with a fresh 120s get
+            # each meant one hung rank delayed dead-rank detection on
+            # every rank queued behind it by up to 120s apiece. A rank
+            # still training answers "pending" within its 30s
+            # long-poll, re-arming the next wave's deadline — only a
+            # rank that cannot answer at all eats the full window.
+            wave = {rank: wg.workers[rank].next_report.remote(index, 30.0)
+                    for rank in sorted(pending)}
+            wave_deadline = time.monotonic() + 120.0
+            for rank, ref in wave.items():
+                try:
+                    r = rt.get(ref, timeout=max(
+                        5.0, wave_deadline - time.monotonic()))
+                except TrainingFailedError:
+                    raise
+                except Exception as e:  # noqa: BLE001 - rank died
+                    # A dead rank (node loss, OOM kill) fails the whole
+                    # gang: an SPMD program cannot continue minus one
+                    # process — the trainer re-forms the gang (possibly
+                    # smaller, FailureConfig.elastic) from the last
+                    # checkpoint.
+                    raise TrainingFailedError(
+                        f"rank {rank} failed: {e!r}") from e
+                if r["status"] == "report":
+                    round_reports[rank] = r
+                    pending.discard(rank)
+                elif r["status"] == "finished":
+                    round_reports[rank] = None
+                    pending.discard(rank)
+                    finished = True
+                elif r["status"] == "error":
+                    raise TrainingFailedError(r["traceback"])
+                # "pending": poll again
+        return round_reports, finished
 
     def shutdown(self) -> None:
         if self.worker_group is not None:

@@ -154,6 +154,11 @@ class DataParallelTrainer(BaseTrainer):
         self.datasets = datasets or {}
 
     def fit(self) -> Result:
+        from ray_tpu.util import events
+        with events.span("train.fit"):
+            return self._fit()
+
+    def _fit(self) -> Result:
         cfg = self.run_config
         trial_dir = os.path.join(
             cfg.storage_path or tempfile.gettempdir(),

@@ -49,14 +49,18 @@ class RayTrainWorker:
         self._session = sess
         self._done.clear()
         self._error = None
+        from ray_tpu.util import events
+        caller = events.current()    # the driver's span, across the thread
 
         def run():
             session_mod._set_session(sess)
             try:
-                if _accepts_config(loop_fn):
-                    loop_fn(config)
-                else:
-                    loop_fn()
+                with events.span("train.loop", ctx=caller,
+                                 rank=self.world_rank):
+                    if _accepts_config(loop_fn):
+                        loop_fn(config)
+                    else:
+                        loop_fn()
             except StopIteration:
                 pass
             except BaseException:  # noqa: BLE001 - shipped to the driver
@@ -92,7 +96,7 @@ class RayTrainWorker:
             r = sess.reports[index]
             return {"status": "report", "metrics": r["metrics"],
                     "checkpoint": r["checkpoint"],
-                    "iteration": r["iteration"]}
+                    "iteration": r["iteration"], "ts": r["ts"]}
 
     def request_stop(self) -> bool:
         if self._session is not None:

@@ -9,10 +9,8 @@ events merged into one cluster timeline). Every plane calls
 and pays one cached-flag check, a tuple build, and a ring-slot store —
 no RPC, no allocation growth (the ring is preallocated and overwrites
 the oldest entry when full, counting what it dropped). A background
-flusher ships ring deltas — and any buffered tracing spans — to the
-conductor in batches, so NOTHING on the submit/execute/pull hot paths
-performs a synchronous conductor RPC (the pre-r10 ``tracing.flush``
-calls did exactly that and halved the task fast path when enabled).
+flusher ships ring deltas to the conductor in batches, so NOTHING on the
+submit/execute/pull hot paths performs a synchronous conductor RPC.
 Processes that already run a periodic conductor RPC (the node daemon's
 heartbeat) piggyback their delta on it via ``heartbeat_payload()``
 instead of paying a second connection.
@@ -25,6 +23,27 @@ Event shape (a plain tuple — cheapest thing that pickles):
 ``ident`` an optional correlation id (task id hex, object id hex),
 ``value`` a number whose meaning the kind fixes (latency seconds,
 bytes, window occupancy), ``attrs`` an optional small dict.
+
+A SPAN is the same tuple with a fixed reading (there is one span system,
+and it is this ring):
+
+    ts     start, by ``time.time()`` (the host's CLOCK_REALTIME, which is
+           also the clock a ``jax.profiler`` trace counts from: its
+           ``profile_start_time`` + an event's ``start_ns``)
+    value  duration in seconds, taken with ``time.perf_counter()``
+    ident  what every span of one request / one lease / one ``fit()`` shares
+    attrs  {"span": id, "parent": id or None, ...counts}
+
+``span(kind)`` is the context manager; the current span lives in a context
+variable, so a child finds its parent and ``ident`` untold.
+``span_record`` writes an interval that began on one thread and ended on
+another. ``current()`` is the context a task spec carries (``trace_ctx``);
+``adopt`` makes it current around the callee's execution, and
+``span(kind, ctx=...)`` opens a span as its child. In a process
+that has imported jax, ``span`` also enters
+``jax.profiler.TraceAnnotation("rt." + kind)``, so a traced run shows the
+runtime's spans beside the device's operations. ``last_session()`` holds the
+span records of the runtime this process last shut down.
 
 On top of the ring:
 
@@ -40,7 +59,10 @@ On top of the ring:
 
 from __future__ import annotations
 
+import contextvars
+import itertools
 import os
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -58,7 +80,10 @@ EVENT_KINDS: Dict[str, str] = {
     "task.exec": "value = execution seconds",
     "task.reply": "value = end-to-end seconds",
     "task.retry": "value = retries remaining",
-    "lease.grant": "value = lease latency seconds",
+    "task.execute": "span: value = seconds; a task whose spec carried a "
+                    "trace_ctx, parent = the caller's span",
+    "lease.grant": "span: value = seconds from the request to the grant "
+                   "(or to the actor alive); attrs carry TPU",
     "actor.window": "value = ordered-push window occupancy",
     "inline.seal": "value = sealed inline bytes",
     # rpc plane
@@ -91,12 +116,47 @@ EVENT_KINDS: Dict[str, str] = {
     "pipeline.stage.op": "value = stage op seconds",
     "pipeline.step": "value = step seconds",
     # serve ingress
-    "serve.request": "value = request seconds",
+    "serve.request": "span: value = request seconds, body parsed to "
+                     "last byte written; mints the request's ident; "
+                     "attrs carry code",
+    "serve.proxy.admit": "span: value = seconds queued for an ongoing slot",
+    "serve.proxy.thread_wait": "span: value = seconds from run_in_executor "
+                               "to the call's first line on a pool thread",
+    "serve.handle.slot_wait": "span: value = seconds in the handle's "
+                              "replica-slot loop",
+    "serve.handle.call": "span: value = seconds from submit to resolved; "
+                         "attrs carry retries",
+    "serve.replica.call": "span: value = seconds in handle_request; attrs "
+                          "carry inflight on entry",
+    "serve.batch.wait": "span: value = seconds from enqueue to the start "
+                        "of the flush that took it; attrs carry flush",
+    "serve.batch.reply": "span: value = seconds from fn's return to the "
+                         "last waiter woken; attrs carry p99_ms on the "
+                         "adaptive path",
     "serve.shed": "value unused; attrs carry reason",
     "serve.timeout": "value = deadline seconds",
     "serve.retry": "value = attempt ordinal",
     "serve.drain": "value = drained ongoing count",
-    "serve.batch.flush": "value = batch size; attrs carry window",
+    "serve.batch.flush": "span: value = seconds in the batched fn; attrs "
+                         "carry rows/max_batch_size/window_s/oldest_wait_s",
+    # trainer gang
+    "train.fit": "span: value = seconds of one fit(); mints the ident",
+    "train.backend.start": "span: value = seconds in BackendExecutor.start",
+    "train.gang.start": "span: value = seconds of placement + actor "
+                        "creation",
+    "train.loop": "span: value = seconds of the user's loop on one rank",
+    "train.report": "span: value = seconds in session.report; attrs carry "
+                    "iteration",
+    "train.pump": "span: value = seconds of one synchronized report "
+                  "round; attrs carry iteration/lag_s",
+    # start-up
+    "init": "span: value = seconds in rt.init()",
+    "init.probe": "span: value = seconds of the chip-probe subprocess; "
+                  "attrs carry chips/platform",
+    "worker.spawn": "span: value = seconds from Popen to registered; "
+                    "attrs carry chips",
+    "worker.boot": "span: value = seconds from main()'s first line to "
+                   "register_worker acknowledged",
     # infrastructure
     "fault.fired": "value unused; ident = site, attrs carry action",
     "lock.cycle": "value unused; attrs carry the lock cycle",
@@ -146,7 +206,7 @@ _scan_reported: set = set()
 
 def enabled() -> bool:
     """Cached flag read (config.get walks os.environ — too hot for a
-    per-event call; same pattern as tracing.enabled)."""
+    per-event call)."""
     global _enabled_gen, _enabled_v
     if _enabled_gen != config.generation:
         _refresh()
@@ -170,11 +230,167 @@ def emit(kind: str, ident: Optional[str] = None, value: float = 0.0,
     """Append one event to the ring. O(1), never blocks on I/O."""
     if not enabled():
         return
+    _store((time.time(), kind, ident, value, attrs))
+
+
+def _store(ev: tuple) -> None:
     global _seq
-    ev = (time.time(), kind, ident, value, attrs)
     with _lock:
         _buf[_seq % _cap] = ev
         _seq += 1
+
+
+# ----------------------------------------------------------------------
+# spans
+# ----------------------------------------------------------------------
+# Ids: a per-process nonce + counter (uuid4 draws urandom per call). A
+# forked worker draws a nonce of its own.
+_nonce = os.urandom(4).hex()
+_ids = itertools.count()
+
+
+def _renonce() -> None:
+    global _nonce
+    _nonce = os.urandom(4).hex()
+
+
+os.register_at_fork(after_in_child=_renonce)
+
+# (ident, span id) of the innermost open span of this thread or task.
+_current: "contextvars.ContextVar[Optional[Tuple[str, str]]]" = \
+    contextvars.ContextVar("span", default=None)
+_last_session: List[dict] = []
+
+
+def new_span_id() -> str:
+    return f"{_nonce}{next(_ids) & 0xFFFFFFFF:08x}"
+
+
+def current() -> Optional[dict]:
+    """The open span as the ``trace_ctx`` of a task spec, or None: a
+    submit outside any span attaches nothing."""
+    cur = _current.get()
+    return None if cur is None else {"ident": cur[0], "span": cur[1]}
+
+
+def _annotation(kind: str):
+    """``TraceAnnotation("rt.<kind>")`` where jax is already imported (the
+    proxy, daemon, conductor and driver never import it for this); with no
+    profiler session it costs a flag test."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        return jax.profiler.TraceAnnotation("rt." + kind)
+    except Exception:       # jax half imported on another thread
+        return None
+
+
+# span(kind, ctx=ROOT): a tree of its own, whatever span is open around it.
+ROOT = {"ident": None, "span": None}
+
+
+class span:
+    """``with span("serve.replica.call", inflight=3) as sp:`` times the
+    body and records it on exit, as the child of the span open around it.
+    ``sp.set(code=200)`` adds counts known only at the end."""
+
+    __slots__ = ("kind", "ident", "attrs", "id", "parent", "ts", "_t0",
+                 "_token", "_ann", "_ctx")
+
+    def __init__(self, kind: str, ident: Optional[str] = None,
+                 ctx: Optional[dict] = None, **attrs):
+        """``ctx``: a ``trace_ctx`` to be the child of (or ``ROOT``: of
+        none) in place of the span open around this one."""
+        self.kind, self.ident, self.attrs, self._ctx = kind, ident, attrs, ctx
+        self.id = self.parent = self._token = self._ann = None
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+
+    def __enter__(self) -> "span":
+        if not enabled():
+            return self
+        cur = _current.get() if self._ctx is None else \
+            (self._ctx["ident"], self._ctx["span"])
+        if cur is not None and cur[1] is not None:
+            self.parent = cur[1]
+            if self.ident is None:
+                self.ident = cur[0]
+        self.id = new_span_id()
+        if self.ident is None:
+            self.ident = self.id
+        self._token = _current.set((self.ident, self.id))
+        self.ts = time.time()
+        self._ann = _annotation(self.kind)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, etype, exc, tb) -> None:
+        if self._token is None:
+            return
+        duration = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
+        _current.reset(self._token)
+        self._token = None
+        attrs = {"span": self.id, "parent": self.parent, **self.attrs}
+        if exc is not None:
+            attrs["error"] = repr(exc)
+        _store((self.ts, self.kind, self.ident, duration, attrs))
+
+
+def span_record(kind: str, start: float, duration: float,
+                ident: Optional[str] = None, parent: Optional[str] = None,
+                **attrs) -> Optional[str]:
+    """Record an interval that began on one thread and ends on another
+    (the executor hop, the waiter woken by a flush): ``start`` by
+    ``time.time()``, ``duration`` by ``time.perf_counter()``. ``span=`` in
+    ``attrs`` is an id minted ahead with ``new_span_id()`` so that children
+    could name it before it ended. Returns the span's id."""
+    if not enabled():
+        return None
+    sid = attrs.pop("span", None) or new_span_id()
+    _store((start, kind, ident or sid, duration,
+            {"span": sid, "parent": parent, **attrs}))
+    return sid
+
+
+class adopt:
+    """Make a ``trace_ctx`` (``current()`` of the caller, carried by a task
+    spec or an RPC) the current span around the callee's execution, so that
+    what the callee records is its child. None adopts nothing."""
+
+    __slots__ = ("_ctx", "_token")
+
+    def __init__(self, ctx: Optional[dict]):
+        self._ctx, self._token = ctx, None
+
+    def __enter__(self) -> "adopt":
+        if self._ctx:
+            self._token = _current.set(
+                (self._ctx.get("ident"), self._ctx.get("span")))
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._token is not None:
+            _current.reset(self._token)
+            self._token = None
+
+
+def keep_session(records: List[dict]) -> None:
+    """``rt.shutdown()`` leaves the session's span records here."""
+    global _last_session
+    _last_session = list(records)
+
+
+def last_session() -> List[dict]:
+    """Span records (the conductor's dicts: node_id, pid, ts, kind, ident,
+    value, attrs) of the runtime this process last shut down: the run's
+    post-mortem, read after the cluster is gone."""
+    return list(_last_session)
 
 
 def snapshot(limit: int = 0) -> List[tuple]:
@@ -430,14 +646,17 @@ def _fold_metrics(evs: List[tuple], dropped: int) -> None:
         elif kind == "lock.long_hold":
             m.builtin(C, "rt_lock_long_holds_total").inc()
         elif kind == "serve.batch.flush":
-            # value = batch size; attrs carry the adaptive-window state.
+            # a span: attrs carry the batch's rows and the window in force
+            # (and the observed p99 on the adaptive path).
             a = attrs or {}
             m.builtin(H, "rt_serve_batch_size",
                       boundaries=[1, 2, 4, 8, 16, 32, 64, 128]
-                      ).observe(value)
-            if a.get("window_ms") is not None:
+                      ).observe(a.get("rows", 0))
+            if a.get("window_s") is not None:
                 m.builtin(m.Gauge, "rt_serve_batch_window_ms").set(
-                    a["window_ms"])
+                    a["window_s"] * 1000.0)
+        elif kind == "serve.batch.reply":
+            a = attrs or {}
             if a.get("p99_ms") is not None:
                 m.builtin(m.Gauge, "rt_serve_p99_ms").set(a["p99_ms"])
     if dropped:
@@ -490,8 +709,8 @@ def heartbeat_payload() -> Optional[dict]:
 
 
 def flush_now() -> None:
-    """One flush pass: ship the ring delta + any buffered tracing spans
-    to the conductor, fold metrics, sample probes."""
+    """One flush pass: ship the ring delta to the conductor, fold
+    metrics, sample probes."""
     global _unshipped, _unshipped_dropped
     addr = _conductor_addr
     if addr is None:
@@ -520,9 +739,6 @@ def flush_now() -> None:
                 _unshipped = merged[-keep:]
                 _unshipped_dropped += dropped + max(0, len(merged) - keep)
             raise
-    from ray_tpu.util import tracing
-    if tracing.enabled():
-        tracing.flush(cli)   # async replacement for the old inline flush
     _sample_probes()
     _check_slow_ops(cli)
 

@@ -37,39 +37,53 @@ def test_profiler_collect_local():
 
 @pytest.fixture()
 def traced_rt():
+    """A runtime with no flag set: the ring, and so its spans, are on by
+    default."""
     ray_tpu.shutdown()
-    ray_tpu.init(num_cpus=2, _system_config={"tracing_enabled": True})
+    ray_tpu.init(num_cpus=2)
     yield ray_tpu
     ray_tpu.shutdown()
 
 
 def test_spans_cover_task_lifecycle(traced_rt):
     from ray_tpu import state
+    from ray_tpu.util import events
 
     @ray_tpu.remote
     def traced_add(x):
         return x + 1
 
-    assert ray_tpu.get(traced_add.remote(41)) == 42
+    with events.span("test.job") as job:
+        assert ray_tpu.get(traced_add.remote(41)) == 42
     deadline = time.time() + 30
     spans = []
     while time.time() < deadline:
+        events.flush_now()
         spans = state.list_spans()
-        if {s["name"] for s in spans} >= {"task.submit", "task.execute"}:
+        if {s["kind"] for s in spans} >= {"test.job", "task.execute"}:
             break
         time.sleep(0.25)
-    names = {s["name"] for s in spans}
-    assert {"task.submit", "task.execute"} <= names, names
-    # execute joins the submit's trace as a child
-    sub = next(s for s in spans if s["name"] == "task.submit"
+    kinds = {s["kind"] for s in spans}
+    assert {"test.job", "task.execute"} <= kinds, kinds
+    # the worker's execute span joins the submitter's span as a child and
+    # shares its ident
+    sub = next(s for s in spans if s["kind"] == "test.job")
+    assert sub["attrs"]["span"] == job.id
+    exe = next(s for s in spans if s["kind"] == "task.execute"
                and "traced_add" in s["attrs"].get("task", ""))
-    exe = next(s for s in spans if s["name"] == "task.execute"
-               and s["trace_id"] == sub["trace_id"])
-    assert exe["parent_id"] == sub["span_id"]
-    assert exe["end"] >= exe["start"]
-    # filtered query narrows to one trace
-    only = state.list_spans(trace_id=sub["trace_id"])
-    assert all(s["trace_id"] == sub["trace_id"] for s in only)
+    assert exe["attrs"]["parent"] == job.id
+    assert exe["ident"] == sub["ident"] == job.ident
+    assert exe["pid"] != sub["pid"]
+    assert exe["value"] >= 0
+    assert sub["ts"] <= exe["ts"] <= sub["ts"] + sub["value"]
+    # the ring's own instants of the same task are joined by the task id
+    ring = state.list_ring_events(kind="task")
+    assert any(e["kind"] == "task.submit"
+               and e["ident"] == exe["attrs"]["task_id"] for e in ring)
+    # filtered query narrows to one ident
+    only = state.list_spans(ident=job.ident)
+    assert only and all(s["ident"] == job.ident for s in only)
+    assert len(only) < len(spans)     # init and the lease have their own
 
 
 def test_profile_worker_via_state_api(traced_rt):
@@ -108,12 +122,17 @@ def test_dashboard_spans_and_profile_endpoints(traced_rt):
     def dash_task():
         return 1
 
-    ray_tpu.get(dash_task.remote())
+    from ray_tpu.util import events
+    with events.span("test.dash"):
+        ray_tpu.get(dash_task.remote())
     rt = _global_runtime()
     dash = Dashboard(rt.conductor_address, port=0)
     try:
-        body = urllib.request.urlopen(
-            f"http://{dash.host}:{dash.port}/api/spans", timeout=10).read()
-        assert b"task.execute" in body or b"task.submit" in body
+        import json
+        spans = json.loads(urllib.request.urlopen(
+            f"http://{dash.host}:{dash.port}/api/spans", timeout=10).read())
+        # span records only (this process's are flushed by the endpoint)
+        assert spans and all("span" in s["attrs"] for s in spans)
+        assert "test.dash" in {s["kind"] for s in spans}
     finally:
         dash.stop()
