@@ -9,6 +9,8 @@ file of its own, found by the name in the manifest:
                                          ``benchmark/apps/<app>.py``
     <paths[0]>/metrics/<metric>.json     definition, unit, layer, moves
     <paths[0]>/metrics/<metric>.py       ``read(record, cell) -> number|None``
+                                         and, where it reads spans,
+                                         ``NEEDS``: their kinds
     benchmark/reference/<family>.py      the plain reference of the
                                          configuration's ``family``
 
@@ -22,6 +24,9 @@ import importlib.util
 import json
 import os
 import sys
+
+from benchmark import spans as spans_mod
+from benchmark.hermetic import log
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 END_TO_END_SOURCES = ("host_clock", "device_trace")
@@ -46,6 +51,7 @@ class Manifest:
         self.root = os.path.dirname(self.path)
         self.data = _json(self.path)
         self.home = os.path.join(self.root, self.data["paths"][0])
+        self._modules = {}         # metric -> its reader module, loaded
 
     def cell(self, name: str) -> dict:
         """One entry of ``workloads`` with its configuration's and its
@@ -71,7 +77,9 @@ class Manifest:
         return [m for m in self.data[kind]
                 if "workloads" not in m or cell_name in m["workloads"]]
 
-    def reader(self, metric: str):
+    def reader_module(self, metric: str):
+        if metric in self._modules:
+            return self._modules[metric]
         path = os.path.join(self.home, "metrics", metric + ".py")
         spec = importlib.util.spec_from_file_location(
             "bench_metric_" + metric.replace(".", "_").replace("-", "_"),
@@ -83,14 +91,42 @@ class Manifest:
         if metrics_dir not in sys.path:      # readers share ``_common.py``
             sys.path.insert(0, metrics_dir)
         spec.loader.exec_module(module)
-        return module.read
+        self._modules[metric] = module
+        return module
+
+    def reader(self, metric: str):
+        return self.reader_module(metric).read
+
+    def span_needs(self, cell_name: str) -> list:
+        """The span kinds this cell's per-layer readers read (``NEEDS``)."""
+        return list(dict.fromkeys(
+            kind for m in self.metrics("per_layer", cell_name)
+            for kind in getattr(self.reader_module(m["name"]), "NEEDS", ())))
 
     def read_metrics(self, kind: str, cell: dict, record: dict) -> dict:
         """name -> {"value", "unit"}; a reader that finds nothing to read
-        returns None and its metric is left out of the line."""
+        returns None and its metric is left out of the line, by name and
+        with the reader's reason on stderr."""
         out = {}
         for m in self.metrics(kind, cell["name"]):
-            value = self.reader(m["name"])(record, cell)
+            module = self.reader_module(m["name"])
+            value = module.read(record, cell)
             if value is not None:
                 out[m["name"]] = {"value": float(value), "unit": m["unit"]}
+                continue
+            try:
+                why = why_not_read(module, m, record)
+            except Exception as e:      # noqa: BLE001 - a reason, no metric
+                why = f"(and its reason could not be worked out: {e!r})"
+            log(f"metric {m['name']} not read: {why}")
         return out
+
+
+def why_not_read(module, metric: dict, record: dict) -> str:
+    """From what the reader says it reads: the span kinds in its ``NEEDS``,
+    or the trace for a ``device_trace`` metric."""
+    if hasattr(module, "NEEDS"):
+        return spans_mod.why_not(record, module.NEEDS)
+    if metric["source"] == "device_trace" and not record.get("trace"):
+        return "no trace: the run reduced no profile of the device"
+    return "its reader found nothing to read in the run's record"

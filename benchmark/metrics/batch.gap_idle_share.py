@@ -1,6 +1,8 @@
 from benchmark import spans as spans_mod
 from benchmark import trace as trace_mod
 
+NEEDS = ("serve.batch.flush",)
+
 
 def read(record, cell):
     spans = spans_mod.load(record, cell)

@@ -1,6 +1,8 @@
 from benchmark import spans as spans_mod
 from _common import median
 
+NEEDS = ("serve.batch.flush",)
+
 
 def read(record, cell):
     spans = spans_mod.load(record, cell)

@@ -1,5 +1,7 @@
 from benchmark import spans as spans_mod
 
+NEEDS = ("serve.batch.flush",)
+
 
 def read(record, cell):
     spans = spans_mod.load(record, cell)

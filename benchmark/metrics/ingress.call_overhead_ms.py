@@ -1,6 +1,8 @@
 from benchmark import spans as spans_mod
 from _common import median
 
+NEEDS = ("serve.request", "serve.handle.call", "serve.replica.call")
+
 
 def read(record, cell):
     spans = spans_mod.load(record, cell)

@@ -7,9 +7,12 @@ A new process per run: a private runtime directory under the caller's
 TPU lease through the cell's application (``benchmark/apps/<app>.py``:
 ``JaxTrainer(...).fit()`` or ``serve.run(...)``), weights made on the device
 from ``--seed``, the correctness check against the plain reference, warm-up
-of the cell's own shapes, the measured window, ``rt.shutdown()``, every
-child gone, then one JSON line on stdout. This process never opens a JAX
-backend.
+of the cell's own shapes, the measured window, in a traced run one read of
+the conductor's span records while the runtime is still up,
+``rt.shutdown()``, every child gone, then one JSON line on stdout. This
+process never opens a JAX backend. A metric of the cell whose reader found
+nothing to read is left out of the line and named on stderr with the
+reader's reason (``[bench] metric <name> not read: <why>``).
 
 No TPU, too few chips, a failed phase, a compile inside the window or a
 device kind without published peaks is a non-zero exit with no result line;
@@ -45,6 +48,7 @@ if CHECKOUT not in sys.path:
     sys.path.insert(0, CHECKOUT)
 
 from benchmark import hermetic, manifest as manifest_mod, ops  # noqa: E402
+from benchmark import spans as spans_mod                       # noqa: E402
 
 REHEARSAL_EXIT = 3
 DEADLINE_S = 1150          # a cold run may take 1200 s; a warm one 360 s
@@ -88,6 +92,7 @@ class RunContext:
         self.rt = None
         self.serve = None
         self.logs = ""
+        self.span_needs = []       # span kinds this cell's readers read
 
     def phase(self, name: str) -> None:
         self.current_phase = name
@@ -117,7 +122,12 @@ class RunContext:
                 f"{chips} (jax found no accelerator, or too few)")
 
     def teardown(self) -> None:
-        """Runtime down, in order; what is left is killed by the caller."""
+        """Runtime down, in order; what is left is killed by the caller.
+        A traced run first reads the conductor's span records with the
+        runtime still up: the readers' session does not hang on what the
+        last flush of a process that is killed next shipped."""
+        if self.rt is not None and self.trace:
+            spans_mod.keep_before_teardown(self.span_needs)
         if self.serve is not None:
             try:
                 self.serve.shutdown()
@@ -258,6 +268,8 @@ def main(argv=None) -> int:
         run.phase("manifest")
         mf = manifest_mod.Manifest(args.manifest)
         cell = run.cell = mf.cell(args.workload)
+        if args.trace:
+            run.span_needs = mf.span_needs(cell["name"])
         if args.seconds is None:
             args.seconds = float(mf.data["run_seconds"])
         run.seconds = args.seconds

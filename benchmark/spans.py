@@ -12,7 +12,14 @@ the spans' timeline.
 
 A program without spans (the parent of the PR that added them), or a run
 without a trace, gives the readers nothing to read: every function here
-then returns None or an empty list, and the metric is left out of the line.
+then returns None or an empty list, and the metric is left out of the line;
+``why_not`` says on stderr which record was missing.
+
+A span of a process that is killed is only as safe as its last flush: the
+trainer's worker stores ``train.loop`` as its loop ends, milliseconds before
+``fit()`` kills it. So the harness also reads the conductor's records once
+with the runtime still up (``keep_before_teardown``), and a session is what
+``rt.shutdown()`` left plus whatever only that earlier read holds.
 
 The first reader to ask for a run's spans also writes what an engineer
 wants to see of them to stderr and to ``benchmark/out/spans-<cell>.json``:
@@ -29,6 +36,7 @@ import math
 import os
 import statistics
 import tempfile
+import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from benchmark import trace as trace_mod
@@ -41,20 +49,114 @@ SETUP_KINDS = ("init", "init.probe", "lease.grant", "worker.spawn",
                "train.gang.start", "train.loop")
 Interval = Tuple[float, float]
 
+KEEP_WAIT_S = 2.0                  # for a push that was in flight
+HELD_CHARS = 600                   # of a session's kinds and pids, in a line
+
 _summarised: set = set()           # cells whose summary this process wrote
+_kept: List[dict] = []             # read with the runtime still up
+
+
+def keep_before_teardown(needs: Sequence[str],
+                         wait_s: float = KEEP_WAIT_S) -> None:
+    """With the runtime still up (after ``fit()``, before
+    ``serve.shutdown()`` and ``rt.shutdown()``): ship this process's own
+    tail, read the conductor's span records, and where a kind of ``needs``
+    (what the cell's readers read) is not there yet, read again for at most
+    ``wait_s`` seconds. What was read is kept for ``session()``. Outside
+    the window and outside ``setup_s``; a program without spans, or a read
+    that fails, keeps nothing and says so."""
+    try:
+        from ray_tpu.state import api as state
+        from ray_tpu.util import events
+    except ImportError:
+        return
+    if not hasattr(events, "last_session"):
+        return
+    deadline = time.monotonic() + wait_s
+    while True:
+        try:
+            events.flush_now()
+            records = state.list_spans()
+        except Exception as e:          # noqa: BLE001 - said, not raised
+            log(f"spans: the conductor's records could not be read before "
+                f"teardown: {e!r}")
+            return
+        lacking = [k for k in needs if not of_kind(records, k)]
+        if not lacking or time.monotonic() >= deadline:
+            break
+        time.sleep(0.1)
+    _kept[:] = records
+    found = ", ".join(
+        f"{k} x{len(of_kind(records, k))} "
+        f"{sorted({str(r['ident']) for r in of_kind(records, k)})[:2]}"
+        for k in needs)
+    log(f"spans: before teardown the conductor holds {len(records)} span "
+        f"records; of what this cell's readers need: {found or 'nothing'}"
+        + (f"; NOT THERE after {wait_s:g}s: {', '.join(lacking)}; it "
+           f"holds {held(records)}" if lacking else ""))
+    if lacking:
+        # a record is lost with a process that died unflushed: what the
+        # cluster says it saw die, and when
+        try:
+            for e in [e for e in state.list_cluster_events()
+                      if e["severity"] != "INFO"][-8:]:
+                log(f"spans:   cluster event at {e['timestamp']:.3f} "
+                    f"{e['event_type']}: {e['message']}")
+        except Exception as e:          # noqa: BLE001 - said, not raised
+            log(f"spans:   cluster events could not be read: {e!r}")
 
 
 def session() -> Optional[List[dict]]:
     """The span records of the runtime this process last shut down (the
-    conductor's dicts: node_id, pid, ts, kind, ident, value, attrs); None
+    conductor's dicts: node_id, pid, ts, kind, ident, value, attrs), and
+    behind them the records that only the read before teardown holds; None
     where the program keeps none."""
     try:
         from ray_tpu.util import events
     except ImportError:
         return None
     last = getattr(events, "last_session", None)
-    spans = last() if last is not None else None
+    spans = list(last() or ()) if last is not None else []
+    if _kept:
+        have = {s["attrs"]["span"] for s in spans}
+        spans += [s for s in _kept if s["attrs"]["span"] not in have]
     return spans or None
+
+
+def held(spans: Iterable[dict]) -> str:
+    """``pid 12: train.fit x1, train.pump x28; pid 40: ...``: which kinds
+    of which processes a session holds, for a reader that misses one."""
+    by_pid: Dict[int, Dict[str, int]] = {}
+    for s in spans:
+        kinds = by_pid.setdefault(s["pid"], {})
+        kinds[s["kind"]] = kinds.get(s["kind"], 0) + 1
+    text = "; ".join(
+        f"pid {pid}: " + ", ".join(f"{k} x{n}" for k, n in sorted(ks.items()))
+        for pid, ks in sorted(by_pid.items()))
+    return text[:HELD_CHARS] + ("..." if len(text) > HELD_CHARS else "")
+
+
+def why_not(record: dict, needs: Sequence[str]) -> str:
+    """Why a reader of span kinds ``needs`` had nothing to read."""
+    spans = session()
+    if spans is None:
+        return ("no session kept: the program left no span records "
+                "(events.last_session())")
+    platform = (record.get("facts") or {}).get("platform")
+    if platform != "tpu":
+        return (f"not a TPU run (platform {platform!r}): host times beside "
+                "another backend are not this metric")
+    lacking = [k for k in needs if not of_kind(spans, k)]
+    if lacking:
+        return (f"the session holds no {', '.join(lacking)}; it holds "
+                f"{held(spans)}")
+    inside = in_window(record, spans)
+    counts = ", ".join(f"{k} x{len(of_kind(spans, k))} "
+                       f"({len(of_kind(inside, k))} began inside the window)"
+                       for k in needs)
+    return (f"the session holds {counts or 'spans'}, but not as the reader "
+            "needs them (ident, rank, counter, window, or a trace beside "
+            f"them); it holds {held(spans)}")
 
 
 def load(record: dict, cell: dict) -> Optional[List[dict]]:

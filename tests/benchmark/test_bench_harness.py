@@ -215,6 +215,32 @@ def test_soak_run_after_a_killed_one_is_clean_and_traced(soak):
     assert line["device"]["count"] == 4      # four virtual CPU devices
 
 
+def test_soak_traced_run_reads_the_session_before_teardown(soak):
+    """With the runtime still up the harness reads the conductor's span
+    records: rank 0's ``train.loop`` is there, under ``train.fit``'s ident,
+    before ``rt.shutdown()`` reads anything. The metrics that it feeds are
+    left out of a rehearsal's line, each by name and with the reason."""
+    import re
+    err = soak["traced"].stderr
+    said = [ln for ln in err.splitlines()
+            if "before teardown the conductor holds" in ln]
+    assert len(said) == 1, err[-3000:]
+    assert err.index(said[0]) < err.index("[bench] phase metrics")
+    fit = re.search(r"train\.fit x1 \['(\w+)'\]", said[0])
+    loop = re.search(r"train\.loop x1 \['(\w+)'\]", said[0])
+    assert fit and loop and fit[1] == loop[1], said[0]
+    # a rehearsal probes no chip; nothing else the readers need is missing
+    assert re.findall(r"NOT THERE after \S+: ([^;]+);", said[0]) == \
+        ["init.probe"], said[0]
+    for name in ("trainer.start_s", "trainer.report_ms", "init.probe_s",
+                 "lease.spawn_s"):
+        assert f"[bench] metric {name} not read: not a TPU run" in err
+    assert "[bench] metric flash_roofline not read: no trace" in err
+    # an untraced run reads nothing early and leaves nothing out
+    assert "before teardown" not in soak["train0"].stderr
+    assert "not read" not in soak["train0"].stderr
+
+
 def test_soak_killed_run_leaves_no_child_and_no_directory(soak):
     assert soak["victim_left"] == []
     assert soak["stale_left"] == [], "a dead run's own record is swept"

@@ -327,3 +327,203 @@ def test_clock_residual_on_a_fresh_trace(tmp_path):
 
 def test_checkout_constant():
     assert spans_mod.CHECKOUT == CHECKOUT
+
+
+# ----------------------------------------------------------------------
+# a metric that is left out says so (PR 28)
+# ----------------------------------------------------------------------
+# metric -> the one span kind taken out of an otherwise whole session
+LACKS = [("ingress.admit_wait_ms", "serve.proxy.admit"),
+         ("ingress.thread_wait_ms", "serve.proxy.thread_wait"),
+         ("ingress.slot_wait_ms", "serve.handle.slot_wait"),
+         ("ingress.call_overhead_ms", "serve.replica.call"),
+         ("batch.flush_wait_ms", "serve.batch.wait"),
+         ("batch.rows_share", "serve.batch.flush"),
+         ("batch.gap_ms", "serve.batch.flush"),
+         ("batch.gap_idle_share", "serve.batch.flush"),
+         ("batch.call_max_over_median", "serve.batch.flush"),
+         ("init.probe_s", "init.probe"),
+         ("lease.spawn_s", "worker.spawn"),
+         ("trainer.start_s", "train.fit"),
+         ("trainer.report_ms", "train.report")]
+
+
+def only(monkeypatch, name):
+    """The manifest as if ``name`` were the cell's one per-layer metric:
+    ``read_metrics`` is the harness's own path from reader to line."""
+    entry = next(m for m in MF.data["per_layer"] if m["name"] == name)
+    monkeypatch.setattr(MF, "metrics", lambda kind, cell_name: [entry])
+    cell = SERVE if name.startswith(("ingress.", "batch.")) else TRAIN
+    whole = serve_spans() if cell is SERVE else train_spans()
+    record = serve_record() if cell is SERVE else train_record()
+    return cell, record, whole
+
+
+def test_every_span_reader_says_which_kinds_it_reads():
+    assert sorted(name for name, _ in LACKS) == sorted(NEW)
+    for name, kind in LACKS:
+        assert kind in MF.reader_module(name).NEEDS
+    needs = MF.span_needs(TRAIN["name"])
+    assert {"train.fit", "train.loop", "train.report", "init.probe",
+            "worker.spawn"} <= set(needs)
+    assert not any(k.startswith("serve.") for k in needs)
+    assert "serve.batch.flush" in MF.span_needs(SERVE["name"])
+
+
+@pytest.mark.parametrize("name,kind", LACKS)
+def test_a_metric_whose_record_is_missing_is_named_with_the_kind(
+        session, monkeypatch, capsys, name, kind):
+    cell, record, whole = only(monkeypatch, name)
+    session([s for s in whole if s["kind"] != kind])
+    assert MF.read_metrics("per_layer", cell, record) == {}
+    said = [ln for ln in capsys.readouterr().err.splitlines()
+            if ln.startswith(f"[bench] metric {name} not read: ")]
+    assert len(said) == 1, said
+    assert f"the session holds no {kind}" in said[0]
+    assert "it holds pid " in said[0]          # what the session does hold
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_a_program_without_spans_gets_its_line_and_the_reason(
+        session, monkeypatch, capsys, name):
+    """PR 25's parent case: the metric is left out of the line as before;
+    stderr now says why."""
+    cell, record, _ = only(monkeypatch, name)
+    session([])
+    assert MF.read_metrics("per_layer", cell, record) == {}
+    assert f"[bench] metric {name} not read: no session kept" in \
+        capsys.readouterr().err
+
+
+def test_a_metric_that_is_read_says_nothing(session, monkeypatch, capsys):
+    cell, record, whole = only(monkeypatch, "trainer.report_ms")
+    session(whole)
+    line = MF.read_metrics("per_layer", cell, record)
+    assert line == {"trainer.report_ms": {
+        "value": pytest.approx(0.03), "unit": "ms"}}
+    assert "not read" not in capsys.readouterr().err
+
+
+def test_in_window_and_rehearsal_reasons(session, monkeypatch, capsys):
+    cell, record, whole = only(monkeypatch, "trainer.report_ms")
+    session([s for s in whole
+             if s["kind"] != "train.report" or s["ts"] < 200.0])
+    assert MF.read_metrics("per_layer", cell, record) == {}
+    assert "train.report x1 (0 began inside the window)" in \
+        capsys.readouterr().err
+    session(whole)
+    record["facts"] = {"platform": "cpu"}
+    assert MF.read_metrics("per_layer", cell, record) == {}
+    assert "not read: not a TPU run (platform 'cpu')" in \
+        capsys.readouterr().err
+
+
+def test_a_trace_metric_without_a_trace_says_so(monkeypatch, capsys):
+    entry = next(m for m in MF.data["per_layer"]
+                 if m["name"] == "flash_roofline")
+    monkeypatch.setattr(MF, "metrics", lambda kind, cell_name: [entry])
+    record = dict(train_record(), trace={})
+    assert MF.read_metrics("per_layer", TRAIN, record) == {}
+    assert "[bench] metric flash_roofline not read: no trace" in \
+        capsys.readouterr().err
+
+
+def test_trainer_start_falls_back_to_the_loops_own_stamp(
+        session, monkeypatch, capsys):
+    cell, record, whole = only(monkeypatch, "trainer.start_s")
+    record["stamps"] = {"entry": 156.5001, "called": 150.0}
+    # both records: the span's value, and not a word
+    session(whole)
+    line = MF.read_metrics("per_layer", cell, record)
+    assert line["trainer.start_s"]["value"] == pytest.approx(6.5)
+    err = capsys.readouterr().err
+    assert "FALLBACK" not in err and "not read" not in err
+    # the worker's tail died with it: train.fit and no train.loop of rank 0
+    # with its ident (rank 1's, and an older fit's, do not stand in)
+    session([s for s in whole if not (
+        s["kind"] == "train.loop" and s["ident"] == "fit"
+        and s["attrs"]["rank"] == 0)])
+    line = MF.read_metrics("per_layer", cell, record)
+    assert line["trainer.start_s"]["value"] == pytest.approx(6.5001)
+    err = capsys.readouterr().err
+    assert "[bench] metric trainer.start_s: FALLBACK to the loop's own " \
+        "first-line stamp" in err
+    assert "train.fit fit but no train.loop of rank 0" in err
+    assert "not read" not in err
+    # and a record without that stamp has nothing to fall back to
+    del record["stamps"]
+    assert MF.read_metrics("per_layer", cell, record) == {}
+    assert "metric trainer.start_s not read" in capsys.readouterr().err
+
+
+@pytest.fixture()
+def conductor(monkeypatch):
+    """What the conductor would answer ``state.list_spans()`` with the
+    runtime up; the driver's own flush is counted, not made."""
+    from ray_tpu.state import api as state
+    held = {"records": [], "flushes": 0}
+
+    def flush_now():
+        held["flushes"] += 1
+    monkeypatch.setattr(events, "flush_now", flush_now)
+    monkeypatch.setattr(state, "list_spans", lambda: list(held["records"]))
+    monkeypatch.setattr(state, "list_cluster_events", lambda: [
+        {"severity": "INFO", "timestamp": 1.0, "event_type": "NODE_ADDED",
+         "message": "node up"},
+        {"severity": "WARNING", "timestamp": 160.25,
+         "event_type": "NODE_DEAD",
+         "message": "node 2106ba20 marked dead: health check timed out"}])
+    yield held
+    spans_mod._kept.clear()
+
+
+def test_session_is_the_shutdown_read_plus_what_only_the_early_read_holds(
+        session, conductor, capsys):
+    whole = train_spans()
+    loop0 = next(s for s in whole if s["kind"] == "train.loop"
+                 and s["ident"] == "fit" and s["attrs"]["rank"] == 0)
+    conductor["records"] = whole
+    spans_mod.keep_before_teardown(["train.fit", "train.loop"])
+    assert conductor["flushes"] == 1
+    err = capsys.readouterr().err
+    assert "before teardown the conductor holds 15 span records" in err
+    assert "train.fit x1 ['fit']" in err and "NOT THERE" not in err
+    # rt.shutdown()'s read lost one record and gained a later one
+    late = span("train.pump", 212.0, 0.1, "fit")
+    session([s for s in whole if s is not loop0] + [late])
+    got = spans_mod.session()
+    assert len(got) == 16 and got[-1] is loop0 and got[-2] is late
+    assert read("trainer.start_s", train_record(), TRAIN) == \
+        pytest.approx(6.5)
+    # the same records twice are one session
+    session(whole)
+    assert len(spans_mod.session()) == 15
+
+
+def test_the_early_read_waits_a_bounded_time_for_a_kind_and_says_so(
+        session, conductor, capsys):
+    conductor["records"] = [s for s in train_spans()
+                            if s["kind"] != "train.loop"]
+    t0 = time.monotonic()
+    spans_mod.keep_before_teardown(["train.fit", "train.loop"], wait_s=0.3)
+    assert 0.3 <= time.monotonic() - t0 < 2.0
+    assert conductor["flushes"] >= 2
+    err = capsys.readouterr().err
+    assert "NOT THERE after 0.3s: train.loop; it holds pid 1: " in err
+    # what the cluster saw die, and when; nothing of the ordinary
+    assert "cluster event at 160.250 NODE_DEAD: node 2106ba20 marked " \
+        "dead: health check timed out" in err
+    assert "NODE_ADDED" not in err
+    assert len(spans_mod._kept) == 12
+
+
+def test_the_early_read_of_a_failing_conductor_keeps_nothing(
+        session, conductor, monkeypatch, capsys):
+    from ray_tpu.state import api as state
+
+    def down():
+        raise RuntimeError("conductor gone")
+    monkeypatch.setattr(state, "list_spans", down)
+    spans_mod.keep_before_teardown(["train.fit"])
+    assert spans_mod._kept == []
+    assert "could not be read before teardown" in capsys.readouterr().err
