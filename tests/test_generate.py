@@ -81,3 +81,125 @@ def test_gqa_and_moe_decode():
     want = _naive_greedy(params, prompt, infer_cfg, 6)
     got = generate(params, prompt, cfg, max_new_tokens=6, temperature=0.0)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# --- the cache is the decode loop's carry, updated in place -----------------
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for v in (value if isinstance(value, (tuple, list)) else (value,)):
+            v = getattr(v, "jaxpr", v)          # ClosedJaxpr -> Jaxpr
+            if hasattr(v, "eqns"):
+                yield v
+
+
+def _scanned_over(jaxpr, shape, inside_scan=False):
+    """Every (direction, aval) of that shape which a scan NESTED in another
+    scan takes as a scanned input (xs) or gives as a scanned output (ys)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        is_scan = eqn.primitive.name == "scan"
+        if is_scan and inside_scan:
+            first_x = eqn.params["num_consts"] + eqn.params["num_carry"]
+            found += [("xs", v.aval) for v in eqn.invars[first_x:]
+                      if v.aval.shape == shape]
+            found += [("ys", v.aval)
+                      for v in eqn.outvars[eqn.params["num_carry"]:]
+                      if v.aval.shape == shape]
+        for sub in _sub_jaxprs(eqn):
+            found += _scanned_over(sub, shape, inside_scan or is_scan)
+    return found
+
+
+def test_decode_loop_carries_the_cache():
+    """Platform-independent statement of "in place": inside the token loop
+    no layer scan takes or re-emits the stacked cache (a scanned input and
+    a scanned output cannot alias, so XLA would copy the stack a token).
+    The prefill's own stacked outputs, at the top level, are allowed."""
+    from functools import partial
+
+    cfg = _cfg()
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    prompt = jnp.zeros((2, 7), jnp.int32)
+    jaxpr = jax.make_jaxpr(partial(generate, cfg=cfg, max_new_tokens=5))(
+        params, prompt)
+    cache_shape = (cfg.n_layers, 2, 12, cfg.kv_heads, cfg.head_dim)
+    # the walker sees the cache at all: prefill stacks it at the top level
+    top = [v.aval.shape for eqn in jaxpr.jaxpr.eqns
+           if eqn.primitive.name == "scan" for v in eqn.outvars]
+    assert top.count(cache_shape) >= 2
+    assert _scanned_over(jaxpr.jaxpr, cache_shape) == []
+
+
+def test_decode_step_is_functional():
+    """decode_step jitted on its own, twice on the SAME un-donated cache:
+    equal logits, and the argument's contents are left as they were."""
+    from functools import partial
+
+    from ray_tpu.models.generate import decode_step
+
+    cfg = _cfg()
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    prompt = jax.random.randint(jax.random.PRNGKey(5), (2, 6), 0, 97)
+    _, cache = prefill(params, prompt, cfg, max_len=12)
+    before = jax.tree.map(np.array, cache)
+    step = jax.jit(partial(decode_step, cfg=cfg))
+    token, pos = jnp.asarray([3, 11], jnp.int32), jnp.asarray(6, jnp.int32)
+    logits_a, cache_a = step(params, token, pos, cache)
+    logits_b, cache_b = step(params, token, pos, cache)
+    np.testing.assert_array_equal(np.asarray(logits_a), np.asarray(logits_b))
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(cache[name]), before[name])
+        np.testing.assert_array_equal(np.asarray(cache_a[name]),
+                                      np.asarray(cache_b[name]))
+        # the step wrote position 6 of every layer, and nothing else
+        changed = np.asarray(cache_a[name]) != before[name]
+        assert changed[:, :, 6].any(axis=(1, 2, 3)).all()
+        changed[:, :, 6] = False
+        assert not changed.any()
+
+
+def test_decoded_cache_matches_prefill_of_longer_sequence():
+    """prefill + k decode_steps leaves, in every layer, rows <= pos equal to
+    the cache of a prefill over the longer sequence, and rows > pos zero."""
+    from ray_tpu.models.generate import decode_step
+
+    cfg = _cfg()
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    s, k, max_len = 5, 4, 14
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (2, s + k), 0, 97)
+    _, cache = prefill(params, tokens[:, :s], cfg, max_len=max_len)
+    for j in range(k):
+        _, cache = decode_step(params, tokens[:, s + j],
+                               jnp.asarray(s + j, jnp.int32), cache, cfg)
+    _, want = prefill(params, tokens, cfg, max_len=max_len)
+    for name in ("k", "v"):
+        got = np.asarray(cache[name])
+        assert got.shape == (3, 2, max_len, 2, 16)
+        np.testing.assert_allclose(got[:, :, :s + k],
+                                   np.asarray(want[name])[:, :, :s + k],
+                                   rtol=2e-5, atol=2e-5)
+        assert np.abs(got[:, :, :s + k]).max() > 0
+        assert not got[:, :, s + k:].any()
+
+
+@pytest.mark.parametrize("overrides, prompt_len, new_tokens", [
+    pytest.param(dict(n_kv_heads=4), 6, 7, id="dense"),
+    pytest.param(dict(n_kv_heads=1), 6, 7, id="gqa"),
+    pytest.param(dict(num_experts=4, expert_top_k=2), 6, 7, id="moe"),
+    pytest.param(dict(tied_embeddings=True), 6, 7, id="tied"),
+    # a cache shorter than the block of positions a decode step writes
+    pytest.param(dict(), 2, 4, id="short-cache"),
+])
+def test_greedy_tokens_pinned_to_reforward(overrides, prompt_len, new_tokens):
+    import dataclasses
+
+    cfg = _cfg(**overrides)
+    params = transformer_init(jax.random.PRNGKey(7), cfg)
+    prompt = jax.random.randint(jax.random.PRNGKey(8), (2, prompt_len), 0, 97)
+    naive_cfg = (dataclasses.replace(cfg, moe_capacity_factor=1e9)
+                 if cfg.num_experts else cfg)     # inference is dropless
+    want = _naive_greedy(params, prompt, naive_cfg, new_tokens)
+    got = generate(params, prompt, cfg, max_new_tokens=new_tokens,
+                   temperature=0.0)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
