@@ -86,6 +86,15 @@ def program_rms_norm_eps(cfg) -> float:
     return float(eps)
 
 
+def over_their_limits(judged: dict, says: dict) -> list:
+    """``judged``: ``{name: [value, limit]}`` -> one reason for every value
+    over its limit (a value that is not a number is over): the check's
+    name, what it says, the number and the limit."""
+    return [f"{name}: {says[name]}: {value:.6g} is over the limit "
+            f"{limit:.6g}" for name, (value, limit) in judged.items()
+            if not value <= limit]       # NaN compares false
+
+
 def reference_module(config: dict):
     return importlib.import_module(
         "benchmark.reference." + config["family"])

@@ -4,8 +4,10 @@
 the replica with ``num_tpus=1``, waits for it, has it check itself against
 the plain reference, sends a warm-up round and then the window's traffic
 through the HTTP proxy with ``benchmark.loadgen``, asks the replica for its
-rows, and last has it hold the tokens its compiled ``generate`` returned in
-the warm-up round against the reference. The replica is what a user would
+rows, and last has it hold the tokens its compiled ``generate`` served two
+of the window's requests against the reference, and measure what bfloat16
+rounding alone does to this seed's model, the floor the logits' error is
+judged by. The replica is what a user would
 write: weights made on the device from the seed in the served dtype, a
 greedy ``generate`` of one shape compiled once, and a handler under
 ``@serve.batch`` that pads a short batch to the full rows.
@@ -25,46 +27,71 @@ TRACE_BATCHES = 3          # window's 3rd batch to the end of its 5th:
 #                            3 executions in the trace = 2 whole periods
 DUMP_PATIENCE_S = 120      # a request the proxy gave up on may still be in
 #                            the replica and hold the dump at its cap
-CHECK_ROWS = 2             # self-check: rows, and decoded positions after
-CHECK_DECODED = 15         # the prefill: 2 * 16 * vocab ~ 1e6 logits
-# Error of prefill-then-decode logits against the reference's full forward,
-# as shares of the reference logits' own standard deviation (~1.28 with
-# these random weights). The weights are the same bfloat16 values on both
-# sides, so what is judged is the system's bfloat16 arithmetic through 24
-# layers and its cache.
+CHECK_ROWS = 2             # rows whose logits are compared before the window
+CHECK_DECODED = 15         # (this many decoded positions after the prefill:
+#                            2 * 16 * vocab ~ 1e6 logits), and requests of the
+#                            window whose every served token is held to the
+#                            reference after it (2 * 128 tokens)
+# What is judged, and where each limit comes from: the sweep kept in
+# benchmark/testdata/serve_checks_sweep.json, made by sweep_serve.py beside
+# it (PR 34, on a TPU v5e: 64 seeds, 0-31 and 32 of the driver's size, the
+# weights made from each as a run makes them, and on each the control, int8
+# weights through the reference; on 12 of them, the most and the least
+# sensitive included, the faults planted in the program: int8 weights, a
+# cache written one position late, a sampler's second-best token and a
+# served token altered to another id).
+# tests/benchmark/test_bench_reference.py holds the limits below to that
+# file and runs ``judge`` over every one of its rows.
 #
-# rms: 0.0154 .. 0.0218 over the 9 seeds tried on the chip (PR 24). Judged
-# at 0.04: 1.8x the worst seen. bfloat16 rounds each activation to 2**-9
-# relative (0.11% rms); int8 weights (absmax per channel, normal weights:
-# step 4 sigma / 127, rms error 0.9% of sigma) perturb every product ~8x more
-# and fp8-e4m3 (3 mantissa bits, 3.6%) ~30x more, which adds in quadrature
-# to ~0.12 and ~0.5 of std: both fail, with room.
-RMS_TOLERANCE = 0.04
-# max over ~1e6 logits: wanders from seed to seed (0.090 .. 0.139 of std
-# over the same seeds; the tail of a million near-normal errors sits ~5 sigma
-# out and one outlier moves it). Kept as a loose guard against a single
-# wrong position or row, which moves one logit by O(1) std: 0.3 is 2.2x the
-# worst seen.
-MAX_TOLERANCE = 0.3
-# The logits above come from ``prefill`` and ``decode_step`` jitted on their
-# own; what the callers get is the compiled ``generate``'s tokens. Two
-# warm-up replies' first CHECK_DECODED + 1 tokens are held against the
-# reference's logits at the positions that predicted them (teacher-forced
-# on the system's own tokens): each must be the reference's argmax or lie
-# within this share of std under it. The system's choice departs from the
-# argmax only where the reference's top two lie closer than the difference
-# of the system's two errors, at most 2 x 0.139 of std by the maxima above
-# and ~0.05 typically; a token from a wrong loop, cache update or sampler
-# lies ~4 std under.
+# Before the window ``prefill`` and ``decode_step`` run CHECK_ROWS seeded
+# rows, and their logits are compared with the plain reference's full
+# forward over the same bfloat16 weights: r = rms of the error, as a share
+# of the reference logits' own standard deviation (~1.28 with these random
+# weights). How far a 2**-9 rounding is amplified through 24 layers differs
+# from one seed's random model to the next: r read 0.0138 .. 0.0518 over
+# the sweep, and the control 0.0355 on the least sensitive seed, so no
+# fixed limit on r tells the two apart on every seed. After the window the
+# reference runs once more over the same tokens with its own activations
+# rounded to the type the configuration's file states (``torch_dtype``,
+# bfloat16; ``forward(dtype=...)``): f, what rounding alone does to this
+# seed's model (0.0094 .. 0.0348, correlation with r 0.998). The type is
+# the file's and never the program's own ``cfg.dtype``, which is held to it
+# exactly (``compute_dtype_not_as_configured``): a program that computes in
+# fewer bits raises r and not f.
+# Judged is r / f: 1.409 .. 1.542 over the 64 seeds (standard deviation
+# 0.028). The limit is 1.5x the worst. Over it on every seed tried: the
+# control 3.71 .. 4.79 on all 64 (mean 4.06, standard deviation 0.27: the
+# limit lies 6.6 of them under the mean), int8 weights through the program
+# 4.56 .. 6.26, the cache written one position late 3.27 .. 6.64 (one key
+# and value of more than 512 missing: a mild fault at these lengths).
+RMS_OVER_FLOOR = 2.32
+# The callers get the compiled ``generate``'s tokens. CHECK_ROWS requests
+# that the window finished, drawn from the seed, are run through the
+# reference, prompt and served tokens, 2 x 128 positions: each served token
+# is the reference's best at its position, or its logit lies this share of
+# std under the best (the system's rounding decides a near-tie the other
+# way). The widest such gap read 0 .. 0.151 over the sweep (median 0.040;
+# its tail falls off by e every ~0.03). The limit is twice the worst: at
+# 1.5x, 0.227, the tail would put about one run in 700 over it, and a check
+# makes dozens of runs. It is there for the timed path's gross faults: a
+# served token altered to another id reads 3.2 .. 6.5, a wrong loop or
+# sampler more. It does not tell int8 from bfloat16 (the control's own
+# first token lies 0.048 .. 0.64 under; r / f does that), nor a
+# second-best token at one step (the top-two gap there, 0.07 .. 1.1).
 TOKEN_TOLERANCE = 0.3
-# rms distance between the reference at the published epsilon (1e-5) and at
-# the one the program runs (fixed at 1e-6), as a share of std: the known
-# difference, held apart from the arithmetic above so that it cannot hide in
-# it. Read 0.0368 .. 0.0475 on the chip (PR 24, 4 seeds) and 0.0434, 0.0564
-# on the CPU (2 seeds, other tokens): 0.1 is 1.8x the worst. A program that
-# takes the configuration's epsilon reads exactly 0, and the next benchmark
-# PR sets this to 0.
-EPS_GAP_TOLERANCE = 0.1
+# The program's RMSNorm epsilon is the configuration's published one or
+# this, the one known departure (ROADMAP.md D0: ``models/transformer.py``
+# fixes 1e-6 where the published models say 1e-5); any third value is not
+# correct. How far the two move the reference's logits is reported
+# (``program_eps_gap``, 0.027 .. 0.113 over the sweep) and not judged: both
+# sides of it are the benchmark's own code. When D0 lands, this constant
+# goes.
+KNOWN_PROGRAM_EPS = 1e-6
+# Reported with the checks and not judged: the largest single error over r
+# (``max_over_std`` / ``rms_over_std``) read 5.2 .. 9.4 clean, 5.1 .. 10.1
+# under the control and 7.9 .. 15.9 with the late cache write: a limit at
+# 1.5x the clean runs' worst would pass every run of the control, so it
+# could only fail sound runs.
 
 
 def make_replica(max_batch_size: int, batch_wait_timeout_s: float):
@@ -110,12 +137,29 @@ def make_replica(max_batch_size: int, batch_wait_timeout_s: float):
             self.count_lock = threading.Lock()
             self.reduced, self.marks, self.stopper = {}, None, None
 
-        def selfcheck(self) -> dict:
-            """Prefill through the cache, then further decoded positions,
-            against the plain reference's full forward, on logits."""
+        def program_logits(self, params, tokens):
+            """``prefill`` over the prompt and ``decode_step`` over the
+            positions after it, through the cache -> logits
+            [rows, decoded + 1, vocab]."""
             from functools import partial
 
             from ray_tpu.models.generate import decode_step, prefill
+            jax, jnp = self.jax, self.jnp
+            p, new = self.prompt, self.spec["new_tokens"]
+            logits, cache = jax.jit(partial(
+                prefill, cfg=self.cfg, max_len=p + new))(
+                    params, tokens[:, :p])
+            system = [logits]
+            step = jax.jit(partial(decode_step, cfg=self.cfg))
+            for j in range(tokens.shape[1] - p):
+                logits, cache = step(params, tokens[:, p + j],
+                                     jnp.asarray(p + j, jnp.int32), cache)
+                system.append(logits)
+            return jnp.stack(system, axis=1)
+
+        def selfcheck(self) -> dict:
+            """Prefill through the cache, then further decoded positions,
+            against the plain reference's full forward, on logits."""
             jax, jnp, np = self.jax, self.jnp, self.np
             spec, cfg = self.spec, self.cfg
             config = spec["config"]
@@ -123,17 +167,7 @@ def make_replica(max_batch_size: int, batch_wait_timeout_s: float):
             tokens = jnp.asarray(np.random.default_rng(
                 [lm.fold_seed(spec["seed"]), 0xC4EC]).integers(
                     0, cfg.vocab_size, (CHECK_ROWS, p + k), dtype=np.int32))
-            logits, cache = jax.jit(partial(
-                prefill, cfg=cfg, max_len=p + spec["new_tokens"]))(
-                    self.params, tokens[:, :p])
-            system = [logits]
-            step = jax.jit(partial(decode_step, cfg=cfg))
-            for j in range(k):
-                logits, cache = step(self.params, tokens[:, p + j],
-                                     jnp.asarray(p + j, jnp.int32), cache)
-                system.append(logits)
-            system = jnp.stack(system, axis=1)       # [rows, k + 1, vocab]
-            del cache
+            system = self.program_logits(self.params, tokens)
             reference = lm.reference_module(config)
             full = self._reference(tokens, lm.program_rms_norm_eps(cfg))
             out = reference.compare_logits(system, full[:, p - 1:p + k])
@@ -145,49 +179,57 @@ def make_replica(max_batch_size: int, batch_wait_timeout_s: float):
                 system[:, 0] - full[:, p - 1]))) / out["reference_std"]
             leaves = jax.tree.leaves(self.params)
             out.update(
-                rms_tolerance=RMS_TOLERANCE, max_tolerance=MAX_TOLERANCE,
                 n_params=int(sum(x.size for x in leaves)),
-                param_dtypes=sorted({str(x.dtype) for x in leaves}))
+                param_dtypes=sorted({str(x.dtype) for x in leaves}),
+                compute_dtype=str(jnp.dtype(cfg.dtype)))
             self.stamps["checked"] = time.time()
             return out
 
-        def _reference(self, tokens, eps: float):
+        def _reference(self, tokens, eps: float, dtype=None):
             config = self.spec["config"]
             return lm.reference_module(config).forward(
                 lm.reference_weights(self.params, config), tokens, config,
-                eps=eps)
+                eps=eps, dtype=dtype)
 
         def aftercheck(self, pairs: list) -> dict:
             """After the window, so that nothing of it sits between the
             warm-up and the measured calls (``generate`` leaves 0.7 GB of
             the chip free). ``pairs``: CHECK_ROWS x (prompt, the tokens
-            ``generate`` returned for it through the proxy and the batcher
-            in the warm-up round): their first decoded positions against
-            the reference, teacher-forced. And the reference at the
-            published epsilon on ``selfcheck``'s tokens against the one at
-            the program's that judged the arithmetic. Both have the shape
-            of ``selfcheck``'s forward."""
+            ``generate`` returned for it through the proxy and the
+            batcher), requests the window finished: every served token
+            against the reference's logits at the position that predicted
+            it, teacher-forced on the served tokens. Then, on
+            ``selfcheck``'s tokens and against its reference logits: the
+            reference with its activations rounded to the type the
+            configuration's file states, never the program's own (the floor
+            the logits' error is judged by), and the reference at the
+            published epsilon (reported)."""
             spec, p = self.spec, self.prompt
             config = spec["config"]
             reference = lm.reference_module(config)
             k = min(CHECK_DECODED, spec["new_tokens"] - 1)
             published_eps = float(config["rms_norm_eps"])
             program_eps = lm.program_rms_norm_eps(self.cfg)
+            new = spec["new_tokens"]
             tokens = self.jnp.asarray(
-                [list(prompt) + list(new[:k]) for prompt, new in pairs],
-                self.jnp.int32)
+                [list(prompt) + list(served[:new - 1])
+                 for prompt, served in pairs], self.jnp.int32)
             out = reference.token_deficit(
-                self._reference(tokens, program_eps)[:, p - 1:p + k],
-                [list(new[:k + 1]) for _, new in pairs])
-            out["token_tolerance"] = TOKEN_TOLERANCE
+                self._reference(tokens, program_eps)[:, p - 1:],
+                [list(served) for _, served in pairs])
+            checked = self.jnp.asarray(self.checked["tokens"])
+            out["floor_rms_over_std"] = reference.compare_logits(
+                self._reference(
+                    checked, program_eps, dtype=self.jnp.dtype(
+                        config["torch_dtype"]))[:, p - 1:p + k],
+                self.checked["logits"])["rms_over_std"]
             out["rms_norm_eps"] = {"published": published_eps,
                                    "program": program_eps}
             out["program_eps_gap"] = 0.0 if program_eps == published_eps \
                 else reference.compare_logits(
                     self.checked["logits"], self._reference(
-                        self.jnp.asarray(self.checked["tokens"]),
-                        published_eps)[:, p - 1:p + k])["rms_over_std"]
-            out["eps_gap_tolerance"] = EPS_GAP_TOLERANCE
+                        checked, published_eps)[:, p - 1:p + k])[
+                            "rms_over_std"]
             return out
 
         @serve.batch(max_batch_size=max_batch_size,
@@ -281,45 +323,63 @@ def make_replica(max_batch_size: int, batch_wait_timeout_s: float):
     return LMReplica
 
 
-def judge(record: dict, config: dict, traffic: dict) -> list:
-    """-> reasons this run is not correct (empty: correct)."""
-    checks, why = record["checks"], []
-    if not checks["rms_over_std"] <= checks["rms_tolerance"]:
-        why.append(f"prefill+decode logits are off the reference by "
-                   f"{checks['rms_over_std']:.4f} of its std (rms), over "
-                   f"{checks['rms_tolerance']}")
-    if not checks["max_over_std"] <= checks["max_tolerance"]:
-        why.append(f"a logit is off the reference by "
-                   f"{checks['max_over_std']:.3f} of its std, over "
-                   f"{checks['max_tolerance']}")
-    if not checks["program_eps_gap"] <= checks["eps_gap_tolerance"]:
-        why.append(f"the reference at the published epsilon "
-                   f"{checks['rms_norm_eps']['published']} and at the "
-                   f"program's {checks['rms_norm_eps']['program']} differ by "
-                   f"{checks['program_eps_gap']:.4f} of std (rms), over "
-                   f"{checks['eps_gap_tolerance']}")
-    if not checks["token_deficit_over_std"] <= checks["token_tolerance"]:
-        why.append(f"a token the compiled generate returned lies "
-                   f"{checks['token_deficit_over_std']:.3f} of std under "
-                   f"the reference's best, over {checks['token_tolerance']}")
-    if checks["param_dtypes"] != [config["param_dtype"]]:
-        why.append(f"weights are {checks['param_dtypes']}, the "
-                   f"configuration says {config['param_dtype']}")
+def judged(record: dict, config: dict, traffic: dict) -> dict:
+    """-> every number this cell's ``correct`` compares, as
+    ``{name: [value, limit]}``: correct while each value is at or under its
+    limit. A limit of 0 is an exact comparison."""
+    checks = record["checks"]
+    eps = checks["rms_norm_eps"]
+    vocab = config["vocab_size"]
     good = [r for r in record["warmup"] + record["window"]["rows"]
             if r["ok"]]
-    vocab = config["vocab_size"]
-    for r in good:
-        toks = r["extra"]["tokens"]
-        if len(toks) != traffic["new_tokens"] or \
-                not all(0 <= t < vocab for t in toks):
-            why.append(f"request {r['rid']}: {len(toks)} tokens, or one "
-                       "outside the vocabulary")
-            break
+    bad = sum(1 for r in good
+              if len(r["extra"]["tokens"]) != traffic["new_tokens"]
+              or not all(0 <= t < vocab for t in r["extra"]["tokens"]))
     twins = [r["extra"]["tokens"] for r in record["warmup"]
              if r["ok"] and r["rid"] in (0, 1)]
-    if len(twins) != 2 or twins[0] != twins[1]:
-        why.append("one prompt sent twice returned different tokens")
-    return why
+    floor = checks.get("floor_rms_over_std") or 0.0
+    return {
+        "rms_over_floor": [
+            checks["rms_over_std"] / floor if floor > 0 else float("inf"),
+            RMS_OVER_FLOOR],
+        "token_deficit_over_std": [checks["token_deficit_over_std"],
+                                   TOKEN_TOLERANCE],
+        "eps_off_known": [min(abs(eps["program"] - known) for known in
+                              (eps["published"], KNOWN_PROGRAM_EPS)), 0],
+        "weights_not_as_configured": [
+            int(checks["param_dtypes"] != [config["param_dtype"]]), 0],
+        "compute_dtype_not_as_configured": [
+            int(checks["compute_dtype"] != config["torch_dtype"]), 0],
+        "replies_malformed": [bad, 0],
+        "twin_replies_differ": [
+            int(len(twins) != 2 or twins[0] != twins[1]), 0],
+    }
+
+
+WHAT_EACH_CHECK_SAYS = {
+    "rms_over_floor": "prefill+decode logits are off the reference (rms) "
+                      "by this many times what bfloat16 rounding alone "
+                      "does to this seed's model",
+    "token_deficit_over_std": "a token the compiled generate served lies "
+                              "this share of std under the reference's best",
+    "eps_off_known": "the program's RMSNorm epsilon is neither the "
+                     "published one nor the known departure",
+    "weights_not_as_configured": "the weights' dtype is not the "
+                                 "configuration's param_dtype",
+    "compute_dtype_not_as_configured": "the program computes in another "
+                                       "type than the configuration's "
+                                       "torch_dtype",
+    "replies_malformed": "replies of another length than new_tokens, or "
+                         "with a token outside the vocabulary",
+    "twin_replies_differ": "one prompt sent twice returned different tokens",
+}
+
+
+def judge(record: dict, config: dict, traffic: dict) -> list:
+    """-> reasons this run is not correct (empty: correct), each naming
+    the check, its number and its limit. Leaves ``record["judged"]``."""
+    record["judged"] = judged(record, config, traffic)
+    return lm.over_their_limits(record["judged"], WHAT_EACH_CHECK_SAYS)
 
 
 def patiently(rt, handle, method: str, *args):
@@ -409,11 +469,20 @@ def drive(run) -> dict:
     run.phase("dump")
     record = patiently(rt, handle, "dump")
     run.phase("aftercheck")
-    # requests 1 and 2 carry two different prompts (with two callers only,
-    # request 1 stands twice)
-    replies = {r["rid"]: r["extra"]["tokens"] for r in warmup}
-    pairs = [(json.loads(body(rid))["prompt"], replies[rid])
-             for rid in (1, min(2, len(warmup) - 1))]
+    # CHECK_ROWS requests the window finished, drawn from the seed (all
+    # are of one length); a window too short to finish that many falls
+    # back on the warm-up round's
+    done = sorted((r for r in window["rows"] if r["ok"]
+                   and len(r["extra"]["tokens"]) == traffic["new_tokens"]),
+                  key=lambda r: r["rid"])
+    if len(done) < CHECK_ROWS:
+        done = [r for r in warmup if r["rid"] > 0]
+    picks = np.random.default_rng([seed, 0x5A3D]).choice(
+        len(done), size=min(CHECK_ROWS, len(done)), replace=False)
+    sample = [done[int(i)] for i in sorted(picks)]
+    pairs = [(json.loads(body(r["rid"]))["prompt"], r["extra"]["tokens"])
+             for r in sample]
+    checks["tokens_checked_of"] = [r["rid"] for r in sample]
     checks.update(patiently(rt, handle, "aftercheck", pairs))
     record["stamps"]["called"] = called
     record["window_start"] = window["start"]

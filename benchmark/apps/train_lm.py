@@ -24,21 +24,31 @@ WARMUP_STEPS = 2
 # published RMSNorm epsilon (1e-5) and the program at its fixed 1e-6. The
 # system computes in bfloat16 with float32 parameters and accumulations; its
 # error on one logit is ~1e-2 but the loss averages >16,000 positions. Read
-# on the chip (PR 24): 2.6e-5 .. 2.5e-4 against a reference at the
-# program's own epsilon (9 seeds on one chip, 6 on four), and the two
-# epsilons move the reference's loss by 7e-5 .. 9.7e-4 (6 runs; 8.8e-4 on
-# the CPU with 2 rows); against the published reference, as judged here,
-# 1.8e-5 .. 9.5e-4 (6 runs, both models). 3e-3 leaves 2.5x room over the
-# two worst added up, and is 1/30 of what a wrong mask, a missing layer or
-# a shifted target moves the loss by (> 0.1 at these sizes). Once the
-# program takes the configuration's epsilon the next benchmark PR can go
-# back to 2e-3.
+# on the chip over 64 seeds on one chip and 48 on four (PR 34,
+# benchmark/testdata/train_checks_sweep.json): 2.5e-5 .. 1.20e-3 and 2.5e-6
+# .. 1.37e-3, the worst at 0.46 of the limit, so the limit stands. It is
+# 1/30 of what a wrong mask, a missing layer or a shifted target moves the
+# loss by (> 0.1 at these sizes). Half of the batch left out reads 6.9e-4 ..
+# 2.3e-2 (how far two random batches' losses differ), over the limit on 10
+# of the 12 seeds it was planted on and no more: that fault is the fall's to
+# catch, below. Once the program takes the configuration's epsilon
+# (ROADMAP.md D0) a benchmark PR can go back to 2e-3.
 LOSS_TOLERANCE = 3e-3
 # The second warm-up step runs on the first batch again: after one AdamW
-# update the loss on the same tokens has to fall by at least the traffic
-# file's ``min_first_update_fall`` (read on the chip beside it). A backward
-# pass or an optimizer that does not do its work leaves it where it was;
-# losses on fresh random batches differ by ~0.01 whatever the update did.
+# update the loss on the same tokens has fallen, and by how much is one
+# number from seed to seed (the same sweep: 10.118 .. 10.162 over 64 seeds
+# on one chip; 1.205 .. 1.319 over 48 on four, standard deviation 0.023). It
+# is held from both sides: the traffic file's ``first_update_fall`` gives
+# the middle of that range (``about``) and how far from it a run may read
+# (``within``: 4.5x the farthest seed on one chip, twice on four, five
+# standard deviations). A backward pass or an optimizer that does not do its
+# work leaves the loss where it was, a fall of 0; an update of a third less
+# effect, or of a third more, is outside too; half of the batch left out
+# (the mean taken over the rest) reads 10.90 .. 10.95 on one chip and 1.020
+# .. 1.121 on four, outside on each of the 12 seeds it was planted on
+# (0.76 from the middle on one chip; on four 0.139 .. 0.240 of 0.12: about
+# one seed in 400 would slip through there). Losses on fresh random batches
+# differ by ~0.01 whatever the update did.
 
 
 def train_loop(spec: dict) -> None:
@@ -100,7 +110,7 @@ def train_loop(spec: dict) -> None:
         "rms_norm_eps": {"published": float(spec["config"]["rms_norm_eps"]),
                          "program": lm.program_rms_norm_eps(cfg)},
         "first_update_fall": warmup_losses[0] - warmup_losses[1],
-        "min_first_update_fall": spec["min_first_update_fall"],
+        "first_update_fall_expected": spec["first_update_fall"],
         "n_params": int(sum(x.size for x in params)),
         "param_dtypes": sorted({str(x.dtype) for x in params}),
         "state_device_sets": sorted({len(x.sharding.device_set)
@@ -147,31 +157,51 @@ def train_loop(spec: dict) -> None:
         "memory": lm.memory_report(devs, step_memory, "the train step")}})
 
 
-def judge(record: dict) -> list:
-    """-> reasons this run is not correct (empty: correct)."""
+def judged(record: dict) -> dict:
+    """-> every number this cell's ``correct`` compares, as
+    ``{name: [value, limit]}``: correct while each value is at or under its
+    limit. A limit of 0 is an exact comparison."""
     import math
     checks, window = record["checks"], record["window"]
-    why = []
-    gap = abs(checks["system_loss"] - checks["reference_loss"])
-    if not gap <= checks["loss_tolerance"]:
-        why.append(f"system loss {checks['system_loss']} vs the plain "
-                   f"reference's {checks['reference_loss']} on the first "
-                   f"batch: off by {gap:.2e} > {checks['loss_tolerance']}")
-    if not checks["first_update_fall"] >= checks["min_first_update_fall"]:
-        why.append(f"after one update the loss on the same batch fell by "
-                   f"{checks['first_update_fall']:.4f}, under "
-                   f"{checks['min_first_update_fall']}: the backward pass "
-                   "or the optimizer is not doing its work")
+    expected = checks["first_update_fall_expected"]
     losses = checks["warmup_losses"] + [s[2] for s in window["steps"]]
-    if not all(math.isfinite(x) for x in losses):
-        why.append("a loss in the run is not finite")
-    if checks["param_dtypes"] != [record["param_dtype"]]:
-        why.append(f"parameters are {checks['param_dtypes']}, the "
-                   f"configuration says {record['param_dtype']}")
-    if checks["state_device_sets"] != [record["facts"]["count"]]:
-        why.append("parameters or optimizer state are not spread over "
-                   f"every chip: {checks['state_device_sets']}")
-    return why
+    return {
+        "loss_gap": [abs(checks["system_loss"] - checks["reference_loss"]),
+                     checks["loss_tolerance"]],
+        "first_update_fall_off": [
+            abs(checks["first_update_fall"] - expected["about"]),
+            expected["within"]],
+        "losses_not_finite": [
+            sum(1 for x in losses if not math.isfinite(x)), 0],
+        "params_not_as_configured": [
+            int(checks["param_dtypes"] != [record["param_dtype"]]), 0],
+        "state_not_on_every_chip": [
+            int(checks["state_device_sets"] != [record["facts"]["count"]]),
+            0],
+    }
+
+
+WHAT_EACH_CHECK_SAYS = {
+    "loss_gap": "system loss against the plain reference's on the first "
+                "batch",
+    "first_update_fall_off": "how far the loss's fall on the same batch "
+                             "after one update lies from what this cell's "
+                             "sound runs read: the backward pass or the "
+                             "optimizer is not doing its work, or not on "
+                             "the whole batch",
+    "losses_not_finite": "losses in the run that are not finite",
+    "params_not_as_configured": "the parameters' dtype is not the "
+                                "configuration's param_dtype",
+    "state_not_on_every_chip": "parameters or optimizer state are not "
+                               "spread over every chip",
+}
+
+
+def judge(record: dict) -> list:
+    """-> reasons this run is not correct (empty: correct), each naming
+    the check, its number and its limit. Leaves ``record["judged"]``."""
+    record["judged"] = judged(record)
+    return lm.over_their_limits(record["judged"], WHAT_EACH_CHECK_SAYS)
 
 
 def drive(run) -> dict:
@@ -193,7 +223,7 @@ def drive(run) -> dict:
         "remat": traffic["remat"], "mesh_axis": traffic["mesh_axis"],
         "seq": traffic["seq"], "rows_per_chip": traffic["rows_per_chip"],
         "reference_rows_per_pass": traffic["reference_rows_per_pass"],
-        "min_first_update_fall": traffic["min_first_update_fall"],
+        "first_update_fall": traffic["first_update_fall"],
     }
     run.phase("rt.init")
     run.init_runtime(rt, chips)

@@ -12,7 +12,11 @@ the conductor's span records while the runtime is still up,
 ``rt.shutdown()``, every child gone, then one JSON line on stdout. This
 process never opens a JAX backend. A metric of the cell whose reader found
 nothing to read is left out of the line and named on stderr with the
-reader's reason (``[bench] metric <name> not read: <why>``).
+reader's reason (``[bench] metric <name> not read: <why>``). The line ends
+with ``checks``: every number ``correct`` compared, ``{name: [value,
+limit]}``, and a line that is not correct carries ``why_not_correct`` before
+it; the same numbers are the last lines on stderr (``[bench] check <name>:
+<value> limit <limit>``).
 
 No TPU, too few chips, a failed phase, a compile inside the window or a
 device kind without published peaks is a non-zero exit with no result line;
@@ -209,19 +213,17 @@ def result_line(run: RunContext, mf, record: dict) -> dict:
     elif run.trace and not run.rehearse:
         raise BenchFailure("the traced run's profile holds no whole period "
                            "of device work")
+    # what ``correct`` compared, each number beside its limit: last on the
+    # line, so that the end of a line that is kept says why
+    if record["why_not_correct"]:
+        line["why_not_correct"] = record["why_not_correct"]
+    line["checks"] = record["judged"]
     return line
 
 
 def report(run: RunContext, record: dict, line: dict) -> None:
-    stamps = record["stamps"]
-    order = sorted((v, k) for k, v in stamps.items())
-    log("set-up: " + " ".join(f"{k}={v - run.started:.2f}s"
-                              for v, k in order)
-        + f" window_start={record['window_start'] - run.started:.2f}s")
-    log("checks: " + json.dumps(record["checks"]))
-    log(f"memory: {json.dumps(record['memory'])}")
-    log(f"attempted={line['attempted']} failed={line['failed']} "
-        f"correct={line['correct']} {record['why_not_correct']}")
+    """The run in words, on stderr. Nothing is logged after it, so its end
+    is the end of stderr: each number compared beside its limit."""
     out_dir = os.path.join(CHECKOUT, "benchmark", "out")
     try:
         os.makedirs(out_dir, exist_ok=True)
@@ -231,6 +233,19 @@ def report(run: RunContext, record: dict, line: dict) -> None:
             json.dump(record, f, default=str)
     except OSError as e:
         log(f"could not keep the record: {e!r}")
+    stamps = record["stamps"]
+    order = sorted((v, k) for k, v in stamps.items())
+    log("set-up: " + " ".join(f"{k}={v - run.started:.2f}s"
+                              for v, k in order)
+        + f" window_start={record['window_start'] - run.started:.2f}s")
+    log("checks: " + json.dumps(record["checks"]))
+    log(f"memory: {json.dumps(record['memory'])}")
+    log(f"attempted={line['attempted']} failed={line['failed']}")
+    for name, (value, limit) in record["judged"].items():
+        log(f"check {name}: {value!r} limit {limit!r}")
+    for why in record["why_not_correct"]:
+        log(f"NOT CORRECT: {why}")
+    log(f"correct={line['correct']}")
 
 
 def attempt(run: RunContext, app) -> dict:
@@ -313,15 +328,14 @@ def main(argv=None) -> int:
             raise BenchFailure("the chip-owning worker is still alive")
         run.phase("metrics")
         line = result_line(run, mf, record)
-        report(run, record, line)
         if args.rehearse:
             log("REHEARSAL result (not printed to stdout): "
                 + json.dumps(line))
-            code = REHEARSAL_EXIT
         else:
             sys.stdout.write(json.dumps(line) + "\n")
             sys.stdout.flush()
-            code = 0
+        report(run, record, line)
+        code = REHEARSAL_EXIT if args.rehearse else 0
     except hermetic.Terminated as e:
         explain(run, args, e)
         code = 128 + e.signum

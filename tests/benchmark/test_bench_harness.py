@@ -92,9 +92,14 @@ def check_clean_rehearsal(proc) -> dict:
 
 
 def check_result_line(line: dict, metrics: set, traced: bool) -> None:
-    """Exactly the contract's keys."""
-    want = {"correct", "attempted", "failed", "metrics", "device"}
+    """Exactly the contract's keys, and last what ``correct`` compared:
+    each number beside its limit."""
+    want = {"correct", "attempted", "failed", "metrics", "device", "checks"}
     assert set(line) - {"breakdown"} == want
+    assert list(line)[-1] == "checks" and line["checks"]
+    for value, limit in line["checks"].values():
+        assert isinstance(value, (int, float))
+        assert isinstance(limit, (int, float))
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     assert set(line["device"]) - {"busy_s", "window_s"} == \
@@ -250,6 +255,14 @@ def test_soak_untraced_serve_run_and_the_callers_environment(soak):
     line = check_clean_rehearsal(soak["serve7"])
     check_result_line(line, {"serve.tokens_per_s", "serve.request_p95_s",
                              "serve.ttft_p95_s", "setup_s"}, traced=False)
+    # stderr ends with each number compared beside its limit
+    said = soak["serve7"].stderr.strip().splitlines()
+    assert said[-1] == "[bench] correct=True"
+    ends = "\n".join(said[-1 - len(line["checks"]):-1])
+    for name, (value, limit) in line["checks"].items():
+        assert f"check {name}: {value!r} limit {limit!r}" in ends
+    assert {"rms_over_floor", "token_deficit_over_std", "eps_off_known",
+            "compute_dtype_not_as_configured"} <= set(line["checks"])
     # nothing was written where the caller's TMPDIR and HOME point
     assert soak["tmpdir_entries"] == [] and soak["home_entries"] == []
 
@@ -291,7 +304,7 @@ def add_toy_cell(data, bench, family="llama", app="train_lm"):
     (bench / "configs" / "toy.json").write_text(json.dumps(config))
     traffic = {"app": app, "seq": 32, "rows_per_chip": 2, "mesh_axis": "dp",
                "remat": False, "reference_rows_per_pass": 1,
-               "min_first_update_fall": 0.0,
+               "first_update_fall": {"about": 0.0, "within": 100.0},
                "clients": 2, "prompt_tokens": 8,
                "new_tokens": 4, "max_batch_size": 2,
                "batch_wait_timeout_s": 0.01, "max_ongoing_requests": 2}
@@ -398,6 +411,89 @@ def test_failure_in_the_worker_is_retried_once_then_explained(tmp_path):
     assert "--- tail of worker-" in proc.stderr
     os.remove(os.path.join(BENCH, "out", "failure-toy-cell-3.txt"))
     assert marker_carriers() == []
+
+
+PLANT = '''"""Plants one fault under a rehearsal, in every process of the run
+that imports the program's module (this file is ``sitecustomize`` on the
+run's PYTHONPATH)."""
+import importlib.abc
+import importlib.util
+import os
+import sys
+
+
+def altered_token(module):
+    """Every token the sampler returns is the next id."""
+    sample = module._sample
+    module._sample = lambda logits, *a: \\
+        (sample(logits, *a) + 1) % logits.shape[-1]
+
+
+def state_unchanged(module):
+    """The optimizer hands the parameters back as it got them."""
+    import optax
+    make = module.make_lm_train_step
+    module.make_lm_train_step = lambda cfg, mesh, *a, **kw: make(
+        cfg, mesh, tx=optax.adamw(0.0, weight_decay=0.0))
+
+
+PLANTS = {"altered_token": ("ray_tpu.models.generate", altered_token),
+          "state_unchanged": ("ray_tpu.train.jax_step", state_unchanged)}
+
+
+class Plant(importlib.abc.MetaPathFinder):
+    def __init__(self, name, plant):
+        self.name, self.plant = name, plant
+
+    def find_spec(self, name, path, target=None):
+        if name != self.name:
+            return None
+        sys.meta_path.remove(self)
+        spec = importlib.util.find_spec(name)
+        run = spec.loader.exec_module
+
+        def exec_module(module):
+            run(module)
+            self.plant(module)
+        spec.loader.exec_module = exec_module
+        return spec
+
+
+if os.environ.get("BENCH_TEST_PLANT") in PLANTS:
+    sys.meta_path.insert(0, Plant(*PLANTS[os.environ["BENCH_TEST_PLANT"]]))
+'''
+
+
+@pytest.mark.parametrize("plant,cell,check", [
+    ("altered_token", "mistral7b-serve-closed32", "token_deficit_over_std"),
+    ("state_unchanged", "mistral7b-train-1chip", "first_update_fall_off")])
+def test_a_run_with_the_timed_path_broken_underneath_is_not_correct(
+        tmp_path, plant, cell, check):
+    """The whole of a run but its look for a chip, with a fault planted in
+    the program it drives: ``correct`` comes out false, the line says which
+    check failed with the number beside its limit, and stderr ends with
+    the same."""
+    (tmp_path / "plant").mkdir()
+    (tmp_path / "plant" / "sitecustomize.py").write_text(PLANT)
+    env = hostile_env(tmp_path)
+    env["BENCH_TEST_PLANT"] = plant
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(tmp_path / "plant")] + env.get("PYTHONPATH", "").split(
+            os.pathsep)).rstrip(os.pathsep)
+    proc = rehearse(["--workload", cell, "--seed", "21", "--seconds", "2",
+                     "--trace", "0"], env)
+    assert proc.returncode == 3 and proc.stdout == "", proc.stderr[-3000:]
+    line = result_of(proc)
+    assert line["correct"] is False and line["failed"] == 0
+    assert list(line)[-2:] == ["why_not_correct", "checks"]
+    assert [why.split(":")[0] for why in line["why_not_correct"]] == [check]
+    value, limit = line["checks"][check]
+    assert f"{value:.6g}" in line["why_not_correct"][0]
+    assert f"limit {limit:.6g}" in line["why_not_correct"][0]
+    said = proc.stderr.strip().splitlines()
+    assert said[-1] == "[bench] correct=False"
+    assert said[-2] == "[bench] NOT CORRECT: " + line["why_not_correct"][0]
+    assert f"[bench] check {check}: {value!r} limit {limit!r}" in said
 
 
 def test_no_accelerator_is_a_non_zero_exit_with_no_result(tmp_path):

@@ -116,7 +116,7 @@ def test_the_reference_keeps_the_published_epsilon_unless_told(tiny):
     """The configuration's ``rms_norm_eps`` is what the reference runs; the
     program's own (fixed at 1e-6 today, its configuration's once it has the
     field) is named by the caller, and the distance between the two is what
-    the apps hold as ``program_eps_gap``."""
+    the serving app reports as ``program_eps_gap``."""
     import types
     cfg, params, tokens = tiny
     ref = lm.reference_module(TINY)
@@ -197,3 +197,518 @@ def test_published_sizes_reach_the_program_unchanged(name, params_b):
     toy = lm.effective_config(data, rehearse=True)
     assert toy["hidden_size"] == 64 and toy["family"] == data["family"]
     assert lm.effective_config(data, rehearse=False) == data
+
+
+# ---- what the serving cell's ``correct`` compares (PR 34) ---------------
+
+def test_the_bfloat16_floor_lies_between_nought_and_the_int8_control(tiny):
+    """``forward(dtype=bfloat16)``: the same plain code with its activations
+    rounded. What rounding alone does to this model is above nought and
+    under what 8-bit weights do, and float32 "rounded" to float32 is the
+    reference itself."""
+    import jax.numpy as jnp
+    cfg, params, tokens = tiny
+    ref = lm.reference_module(TINY)
+    weights = lm.reference_weights(params, TINY)
+    plain = ref.forward(weights, tokens, TINY)
+    floor = ref.compare_logits(
+        ref.forward(weights, tokens, TINY, dtype=jnp.bfloat16), plain)
+    control = ref.compare_logits(
+        ref.forward(ref.int8_weights(weights), tokens, TINY), plain)
+    assert 0 < floor["rms_over_std"] < control["rms_over_std"]
+    assert 0 < floor["max_over_std"]
+    same = ref.forward(weights, tokens, TINY, dtype=jnp.float32)
+    assert float(jnp.max(jnp.abs(same - plain))) == 0.0
+
+
+def test_int8_on_the_systems_side_is_the_references_int8(tiny):
+    """The control has one meaning whichever side it is planted on: the
+    program's tree through the sweep's ``int8_params`` holds the values
+    ``reference.int8_weights`` gives the plain matrices; norms stay."""
+    import jax.numpy as jnp
+    from benchmark.testdata.sweep_serve import int8_params
+    cfg, params, tokens = tiny
+    ref = lm.reference_module(TINY)
+    a = ref.int8_weights(lm.reference_weights(params, TINY))
+    b = lm.reference_weights(int8_params(params), TINY)
+    assert float(jnp.max(jnp.abs(a.embed - b.embed))) == 0.0
+    assert float(jnp.max(jnp.abs(a.lm_head - b.lm_head))) == 0.0
+    for i in range(a.n_layers):
+        la, lb = a.layer(i), b.layer(i)
+        for name in la:
+            assert float(jnp.max(jnp.abs(la[name] - lb[name]))) == 0.0
+    plain = lm.reference_weights(params, TINY)
+    assert float(jnp.max(jnp.abs(a.lm_head - plain.lm_head))) > 0
+    assert float(jnp.max(jnp.abs(a.layer(0)["ln1"]
+                                 - plain.layer(0)["ln1"]))) == 0.0
+    levels = jnp.unique(jnp.round(
+        a.lm_head[:, 0] / (jnp.max(jnp.abs(plain.lm_head[:, 0])) / 127)))
+    assert len(levels) <= 255
+
+
+@pytest.fixture(scope="module")
+def replica():
+    """The serving app's replica at the rehearsal's toy size, built as a
+    run builds it but called directly: no runtime, no proxy."""
+    import json
+    import os
+
+    from bench_paths import BENCH
+    from benchmark.apps import serve_lm
+    data = lm.effective_config(config("mistral-7b-v0.3-l24"), True)
+    with open(os.path.join(BENCH, "traffic", "serve-closed32.json")) as f:
+        traffic = lm.effective_traffic(json.load(f), True)
+    spec = {"seed": 11, "trace": False, "trace_dir": "", "rehearse": True,
+            "config": data,
+            "model": lm.model_kwargs(
+                data, traffic["prompt_tokens"] + traffic["new_tokens"],
+                "auto"),
+            "rows": traffic["max_batch_size"],
+            "prompt_tokens": traffic["prompt_tokens"],
+            "new_tokens": traffic["new_tokens"]}
+    rep = serve_lm.make_replica(traffic["max_batch_size"],
+                                traffic["batch_wait_timeout_s"])(spec)
+    return rep, data, traffic
+
+
+def a_run_of(rep, data, traffic, gen=None, params=None, after=None) -> dict:
+    """What ``serve_lm.drive`` leaves ``judge``, with the replica called
+    directly: the self-check, one warm-up batch (requests 0 and 1 carry one
+    prompt), one batch of the window, the after-check on two of its
+    requests. ``gen`` / ``params``: the compiled ``generate`` and the
+    weights the requests are served by, where a fault is planted there;
+    ``after``: alters the served replies."""
+    import numpy as np
+    seed = lm.fold_seed(rep.spec["seed"])
+    rows, p = rep.rows, rep.prompt
+    gen = gen or rep.gen
+    params = rep.params if params is None else params
+
+    def batch(rids):
+        prompts = np.stack([np.random.default_rng(
+            [seed, max(rid, 1)]).integers(0, data["vocab_size"], p)
+            for rid in rids]).astype(np.int32)
+        tokens = np.asarray(gen(params, prompts))
+        return [{"ok": True, "rid": rid, "prompt": prompts[i].tolist(),
+                 "extra": {"tokens": tokens[i].tolist()}}
+                for i, rid in enumerate(rids)]
+    checks = rep.selfcheck()
+    warmup = batch(range(rows))
+    window = batch(range(rows, 2 * rows))
+    if after:
+        after(window)
+    checks.update(rep.aftercheck(
+        [(r["prompt"], r["extra"]["tokens"]) for r in window[:2]]))
+    return {"checks": checks, "warmup": warmup, "window": {"rows": window}}
+
+
+def failed_checks(why: list) -> set:
+    return {reason.split(":", 1)[0] for reason in why}
+
+
+def test_judge_passes_a_clean_run_and_gives_every_number_its_limit(replica):
+    from benchmark.apps import serve_lm
+    rep, data, traffic = replica
+    record = a_run_of(rep, data, traffic)
+    assert serve_lm.judge(record, data, traffic) == []
+    judged = record["judged"]
+    assert list(judged)[:2] == ["rms_over_floor", "token_deficit_over_std"]
+    assert judged["rms_over_floor"] == [
+        record["checks"]["rms_over_std"]
+        / record["checks"]["floor_rms_over_std"], serve_lm.RMS_OVER_FLOOR]
+    assert 0 < judged["rms_over_floor"][0] < serve_lm.RMS_OVER_FLOOR
+    assert judged["token_deficit_over_std"][1] == serve_lm.TOKEN_TOLERANCE
+    assert all(limit == 0 for name, (_, limit) in judged.items()
+               if name not in ("rms_over_floor", "token_deficit_over_std"))
+    assert set(judged) == set(serve_lm.WHAT_EACH_CHECK_SAYS)
+    # the known departure and the largest single error: reported, not
+    # judged
+    assert record["checks"]["program_eps_gap"] > 0
+    assert record["checks"]["max_over_std"] > 0
+    assert not {"program_eps_gap", "max_over_std", "max_over_rms"} \
+        & set(judged)
+
+
+def test_judge_names_the_check_a_cache_written_one_position_late_fails(
+        replica, monkeypatch):
+    """Fault (b), planted in the program: every decoded position attends
+    over a cache that lacks its own key and value."""
+    import sys
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    from benchmark.apps import serve_lm
+    from ray_tpu.models import generate
+    rep, data, traffic = replica
+    gen_mod = sys.modules["ray_tpu.models.generate"]
+    write = gen_mod._write_position
+    monkeypatch.setattr(
+        gen_mod, "_write_position", lambda cache, l, pos, new: write(
+            cache, l, jnp.minimum(pos + 1, cache.shape[2] - 1), new))
+    late = jax.jit(partial(generate, cfg=rep.cfg, temperature=0.0,
+                           max_new_tokens=traffic["new_tokens"]))
+    record = a_run_of(rep, data, traffic, gen=late)
+    why = serve_lm.judge(record, data, traffic)
+    assert "rms_over_floor" in failed_checks(why), why
+    value, limit = record["judged"]["rms_over_floor"]
+    assert value > 2 * limit
+    assert f"{value:.6g} is over the limit {limit:.6g}" in why[0]
+
+
+def test_judge_names_the_check_int8_weights_fail(replica):
+    """Fault (a), the control on the system's side: the program's own
+    ``prefill`` and ``decode_step`` over its weights rounded to 8 bits,
+    against the reference over the weights as they are."""
+    import jax.numpy as jnp
+    from benchmark.apps import serve_lm
+    from benchmark.testdata.sweep_serve import int8_params
+    rep, data, traffic = replica
+    record = a_run_of(rep, data, traffic)
+    ref = lm.reference_module(data)
+    got = ref.compare_logits(
+        rep.program_logits(int8_params(rep.params),
+                           jnp.asarray(rep.checked["tokens"])),
+        rep.checked["logits"])
+    assert got["rms_over_std"] > 2 * record["checks"]["rms_over_std"]
+    record["checks"].update(rms_over_std=got["rms_over_std"],
+                            max_over_std=got["max_over_std"])
+    why = serve_lm.judge(record, data, traffic)
+    assert failed_checks(why) == {"rms_over_floor"}, why
+
+
+@pytest.mark.parametrize("fault", ["altered", "another_length", "twins"])
+def test_judge_names_the_check_a_wrong_reply_fails(replica, fault):
+    """Fault (c) and its kin, planted where the reply is produced: a served
+    token altered to another id lies far under the reference's best; a
+    reply of another length and a twin that differs are exact checks."""
+    from benchmark.apps import serve_lm
+    rep, data, traffic = replica
+
+    def after(window):
+        tokens = window[0]["extra"]["tokens"]
+        if fault == "altered":
+            tokens[3] = (tokens[3] + 1) % data["vocab_size"]
+        elif fault == "another_length":
+            window[-1]["extra"]["tokens"] = tokens[:-1]
+    record = a_run_of(rep, data, traffic, after=after)
+    if fault == "twins":
+        record["warmup"][1]["extra"]["tokens"][0] ^= 1
+    why = serve_lm.judge(record, data, traffic)
+    want = {"altered": "token_deficit_over_std",
+            "another_length": "replies_malformed",
+            "twins": "twin_replies_differ"}[fault]
+    assert failed_checks(why) == {want}, why
+    value, limit = record["judged"][want]
+    assert value > 2 * limit if limit else value == 1
+
+
+def test_a_third_epsilon_is_not_correct_and_the_known_two_are(replica):
+    from benchmark.apps import serve_lm
+    rep, data, traffic = replica
+    record = a_run_of(rep, data, traffic)
+    eps = record["checks"]["rms_norm_eps"]
+    assert eps == {"published": 1e-5, "program": 1e-6}
+    assert serve_lm.KNOWN_PROGRAM_EPS == 1e-6
+    for program, verdict in ((1e-6, set()), (1e-5, set()),
+                             (1e-4, {"eps_off_known"}),
+                             (0.0, {"eps_off_known"})):
+        eps["program"] = program
+        assert failed_checks(serve_lm.judge(record, data, traffic)) \
+            == verdict
+    assert not hasattr(serve_lm, "EPS_GAP_TOLERANCE")
+
+
+def test_a_program_that_computes_below_the_stated_type_is_not_correct(
+        replica, monkeypatch):
+    """The floor is made in the type the configuration's file states, not
+    in the program's own ``cfg.dtype``: a program that computes in a type
+    of fewer bits than stated reads far over what the stated type's
+    rounding does to the same model, and the type itself is held exactly.
+    (The program does not run in 8 bits, so here the file states float16,
+    11 bits, and the program's bfloat16, 8 bits, is the lower type.)"""
+    import jax.numpy as jnp
+    from benchmark.apps import serve_lm
+    rep, data, traffic = replica
+    assert rep.cfg.dtype == jnp.bfloat16 == jnp.dtype(data["torch_dtype"])
+    stated = dict(data, torch_dtype="float16")
+    monkeypatch.setitem(rep.spec, "config", stated)
+    record = a_run_of(rep, stated, traffic)
+    assert record["checks"]["compute_dtype"] == "bfloat16"
+    why = serve_lm.judge(record, stated, traffic)
+    assert failed_checks(why) == {"rms_over_floor",
+                                  "compute_dtype_not_as_configured"}, why
+    value, limit = record["judged"]["rms_over_floor"]
+    assert value > 2 * limit
+    # a floor taken in the program's own type would have passed it
+    monkeypatch.setitem(rep.spec, "config", data)
+    own = a_run_of(rep, data, traffic)["checks"]["floor_rms_over_std"]
+    assert record["checks"]["rms_over_std"] / own < limit
+
+
+def test_a_number_that_is_not_a_number_is_over_its_limit():
+    from benchmark.apps import serve_lm
+    says = {"a": "x", "b": "y"}
+    assert lm.over_their_limits({"a": [1.0, 1.0], "b": [0, 0]}, says) == []
+    why = lm.over_their_limits({"a": [float("nan"), 1.0], "b": [1, 0]}, says)
+    assert [w.split(":")[0] for w in why] == ["a", "b"]
+    assert "1 is over the limit 0" in why[1]
+    # and so is an error over a floor of nought, or over none
+    record = record_of({"rms_over_std": 0.02, "max_over_std": 0.1}, 0.0,
+                       {"token_deficit_over_std": 0.0})
+    for floor in (0.0, -1.0, float("nan"), None):
+        record["checks"]["floor_rms_over_std"] = floor
+        why = serve_lm.judge(record, config("mistral-7b-v0.3-l24"),
+                             {"new_tokens": 128})
+        assert failed_checks(why) == {"rms_over_floor"}
+        assert record["judged"]["rms_over_floor"][0] == float("inf")
+
+
+@pytest.mark.parametrize("why", [[], ["rms_over_floor: ...: 9 is over 2"]])
+def test_the_result_line_ends_with_what_was_compared(why):
+    """Every line carries ``checks`` last, each number beside its limit; a
+    line that is not correct says why before it."""
+    import types
+
+    from benchmark import run as run_mod
+    judged = {"rms_over_floor": [9.0 if why else 1.2, 2.0],
+              "replies_malformed": [0, 0]}
+    record = {"facts": {"platform": "cpu", "kind": "cpu", "count": 1},
+              "compiles_in_window": 0, "window_start": 12.0,
+              "memory": {"peak_bytes": 5}, "attempted": 3, "failed": 0,
+              "why_not_correct": why, "judged": judged}
+    run = types.SimpleNamespace(rehearse=True, trace=False, started=2.0,
+                                cell={"name": "x"})
+    mf = types.SimpleNamespace(read_metrics=lambda kind, cell, record: {
+        "setup_s": {"value": record["setup_s"], "unit": "s"}})
+    line = run_mod.result_line(run, mf, record)
+    keys = ["correct", "attempted", "failed", "metrics", "device"]
+    assert list(line) == keys + (["why_not_correct"] if why else []) \
+        + ["checks"]
+    assert line["correct"] is (not why)
+    assert line["checks"] == judged
+    if why:
+        assert line["why_not_correct"] == why
+
+
+# ---- the limits' provenance: the sweep on the chip, kept as data --------
+
+@pytest.fixture(scope="module")
+def sweep():
+    import json
+    import os
+
+    from bench_paths import BENCH
+    with open(os.path.join(BENCH, "testdata",
+                           "serve_checks_sweep.json")) as f:
+        return json.load(f)
+
+
+PLANTED = ("fault_int8_weights", "fault_cache_one_late",
+           "fault_second_best_token", "fault_token_altered")
+# The room every committed limit has, one factor each way: a limit is at
+# least this many times the worst reading of the sweep's sound runs ...
+OVER_SOUND = 1.5
+# ... and every reading of the control, or of a fault the number is there
+# to catch, lies at least this many times over the limit.
+UNDER_FAULT = 1.4
+
+
+def over_floor(row: dict, floor: float) -> float:
+    return row["rms_over_std"] / floor
+
+
+def test_the_sweep_is_of_the_cell_and_wide_enough(sweep):
+    seeds = [row["seed"] for row in sweep["seeds"]]
+    assert sweep["cell"] == "mistral7b-serve-closed32"
+    assert sweep["device"]["platform"] == "tpu"
+    assert len(set(seeds)) == len(seeds) >= 64
+    assert 3200000101 in seeds
+    assert sum(1 for s in seeds if s < 32) >= 32
+    assert sum(1 for s in seeds if s >= 2 ** 31) >= 32
+    assert all(row["tokens_checked"] >= 256 for row in sweep["seeds"])
+    assert all(row["compute_dtype"] == "bfloat16" for row in sweep["seeds"])
+    # the control ran on every seed, the planted faults on a dozen
+    assert all("control_int8_reference" in row for row in sweep["seeds"])
+    tried = [row["seed"] for row in sweep["faults"]]
+    assert len(tried) >= 8 and set(tried) <= set(seeds)
+    by_r = sorted(sweep["seeds"], key=lambda row: row["rms_over_std"])
+    assert {by_r[0]["seed"], by_r[-1]["seed"], 3200000101} <= set(tried)
+    assert all(set(PLANTED) < set(row) for row in sweep["faults"])
+
+
+def test_every_committed_limit_comes_from_the_sweep(sweep):
+    """A limit is at least OVER_SOUND x the worst clean reading of 64
+    seeds, and every reading of the control and of each fault it is there
+    to catch is at least UNDER_FAULT x the limit: the provenance is
+    checked, not remembered."""
+    from benchmark.apps import serve_lm
+    clean, faults = sweep["seeds"], sweep["faults"]
+    floors = {row["seed"]: row["floor_rms_over_std"] for row in clean}
+    ratios = [over_floor(row, floors[row["seed"]]) for row in clean]
+    errors = [row["rms_over_std"] for row in clean]
+    assert OVER_SOUND * max(ratios) <= serve_lm.RMS_OVER_FLOOR \
+        <= 1.01 * OVER_SOUND * max(ratios)
+    # what sound runs read is one number to within a few percent, where
+    # the error itself spans nearly four times
+    assert max(ratios) / min(ratios) < 1.1 < 3.5 < max(errors) / min(errors)
+    caught_by_rms = {
+        "control_int8_reference": [over_floor(
+            row["control_int8_reference"], floors[row["seed"]])
+            for row in clean]}
+    for name in ("fault_int8_weights", "fault_cache_one_late"):
+        caught_by_rms[name] = [over_floor(row[name], floors[row["seed"]])
+                               for row in faults]
+    for name, readings in caught_by_rms.items():
+        assert UNDER_FAULT * serve_lm.RMS_OVER_FLOOR <= min(readings), name
+    # a fixed limit on r could not do it: the control on the least
+    # sensitive seed reads under the clean run of the most sensitive one
+    assert min(row["control_int8_reference"]["rms_over_std"]
+               for row in clean) < max(errors)
+    widest = max(row["token_deficit_over_std"] for row in clean)
+    altered = min(row["fault_token_altered"]["token_deficit_over_std"]
+                  for row in faults)
+    assert OVER_SOUND * widest <= serve_lm.TOKEN_TOLERANCE \
+        <= altered / UNDER_FAULT
+    # what is reported and not judged has no reading to set a limit by: a
+    # limit by the same rule would pass every run of the control
+    def max_over_rms(row):
+        return row["max_over_std"] / row["rms_over_std"]
+    assert max(max_over_rms(row["control_int8_reference"]) for row in clean) \
+        < OVER_SOUND * max(max_over_rms(row) for row in clean)
+    assert min(row["control_int8_reference"]["token_deficit_over_std"]
+               for row in clean) < widest
+
+
+def record_of(row: dict, floor: float, tokens: dict) -> dict:
+    """A sweep row as the record ``judge`` is given: its logits' readings,
+    a seed's floor, its served tokens' readings; replies well formed."""
+    checks = {"rms_over_std": row["rms_over_std"],
+              "max_over_std": row["max_over_std"],
+              "floor_rms_over_std": floor,
+              "token_deficit_over_std": tokens["token_deficit_over_std"],
+              "rms_norm_eps": {"published": 1e-5, "program": 1e-6},
+              "param_dtypes": ["bfloat16"], "compute_dtype": "bfloat16"}
+    twins = [{"ok": True, "rid": rid, "extra": {"tokens": [1] * 128}}
+             for rid in (0, 1)]
+    return {"checks": checks, "warmup": twins, "window": {"rows": []}}
+
+
+def test_the_committed_judge_over_every_row_of_the_sweep(sweep):
+    """Every seed of the sweep is correct with its worst number at or under
+    two thirds of the limit, and its control is not, by ``rms_over_floor``;
+    each planted fault is not correct on every seed it was tried on, by the
+    check named (but the second-best token at one step, which reads the
+    top-two gap of that position: no limit on a gap catches it on every
+    seed)."""
+    from benchmark.apps import serve_lm
+    data = config("mistral-7b-v0.3-l24")
+    traffic = {"new_tokens": 128}
+    clean = {row["seed"]: row for row in sweep["seeds"]}
+    for row in sweep["seeds"]:
+        floor = row["floor_rms_over_std"]
+        record = record_of(row, floor, row)
+        assert serve_lm.judge(record, data, traffic) == [], row["seed"]
+        for name in ("rms_over_floor", "token_deficit_over_std"):
+            value, limit = record["judged"][name]
+            assert value <= limit * 0.667, (row["seed"], name)
+        control = row["control_int8_reference"]
+        why = serve_lm.judge(record_of(control, floor, control), data,
+                             traffic)
+        assert "rms_over_floor" in failed_checks(why), row["seed"]
+    caught = {name: 0 for name in PLANTED}
+    for row in sweep["faults"]:
+        mine = clean[row["seed"]]
+        for name in PLANTED:
+            fault = row[name]
+            record = record_of(fault if "rms_over_std" in fault else mine,
+                               mine["floor_rms_over_std"], fault)
+            why = failed_checks(serve_lm.judge(record, data, traffic))
+            caught[name] += bool(why)
+            if "rms_over_std" in fault:
+                assert "rms_over_floor" in why, (row["seed"], name)
+            elif name == "fault_token_altered":
+                assert why == {"token_deficit_over_std"}, row["seed"]
+    tried = len(sweep["faults"])
+    assert all(caught[name] == tried for name in PLANTED
+               if name != "fault_second_best_token"), caught
+    assert 0 < caught["fault_second_best_token"] <= tried
+
+
+def test_training_limits_come_from_their_sweep():
+    """Both training cells over the seeds of the serving sweep (48 of them
+    on four chips). The loss gap's worst seed is under two thirds of its
+    limit, which therefore stands. The first update's fall is held from
+    both sides, within OVER_SOUND x the farthest seed from the middle of the
+    sweep's range or more; a state handed back unchanged (a fall of 0) reads UNDER_FAULT
+    x that or more, and half of the batch left out is outside on every seed
+    it was planted on."""
+    import json
+    import os
+
+    from bench_paths import BENCH, manifest_data
+    from benchmark.apps import train_lm
+    with open(os.path.join(BENCH, "testdata",
+                           "train_checks_sweep.json")) as f:
+        sweep = json.load(f)
+    cells = {w["name"]: w for w in manifest_data()["workloads"]}
+    assert set(sweep["cells"]) == {n for n, w in cells.items()
+                                   if w["traffic"].startswith("train")}
+    halved = sweep["faults"]["half_of_the_batch_left_out"]
+    assert set(halved) == set(sweep["cells"])
+
+    def record_of_row(row, fall, expected):
+        return {"checks": {
+            "system_loss": row["system_loss"],
+            "reference_loss": row["reference_loss"],
+            "loss_tolerance": train_lm.LOSS_TOLERANCE,
+            "first_update_fall": fall,
+            "first_update_fall_expected": expected,
+            "warmup_losses": [row["system_loss"]],
+            "param_dtypes": ["float32"], "state_device_sets": [1]},
+            "window": {"steps": []}, "param_dtype": "float32",
+            "facts": {"count": 1}}
+
+    for name, swept in sweep["cells"].items():
+        rows = swept["seeds"]
+        assert swept["device"]["platform"] == "tpu"
+        assert swept["device"]["count"] == cells[name]["chips"]
+        assert len(rows) >= (64 if cells[name]["chips"] == 1 else 48)
+        assert 3200000101 in [row["seed"] for row in rows]
+        worst = max(row["loss_gap"] for row in rows)
+        assert OVER_SOUND * worst <= train_lm.LOSS_TOLERANCE
+        with open(os.path.join(BENCH, "traffic",
+                               cells[name]["traffic"] + ".json")) as f:
+            expected = json.load(f)["first_update_fall"]
+        falls = [row["first_update_fall"] for row in rows]
+        about, within = expected["about"], expected["within"]
+        assert abs(about - (min(falls) + max(falls)) / 2) < 0.002
+        farthest = max(abs(fall - about) for fall in falls)
+        assert OVER_SOUND * farthest <= within <= about / UNDER_FAULT
+        for row in rows:
+            record = record_of_row(row, row["first_update_fall"], expected)
+            assert train_lm.judge(record) == []
+            value, limit = record["judged"]["first_update_fall_off"]
+            assert value <= limit * 0.667
+            # a step that hands its state back unchanged falls by nought;
+            # an update of a third less effect, or a third more, is out too
+            for fall in (0.0, row["first_update_fall"] * 2 / 3,
+                         row["first_update_fall"] * 4 / 3):
+                assert failed_checks(train_lm.judge(
+                    record_of_row(row, fall, expected))) == \
+                    {"first_update_fall_off"}
+        # half of the batch left out, the mean taken over the rest: the
+        # fall is off on every seed it was planted on (the loss gap alone
+        # sees it on most, not all); where it reads ten times the sound
+        # runs' farthest or more it is this number's upper reading, with
+        # the stated room
+        planted = halved[name]["seeds"]
+        assert len(planted) >= 12
+        assert halved[name]["device"]["count"] == cells[name]["chips"]
+        off = [abs(row["first_update_fall"] - about) for row in planted]
+        if min(off) >= 10 * farthest:
+            assert min(off) >= UNDER_FAULT * within, name
+        for row in planted:
+            assert "first_update_fall_off" in failed_checks(train_lm.judge(
+                record_of_row(row, row["first_update_fall"], expected))), \
+                (name, row["seed"])
