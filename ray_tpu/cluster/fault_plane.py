@@ -44,8 +44,7 @@ Action contract at a fault point:
 Disabled cost: fire() compares one cached generation int and does one
 dict lookup, then returns — no config re-resolution, no allocation —
 so fault points stay free on the hot RPC/dispatch paths when no plan
-is loaded. The legacy ``testing_rpc_delay_us`` flag is subsumed: it is
-compiled into delay rules on the ``rpc.server.dispatch`` site.
+is loaded.
 
 Object-tiering sites (spill/restore/evict, r12): ``object.spill.write``
 fires before the daemon writes a cold primary through the spill backend
@@ -89,8 +88,7 @@ from ray_tpu import config
 # site sits and what a fired rule models.
 SITES: Dict[str, str] = {
     "rpc.server.dispatch": "server, before a handler runs (delay models "
-                           "a slow/overloaded server; subsumes "
-                           "testing_rpc_delay_us)",
+                           "a slow/overloaded server)",
     "rpc.server.reply": "server, before the reply frame is written "
                         "(drop_reply models a reply lost on the wire)",
     "rpc.client.send": "client, before a request frame is written "
@@ -216,13 +214,12 @@ class _Rule:
 
 
 class _Compiled:
-    __slots__ = ("gen", "exact", "patterns", "legacy")
+    __slots__ = ("gen", "exact", "patterns")
 
     def __init__(self, gen: int):
         self.gen = gen
         self.exact: Dict[str, List[_Rule]] = {}
         self.patterns: List[_Rule] = []
-        self.legacy: Optional[str] = None  # testing_rpc_delay_us spec
 
 
 _compiled = _Compiled(-1)
@@ -251,22 +248,8 @@ def _recompile() -> _Compiled:
                 new.patterns.append(rule)
             else:
                 new.exact.setdefault(rule.site, []).append(rule)
-        legacy = config.get("testing_rpc_delay_us")
-        new.legacy = str(legacy) if legacy else None
         _compiled = new
         return new
-
-
-def _legacy_delay(spec: str, method: str) -> None:
-    # testing_rpc_delay_us compatibility: "<us>" or "<method>:<us>,..."
-    if ":" in spec:
-        for part in spec.split(","):
-            name, _, us = part.partition(":")
-            if name == method and us.isdigit():
-                time.sleep(int(us) / 1e6)
-                return
-    elif spec.isdigit() and int(spec):
-        time.sleep(int(spec) / 1e6)
 
 
 def fire(site: str, **ctx: Any) -> Optional[str]:
@@ -277,10 +260,8 @@ def fire(site: str, **ctx: Any) -> Optional[str]:
     if c.gen != config.generation:
         c = _recompile()
     rules = c.exact.get(site)
-    if rules is None and not c.patterns and c.legacy is None:
+    if rules is None and not c.patterns:
         return None  # disabled fast path
-    if c.legacy is not None and site == "rpc.server.dispatch":
-        _legacy_delay(c.legacy, ctx.get("method", ""))
     out: Optional[str] = None
     matched = list(rules) if rules else []
     for r in c.patterns:

@@ -666,8 +666,7 @@ def main(argv=None) -> int:
 
         # -- r16: device-native array plane ---------------------------
         # Same-host array put/get on the RTAR fast path (header + raw
-        # buffer, single copy in, read-only view out) vs the classic
-        # pickle-5 path measured back to back as the same-day control.
+        # buffer, single copy in, read-only view out).
         settle()
 
         def array_put_get():
@@ -676,11 +675,6 @@ def main(argv=None) -> int:
 
         per, _ = timed(array_put_get, min_time=2.0 * scale, min_iters=2)
         results["array_put_get_100mb_gb_per_sec"] = round(0.1 / per, 2)
-        config.set_override("array_zero_copy_enabled", False)
-        per, _ = timed(array_put_get, min_time=2.0 * scale, min_iters=2)
-        results["array_put_get_100mb_classic_gb_per_sec"] = round(
-            0.1 / per, 2)
-        config.clear_override("array_zero_copy_enabled")
 
         # Coordinated broadcast tree (ObjectPlane.broadcast_object) to
         # the same 4 peers the directory-driven broadcast above used:

@@ -1433,8 +1433,7 @@ class NodeDaemon:
             self._release_actor_resources(target)
             return {"recycled": False}
         self._release_actor_resources(target)
-        if (recycle and target.env_key == ""
-                and config.get("actor_worker_recycle")):
+        if recycle and target.env_key == "":
             cap = max(config.get("worker_pool_max_size"),
                       config.get("actor_recycle_pool_cap"))
             if self._checkin_worker(target, cap=cap):

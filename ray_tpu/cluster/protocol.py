@@ -261,10 +261,9 @@ def _recv_frame(sock: socket.socket) -> bytearray:
 def _dispatch(service: Any, method: str, kwargs: dict) -> Tuple[bool, Any]:
     """Resolve and run one method; exceptions become the payload."""
     try:
-        # Fault point: delay/raise before serving (subsumes the old
-        # _maybe_inject_delay / testing_rpc_delay_us hook). A raise here
-        # ships to the caller as the call's error payload — a handler
-        # failure, not a transport failure.
+        # Fault point: delay/raise before serving. A raise here ships to
+        # the caller as the call's error payload — a handler failure, not
+        # a transport failure.
         fault_plane.fire("rpc.server.dispatch", method=method)
         if method == "__batch__":
             return True, [_dispatch(service, m, kw)

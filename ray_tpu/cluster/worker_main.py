@@ -285,12 +285,11 @@ class WorkerService:
             t.flush()
 
     def _inline_limit(self) -> int:
-        """Reply-carried return size cap (-1 = feature off); cached against
-        the config generation (this sits on every task return)."""
+        """Reply-carried return size cap; cached against the config
+        generation (this sits on every task return)."""
         from ray_tpu import config
         if self._ilim_gen != config.generation:
-            self._ilim_v = (int(config.get("max_inline_object_bytes"))
-                            if config.get("task_inline_returns") else -1)
+            self._ilim_v = int(config.get("max_inline_object_bytes"))
             self._ilim_gen = config.generation
         return self._ilim_v
 
@@ -306,7 +305,7 @@ class WorkerService:
             return
         limit = self._inline_limit()
         total, segments, refs = serialization.serialize_segments(value)
-        if limit < 0 or total > limit:
+        if total > limit:
             self.plane.put_segments(oid, total, segments, refs)
             collect.append({"stored": True})
             return
@@ -706,9 +705,6 @@ class WorkerService:
         nothing of the dead actor can leak into the next tenant: sync-only
         (an event loop / thread pool may still be running user coroutines),
         and no push in flight."""
-        from ray_tpu import config
-        if not config.get("actor_worker_recycle"):
-            return False
         if self.actor_is_async or self.actor_pool is not None:
             return False
         with self._seq_lock:

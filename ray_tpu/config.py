@@ -120,17 +120,6 @@ define("max_inline_object_bytes", int, 100 * 1024,
        "ops (ObjectPlane.put_value/put_blob, get_inline), task returns ride "
        "the push reply (reply-carried results, sealed lazily), and task "
        "args ship inside the task spec instead of put+pin+dependency-gate.")
-define("task_inline_returns", bool, True,
-       "Serialize task/actor results <= max_inline_object_bytes straight "
-       "into the push_task/push_actor_task reply; the caller seeds its "
-       "inline cache from the reply so get() touches no store/conductor. "
-       "The worker still seals the value into the store lazily so remote "
-       "pulls, wait() and lineage reconstruction keep working.")
-define("task_inline_args", bool, True,
-       "Ship top-level ObjectRef args whose serialized value is <= "
-       "max_inline_object_bytes inside the task spec (reference: in-spec "
-       "small args), skipping the dependency gate and the worker-side "
-       "store fetch for them.")
 define("inline_cache_max_bytes", int, 64 * 1024 * 1024,
        "Byte budget of the caller-side LRU cache of reply-carried inline "
        "results; entries are dropped when the local refcount hits zero.")
@@ -160,12 +149,6 @@ define("object_spill_reconstruct_min_bytes", int, 0,
        "restore when a spill copy exists.")
 
 # Device-native array objects (r16)
-define("array_zero_copy_enabled", bool, True,
-       "Serialize top-level numpy/jax arrays as a tiny RTAR header plus "
-       "the raw buffer (exported zero-copy via dlpack/PickleBuffer) "
-       "instead of pickling the payload; gets return read-only array "
-       "views over the pinned shm mapping. Off = the classic pickle-5 "
-       "path, byte-identical to pre-r16 blobs (regression baseline).")
 define("array_bcast_min_bytes", int, 1 << 20,
        "Objects at least this large take the collective broadcast tree "
        "(ObjectPlane.broadcast_object); smaller ones fall back to plain "
@@ -217,27 +200,14 @@ define("object_pull_shm_direct", bool, True,
        "and copying mapping-to-mapping instead of streaming chunks over "
        "TCP (parity: plasma same-node zero-copy sharing). Tests that "
        "exercise the chunked TCP path disable this.")
-define("lease_reuse_enabled", bool, True,
-       "Reuse a granted worker lease for queued tasks with the same scheduling "
-       "key (the reference's lease-reuse fast path, direct_task_transport.cc). "
-       "Off = every task pays a fresh grant; kept as the no-reuse "
-       "regression baseline for benchmarks.")
 define("max_pending_lease_requests", int, 10, "In-flight lease requests per key.")
 define("actor_start_pool_size", int, 8,
        "Bounded pool of concurrent actor bring-ups per node daemon: a wave "
        "spawns this many workers at once instead of one thread per actor "
        "(unbounded concurrent boots thrash small hosts).")
-define("actor_worker_recycle", bool, True,
-       "Return the worker of a cleanly killed sync actor to the idle pool "
-       "instead of killing the process; the next actor creation then skips "
-       "fork+boot entirely (the dominant cost of an actor wave).")
 define("actor_recycle_pool_cap", int, 128,
        "Idle-pool cap applied when recycling actor workers (the task "
        "pool's worker_pool_max_size stays the spawn-side cap).")
-define("control_plane_batching", bool, True,
-       "Batch control-plane RPCs (register_actors waves, shared actor "
-       "resolution, multi-lease grants). Off = serialized per-actor "
-       "round-trips; kept as the regression baseline for benchmarks.")
 define("lease_multi_grant", int, 4,
        "Max leases granted per request_leases round-trip when a deep task "
        "queue needs pool growth (1 = single-grant behavior).")
@@ -259,12 +229,6 @@ define("worker_fetch_timeout_s", float, 120.0,
        "Executor-side bound on fetching a task argument; a freed/lost dep "
        "fails the task instead of hanging the worker.")
 define("actor_max_restarts_default", int, 0, "Default actor restarts.")
-define("testing_rpc_delay_us", str, "",
-       "Deterministic delay injected before serving matching RPCs; format "
-       "'method:us' pairs comma-separated, or bare int for all methods "
-       "(reference: RAY_testing_asio_delay_us). Subsumed by the fault "
-       "plane (cluster/fault_plane.py) as delay rules on "
-       "rpc.server.dispatch; kept for compatibility.")
 define("fault_plan", str, "",
        "JSON list of fault-injection rules evaluated at named fault "
        "points (cluster/fault_plane.py). Empty = every fault point is a "

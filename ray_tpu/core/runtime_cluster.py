@@ -180,7 +180,6 @@ class TaskSubmitter:
                 "max_pending_lease_requests")
             self._default_max_retries = config.get(
                 "task_max_retries_default")
-            self._lease_reuse = config.get("lease_reuse_enabled")
             self._flags_gen = config.generation
 
     def submit(self, task: dict) -> None:
@@ -338,11 +337,9 @@ class TaskSubmitter:
         from ray_tpu.core.exceptions import RuntimeEnvSetupError
         # Deep queue -> ask for several grants in ONE round-trip (extras
         # only come from already-warm workers, so over-asking is cheap).
-        want = 1
-        if config.get("control_plane_batching"):
-            with st.lock:
-                want = max(1, min(int(config.get("lease_multi_grant")),
-                                  len(st.queue)))
+        with st.lock:
+            want = max(1, min(int(config.get("lease_multi_grant")),
+                              len(st.queue)))
         try:
             try:
                 ws = self.rt._lease_worker(task["resources"],
@@ -513,16 +510,6 @@ class TaskSubmitter:
     def _return_worker(self, st: _KeyState, w: _LeasedWorker) -> None:
         if not w.alive:
             return
-        if not self._lease_reuse:
-            # lease_reuse_enabled=False: the no-reuse regression baseline —
-            # every task pays a fresh grant instead of picking up a
-            # lingering lease.
-            self.rt._release_lease(w)
-            with st.lock:
-                has_work = bool(st.queue)
-            if has_work:
-                self._pump(st)
-            return
         with st.lock:
             w.idle_since = time.monotonic()
             st.idle.append(w)
@@ -612,8 +599,7 @@ class _ActorResolver:
     ``get_actor_infos`` long-poll serves every _ActorClient of this process
     that is waiting for an address. A 100-actor wave would otherwise hold
     100 sockets in per-actor long-polls and pay 100 serialized round-trips
-    (the r05 wave collapse). Falls back to per-actor ``get_actor_info``
-    when control_plane_batching is off."""
+    (the r05 wave collapse)."""
 
     def __init__(self, rt: "ClusterRuntime"):
         self.rt = rt
@@ -623,10 +609,6 @@ class _ActorResolver:
         self._stop = False
 
     def resolve(self, actor_id: bytes, timeout: float) -> dict:
-        if not config.get("control_plane_batching"):
-            return self.rt.conductor.call("get_actor_info",
-                                          actor_id=actor_id,
-                                          wait_alive_timeout=timeout)
         req = {"actor_id": actor_id, "info": None, "ev": threading.Event()}
         with self._cv:
             self._reqs.append(req)
@@ -1046,9 +1028,6 @@ class ClusterRuntime:
         # synchronous conductor RPC).
         _events.configure(self.node_id, self.conductor_address)
         _events.register_probe("object_plane", self.plane.metrics_probe)
-        # inline-arg flag cache (config.get walks os.environ; hot path)
-        self._iargs_gen = None
-        self._iargs_on = True
         # Worker stdout/stderr -> this driver (log_monitor.py role). Only
         # true drivers subscribe: a worker echoing the channel into its own
         # captured stdout would feed back into the channel.
@@ -1548,12 +1527,6 @@ class ClusterRuntime:
         self.submitter.submit(task)
         return out
 
-    def _inline_args_on(self) -> bool:
-        if self._iargs_gen != config.generation:
-            self._iargs_on = bool(config.get("task_inline_args"))
-            self._iargs_gen = config.generation
-        return self._iargs_on
-
     def _inline_args(self, arg_refs: List[ObjectRef]):
         """Resolve small already-available args to blobs riding the task
         spec (reference parity: in-spec inlined args of the direct call
@@ -1563,7 +1536,7 @@ class ClusterRuntime:
         into the next task — the hot pipeline shape) or from the local
         store in ONE batched round trip. Inlined refs skip the dependency
         gate: the value travels with the task."""
-        if not arg_refs or not self._inline_args_on():
+        if not arg_refs:
             return {}, set()
         limit = self.plane._inline_max()
         out: Dict[bytes, bytes] = {}
@@ -1650,8 +1623,7 @@ class ClusterRuntime:
         grant = _GrantSpan.begin(resources)
         if grant:
             spec["trace_ctx"] = grant.ctx
-        if (not opts.name and not opts.get_if_exists
-                and config.get("control_plane_batching")):
+        if not opts.name and not opts.get_if_exists:
             # Unnamed actor: the id is client-generated and collisions are
             # impossible, so registration needs no reply — coalesce it.
             # A 100-actor wave then costs O(few) conductor round-trips.

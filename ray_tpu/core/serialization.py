@@ -104,26 +104,12 @@ def _restore_array(arr):
 # and deserialize returns a read-only view over the pinned mapping.
 # ---------------------------------------------------------------------------
 
-_zc_gen: Optional[int] = None
-_zc_v = True
-
 # Live read-only array views whose base is a pinned shm mapping: each
 # deserialized array registers a finalizer on the mmap, so the conftest
 # hygiene gate (and the rt_array_pins_live gauge) can assert no test
 # leaks a pin past its own teardown.
 _pin_lock = threading.Lock()
 _live_array_pins = 0
-
-
-def _zero_copy_enabled() -> bool:
-    """Generation-cached array_zero_copy_enabled read (serialize sits on
-    the put hot path; config.get walks os.environ)."""
-    global _zc_gen, _zc_v
-    from ray_tpu import config
-    if _zc_gen != config.generation:
-        _zc_v = bool(config.get("array_zero_copy_enabled"))
-        _zc_gen = config.generation
-    return _zc_v
 
 
 def _untrack_pin() -> None:
@@ -222,8 +208,6 @@ def _export_array(value):
 def _array_segments(value) -> Optional[Tuple[int, List]]:
     """RTAR (total, segments) for a top-level array value, or None to
     take the classic pickle path."""
-    if not _zero_copy_enabled():
-        return None
     exported = _export_array(value)
     if exported is None:
         return None

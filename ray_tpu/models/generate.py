@@ -16,10 +16,12 @@ external model in Ray Serve. Here decode is a first-class TPU program
 - `generate` runs the whole decode loop INSIDE jit via lax.scan: static
   shapes (cache padded to max length, attention masked by position), PRNG
   threaded through the scan — zero host round-trips per token.
-- Prefill reuses the training forward structure, collecting per-layer K/V
-  as scan outputs (once a call); decode steps attend over the cache with a
-  position mask (S=1 queries are bandwidth-bound; masking the padded tail
-  costs nothing against reading the cache itself).
+- Prefill and decode run the training forward's one layer
+  (transformer._layer_apply) and hand it only the attention step: prefill
+  keeps each layer's rotated K/V as scan outputs (once a call); decode
+  steps attend over the cache with a position mask (S=1 queries are
+  bandwidth-bound; masking the padded tail costs nothing against reading
+  the cache itself).
 
 GQA (n_kv_heads < n_heads) is supported; pp_stages>1 is not (decode
 pipelining is a different schedule than GPipe microbatching).
@@ -35,8 +37,8 @@ from jax import lax
 
 import dataclasses
 
-from ray_tpu.models.transformer import (TransformerConfig, _layer_apply,
-                                        _rmsnorm, _rope)
+from ray_tpu.models.transformer import (TransformerConfig, _attention,
+                                        _head, _layer_apply)
 
 
 def _inference_cfg(cfg: TransformerConfig) -> TransformerConfig:
@@ -53,14 +55,6 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
     shape = (cfg.n_layers, batch, max_len, cfg.kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype)}
-
-
-def _project_kv(cfg: TransformerConfig, layer, h, positions):
-    a = layer["attn"]
-    dt = cfg.dtype
-    k = jnp.einsum("bse,ehd->bshd", h, a["wk"].astype(dt))
-    v = jnp.einsum("bse,ehd->bshd", h, a["wv"].astype(dt))
-    return _rope(k, positions, cfg.rope_theta), v
 
 
 def _cached_attention(cfg: TransformerConfig, q, k_cache, v_cache, pos):
@@ -102,39 +96,6 @@ def _write_position(cache, l, pos, new):
                                     at)
 
 
-def _decode_layer(cfg: TransformerConfig, layer, l, cache_k, cache_v, x,
-                  pos):
-    """One layer, one token: x [B, 1, E]; cache_k/v the whole stack
-    [L, B, T, KVH, D], of which layer ``l`` gets position ``pos`` written
-    and is then attended over. -> (x, cache_k, cache_v)."""
-    dt = cfg.dtype
-    h = _rmsnorm(x, layer["ln1"])
-    a = layer["attn"]
-    positions = jnp.full((x.shape[0], 1), pos)
-    q = jnp.einsum("bse,ehd->bshd", h, a["wq"].astype(dt))
-    q = _rope(q, positions, cfg.rope_theta)
-    k_new, v_new = _project_kv(cfg, layer, h, positions)
-    # Write, then read the slab from the UPDATED stack: a read of the old
-    # stack after the write would make XLA keep two buffers and copy.
-    cache_k = _write_position(cache_k, l, pos, k_new)
-    cache_v = _write_position(cache_v, l, pos, v_new)
-    o = _cached_attention(
-        cfg, q, lax.dynamic_index_in_dim(cache_k, l, 0, keepdims=False),
-        lax.dynamic_index_in_dim(cache_v, l, 0, keepdims=False), pos)
-    o = jnp.einsum("bshd,hde->bse", o, a["wo"].astype(dt))
-    x = x + o
-    h = _rmsnorm(x, layer["ln2"])
-    if cfg.num_experts:
-        from ray_tpu.models.moe import moe_apply
-        y = moe_apply(cfg, layer["moe"], h)
-    else:
-        m = layer["mlp"]
-        gate = jax.nn.silu(h @ m["w1"].astype(dt))
-        up = h @ m["w3"].astype(dt)
-        y = (gate * up) @ m["w2"].astype(dt)
-    return x + y, cache_k, cache_v
-
-
 def prefill(params, tokens, cfg: TransformerConfig, max_len: int,
             mesh=None) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """Run the prompt through the trunk, returning (last-position logits
@@ -145,22 +106,19 @@ def prefill(params, tokens, cfg: TransformerConfig, max_len: int,
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     x = params["embed"].astype(cfg.dtype)[tokens]
+    pad = ((0, 0), (0, max_len - s), (0, 0), (0, 0))
+
+    def attend(q, k, v):
+        # The training forward's attention; the layer's rotated K and V
+        # are kept, so the cache matches the forward bit for bit.
+        return (_attention(cfg, q, k, v, mesh),
+                {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)})
 
     def step(carry, layer):
-        # return_kv hands back the layer's already-computed rotated K/V —
-        # cache matches the forward bit-for-bit at zero extra FLOPs.
-        out, (k, v) = _layer_apply(cfg, mesh, layer, carry, positions,
-                                   return_kv=True)
-        pad = max_len - s
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        return out, {"k": k, "v": v}
+        return _layer_apply(cfg, layer, carry, positions, attend)
 
     x, cache = lax.scan(step, x, params["layers"])
-    x = _rmsnorm(x, params["final_norm"])
-    head = (params["embed"].T if cfg.tied_embeddings else params["lm_head"])
-    logits = (x[:, -1:] @ head.astype(cfg.dtype)).astype(jnp.float32)
-    return logits[:, 0], cache
+    return _head(params, x[:, -1:], cfg)[:, 0], cache
 
 
 def decode_step(params, token, pos, cache, cfg: TransformerConfig):
@@ -168,20 +126,33 @@ def decode_step(params, token, pos, cache, cfg: TransformerConfig):
     -> (logits [B, vocab], updated cache)."""
     cfg = _inference_cfg(cfg)
     x = params["embed"].astype(cfg.dtype)[token][:, None, :]   # [B, 1, E]
+    positions = jnp.full((x.shape[0], 1), pos)
 
     def step(carry, layer_and_index):
         x, cache_k, cache_v = carry
         layer, l = layer_and_index
-        return _decode_layer(cfg, layer, l, cache_k, cache_v, x, pos), None
+
+        def attend(q, k, v):
+            # Write, then read the slab from the UPDATED stack: a read of
+            # the old stack after the write would make XLA keep two
+            # buffers and copy.
+            stack_k = _write_position(cache_k, l, pos, k)
+            stack_v = _write_position(cache_v, l, pos, v)
+            o = _cached_attention(
+                cfg, q,
+                lax.dynamic_index_in_dim(stack_k, l, 0, keepdims=False),
+                lax.dynamic_index_in_dim(stack_v, l, 0, keepdims=False),
+                pos)
+            return o, (stack_k, stack_v)
+
+        x, (cache_k, cache_v) = _layer_apply(cfg, layer, x, positions,
+                                             attend)
+        return (x, cache_k, cache_v), None
 
     (x, cache_k, cache_v), _ = lax.scan(
         step, (x, cache["k"], cache["v"]),
         (params["layers"], jnp.arange(cfg.n_layers)))
-    cache = {"k": cache_k, "v": cache_v}
-    x = _rmsnorm(x, params["final_norm"])
-    head = (params["embed"].T if cfg.tied_embeddings else params["lm_head"])
-    logits = (x @ head.astype(cfg.dtype)).astype(jnp.float32)
-    return logits[:, 0], cache
+    return _head(params, x, cfg)[:, 0], {"k": cache_k, "v": cache_v}
 
 
 def _sample(logits, key, temperature: float, top_k: Optional[int]):

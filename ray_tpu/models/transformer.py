@@ -195,7 +195,8 @@ def _rope(x, positions, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _attention(cfg: TransformerConfig, q, k, v, mesh, rules):
+def _attention(cfg: TransformerConfig, q, k, v, mesh,
+               rules: LogicalRules = DEFAULT_RULES):
     impl = cfg.attn_impl
     if impl == "ring":
         return ring_attention(q, k, v, mesh, causal=cfg.causal)
@@ -205,9 +206,22 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh, rules):
                rules=rules)
 
 
-def _layer_apply(cfg: TransformerConfig, mesh, layer, x, positions,
-                 return_kv: bool = False,
-                 rules: LogicalRules = DEFAULT_RULES):
+def _feed_forward(cfg: TransformerConfig, layer, h):
+    if cfg.num_experts:
+        from ray_tpu.models.moe import moe_apply
+        return moe_apply(cfg, layer["moe"], h)
+    dt = cfg.dtype
+    m = layer["mlp"]
+    gate = jax.nn.silu(h @ m["w1"].astype(dt))
+    up = h @ m["w3"].astype(dt)
+    return (gate * up) @ m["w2"].astype(dt)
+
+
+def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend):
+    """One block. ``attend(q, k, v) -> (o, kept)`` is all that differs
+    between training, prefill and decode (models/generate.py): what
+    attention does with the rotated k and v, and what it keeps of them.
+    -> (x, kept)."""
     dt = cfg.dtype
     h = _rmsnorm(x, layer["ln1"])
     a = layer["attn"]
@@ -216,38 +230,47 @@ def _layer_apply(cfg: TransformerConfig, mesh, layer, x, positions,
     v = jnp.einsum("bse,ehd->bshd", h, a["wv"].astype(dt))
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
-    o = _attention(cfg, q, k, v, mesh, rules)
+    o, kept = attend(q, k, v)
     o = jnp.einsum("bshd,hde->bse", o, a["wo"].astype(dt))
     x = x + o
     h = _rmsnorm(x, layer["ln2"])
-    if cfg.num_experts:
-        from ray_tpu.models.moe import moe_apply
-        y = moe_apply(cfg, layer["moe"], h)
-    else:
-        m = layer["mlp"]
-        gate = jax.nn.silu(h @ m["w1"].astype(dt))
-        up = h @ m["w3"].astype(dt)
-        y = (gate * up) @ m["w2"].astype(dt)
-    if return_kv:
-        # KV-cache prefill path (models/generate.py): hand back the
-        # ALREADY-COMPUTED rotated K and V instead of recomputing them.
-        return x + y, (k, v)
-    return x + y
+    return x + _feed_forward(cfg, layer, h), kept
 
 
 def _stage_apply(cfg: TransformerConfig, mesh, stage_layers, x, positions,
                  rules: LogicalRules = DEFAULT_RULES):
     """Apply a stack of layers (leading dim = layers) with lax.scan.
     ``rules``: what the caller sharded params and batch by over ``mesh``."""
-    body = partial(_layer_apply, cfg, mesh, rules=rules)
+    body = partial(
+        _layer_apply, cfg,
+        attend=lambda q, k, v: (_attention(cfg, q, k, v, mesh, rules), None))
     if cfg.remat:
         body = jax.checkpoint(body)
 
     def step(carry, layer):
-        return body(layer, carry, positions), None
+        return body(layer, carry, positions)
 
     out, _ = lax.scan(step, x, stage_layers)
     return out
+
+
+def _head(params, x, cfg: TransformerConfig):
+    """Final norm + (tied or untied) head: x [..., E] -> float32 logits."""
+    x = _rmsnorm(x, params["final_norm"])
+    head = (params["embed"].T if cfg.tied_embeddings else params["lm_head"])
+    return (x @ head.astype(cfg.dtype)).astype(jnp.float32)
+
+
+def _next_token_loss(logits, tokens, mask=None):
+    """Cross-entropy of logits[:, :-1] against tokens[:, 1:], mean over
+    the positions ``mask`` keeps (all when None)."""
+    targets = tokens[:, 1:]
+    logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
+    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    if mask is not None:
+        mask = mask[:, 1:]
+        return (nll * mask).sum() / jnp.maximum(mask.sum(), 1)
+    return nll.mean()
 
 
 def transformer_apply(params, tokens, cfg: TransformerConfig, *,
@@ -279,9 +302,7 @@ def transformer_apply(params, tokens, cfg: TransformerConfig, *,
         x = x.reshape(b, s, cfg.d_model)
     else:
         x = _stage_apply(cfg, mesh, params["layers"], x, positions, rules)
-    x = _rmsnorm(x, params["final_norm"])
-    head = (params["embed"].T if cfg.tied_embeddings else params["lm_head"])
-    return (x @ head.astype(cfg.dtype)).astype(jnp.float32)
+    return _head(params, x, cfg)
 
 
 def transformer_loss(params, batch, cfg: TransformerConfig, *, mesh=None,
@@ -290,15 +311,7 @@ def transformer_loss(params, batch, cfg: TransformerConfig, *, mesh=None,
     non-final positions)."""
     tokens = batch["tokens"]
     logits = transformer_apply(params, tokens, cfg, mesh=mesh, rules=rules)
-    targets = tokens[:, 1:]
-    logits = logits[:, :-1]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    mask = batch.get("mask")
-    if mask is not None:
-        mask = mask[:, 1:]
-        return (nll * mask).sum() / jnp.maximum(mask.sum(), 1)
-    return nll.mean()
+    return _next_token_loss(logits, tokens, batch.get("mask"))
 
 
 # ---------------------------------------------------------------------------
@@ -350,13 +363,7 @@ def transformer_stage_loss(stage_params, x, tokens,
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     x = transformer_stage_forward(stage_params, x, positions, cfg,
                                   part=cfg.pp_stages - 1, mesh=mesh)
-    x = _rmsnorm(x, stage_params["final_norm"])
-    logits = (x @ stage_params["lm_head"].astype(cfg.dtype)) \
-        .astype(jnp.float32)
-    targets = tokens[:, 1:]
-    logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return nll.mean()
+    return _next_token_loss(_head(stage_params, x, cfg), tokens)
 
 
 def transformer_num_params(cfg: TransformerConfig) -> int:

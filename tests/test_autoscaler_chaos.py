@@ -132,20 +132,22 @@ def test_autoscaler_terminates_zombie_provider(cluster):
 
 
 def test_rpc_delay_injection(cluster):
-    from ray_tpu import config
+    from ray_tpu.cluster import fault_plane
     from ray_tpu.cluster.protocol import get_client
     cli = get_client(cluster.address)
     t0 = time.perf_counter()
     cli.call("ping")
     base = time.perf_counter() - t0
-    config.set_override("testing_rpc_delay_us", "ping:200000")
+    fault_plane.load_plan([{"site": "rpc.server.dispatch",
+                            "match": {"method": "ping"},
+                            "action": "delay", "delay_s": 0.2}])
     try:
         t0 = time.perf_counter()
         cli.call("ping")
         delayed = time.perf_counter() - t0
         assert delayed > base + 0.15  # the 200ms injected delay is visible
     finally:
-        config.clear_override("testing_rpc_delay_us")
+        fault_plane.clear_plan()
 
 
 def test_chaos_worker_killing_with_retries(cluster):

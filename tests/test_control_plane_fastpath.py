@@ -13,7 +13,6 @@ import time
 import pytest
 
 import ray_tpu as rt
-from ray_tpu import config
 from ray_tpu.cluster.cluster_utils import Cluster
 from ray_tpu.cluster.protocol import RpcClient, RpcError, RpcServer
 from ray_tpu.core import api as core_api
@@ -101,7 +100,7 @@ def test_classic_and_pipelined_share_one_client(rpc_pair):
         cli.call("no_such_method")
 
 
-# -- end-to-end: actor wave, batched vs serialized ------------------------
+# -- end-to-end: actor wave ------------------------------------------------
 
 
 @pytest.fixture(scope="module")
@@ -116,8 +115,7 @@ def cluster():
 
 
 def _actor_wave(n):
-    """Create n actors, ack one call on each, kill them; return elapsed
-    seconds for the create+ack part (the wave latency a trainer sees)."""
+    """Create n actors, ack one call on each, kill them."""
 
     @rt.remote
     class Probe:
@@ -125,34 +123,44 @@ def _actor_wave(n):
             return 1
 
     cls = Probe.options(num_cpus=0.01)
-    t0 = time.perf_counter()
     actors = [cls.remote() for _ in range(n)]
     assert rt.get([a.ping.remote() for a in actors]) == [1] * n
-    dt = time.perf_counter() - t0
     for a in actors:
         rt.kill(a)
-    return dt
 
 
-def test_actor_wave_batched_vs_serialized(cluster):
+def _idle_workers(daemon):
+    return sum(daemon.rpc_debug_state()["idle_workers"].values())
+
+
+def test_actor_wave_is_batched_and_recycled(cluster):
+    """A 100-actor wave reaches the conductor in a handful of
+    ``register_actors`` calls, not one an actor, and once its workers are
+    back in the idle pool a second wave forks no process. Counted in the
+    in-process conductor and daemon by no-op rules of the fault plane."""
+    from ray_tpu.cluster import fault_plane
     n = 100
-    # Serialized baseline: per-actor register/resolve round-trips and a
-    # fresh fork+boot per actor (no recycling). The overrides reach the
-    # in-process daemon directly and spawned workers via env propagation.
-    config.set_override("control_plane_batching", False)
-    config.set_override("actor_worker_recycle", False)
+    fault_plane.load_plan([
+        {"site": "rpc.server.dispatch", "action": "delay", "delay_s": 0.0,
+         "match": {"method": "register_actors"}},
+        {"site": "daemon.worker.spawn", "action": "delay", "delay_s": 0.0},
+    ])
     try:
-        serial_s = _actor_wave(n)
+        (daemon,) = cluster.nodes
+        _actor_wave(n)      # warms the recycle pool (it still pays forks)
+        deadline = time.monotonic() + 60
+        while _idle_workers(daemon) < n and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert _idle_workers(daemon) >= n
+        before = fault_plane.stats()
+        _actor_wave(n)
+        after = fault_plane.stats()
     finally:
-        config.clear_override("control_plane_batching")
-        config.clear_override("actor_worker_recycle")
-    # Batched path: first wave warms the recycle pool (it still pays the
-    # forks), the second is the steady state the wave metric targets.
-    _actor_wave(n)
-    fast_s = _actor_wave(n)
-    assert fast_s * 5 <= serial_s, (
-        f"batched wave {n / fast_s:.0f}/s not >=5x serialized "
-        f"{n / serial_s:.0f}/s")
+        fault_plane.clear_plan()
+    registrations = after["rpc.server.dispatch"] \
+        - before["rpc.server.dispatch"]
+    assert 1 <= registrations <= n // 5, registrations
+    assert after["daemon.worker.spawn"] == before["daemon.worker.spawn"]
 
 
 def test_batched_registration_failure_surfaces(cluster):

@@ -45,8 +45,8 @@ def cluster():
 @pytest.fixture(autouse=True)
 def _clean_overrides():
     yield
-    for flag in ("array_zero_copy_enabled", "array_bcast_min_bytes",
-                 "array_bcast_fanout", "array_bcast_leg_timeout_s"):
+    for flag in ("array_bcast_min_bytes", "array_bcast_fanout",
+                 "array_bcast_leg_timeout_s"):
         config.clear_override(flag)
     fault_plane.clear_plan()
 
@@ -114,32 +114,22 @@ def test_rtar_jax_arrays_record_device():
     assert np.array_equal(out, np.asarray(x))
 
 
-def test_flag_off_classic_path_byte_identical(monkeypatch):
-    """array_zero_copy_enabled=False must reproduce the classic pickle-5
-    blob BYTE-IDENTICAL to a build with no array fast path at all."""
-    arr = np.arange(1 << 12, dtype=np.float32).reshape(64, 64)
-    config.set_override("array_zero_copy_enabled", False)
-    flag_off_blob, _ = serialization.serialize(arr)
-    config.clear_override("array_zero_copy_enabled")
-    assert not serialization.is_array_blob(flag_off_blob)
-    # Simulate the pre-r16 serializer: the fast path is simply absent.
-    monkeypatch.setattr(serialization, "_array_segments", lambda v: None)
-    classic_blob, _ = serialization.serialize(arr)
-    assert bytes(flag_off_blob) == bytes(classic_blob)
-    out = serialization.deserialize(flag_off_blob)
-    assert np.array_equal(out, arr) and out.dtype == arr.dtype
-
-
-def test_export_fault_falls_back_to_classic(chaos_seed):
+def test_export_fault_falls_back_to_classic(chaos_seed, monkeypatch):
     fault_plane.load_plan([{"site": "object.array.export",
                             "action": "raise", "nth": 1, "times": 1}],
                           seed=chaos_seed)
     arr = np.arange(256, dtype=np.int32)
     blob, _ = serialization.serialize(arr)
     assert not serialization.is_array_blob(blob)   # export failed: classic
-    assert np.array_equal(serialization.deserialize(blob), arr)
+    out = serialization.deserialize(blob)
+    assert np.array_equal(out, arr) and out.dtype == arr.dtype
     blob2, _ = serialization.serialize(arr)
     assert serialization.is_array_blob(blob2)      # plan exhausted: RTAR
+    # The fallback blob is BYTE-IDENTICAL to what a serializer with no
+    # array fast path at all writes: a plain pickle-5 blob.
+    monkeypatch.setattr(serialization, "_array_segments", lambda v: None)
+    plain, _ = serialization.serialize(arr)
+    assert bytes(blob) == bytes(plain)
 
 
 # ---------------------------------------------------------------------------

@@ -46,9 +46,7 @@ def cluster():
 @pytest.fixture(autouse=True)
 def _clean_state():
     yield
-    for flag in ("task_inline_returns", "task_inline_args",
-                 "max_inline_object_bytes"):
-        config.clear_override(flag)
+    config.clear_override("max_inline_object_bytes")
     fault_plane.clear_plan()
 
 
@@ -191,54 +189,31 @@ def test_inline_cache_entry_dropped_on_zero_refcount(cluster):
 # ---------------------------------------------------------------------------
 
 
-def test_fastpath_flags_off_regression():
-    """With task_inline_returns/task_inline_args forced off cluster-wide,
-    tasks must take the classic store path and still round-trip — the
-    fast path is an optimization, not a semantic dependency."""
-    config.set_override("task_inline_returns", False)
-    config.set_override("task_inline_args", False)
-    c = Cluster(initialize_head=True, head_node_args={"num_cpus": 2})
-    rt_ = ClusterRuntime(address=c.address)
-    prior = core_api._runtime
-    core_api._runtime = rt_
-    try:
-        @rt.remote
-        def echo(x):
-            return x
-
-        ref = echo.remote(b"classic")
-        assert rt.get(ref, timeout=60) == b"classic"
-        # No reply blob was cached: the result went store-only.
-        assert not rt_.plane._inline.has(_key_of(ref))
-
-        @rt.remote
-        def add(x, y):
-            return x + y
-
-        assert rt.get(add.remote(echo.remote(20), 22), timeout=60) == 42
-    finally:
-        core_api._runtime = prior
-        rt_.shutdown()
-        c.shutdown()
-        config.clear_override("task_inline_returns")
-        config.clear_override("task_inline_args")
-
-
-def test_put_blob_threshold_reads_config(cluster):
+def test_put_blob_threshold_reads_config():
     """max_inline_object_bytes is THE single knob: shrinking it must push
     a previously-inline-sized return onto the store path (observable as a
-    cache miss on the owner) while keeping it gettable."""
-    runtime = core_api._runtime
+    cache miss on the owner) while keeping it gettable. The worker reads
+    the knob when it is spawned, so the cluster is brought up under it."""
     config.set_override("max_inline_object_bytes", 64)
+    c = Cluster(initialize_head=True, head_node_args={"num_cpus": 2})
+    runtime = ClusterRuntime(address=c.address)
+    prior = core_api._runtime
+    core_api._runtime = runtime
     try:
         @rt.remote
-        def over_threshold():
-            return b"x" * 512  # > 64B cap: must NOT ride the reply
+        def of_size(n):
+            return b"x" * n
 
-        ref = over_threshold.remote()
-        assert rt.get(ref, timeout=30) == b"x" * 512
-        assert not runtime.plane._inline.has(_key_of(ref))
+        over = of_size.remote(512)      # > 64B cap: must NOT ride the reply
+        assert rt.get(over, timeout=60) == b"x" * 512
+        assert not runtime.plane._inline.has(_key_of(over))
+        under = of_size.remote(8)
+        assert rt.get(under, timeout=60) == b"x" * 8
+        assert runtime.plane._inline.has(_key_of(under))
     finally:
+        core_api._runtime = prior
+        runtime.shutdown()
+        c.shutdown()
         config.clear_override("max_inline_object_bytes")
 
 

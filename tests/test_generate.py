@@ -203,3 +203,96 @@ def test_greedy_tokens_pinned_to_reforward(overrides, prompt_len, new_tokens):
     got = generate(params, prompt, cfg, max_new_tokens=new_tokens,
                    temperature=0.0)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# --- one layer and one head, shared by training, prefill and decode ----------
+
+def _last_logits(params, tokens, cfg):
+    """The logits after ``tokens``' last position, three ways."""
+    from ray_tpu.models import decode_step
+    s = tokens.shape[1]
+    _, cache = prefill(params, tokens[:, :-1], cfg, max_len=s + 3)
+    return {
+        "train": transformer_apply(params, tokens, cfg)[:, -1],
+        "prefill": prefill(params, tokens, cfg, max_len=s + 3)[0],
+        "decode": decode_step(params, tokens[:, -1],
+                              jnp.asarray(s - 1, jnp.int32), cache, cfg)[0],
+    }
+
+
+def _halved(fn):
+    return lambda *a, **kw: 0.5 * fn(*a, **kw)
+
+
+@pytest.mark.parametrize("overrides", [
+    pytest.param(dict(), id="dense"),
+    pytest.param(dict(num_experts=4, expert_top_k=2,
+                      moe_capacity_factor=1e9), id="moe"),
+])
+@pytest.mark.parametrize("shared", ["_feed_forward", "_head"])
+@pytest.mark.parametrize("path", ["train", "prefill", "decode"])
+def test_every_path_runs_the_one_definition(monkeypatch, path, shared,
+                                            overrides):
+    """The feed-forward and the head are written once: a marked stand-in
+    put in the place of the one function moves the full forward, prefill's
+    last-position logits and decode_step's logits alike, and the three go
+    on agreeing as they do untouched."""
+    import sys
+
+    from ray_tpu.models import transformer
+    # ray_tpu.models.generate, the attribute, is the function
+    generate_module = sys.modules["ray_tpu.models.generate"]
+
+    cfg = _cfg(**overrides)
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 9), 0, 97)
+    plain = _last_logits(params, tokens, cfg)
+    stand_in = _halved(getattr(transformer, shared))
+    monkeypatch.setattr(transformer, shared, stand_in)
+    if hasattr(generate_module, shared):        # imported by name there
+        monkeypatch.setattr(generate_module, shared, stand_in)
+    marked = _last_logits(params, tokens, cfg)
+    assert not np.allclose(np.asarray(marked[path]), np.asarray(plain[path]),
+                           rtol=1e-2, atol=1e-2)
+    for other in marked:
+        np.testing.assert_allclose(
+            np.asarray(marked[path]), np.asarray(marked[other]),
+            rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(
+            np.asarray(plain[path]), np.asarray(plain[other]),
+            rtol=2e-4, atol=2e-4)
+    if shared == "_head":
+        np.testing.assert_allclose(np.asarray(marked[path]),
+                                   0.5 * np.asarray(plain[path]),
+                                   rtol=1e-6, atol=1e-6)
+
+
+def test_generate_names_no_weight_of_the_layer():
+    """What a layer computes from its weights stands in
+    transformer._layer_apply alone: no function of generate.py subscripts
+    a layer's weight, so a new mechanism of the block is written once."""
+    import ast
+    import inspect
+    import sys
+
+    generate_module = sys.modules["ray_tpu.models.generate"]
+
+    def keys(tree):
+        for k, v in tree.items():
+            yield k
+            if isinstance(v, dict):
+                yield from keys(v)
+
+    weights = set()
+    for overrides in (dict(), dict(num_experts=2)):
+        cfg = _cfg(n_layers=1, **overrides)
+        layers = jax.eval_shape(
+            lambda: transformer_init(jax.random.PRNGKey(0), cfg))["layers"]
+        weights |= set(keys(layers))
+    assert {"wq", "wk", "wv", "wo", "w1", "w2", "w3", "moe"} <= weights
+    named = [(node.lineno, node.slice.value)
+             for node in ast.walk(ast.parse(inspect.getsource(generate_module)))
+             if isinstance(node, ast.Subscript)
+             and isinstance(node.slice, ast.Constant)
+             and node.slice.value in weights]
+    assert named == []
