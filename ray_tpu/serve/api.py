@@ -67,13 +67,16 @@ class ServeCallRef(ChannelResolvedRef):
     handle so a call that died with its replica (or was shed at the
     replica's in-flight cap) is retried ONCE on a different replica,
     transparently to rt.get()/rt.wait(). Timeouts cancel the in-flight
-    actor task instead of leaking it."""
+    actor task instead of leaking it. ``_key`` is the replica whose slot
+    of the handle's in-flight book the call holds now; ``tracked`` says
+    who gives it back: the handle's drainer (``.remote()``) or the caller
+    (``.call()``)."""
 
     __slots__ = ("_handle", "_inner", "_key", "_args_blob", "_method",
-                 "_retried")
+                 "_retried", "_tracked")
 
     def __init__(self, handle: "DeploymentHandle", inner, key,
-                 method: str, args_blob: bytes):
+                 method: str, args_blob: bytes, tracked: bool = True):
         super().__init__(inner.id)
         self._handle = handle
         self._inner = inner
@@ -81,6 +84,7 @@ class ServeCallRef(ChannelResolvedRef):
         self._method = method
         self._args_blob = args_blob
         self._retried = False
+        self._tracked = tracked
 
     def _resolve(self, timeout: Optional[float] = None):
         import ray_tpu as rt
@@ -107,14 +111,14 @@ class ServeCallRef(ChannelResolvedRef):
                 self._retried = True
                 wait_s = 2.0 if deadline is None else \
                     max(0.0, deadline - time.monotonic())
-                inner = self._handle._resubmit(
+                again = self._handle._resubmit(
                     self._key, self._method, self._args_blob,
-                    wait_s=min(wait_s, 30.0))
-                if inner is None:
+                    wait_s=min(wait_s, 30.0), track=self._tracked)
+                if again is None:
                     raise
                 _emit("serve.retry", self._handle.name)
-                self._inner = inner
-                self._key = None  # key travels with the new submission
+                # the failed replica's slots went with its eviction
+                self._inner, self._key = again
 
     def _is_ready(self) -> bool:
         import ray_tpu as rt
@@ -238,23 +242,36 @@ class DeploymentHandle:
             return a if self._inflight.get(a._rt_actor_id, 0) <= \
                 self._inflight.get(b._rt_actor_id, 0) else b
 
-    def _submit(self, replica, args_blob: bytes):
+    def _submit(self, replica, args_blob: bytes, track: bool = True):
+        """Take a slot of ``replica`` in the in-flight book and submit.
+        The slot is given back once, when the request completes: with
+        ``track`` by the drainer thread, which watches every outstanding
+        ref of this handle (the caller of ``.remote()`` may never resolve
+        its ref); without it by the caller, through ``_release``."""
         key = replica._rt_actor_id
         with self._lock:
             self._inflight[key] = self._inflight.get(key, 0) + 1
-        ref = replica.handle_request.remote(self.method, args_blob)
-        # Decrement when the request actually completes (the ref resolves);
-        # a single drainer thread per handle watches all outstanding refs.
-        self._track(ref, key)
+        try:
+            ref = replica.handle_request.remote(self.method, args_blob)
+        except BaseException:  # noqa: BLE001 - re-raised, slot returned
+            self._release(key)
+            raise
+        if track:
+            self._track(ref, key)
         return ref, key
 
+    def _release(self, key) -> None:
+        with self._lock:
+            self._inflight[key] = max(0, self._inflight.get(key, 1) - 1)
+
     def _resubmit(self, failed_key, method: str, args_blob: bytes,
-                  wait_s: float = 0.0):
+                  wait_s: float = 0.0, track: bool = True):
         """Retry path for ServeCallRef: evict the failed replica, pick a
         DIFFERENT one, submit there. The pick honors the per-replica
         in-flight cap — a retry dumped onto a saturated replica would be
         shed a second time and surface as a hard failure — waiting up to
-        ``wait_s`` for a slot. None when no alternative exists."""
+        ``wait_s`` for a slot. -> (ref, the replica's key), or None when
+        no alternative exists."""
         from ray_tpu.serve.controller import ReplicaBusyError
         if failed_key is not None:
             self._evict(failed_key)
@@ -271,8 +288,7 @@ class DeploymentHandle:
                 time.sleep(0.005)
             except Exception:
                 return None
-        ref, _ = self._submit(replica, args_blob)
-        return ref
+        return self._submit(replica, args_blob, track=track)
 
     def remote(self, *args, **kwargs):
         replica = self._pick()
@@ -285,9 +301,7 @@ class DeploymentHandle:
             if ref is not None:
                 self._track(ref, key)
                 return ref
-            with self._lock:
-                self._inflight[key] = max(
-                    0, self._inflight.get(key, 1) - 1)
+            self._release(key)
         ref, key = self._submit(replica, args_blob)
         return ServeCallRef(self, ref, key, self.method, args_blob)
 
@@ -296,7 +310,13 @@ class DeploymentHandle:
         a replica slot (per-replica in-flight cap), submits, resolves with
         the one-retry policy. Raises ReplicaBusyError when no capacity
         frees up in time, GetTimeoutError past the deadline. This is the
-        proxy's dispatch path."""
+        proxy's dispatch path.
+
+        The caller gives its slot of the in-flight book back itself, the
+        moment its call resolved (reply, error or deadline), so the next
+        ``call`` finds the slot at once. The drainer thread, which lowers
+        the book one ``rt.wait`` round a ref, is ``.remote()``'s alone: at
+        a full cap it would be the wait of every re-sent request."""
         from ray_tpu import config
         from ray_tpu.serve.controller import ReplicaBusyError
         if timeout is None:
@@ -313,11 +333,13 @@ class DeploymentHandle:
                         raise
                     time.sleep(0.005)
         with events.span("serve.handle.call") as call:
-            ref, key = self._submit(replica, args_blob)
-            sref = ServeCallRef(self, ref, key, self.method, args_blob)
+            ref, key = self._submit(replica, args_blob, track=False)
+            sref = ServeCallRef(self, ref, key, self.method, args_blob,
+                                tracked=False)
             try:
                 return sref._resolve(max(0.0, deadline - time.monotonic()))
             finally:
+                self._release(sref._key)
                 call.set(retries=int(sref._retried))
 
     def _remote_compiled(self, replica, key, args_blob):

@@ -4,9 +4,11 @@ Role parity: serve/_private/http_proxy.py:250 — per-node proxy actor
 translating HTTP to deployment calls. The reference runs uvicorn/starlette
 (ASGI); here an asyncio HTTP/1.1 server keeps the image dependency-free
 while matching the ASGI proxy's operational shape: one event loop, many
-concurrent in-flight requests (each deployment call runs in an executor so
-the loop never blocks), keep-alive connections, and chunked
-Transfer-Encoding for streaming responses (serve.StreamingResponse).
+concurrent in-flight requests (each admitted deployment call blocks a thread
+of the proxy's own, never the loop and never one of the loop's default
+executor: admission is the only limit on how many are in flight),
+keep-alive connections, and chunked Transfer-Encoding for streaming
+responses (serve.StreamingResponse).
 
 Admission control (parity: the proxy's backpressure +
 max_queued_requests): each deployment gets a queue budget
@@ -20,7 +22,9 @@ in-flight call cancelled rather than leaked.
 from __future__ import annotations
 
 import asyncio
+import concurrent.futures
 import json
+import queue
 import threading
 import time
 import weakref
@@ -75,18 +79,91 @@ def _emit(kind: str, ident: str, value: float = 1.0, **attrs) -> None:
         pass
 
 
+class _CallThreads:
+    """The threads admitted deployment calls block on: one a call, made
+    when no idle one waits, reused while idle, ended and joined by
+    ``close()``. Admission bounds how many calls are in flight, so it
+    bounds these too and no size is configured (the loop's default executor
+    has ``min(32, cpus + 4)`` threads, a limit that would depend on the
+    host)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._idle: list = []       # inboxes of the threads with no call
+        self._threads: list = []
+        self._closed = False
+
+    def submit(self, fn) -> concurrent.futures.Future:
+        fut = concurrent.futures.Future()
+        with self._lock:
+            if self._closed:
+                raise RuntimeError("serve proxy is closed")
+            if self._idle:
+                inbox = self._idle.pop()
+            else:
+                inbox = queue.SimpleQueue()
+                thread = threading.Thread(
+                    target=self._work, args=(inbox,), daemon=True,
+                    name=f"serve-call-{len(self._threads)}")
+                self._threads.append(thread)
+                thread.start()
+        inbox.put((fut, fn))
+        return fut
+
+    def _work(self, inbox: queue.SimpleQueue) -> None:
+        while True:
+            item = inbox.get()
+            if item is None:
+                return
+            fut, fn = item
+            out = error = None
+            if fut.set_running_or_notify_cancel():
+                try:
+                    out = fn()
+                except BaseException as e:  # noqa: BLE001 - the awaiter's
+                    error = e
+            # Idle BEFORE the call resolves: the resolution is what lets
+            # admission take the next request in, and that one finds this
+            # thread. So there are never more threads than admitted calls.
+            with self._lock:
+                closed = self._closed
+                if not closed:
+                    self._idle.append(inbox)
+            if error is not None:
+                fut.set_exception(error)
+            elif not fut.cancelled():
+                fut.set_result(out)
+            if closed:
+                return
+
+    def close(self) -> None:
+        """No new calls; idle threads end now, a busy one when its call
+        does. Joins them all, each for no longer than a call may last
+        (every call carries the request deadline)."""
+        from ray_tpu import config
+        with self._lock:
+            self._closed = True
+            idle, self._idle = self._idle, []
+        for inbox in idle:
+            inbox.put(None)
+        patience = float(config.get("serve_request_timeout_s")) + 5.0
+        for thread in self._threads:
+            thread.join(patience)
+
+
 class HTTPProxy:
     def __init__(self, host: str = "127.0.0.1", port: int = 0):
-        import concurrent.futures
         self._routes_cache: dict = {}
         self._routes_ts = 0.0
         self._routes_lock = threading.Lock()
         self._routes_refreshing = False
         self._fetch_future = None   # in-flight fetch shared by missers
-        # dedicated 1-thread executor for route refreshes: deployment
-        # calls saturating the default pool must never block routing
+        # dedicated 1-thread executor for route refreshes: routing never
+        # queues behind anything else (deployment calls have threads of
+        # their own, below)
         self._route_pool = concurrent.futures.ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="serve-routes")
+        self._calls = _CallThreads()
         # Admission book, touched only on the loop thread: per-deployment
         # {"queued": n, "ongoing": n}. Counters for stats()/acceptance.
         self._adm: dict = {}
@@ -98,8 +175,9 @@ class HTTPProxy:
         self._boot_error: Optional[BaseException] = None
         self._host, self._want_port = host, port
         self._port: Optional[int] = None
-        threading.Thread(target=self._run_loop, daemon=True,
-                         name="serve-proxy").start()
+        self._thread = threading.Thread(target=self._run_loop, daemon=True,
+                                        name="serve-proxy")
+        self._thread.start()
         if not self._started.wait(10.0) or self._boot_error is not None:
             raise self._boot_error or RuntimeError(
                 "serve proxy failed to start within 10s")
@@ -220,6 +298,22 @@ class HTTPProxy:
             code, msg, retry_after=1 if code == 503 else None))
         return code
 
+    def _reject_failed_call(self, writer, name: str,
+                            e: BaseException) -> int:
+        from ray_tpu.core.exceptions import GetTimeoutError
+        from ray_tpu.serve.api import _retryable
+        from ray_tpu.serve.controller import ReplicaBusyError
+        if isinstance(e, GetTimeoutError):
+            # the in-flight call was cancelled by ServeCallRef
+            return self._reject(writer, name, 504,
+                                "deployment call timed out")
+        if isinstance(e, (ReplicaBusyError, RuntimeError)) or _retryable(e):
+            # _retryable covers the call that burned its one retry on a
+            # SECOND dying replica: the failure is the cluster's, not the
+            # request's — the client may retry (503), this is not a 500.
+            return self._reject(writer, name, 503, repr(e))
+        return self._reject(writer, name, 500, repr(e))
+
     async def _dispatch(self, method: str, target: str, body: bytes,
                         writer: asyncio.StreamWriter) -> None:
         path = target.split("?")[0]
@@ -270,8 +364,8 @@ class HTTPProxy:
     async def _admit_and_call(self, name: str, path: str, args: tuple,
                               kwargs: dict,
                               writer: asyncio.StreamWriter) -> int:
-        """Admission, the deployment call on a pool thread, the reply.
-        -> the HTTP code written."""
+        """Admission, the deployment call on a thread of its own, the
+        reply. -> the HTTP code written."""
         from ray_tpu import config
         t0 = time.monotonic()
         try:
@@ -301,10 +395,12 @@ class HTTPProxy:
         finally:
             st["queued"] -= 1
 
-        # run_in_executor carries no context variables: the request's span
-        # crosses to the pool thread by hand, and the wait for that thread
-        # (run_in_executor called -> call_blocking's first line) is a span
-        # that begins here and ends there.
+        # Admitted: the call goes straight to a thread of _CallThreads,
+        # idle or made now, and waits for nothing on the way. A thread
+        # hand-off carries no context variables: the request's span crosses
+        # by hand, and the hand-off itself (submit -> call_blocking's first
+        # line, a thread's wake-up or start) is a span that begins here and
+        # ends there.
         request = events.current()
         queued, q0 = time.time(), time.perf_counter()
 
@@ -321,28 +417,21 @@ class HTTPProxy:
                     timeout=max(0.05, deadline - time.monotonic()),
                     **kwargs)
 
+        failure = None
         try:
-            # executor offload: slow model calls never stall the loop —
-            # other connections keep being served (the ASGI property)
-            out = await self._loop.run_in_executor(None, call_blocking)
+            # slow model calls never stall the loop — other connections
+            # keep being served (the ASGI property) — and never wait for
+            # each other: as many run as admission let in
+            out = await asyncio.wrap_future(
+                self._calls.submit(call_blocking))
         except Exception as e:  # noqa: BLE001 - HTTP error surface
-            from ray_tpu.core.exceptions import GetTimeoutError
-            from ray_tpu.serve.api import _retryable
-            from ray_tpu.serve.controller import ReplicaBusyError
-            if isinstance(e, GetTimeoutError):
-                # the in-flight call was cancelled by ServeCallRef
-                return self._reject(writer, name, 504,
-                                    "deployment call timed out")
-            if isinstance(e, (ReplicaBusyError, RuntimeError)) \
-                    or _retryable(e):
-                # _retryable covers the call that burned its one retry on
-                # a SECOND dying replica: the failure is the cluster's,
-                # not the request's — the client may retry (503), this is
-                # not a 500.
-                return self._reject(writer, name, 503, repr(e))
-            return self._reject(writer, name, 500, repr(e))
+            failure = e
         finally:
+            # before the reply is written: a client that has its answer
+            # finds its slot given back
             st["ongoing"] -= 1
+        if failure is not None:
+            return self._reject_failed_call(writer, name, failure)
         self._counts["served"] += 1
         if isinstance(out, StreamingResponse):
             writer.write((
@@ -448,17 +537,19 @@ class HTTPProxy:
         }
 
     def close(self) -> None:
-        """Stop the server and the loop thread (idempotent). In-process
-        protocol tests must call this; the actor path dies with its
-        process."""
+        """Stop the server, the loop thread and the call threads, and
+        join them (idempotent). In-process protocol tests must call this;
+        the actor path dies with its process."""
         if self._closed:
             return
         self._closed = True
+        self._calls.close()     # before the loop: their replies still land
         try:
             self._loop.call_soon_threadsafe(self._loop.stop)
         except Exception:
             pass
-        self._route_pool.shutdown(wait=False)
+        self._thread.join(10.0)
+        self._route_pool.shutdown(wait=True, cancel_futures=True)
         _live_proxies.discard(self)
 
     @property

@@ -604,6 +604,212 @@ def test_proxy_close_is_hygienic():
 
 
 # ---------------------------------------------------------------------------
+# Tentpole (PR 36): admission is the only limit on calls in flight. The
+# proxy runs in THIS process: its admission book, its handle's in-flight
+# book and its threads are the test's to read.
+# ---------------------------------------------------------------------------
+
+
+@contextlib.contextmanager
+def _proxy_here(overrides=None):
+    from ray_tpu.serve.http_proxy import HTTPProxy
+    from ray_tpu.util import events
+    events.reset_for_tests()    # this process's ring: no earlier test's spans
+    with _cluster(overrides=overrides):
+        proxy = HTTPProxy("127.0.0.1", 0)
+        try:
+            yield proxy
+        finally:
+            proxy.close()
+
+
+def _deploy_sleeper(name, cap, seconds):
+    @serve.deployment(name=name, route_prefix=f"/{name}",
+                      max_ongoing_requests=cap)
+    def sleeper(x=0):
+        time.sleep(seconds)
+        return {"x": x}
+
+    serve.run(sleeper.bind())
+
+
+def _burst(port, path, callers, rounds=1):
+    """``callers`` closed-loop clients, ``rounds`` requests each -> codes."""
+    import concurrent.futures
+
+    def client(i):
+        return [_http(port, path, {"x": i})[0] for _ in range(rounds)]
+
+    with concurrent.futures.ThreadPoolExecutor(callers) as pool:
+        return [c for codes in pool.map(client, range(callers))
+                for c in codes]
+
+
+def _spans_once(kind, count, timeout=30.0):
+    """Span records at the conductor once ``count`` of ``kind`` are there
+    (this process's tail is flushed now, a replica's every half second)."""
+    from ray_tpu import state
+    from ray_tpu.util import events
+    deadline = time.time() + timeout
+    while True:
+        events.flush_now()
+        spans = [s for s in state.list_spans()
+                 if s["attrs"] and "span" in s["attrs"]]
+        if sum(s["kind"] == kind for s in spans) >= count or \
+                time.time() > deadline:
+            return spans
+        time.sleep(0.2)
+
+
+def _values(spans, kind, attr=None):
+    return [s["attrs"][attr] if attr else s["value"]
+            for s in spans if s["kind"] == kind]
+
+
+def test_callers_past_the_default_executor_are_all_inside():
+    """A budget that covers them lets more callers in at once than the
+    event loop's default executor has threads (its size was the limit)."""
+    callers = min(32, (os.cpu_count() or 1) + 4) + 4
+    with _proxy_here() as proxy:
+        _deploy_sleeper("wide", callers, 1.0)
+        assert _http(proxy.port(), "/wide")[0] == 200     # handle warm
+        assert _burst(proxy.port(), "/wide", callers) == [200] * callers
+        spans = _spans_once("serve.replica.call", callers + 1)
+        assert max(_values(spans, "serve.replica.call", "inflight")) \
+            == callers
+        assert proxy.stats()["shed"] == 0
+
+
+def test_closed_loop_at_the_cap_finds_its_slot(monkeypatch):
+    """Budget-many closed-loop callers, three rounds: a re-sent request
+    finds the slot its caller's last call gave back. The handle's book
+    never reads full to ``_pick``, nothing is retried and nothing shed."""
+    from ray_tpu.serve.api import DeploymentHandle
+    from ray_tpu.serve.controller import ReplicaBusyError
+    cap, rounds, full = 8, 3, []
+    pick = DeploymentHandle._pick
+
+    def counting_pick(self, *args, **kwargs):
+        try:
+            return pick(self, *args, **kwargs)
+        except ReplicaBusyError:
+            full.append(dict(self._inflight))
+            raise
+
+    monkeypatch.setattr(DeploymentHandle, "_pick", counting_pick)
+    with _proxy_here() as proxy:
+        _deploy_sleeper("loop", cap, 0.5)
+        assert _http(proxy.port(), "/loop")[0] == 200
+        codes = _burst(proxy.port(), "/loop", cap, rounds)
+        assert codes == [200] * (cap * rounds)
+        assert full == []
+        spans = _spans_once("serve.replica.call", cap * rounds + 1)
+        assert _values(spans, "serve.handle.call", "retries") == \
+            [0] * (cap * rounds + 1)
+        assert len(_values(spans, "serve.handle.slot_wait")) == \
+            cap * rounds + 1
+        assert max(_values(spans, "serve.replica.call", "inflight")) == cap
+        stats = proxy.stats()
+        assert (stats["served"], stats["shed"], stats["ongoing"]) == \
+            (cap * rounds + 1, 0, 0)
+
+
+def test_the_budget_still_binds():
+    """Cap 4, 12 callers: four inside at once, the other eight wait in
+    ``serve.proxy.admit`` (not for a thread), none shed."""
+    cap, callers, run_s = 4, 12, 1.0
+    with _proxy_here() as proxy:
+        _deploy_sleeper("narrow", cap, run_s)
+        assert _http(proxy.port(), "/narrow")[0] == 200
+        assert _burst(proxy.port(), "/narrow", callers) == [200] * callers
+        spans = _spans_once("serve.replica.call", callers + 1)
+        assert max(_values(spans, "serve.replica.call", "inflight")) == cap
+        admits = sorted(_values(spans, "serve.proxy.admit"))
+        assert len(admits) == callers + 1
+        # the first four (and the warm one) walked in; eight waited for
+        # at least most of a run
+        assert admits[cap] < run_s / 2 <= admits[cap + 1]
+        assert max(_values(spans, "serve.proxy.thread_wait")) < run_s / 2
+        assert proxy.stats()["shed"] == 0
+
+
+def test_deadline_cancels_the_call_and_frees_both_books(monkeypatch):
+    """504: the in-flight call is cancelled, and by the time the client
+    has its answer the slot is back in the proxy's admission book and in
+    the handle's in-flight book."""
+    from ray_tpu.serve.api import _handle_for
+    cancelled = []
+    cancel = rt.cancel
+    monkeypatch.setattr(
+        rt, "cancel", lambda ref, **kw: (cancelled.append(ref),
+                                         cancel(ref, **kw))[1])
+    overrides = {"serve_request_timeout_s": 1.0,
+                 "serve_drain_timeout_s": 2.0}
+    with _proxy_here(overrides) as proxy:
+        _deploy_sleeper("stuck", 4, 5.0)
+        code, _, _ = _http(proxy.port(), "/stuck", timeout=30)
+        assert code == 504
+        assert len(cancelled) == 1
+        stats = proxy.stats()
+        assert (stats["timeouts"], stats["ongoing"], stats["queued"]) == \
+            (1, 0, 0)
+        assert set(_handle_for("stuck")._inflight.values()) == {0}
+        serve.delete("stuck")
+
+
+def test_call_threads_never_outnumber_the_calls_in_flight():
+    """``_CallThreads`` alone, under a short switch interval: waves of
+    more concurrent calls than cores, each wave submitted when the last
+    one's futures resolved (as admission does). Every result is its own
+    call's, a thread is reused, never more threads than one wave, and
+    ``close()`` leaves none alive."""
+    import sys
+    from ray_tpu.serve.http_proxy import _CallThreads
+    wave, waves = 4 * (os.cpu_count() or 1), 20
+    calls = _CallThreads()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for w in range(waves):
+            gate = threading.Event()
+            futs = [calls.submit(lambda i=i: (gate.wait(10), w, i)[1:])
+                    for i in range(wave)]
+            gate.set()
+            assert [f.result(timeout=10) for f in futs] == \
+                [(w, i) for i in range(wave)]
+        with pytest.raises(ZeroDivisionError):      # the awaiter's to read
+            calls.submit(lambda: 1 // 0).result(timeout=10)
+        assert len(calls._threads) == wave
+    finally:
+        sys.setswitchinterval(interval)
+        calls.close()
+    assert not any(t.is_alive() for t in calls._threads)
+    with pytest.raises(RuntimeError):
+        calls.submit(lambda: None)
+
+
+def test_close_joins_every_thread_of_the_proxy():
+    """``close()`` ends and joins the loop thread, the route thread and
+    the threads admitted calls ran on."""
+    def mine():
+        return sorted(t.name for t in threading.enumerate()
+                      if t.name.startswith(("serve-proxy", "serve-routes",
+                                            "serve-call")))
+
+    assert mine() == []
+    with _proxy_here() as proxy:
+        _deploy_sleeper("four", 4, 0.8)
+        assert _burst(proxy.port(), "/four", 4, rounds=2) == [200] * 8
+        names = mine()
+        # one thread an admitted call, reused by the second round
+        assert sum(n.startswith("serve-call") for n in names) == 4
+        assert "serve-proxy" in names
+        proxy.close()
+        assert mine() == []
+        assert proxy.closed
+
+
+# ---------------------------------------------------------------------------
 # Tentpole: adaptive micro-batching (in-process, no cluster)
 # ---------------------------------------------------------------------------
 

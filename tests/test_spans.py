@@ -350,10 +350,13 @@ def test_serve_chain_through_proxy_and_batcher():
         assert any(s["value"] >= 0.05 for s in requests)
 
 
-def test_thread_wait_shows_a_full_executor():
-    """More concurrent callers of a slow handler than the proxy has
-    executor threads: some request waits for a thread at least as long as
-    the handler runs, and its ``serve.proxy.thread_wait`` says so."""
+def test_an_admitted_call_waits_for_no_executor_thread():
+    """More concurrent callers of a slow handler than the loop's default
+    executor has threads, under an admission budget that covers them: an
+    admitted call goes straight to the replica. No
+    ``serve.proxy.thread_wait`` is as long as half a handler run, and all
+    six are inside the replica at once (until PR 36 the call ran on the
+    default executor: two at a time, the last after two handler runs)."""
     from ray_tpu.serve.http_proxy import HTTPProxy
     with _runtime(num_cpus=8):
         @serve.deployment(name="slow", route_prefix="/slow",
@@ -363,7 +366,7 @@ def test_thread_wait_shows_a_full_executor():
             return x
 
         serve.run(slow.bind())
-        proxy = HTTPProxy("127.0.0.1", 0)     # in this process, 2 threads
+        proxy = HTTPProxy("127.0.0.1", 0)     # in this process
         pool2 = concurrent.futures.ThreadPoolExecutor(2)
         proxy._loop.call_soon_threadsafe(
             proxy._loop.set_default_executor, pool2)
@@ -381,14 +384,21 @@ def test_thread_wait_shows_a_full_executor():
         finally:
             proxy.close()
             pool2.shutdown(wait=False)
-        spans = _spans(events.snapshot())     # the proxy's are this ring's
+        deadline = time.time() + 30
+        while True:                           # the replica's are another's
+            spans = _cluster_spans({"serve.replica.call"})
+            inside = [s["attrs"]["inflight"]
+                      for s in _kind(spans, "serve.replica.call")]
+            if len(inside) >= 7 or time.time() > deadline:
+                break
+            time.sleep(0.2)
         waits = sorted(s["value"]
                        for s in _kind(spans, "serve.proxy.thread_wait"))
-        assert len(waits) == 7
-        assert waits[-1] >= SLOW_S            # behind at least one call
-        assert waits[-1] >= 2 * SLOW_S - 0.1  # 6 callers, 2 threads: 3 rounds
-        assert waits[1] < SLOW_S / 2          # the first two found a thread
+        assert len(waits) == 7 == len(inside)
+        assert waits[-1] < SLOW_S / 2         # behind no other call
+        assert max(inside) == 6               # all six at once
         admits = _kind(spans, "serve.proxy.admit")
+        assert len(admits) == 7
         assert max(s["value"] for s in admits) < SLOW_S / 2
 
 
