@@ -1,7 +1,11 @@
 """Model zoo, TPU-first.
 
-Flagship: decoder-only Transformer LM (llama-style: RMSNorm / SwiGLU / RoPE /
-GQA, optional MoE), pure-functional params pytree with logical-axis
+Flagship: decoder-only Transformer LM, one block whose layers are, by
+configuration: softmax attention (RMSNorm / RoPE, whole or partial / GQA;
+optionally per-head q/k norm, an output gate, any head width) or the gated
+delta rule (linear attention with a short causal convolution), over a SwiGLU
+MLP or a top-k expert layer with no capacity per expert that holds a share
+of the experts, and a shared expert. Pure-functional params pytree with logical-axis
 annotations so one definition runs under any MeshSpec (dp/fsdp/tp/pp/sp/ep).
 Plus ResNet-50 (the north-star image benchmark, BASELINE.json) and an MLP.
 
@@ -15,6 +19,7 @@ from ray_tpu.models.transformer import (
     transformer_init,
     transformer_apply,
     transformer_loss,
+    transformer_loss_and_stats,
     transformer_logical_axes,
 )
 from ray_tpu.models.generate import (decode_step, generate, init_cache,
@@ -25,7 +30,8 @@ from ray_tpu.models.vit import ViTConfig, vit_init, vit_apply, vit_loss
 
 __all__ = [
     "TransformerConfig", "transformer_init", "transformer_apply",
-    "transformer_loss", "transformer_logical_axes",
+    "transformer_loss", "transformer_loss_and_stats",
+    "transformer_logical_axes",
     "generate", "prefill", "decode_step", "init_cache",
     "resnet50_init", "resnet50_apply", "resnet_loss",
     "mlp_init", "mlp_apply",

@@ -35,19 +35,22 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-import dataclasses
-
 from ray_tpu.models.transformer import (TransformerConfig, _attention,
                                         _head, _layer_apply)
 
 
-def _inference_cfg(cfg: TransformerConfig) -> TransformerConfig:
-    """Dropless MoE at inference: capacity dropping is a training
-    throughput trade; S=1 decode never drops, so prefill must not either
-    or cached and uncached passes diverge."""
-    if cfg.num_experts and cfg.moe_capacity_factor is None:
-        return dataclasses.replace(cfg, moe_capacity_factor=1e9)
-    return cfg
+def _refuse_recurrent(cfg: TransformerConfig) -> None:
+    if "linear" in cfg.layer_types:
+        raise NotImplementedError(
+            "generate serves softmax-attention layers only: this "
+            "configuration has gated-delta-rule layers, whose recurrent "
+            "state [B, Hv, dk, dv] and convolution tail would have to live "
+            "beside the keys and values, and the cache here holds one kind "
+            "(ROADMAP.md R8)")
+    if cfg.layer_types:
+        raise NotImplementedError(
+            "generate scans one stack of like layers; a layer pattern "
+            "(layer_types) is not served yet (ROADMAP.md R5)")
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
@@ -102,7 +105,7 @@ def prefill(params, tokens, cfg: TransformerConfig, max_len: int,
     [B, vocab], filled cache). tokens [B, S], S <= max_len."""
     if cfg.pp_stages > 1:
         raise NotImplementedError("decode with pp_stages>1 is not supported")
-    cfg = _inference_cfg(cfg)
+    _refuse_recurrent(cfg)
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     x = params["embed"].astype(cfg.dtype)[tokens]
@@ -115,7 +118,7 @@ def prefill(params, tokens, cfg: TransformerConfig, max_len: int,
                 {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)})
 
     def step(carry, layer):
-        return _layer_apply(cfg, layer, carry, positions, attend)
+        return _layer_apply(cfg, layer, carry, positions, attend)[:2]
 
     x, cache = lax.scan(step, x, params["layers"])
     return _head(params, x[:, -1:], cfg)[:, 0], cache
@@ -124,7 +127,7 @@ def prefill(params, tokens, cfg: TransformerConfig, max_len: int,
 def decode_step(params, token, pos, cache, cfg: TransformerConfig):
     """One token for the whole batch: token [B] int32, pos scalar int32.
     -> (logits [B, vocab], updated cache)."""
-    cfg = _inference_cfg(cfg)
+    _refuse_recurrent(cfg)
     x = params["embed"].astype(cfg.dtype)[token][:, None, :]   # [B, 1, E]
     positions = jnp.full((x.shape[0], 1), pos)
 
@@ -145,8 +148,8 @@ def decode_step(params, token, pos, cache, cfg: TransformerConfig):
                 pos)
             return o, (stack_k, stack_v)
 
-        x, (cache_k, cache_v) = _layer_apply(cfg, layer, x, positions,
-                                             attend)
+        x, (cache_k, cache_v), _ = _layer_apply(cfg, layer, x, positions,
+                                                attend)
         return (x, cache_k, cache_v), None
 
     (x, cache_k, cache_v), _ = lax.scan(
@@ -175,7 +178,7 @@ def generate(params, prompt, cfg: TransformerConfig, *,
     (wrap with jax.jit(partial(generate, ...)) or call under jit): no
     per-token host round trips.
     """
-    cfg = _inference_cfg(cfg)
+    _refuse_recurrent(cfg)
     b, s = prompt.shape
     max_len = s + max_new_tokens
     with jax.named_scope("rt.generate.prefill"):

@@ -1,72 +1,128 @@
-"""Mixture-of-Experts layer with expert parallelism (GShard/Switch style).
+"""The expert layer: top-k routing with no capacity per expert over a share
+of the experts, with an optional shared expert.
 
-Capacity-based top-k routing with einsum dispatch/combine tensors — the
-XLA-friendly formulation: no dynamic shapes, tokens over capacity are
-dropped (residual path keeps them). Expert weights carry the "expert"
-logical axis -> "ep" mesh axis (parallel/sharding.py DEFAULT_RULES), so
-pjit turns the expert einsums into all-to-all dispatch over ICI.
+The router scores every published expert (``cfg.num_experts`` wide,
+softmax in float32), keeps the ``expert_top_k`` largest and, under
+``norm_topk_prob``, divides them by their sum. This program holds
+``cfg.held`` experts from ``cfg.first_expert`` on: the (token, expert) pairs
+that fall on them are sorted by expert, their rows gathered, run through the
+experts as one grouped matmul a weight (``lax.ragged_dot``), weighted and
+added back to their tokens. No one-hot dispatch tensor, no capacity per
+expert: an expert takes as many rows as are routed to it. What the experts
+held elsewhere would add is left out, and nothing stands in for them or for
+their exchange: under expert parallelism that partial result is what this
+member of the group contributes (the all-to-all itself is not built; the
+``expert`` logical axis still places the weights over an ``ep`` mesh axis,
+where GSPMD gathers them). The shared expert, ``sigmoid(w_g . x) *
+swiglu(x)``, is computed for every token.
 
-Expert parallelism is absent from the reference (SURVEY.md §2d row EP).
+The buffer of routed rows is static: ``BUFFER_OVER_MEAN`` x the mean of the
+pairs routed here (tokens x top_k x held / experts), and never more than
+the most any routing can send (tokens x min(top_k, held)). A program that
+holds a quarter of the experts or more therefore never drops a pair; one
+that holds a smaller share drops, AND counts, what a router sends past 4 x
+its mean: at a sixteenth with top-10, the pairs of a router collapsed onto
+three or more of this share's experts at once. The counters go out with the
+result: ``rows_here`` (pairs routed to held experts), ``rows_dropped``,
+``load`` (rows of each held expert).
+
+Scopes ``rt.moe.route``, ``rt.moe.experts``, ``rt.moe.shared`` name the three
+parts in the compiled program.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+
+# Row gathers and scatter-adds cost by the buffer's rows, filled or not, and
+# so does the step's memory. Measured on one chip's sixteenth of 512 experts
+# at 16,384 tokens (PERF.md, PR 37): 1.5 x the mean dropped rows from the
+# third step of a router trained from random weights; 4 x never did in 46
+# runs of 45 s (four layers' rows together at most 64,077 of 163,840); the
+# full 16 x took 811 ms a step for 633 and 15.2 GB for 14.0, and a step
+# stalled for seconds in three of nine runs at that size.
+BUFFER_OVER_MEAN = 4
 
 
-def moe_apply(cfg, moe_params, h, *, capacity_factor=None):
-    """h: [B, S, D] -> [B, S, D]. Top-k capacity routing per batch row.
+def buffer_rows(cfg, tokens: int) -> int:
+    """Rows of the routed-pairs buffer for ``tokens`` tokens."""
+    most = tokens * min(cfg.expert_top_k, cfg.held)
+    mean = -(-tokens * cfg.expert_top_k * cfg.held // cfg.num_experts)
+    return min(most, BUFFER_OVER_MEAN * mean)
 
-    capacity resolution: explicit arg > cfg.moe_capacity_factor > 1.25
-    (training default). Inference passes a huge factor (dropless) so
-    cached decode matches the full forward (models/generate.py)."""
+
+def route(cfg, router, x):
+    """x [N, D] -> (weights [N, k] float32, experts [N, k] int32)."""
+    logits = (x @ router.astype(x.dtype)).astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = lax.top_k(probs, cfg.expert_top_k)
+    if cfg.norm_topk_prob:
+        top_p = top_p / top_p.sum(-1, keepdims=True)
+    return top_p, top_e
+
+
+def moe_apply(cfg, moe_params, h):
+    """h: [B, S, D] -> (y [B, S, D], stats)."""
     dt = h.dtype
     b, s, d = h.shape
-    e = cfg.num_experts
-    k = cfg.expert_top_k
-    if capacity_factor is None:
-        capacity_factor = getattr(cfg, "moe_capacity_factor", None) or 1.25
-    cap = min(s * k, max(1, int(capacity_factor * s * k / e)))
+    n, k, held = b * s, cfg.expert_top_k, cfg.held
+    x = h.reshape(n, d)
+    rows = buffer_rows(cfg, n)
 
-    logits = jnp.einsum("bsd,de->bse", h, moe_params["router"].astype(dt))
-    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    with jax.named_scope("rt.moe.route"):
+        top_p, top_e = route(cfg, moe_params["router"], x)
+        local = top_e - cfg.first_expert
+        here = (local >= 0) & (local < held)
+        # pairs held elsewhere sort behind every held expert
+        key = jnp.where(here, local, held).reshape(n * k)
+        order = jnp.argsort(key, stable=True)
+        starts = jnp.searchsorted(key[order], jnp.arange(held + 1),
+                                  side="left").astype(jnp.int32)
+        load = starts[1:] - starts[:-1]                     # [held]
+        ends = jnp.minimum(starts, rows)                    # fit the buffer
+        sizes = ends[1:] - ends[:-1]
+        order = order[:rows]
+        token = order // k
+        # Rows past the last group are no expert's: ragged_dot leaves them
+        # unwritten, forward and transposed (on the TPU they hold whatever
+        # the memory held). Masked where they come in and where they go
+        # out, so that neither a value nor a gradient of theirs reaches a
+        # token.
+        kept = (jnp.arange(rows) < ends[-1])[:, None]
+        weight = top_p.reshape(n * k)[order][:, None]
+        stats = {"rows_here": starts[-1], "load": load,
+                 "rows_dropped": starts[-1] - ends[-1]}
 
-    # iterative top-k: take the best expert, mask it out, repeat
-    dispatch = jnp.zeros((b, s, e, cap), jnp.float32)
-    combine = jnp.zeros((b, s, e, cap), jnp.float32)
-    remaining = gates
-    used = jnp.zeros((b, e), jnp.int32)  # slots taken per expert
-    for _ in range(k):
-        gate_val = remaining.max(axis=-1)                     # [B,S]
-        idx = remaining.argmax(axis=-1)                       # [B,S]
-        onehot = jax.nn.one_hot(idx, e, dtype=jnp.float32)    # [B,S,E]
-        # position of each token within its expert's capacity buffer
-        pos = jnp.cumsum(onehot, axis=1) - 1 + used[:, None, :]
-        pos_tok = jnp.take_along_axis(pos, idx[..., None], -1)[..., 0]
-        pos_tok = pos_tok.astype(jnp.int32)
-        keep = pos_tok < cap
-        gv = jnp.where(keep, gate_val, 0.0)
-        pos_oh = jax.nn.one_hot(jnp.where(keep, pos_tok, cap), cap,
-                                dtype=jnp.float32)            # [B,S,C]
-        slot = onehot[..., None] * pos_oh[:, :, None, :]       # [B,S,E,C]
-        dispatch = dispatch + slot
-        combine = combine + slot * gv[..., None, None]
-        used = used + (onehot * keep[..., None]).sum(1).astype(jnp.int32)
-        remaining = remaining * (1.0 - onehot)
+    with jax.named_scope("rt.moe.experts"):
+        xs = jnp.where(kept, x[token], 0)                   # [rows, D]
+        w1, w3, w2 = (moe_params[name].astype(dt)
+                      for name in ("w1", "w3", "w2"))
+        gate = jax.nn.silu(lax.ragged_dot(xs, w1, sizes))
+        up = lax.ragged_dot(xs, w3, sizes)
+        ys = lax.ragged_dot(gate * up, w2, sizes)           # [rows, D]
+        ys = jnp.where(kept, ys.astype(jnp.float32), 0.0) * weight
+        y = jax.ops.segment_sum(ys, token, num_segments=n).astype(dt)
 
-    xs = jnp.einsum("bsec,bsd->becd", dispatch.astype(dt), h)  # [B,E,C,D]
-    w1, w3, w2 = (moe_params[n].astype(dt) for n in ("w1", "w3", "w2"))
-    gate = jax.nn.silu(jnp.einsum("becd,edf->becf", xs, w1))
-    up = jnp.einsum("becd,edf->becf", xs, w3)
-    ys = jnp.einsum("becf,efd->becd", gate * up, w2)           # [B,E,C,D]
-    return jnp.einsum("bsec,becd->bsd", combine.astype(dt), ys)
+    if "shared" in moe_params:
+        with jax.named_scope("rt.moe.shared"):
+            sh = moe_params["shared"]
+            mid = jax.nn.silu(x @ sh["w1"].astype(dt)) \
+                * (x @ sh["w3"].astype(dt))
+            open_ = jax.nn.sigmoid(
+                (x @ sh["gate"].astype(dt)).astype(jnp.float32))
+            y = y + (mid @ sh["w2"].astype(dt)) * open_[:, None].astype(dt)
+    return y.reshape(b, s, d), stats
 
 
-def load_balance_loss(gates, dispatch):
-    """Switch-style auxiliary loss: encourages uniform expert load.
-    gates: [B,S,E] softmax probs; dispatch: [B,S,E,C]."""
-    e = gates.shape[-1]
-    frac_tokens = dispatch.sum((1, 3)) / jnp.maximum(dispatch.sum((1, 2, 3,))[:, None], 1)
-    frac_probs = gates.mean(1)
-    return e * (frac_tokens * frac_probs).sum(-1).mean()
+def load_balance_loss(probs, top_e):
+    """Switch-style auxiliary loss over the whole router (not a share):
+    experts x sum_e (fraction of (token, expert) pairs on e) x (mean router
+    probability of e). 1 when both are uniform. probs: [N, E] softmax;
+    top_e: [N, k] chosen experts. Not part of ``transformer_loss``: the
+    published configurations this model runs leave it off."""
+    e = probs.shape[-1]
+    pairs = jax.nn.one_hot(top_e, e, dtype=jnp.float32).sum(1)    # [N, E]
+    frac_pairs = pairs.sum(0) / jnp.maximum(pairs.sum(), 1.0)
+    return e * (frac_pairs * probs.mean(0)).sum()

@@ -1,4 +1,7 @@
-"""Flagship decoder-only Transformer LM (llama-style), pure-functional.
+"""Flagship decoder-only Transformer LM, pure-functional: the llama block
+(RMSNorm / SwiGLU / RoPE / GQA) and, by configuration, the hybrid block of
+Qwen3-Next (a pattern of gated-delta-rule and gated full-attention layers
+over an expert layer without a capacity per expert, with a shared expert).
 
 Design notes (TPU-first):
 - Params are a pytree of jnp arrays; layers are *stacked* on a leading dim
@@ -17,7 +20,9 @@ strategies and the bench flagship.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -48,15 +53,46 @@ class TransformerConfig:
     remat: bool = True
     pp_stages: int = 1                    # >1: split layers into pipeline stages
     num_microbatches: int = 1             # pipeline microbatches
-    # MoE (0 = dense)
+    # Expert layer (0 = dense), models/moe.py. ``num_experts`` is the
+    # router's width; this program holds ``experts_held`` of them from
+    # ``first_expert`` on (None: all) and computes their part of the result.
     num_experts: int = 0
-    # None -> moe_apply's training default (1.25). Inference sets a huge
-    # factor (dropless): capacity dropping is a TRAINING throughput trade;
-    # at decode S=1 every token always fits, so prefill must match or
-    # cached and uncached forward passes diverge (models/generate.py).
-    moe_capacity_factor: Optional[float] = None
+    experts_held: Optional[int] = None
+    first_expert: int = 0
     expert_top_k: int = 1
+    norm_topk_prob: bool = False          # top-k weights divided by their sum
+    expert_ff: Optional[int] = None       # None -> ff_dim
+    shared_expert_ff: int = 0             # 0 = no shared expert
     tied_embeddings: bool = False
+    # Hybrid block. ``layer_types``: one period of "full" | "linear", () =
+    # every layer full attention; n_layers is a multiple of its length.
+    layer_types: Tuple[str, ...] = ()
+    head_width: Optional[int] = None      # None -> d_model / n_heads
+    partial_rotary_factor: float = 1.0    # share of a head RoPE rotates
+    qk_norm: bool = False                 # RMSNorm of q and k per head
+    attn_output_gate: bool = False        # wq also gives a sigmoid gate
+    norm_plus_one: bool = False           # norms scale by 1 + w, w from 0
+    linear_key_heads: int = 0             # gated delta rule (ops/gated_delta)
+    linear_value_heads: int = 0
+    linear_key_dim: int = 128
+    linear_value_dim: int = 128
+    linear_conv_kernel: int = 4
+
+    def __post_init__(self):
+        kinds = set(self.layer_types)
+        if kinds - {"full", "linear"}:
+            raise ValueError(f"layer_types {self.layer_types}: each is "
+                             "'full' or 'linear'")
+        if self.layer_types and self.n_layers % len(self.layer_types):
+            raise ValueError(f"n_layers {self.n_layers} is not a multiple "
+                             f"of the period {len(self.layer_types)}")
+        if "linear" in kinds and not (self.linear_key_heads
+                                      and self.linear_value_heads):
+            raise ValueError("linear layers need linear_key_heads and "
+                             "linear_value_heads")
+        if self.layer_types and self.pp_stages > 1:
+            raise ValueError("a layer pattern with pp_stages > 1 is not "
+                             "supported")
 
     @property
     def kv_heads(self) -> int:
@@ -64,7 +100,20 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_width or self.d_model // self.n_heads
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None \
+            else self.experts_held
+
+    @property
+    def expert_ff_dim(self) -> int:
+        return self.expert_ff if self.expert_ff is not None else self.ff_dim
 
     @property
     def ff_dim(self) -> int:
@@ -80,31 +129,58 @@ class TransformerConfig:
 # init
 # ---------------------------------------------------------------------------
 
-def _layer_init(key, cfg: TransformerConfig) -> Dict[str, Any]:
+def _layer_init(key, cfg: TransformerConfig, kind: str = "full"
+                ) -> Dict[str, Any]:
     d, h, hk, hd, f = (cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim,
                        cfg.ff_dim)
     ks = jax.random.split(key, 8)
     init = jax.nn.initializers.normal(0.02)
     pd = cfg.param_dtype
-    layer = {
-        "attn": {
-            "wq": init(ks[0], (d, h, hd), pd),
+    norm = jnp.zeros if cfg.norm_plus_one else jnp.ones
+    layer = {"ln1": norm((d,), pd), "ln2": norm((d,), pd)}
+    if kind == "full":
+        gate = 2 if cfg.attn_output_gate else 1   # per head: query, gate
+        layer["attn"] = {
+            "wq": init(ks[0], (d, h, gate * hd), pd),
             "wk": init(ks[1], (d, hk, hd), pd),
             "wv": init(ks[2], (d, hk, hd), pd),
             "wo": init(ks[3], (h, hd, d), pd),
-        },
-        "ln1": jnp.ones((d,), pd),
-        "ln2": jnp.ones((d,), pd),
-    }
-    if cfg.num_experts:
-        ek = jax.random.split(ks[4], 4)
-        e = cfg.num_experts
-        layer["moe"] = {
-            "router": init(ek[0], (d, e), pd),
-            "w1": init(ek[1], (e, d, f), pd),
-            "w3": init(ek[2], (e, d, f), pd),
-            "w2": init(ek[3], (e, f, d), pd),
         }
+        if cfg.qk_norm:
+            layer["attn"]["q_norm"] = norm((hd,), pd)
+            layer["attn"]["k_norm"] = norm((hd,), pd)
+    else:
+        gk = jax.random.split(ks[0], 5)
+        hv = cfg.linear_value_heads
+        kd = cfg.linear_key_heads * cfg.linear_key_dim
+        vd = hv * cfg.linear_value_dim
+        layer["gdn"] = {
+            # flat: [all q | all k | all v | all z] and [all b | all a]
+            "in_qkvz": init(gk[0], (d, 2 * kd + 2 * vd), pd),
+            "in_ba": init(gk[1], (d, 2 * hv), pd),
+            "conv": init(gk[2], (2 * kd + vd, cfg.linear_conv_kernel), pd),
+            "dt_bias": jnp.ones((hv,), pd),
+            "A_log": jnp.log(jax.random.uniform(
+                gk[3], (hv,), pd, minval=1e-3, maxval=16.0)),
+            "norm": jnp.ones((cfg.linear_value_dim,), pd),
+            "out": init(gk[4], (vd, d), pd),
+        }
+    if cfg.num_experts:
+        ek = jax.random.split(ks[4], 8)
+        e, ef, sf = cfg.held, cfg.expert_ff_dim, cfg.shared_expert_ff
+        layer["moe"] = {
+            "router": init(ek[0], (d, cfg.num_experts), pd),
+            "w1": init(ek[1], (e, d, ef), pd),
+            "w3": init(ek[2], (e, d, ef), pd),
+            "w2": init(ek[3], (e, ef, d), pd),
+        }
+        if sf:
+            layer["moe"]["shared"] = {
+                "w1": init(ek[4], (d, sf), pd),
+                "w3": init(ek[5], (d, sf), pd),
+                "w2": init(ek[6], (sf, d), pd),
+                "gate": init(ek[7], (d,), pd),
+            }
     else:
         layer["mlp"] = {
             "w1": init(ks[5], (d, f), pd),
@@ -115,18 +191,29 @@ def _layer_init(key, cfg: TransformerConfig) -> Dict[str, Any]:
 
 
 def transformer_init(key, cfg: TransformerConfig) -> Dict[str, Any]:
+    """``params["layers"]``: the layer tree stacked on a leading dim, or,
+    under a layer pattern, a tuple of such stacks, one a position of the
+    period, each stacked over the periods."""
     k_emb, k_layers, k_head = jax.random.split(key, 3)
     init = jax.nn.initializers.normal(0.02)
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
-    stacked = jax.vmap(lambda k: _layer_init(k, cfg))(layer_keys)
+    if cfg.layer_types:
+        period = len(cfg.layer_types)
+        stacked = tuple(
+            jax.vmap(lambda k, kind=kind: _layer_init(k, cfg, kind))(
+                layer_keys[i::period])
+            for i, kind in enumerate(cfg.layer_types))
+    else:
+        stacked = jax.vmap(lambda k: _layer_init(k, cfg))(layer_keys)
     if cfg.pp_stages > 1:
         stacked = jax.tree.map(
             lambda a: a.reshape((cfg.pp_stages, cfg.layers_per_stage)
                                 + a.shape[1:]), stacked)
+    norm = jnp.zeros if cfg.norm_plus_one else jnp.ones
     params = {
         "embed": init(k_emb, (cfg.vocab_size, cfg.d_model), cfg.param_dtype),
         "layers": stacked,
-        "final_norm": jnp.ones((cfg.d_model,), cfg.param_dtype),
+        "final_norm": norm((cfg.d_model,), cfg.param_dtype),
     }
     if not cfg.tied_embeddings:
         params["lm_head"] = init(k_head, (cfg.d_model, cfg.vocab_size),
@@ -140,32 +227,51 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     stage = ("stage", "layers") if cfg.pp_stages > 1 else ("layers",)
     def L(*axes):  # layer leaf: leading stacked dim(s)
         return stage + axes
-    layer = {
-        "attn": {
-            "wq": L("embed", "heads", "kv"),
-            "wk": L("embed", "heads", "kv"),
-            "wv": L("embed", "heads", "kv"),
-            "wo": L("heads", "kv", "embed"),
-        },
-        "ln1": L("embed"),
-        "ln2": L("embed"),
-    }
-    if cfg.num_experts:
-        layer["moe"] = {
-            "router": L("embed", None),
-            "w1": L("expert", "embed", "expert_mlp"),
-            "w3": L("expert", "embed", "expert_mlp"),
-            "w2": L("expert", "expert_mlp", "embed"),
-        }
-    else:
-        layer["mlp"] = {
-            "w1": L("embed", "mlp"),
-            "w3": L("embed", "mlp"),
-            "w2": L("mlp", "embed"),
-        }
+
+    def layer_axes(kind: str) -> Dict[str, Any]:
+        layer = {"ln1": L("embed"), "ln2": L("embed")}
+        if kind == "full":
+            layer["attn"] = {
+                "wq": L("embed", "heads", "kv"),
+                "wk": L("embed", "heads", "kv"),
+                "wv": L("embed", "heads", "kv"),
+                "wo": L("heads", "kv", "embed"),
+            }
+            if cfg.qk_norm:
+                layer["attn"]["q_norm"] = L(None)
+                layer["attn"]["k_norm"] = L(None)
+        else:
+            # the packed projections keep their columns whole: q, k, v and
+            # z blocks of unequal width do not split over tp
+            layer["gdn"] = {
+                "in_qkvz": L("embed", None), "in_ba": L("embed", None),
+                "conv": L(None, None), "dt_bias": L(None), "A_log": L(None),
+                "norm": L(None), "out": L(None, "embed"),
+            }
+        if cfg.num_experts:
+            layer["moe"] = {
+                "router": L("embed", None),
+                "w1": L("expert", "embed", "expert_mlp"),
+                "w3": L("expert", "embed", "expert_mlp"),
+                "w2": L("expert", "expert_mlp", "embed"),
+            }
+            if cfg.shared_expert_ff:
+                layer["moe"]["shared"] = {
+                    "w1": L("embed", "mlp"), "w3": L("embed", "mlp"),
+                    "w2": L("mlp", "embed"), "gate": L("embed"),
+                }
+        else:
+            layer["mlp"] = {
+                "w1": L("embed", "mlp"),
+                "w3": L("embed", "mlp"),
+                "w2": L("mlp", "embed"),
+            }
+        return layer
+
     axes = {
         "embed": ("vocab", "embed"),
-        "layers": layer,
+        "layers": tuple(layer_axes(kind) for kind in cfg.layer_types)
+        if cfg.layer_types else layer_axes("full"),
         "final_norm": ("embed",),
     }
     if not cfg.tied_embeddings:
@@ -182,9 +288,20 @@ def _rmsnorm(x, scale, eps=1e-6):
     return (x * lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
 
 
-def _rope(x, positions, theta: float):
-    """x: [B, S, H, D]; rotate pairs (d, d + D/2)."""
+def _norm(cfg: TransformerConfig, x, w):
+    """RMSNorm over the last dim with the configuration's scale: ``w``, or
+    ``1 + w`` (``norm_plus_one``)."""
+    return _rmsnorm(x, 1.0 + w if cfg.norm_plus_one else w)
+
+
+def _rope(x, positions, theta: float, rotary_dim: Optional[int] = None):
+    """x: [B, S, H, D]; rotate pairs (d, d + R/2) of the first R =
+    ``rotary_dim`` dims (all of them when None); the rest pass."""
     d = x.shape[-1]
+    if rotary_dim is not None and rotary_dim < d:
+        return jnp.concatenate(
+            [_rope(x[..., :rotary_dim], positions, theta),
+             x[..., rotary_dim:]], -1)
     half = d // 2
     freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
                     * (jnp.log(theta) / half))
@@ -207,6 +324,8 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh,
 
 
 def _feed_forward(cfg: TransformerConfig, layer, h):
+    """-> (y, stats): ``stats`` is the expert layer's counters (None for
+    the dense MLP)."""
     if cfg.num_experts:
         from ray_tpu.models.moe import moe_apply
         return moe_apply(cfg, layer["moe"], h)
@@ -214,49 +333,116 @@ def _feed_forward(cfg: TransformerConfig, layer, h):
     m = layer["mlp"]
     gate = jax.nn.silu(h @ m["w1"].astype(dt))
     up = h @ m["w3"].astype(dt)
-    return (gate * up) @ m["w2"].astype(dt)
+    return (gate * up) @ m["w2"].astype(dt), None
 
 
-def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend):
-    """One block. ``attend(q, k, v) -> (o, kept)`` is all that differs
-    between training, prefill and decode (models/generate.py): what
-    attention does with the rotated k and v, and what it keeps of them.
-    -> (x, kept)."""
+def _full_attention_mix(cfg: TransformerConfig, a, h, positions, attend):
+    """Softmax attention over the normed input ``h``: projections, per-head
+    q/k norm and output gate where configured, RoPE, ``attend``, ``wo``."""
     dt = cfg.dtype
-    h = _rmsnorm(x, layer["ln1"])
-    a = layer["attn"]
     q = jnp.einsum("bse,ehd->bshd", h, a["wq"].astype(dt))
     k = jnp.einsum("bse,ehd->bshd", h, a["wk"].astype(dt))
     v = jnp.einsum("bse,ehd->bshd", h, a["wv"].astype(dt))
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    if cfg.attn_output_gate:
+        q, gate = jnp.split(q, 2, axis=-1)
+    if cfg.qk_norm:
+        q, k = _norm(cfg, q, a["q_norm"]), _norm(cfg, k, a["k_norm"])
+    q = _rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+    k = _rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
     o, kept = attend(q, k, v)
-    o = jnp.einsum("bshd,hde->bse", o, a["wo"].astype(dt))
+    if cfg.attn_output_gate:
+        o = _output_gate(o, gate)
+    return jnp.einsum("bshd,hde->bse", o, a["wo"].astype(dt)), kept
+
+
+def _output_gate(o, gate):
+    return o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+
+
+def _gated_delta_mix(cfg: TransformerConfig, p, h):
+    """The gated delta rule over the normed input ``h`` [B, S, E]: packed
+    projections, short causal convolution, the chunked rule, gated norm."""
+    from ray_tpu.ops.gated_delta import causal_conv, gated_delta_rule
+    dt, f32 = cfg.dtype, jnp.float32
+    b, s, _ = h.shape
+    hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
+    dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+    kd, vd = hk * dk, hv * dv
+    with jax.named_scope("rt.gdn.proj"):
+        qkvz = h @ p["in_qkvz"].astype(dt)
+        ba = (h @ p["in_ba"].astype(dt)).astype(f32)
+    qkv = causal_conv(qkvz[..., :2 * kd + vd], p["conv"])
+    z = qkvz[..., 2 * kd + vd:].reshape(b, s, hv, dv)
+    with jax.named_scope("rt.gdn.scan"):
+        def unit(x):            # L2-normalised over the head, in float32
+            x = x.reshape(b, s, hk, dk).astype(f32)
+            x = x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+            return jnp.repeat(x, hv // hk, axis=2)
+
+        q = (unit(qkv[..., :kd]) * dk ** -0.5).astype(dt)
+        k = unit(qkv[..., kd:2 * kd]).astype(dt)
+        v = qkv[..., 2 * kd:].reshape(b, s, hv, dv)
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., hv:] + p["dt_bias"].astype(f32))
+    o = gated_delta_rule(q, k, v, g, beta)               # [B, S, Hv, dv]
+    with jax.named_scope("rt.gdn.proj"):
+        o = _rmsnorm(o, p["norm"]) * jax.nn.silu(z.astype(f32)).astype(dt)
+        return o.reshape(b, s, vd) @ p["out"].astype(dt)
+
+
+def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend):
+    """One block: ``x + mixer(norm(x))``, then ``+ feed_forward(norm(.))``.
+    The mixer is the layer's own: softmax attention where it holds
+    ``attn``, the gated delta rule where it holds ``gdn``. ``attend(q, k,
+    v) -> (o, kept)`` is all that differs between training, prefill and
+    decode (models/generate.py): what attention does with the rotated k
+    and v, and what it keeps of them. -> (x, kept, expert-layer stats)."""
+    h = _norm(cfg, x, layer["ln1"])
+    if "attn" in layer:
+        # the gated variant's device time is found by this scope
+        with jax.named_scope("rt.attn.gated") if cfg.attn_output_gate \
+                else contextlib.nullcontext():
+            o, kept = _full_attention_mix(cfg, layer["attn"], h, positions,
+                                          attend)
+    else:
+        o, kept = _gated_delta_mix(cfg, layer["gdn"], h), None
     x = x + o
-    h = _rmsnorm(x, layer["ln2"])
-    return x + _feed_forward(cfg, layer, h), kept
+    y, stats = _feed_forward(cfg, layer, _norm(cfg, x, layer["ln2"]))
+    return x + y, kept, stats
 
 
-def _stage_apply(cfg: TransformerConfig, mesh, stage_layers, x, positions,
-                 rules: LogicalRules = DEFAULT_RULES):
-    """Apply a stack of layers (leading dim = layers) with lax.scan.
-    ``rules``: what the caller sharded params and batch by over ``mesh``."""
+def _stage_scan(cfg: TransformerConfig, mesh, stage_layers, x, positions,
+                rules: LogicalRules = DEFAULT_RULES):
+    """Apply a stack of layers (leading dim = layers, or under a layer
+    pattern a tuple of stacks with leading dim = periods) with lax.scan.
+    ``rules``: what the caller sharded params and batch by over ``mesh``.
+    -> (x, the expert layers' stats stacked over the scan, or None)."""
     body = partial(
         _layer_apply, cfg,
         attend=lambda q, k, v: (_attention(cfg, q, k, v, mesh, rules), None))
     if cfg.remat:
         body = jax.checkpoint(body)
 
-    def step(carry, layer):
-        return body(layer, carry, positions)
+    def step(carry, period):
+        stats = []
+        for layer in period:
+            carry, _, layer_stats = body(layer, carry, positions)
+            stats.append(layer_stats)
+        return carry, stats if cfg.num_experts else None
 
-    out, _ = lax.scan(step, x, stage_layers)
-    return out
+    return lax.scan(step, x, stage_layers if cfg.layer_types
+                    else (stage_layers,))
+
+
+def _stage_apply(cfg: TransformerConfig, mesh, stage_layers, x, positions,
+                 rules: LogicalRules = DEFAULT_RULES):
+    return _stage_scan(cfg, mesh, stage_layers, x, positions, rules)[0]
 
 
 def _head(params, x, cfg: TransformerConfig):
     """Final norm + (tied or untied) head: x [..., E] -> float32 logits."""
-    x = _rmsnorm(x, params["final_norm"])
+    x = _norm(cfg, x, params["final_norm"])
     head = (params["embed"].T if cfg.tied_embeddings else params["lm_head"])
     return (x @ head.astype(cfg.dtype)).astype(jnp.float32)
 
@@ -273,11 +459,8 @@ def _next_token_loss(logits, tokens, mask=None):
     return nll.mean()
 
 
-def transformer_apply(params, tokens, cfg: TransformerConfig, *,
-                      mesh=None, positions=None,
-                      rules: LogicalRules = DEFAULT_RULES):
-    """tokens: [B, S] int32 -> logits [B, S, vocab] (compute in cfg.dtype,
-    logits float32)."""
+def _logits_and_stats(params, tokens, cfg: TransformerConfig, mesh,
+                      positions, rules: LogicalRules):
     b, s = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
@@ -299,19 +482,46 @@ def transformer_apply(params, tokens, cfg: TransformerConfig, *,
 
         x = pipeline_apply(stage_fn, params["layers"], xs, mesh,
                            num_microbatches=m)
-        x = x.reshape(b, s, cfg.d_model)
+        x, stats = x.reshape(b, s, cfg.d_model), None
     else:
-        x = _stage_apply(cfg, mesh, params["layers"], x, positions, rules)
-    return _head(params, x, cfg)
+        x, stats = _stage_scan(cfg, mesh, params["layers"], x, positions,
+                               rules)
+    return _head(params, x, cfg), stats
+
+
+def transformer_apply(params, tokens, cfg: TransformerConfig, *,
+                      mesh=None, positions=None,
+                      rules: LogicalRules = DEFAULT_RULES):
+    """tokens: [B, S] int32 -> logits [B, S, vocab] (compute in cfg.dtype,
+    logits float32)."""
+    return _logits_and_stats(params, tokens, cfg, mesh, positions, rules)[0]
+
+
+def transformer_loss_and_stats(params, batch, cfg: TransformerConfig, *,
+                               mesh=None,
+                               rules: LogicalRules = DEFAULT_RULES):
+    """batch: {"tokens": [B, S]} -> (next-token cross-entropy, mean over
+    non-final positions; the step's expert-layer counters as scalars,
+    ``{}`` for a dense model): ``moe_rows_here`` and ``moe_rows_dropped``
+    summed over the layers, ``moe_load_max`` and ``moe_load_mean`` the
+    fullest held expert's rows and the mean, over layers and experts."""
+    tokens = batch["tokens"]
+    logits, stats = _logits_and_stats(params, tokens, cfg, mesh, None, rules)
+    loss = _next_token_loss(logits, tokens, batch.get("mask"))
+    if stats is None:
+        return loss, {}
+    load = jnp.stack([s["load"] for s in stats])     # [period, periods, held]
+    return loss, {
+        "moe_rows_here": sum(s["rows_here"].sum() for s in stats),
+        "moe_rows_dropped": sum(s["rows_dropped"].sum() for s in stats),
+        "moe_load_max": load.max(), "moe_load_mean": load.mean()}
 
 
 def transformer_loss(params, batch, cfg: TransformerConfig, *, mesh=None,
                      rules: LogicalRules = DEFAULT_RULES):
-    """batch: {"tokens": [B, S]} next-token cross-entropy (mean over
-    non-final positions)."""
-    tokens = batch["tokens"]
-    logits = transformer_apply(params, tokens, cfg, mesh=mesh, rules=rules)
-    return _next_token_loss(logits, tokens, batch.get("mask"))
+    """The loss alone (see ``transformer_loss_and_stats``)."""
+    return transformer_loss_and_stats(params, batch, cfg, mesh=mesh,
+                                      rules=rules)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -367,14 +577,8 @@ def transformer_stage_loss(stage_params, x, tokens,
 
 
 def transformer_num_params(cfg: TransformerConfig) -> int:
-    d, f, v = cfg.d_model, cfg.ff_dim, cfg.vocab_size
-    per_layer = d * cfg.n_heads * cfg.head_dim * 2 \
-        + d * cfg.kv_heads * cfg.head_dim * 2 + 2 * d
-    if cfg.num_experts:
-        per_layer += d * cfg.num_experts + cfg.num_experts * 3 * d * f
-    else:
-        per_layer += 3 * d * f
-    total = v * d + cfg.n_layers * per_layer + d
-    if not cfg.tied_embeddings:
-        total += d * v
-    return total
+    """The parameters this program holds (under a share, its own experts
+    and its own slice of the vocabulary)."""
+    shapes = jax.eval_shape(lambda: transformer_init(jax.random.PRNGKey(0),
+                                                     cfg))
+    return sum(math.prod(x.shape) for x in jax.tree.leaves(shapes))

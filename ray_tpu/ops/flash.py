@@ -324,6 +324,11 @@ def _fit_block(seq: int, want: int) -> int:
 # work, 67 TF/s fwd at S=16k vs 10 TF/s). Larger-VMEM generations take a
 # wider kv block. autotune_blocks() refines these per (generation, seq)
 # on the live chip and its results take precedence.
+# Head width 256 (16 q heads on 2 kv heads, S = 8192, 2 rows; v5e, PR 37):
+# fwd+bwd 43.8 ms at 512x1024 (113 TFLOP/s causal), 43.7 at 1024x512, 47.2 at
+# 512x512, 49.5 at 256x2048, 51.2 at 256x1024, 57.5 at 256x512, 74.4 at
+# 128x1024; 1024x1024 and 512x2048 do not fit VMEM at that width (the dkv
+# kernel's stack). The v5e entry stands for both widths.
 _GEN_BLOCKS = {
     "v3": (256, 512),
     "v4": (512, 1024),

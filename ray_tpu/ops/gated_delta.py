@@ -1,0 +1,170 @@
+"""The gated delta rule (Gated DeltaNet), chunked, and the short causal
+convolution that stands in front of it.
+
+Per head, with ``S`` a ``[dk, dv]`` float32 state that starts at 0:
+
+    S_t = exp(g_t) S_{t-1}
+    S_t = S_t + k_t (beta_t (v_t - S_t^T k_t))^T
+    o_t = S_t^T q_t
+
+A loop over positions is latency-bound on a TPU, so the sequence is cut into
+chunks of ``chunk`` positions and each chunk is solved at once (the WY / UT
+transform of the DeltaNet papers): with ``G`` the running sum of ``g`` inside
+a chunk and ``A = strict_lower(diag(beta) K K^T * exp(G_i - G_j))``,
+
+    T = (I + A)^-1        u = T (beta v)        w = T (beta k e^G)
+
+and then, chunk after chunk, ``v' = u - w S``, ``o = (q e^G) S +
+lower(Q K^T * decay) v'``, ``S <- e^{G_last} S + (k e^{G_last - G})^T v'``.
+Only that last recurrence is a scan (one step a chunk); everything else is
+batched matmuls. ``T`` is made by block forward substitution
+(``_unit_lower_inverse``), in float32 at the highest precision (it is the
+one place where rounding compounds). The large matmuls take their operands
+in the inputs' dtype (bfloat16 in the model) and accumulate in float32.
+
+Backward is XLA's: the scan keeps one state a chunk (``n_chunks x dk x dv``
+a head), which under the model's whole-layer remat lives only while that
+layer's backward runs. Every operation here runs under the scope
+``rt.gdn.scan`` (``rt.gdn.conv`` for the convolution), which is how the
+benchmark's reducer finds their device time.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+CHUNK = 64
+BASE = 8            # side of the diagonal blocks inverted directly
+VPU_SIDE = 32       # blocks up to this side multiply on the VPU
+
+
+def causal_conv(x, w):
+    """Depthwise causal convolution, no bias, then SiLU.
+    x: [B, S, C]; w: [C, K] (``w[:, K-1]`` weighs the current position).
+    -> [B, S, C] in x's dtype."""
+    with jax.named_scope("rt.gdn.conv"):
+        width = w.shape[1]
+        s = x.shape[1]
+        padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+        w = w.astype(jnp.float32)
+        y = sum(padded[:, j:j + s].astype(jnp.float32) * w[:, j]
+                for j in range(width))
+        return jax.nn.silu(y).astype(x.dtype)
+
+
+def _block_matmul(a, b):
+    """a @ b for stacks of small square float32 blocks. Up to VPU_SIDE a
+    side they are multiplied and summed elementwise, exactly: the MXU pads
+    an 8-wide block to its 128-wide tile, and XLA lowers a batch of such
+    dots as convolutions that take ~1 ms for 17 MB of blocks (on a v5e the
+    rule's forward and backward at [2, 8192, 32, 128] take 39.0 ms this way
+    against 74.1 ms through the MXU; PERF.md, PR 37)."""
+    if a.shape[-1] <= VPU_SIDE:
+        return jnp.sum(a[..., :, :, None] * b[..., None, :, :], axis=-2)
+    return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
+
+
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for strictly lower triangular ``a`` [..., C, C], float32,
+    C a power of two: the diagonal blocks of side BASE by their finite
+    Neumann series (terms up to binomial(7, 3), so little cancels), then
+    block forward substitution, doubling the side: ``[[Ta, 0], [-Tb A21
+    Ta, Tb]]``. The whole series at side 64 has terms of 1e18 that cancel
+    to O(1) once keys align, and float32 then returns noise or NaN."""
+    c = a.shape[-1]
+    side = min(BASE, c)
+
+    def blocks(row0, col0, count, stride):
+        return jnp.stack([
+            a[..., row0 + i * stride:row0 + i * stride + side,
+              col0 + i * stride:col0 + i * stride + side]
+            for i in range(count)], axis=-3)
+
+    power = -blocks(0, 0, c // side, side)              # [..., nb, s, s]
+    t = jnp.eye(side, dtype=a.dtype) + power
+    for _ in range(int(math.log2(side)) - 1):
+        power = _block_matmul(power, power)
+        t = t + _block_matmul(t, power)
+    while side < c:
+        below = blocks(side, 0, c // (2 * side), 2 * side)      # the A21s
+        ta, tb = t[..., 0::2, :, :], t[..., 1::2, :, :]
+        t21 = -_block_matmul(_block_matmul(tb, below), ta)
+        t = jnp.concatenate([
+            jnp.concatenate([ta, jnp.zeros_like(ta)], -1),
+            jnp.concatenate([t21, tb], -1)], -2)
+        side *= 2
+    return t[..., 0, :, :]
+
+
+def _mm(spec, a, b, dt):
+    return jnp.einsum(spec, a.astype(dt), b.astype(dt),
+                      preferred_element_type=jnp.float32)
+
+
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
+    """q, k: [B, S, H, dk] (k of unit length, q already scaled); v: [B, S,
+    H, dv]; g (log decay, <= 0), beta: [B, S, H]. -> o [B, S, H, dv] in v's
+    dtype. Any S: the tail is padded with positions that leave the state
+    alone."""
+    with jax.named_scope("rt.gdn.scan"):
+        return _chunked(q, k, v, g, beta, chunk)
+
+
+def _chunked(q, k, v, g, beta, c):
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    dt = v.dtype
+    f32 = jnp.float32
+    pad = -s % c
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    n = (s + pad) // c
+
+    def chunks(x):          # [B, S, H, ...] -> [B, H, n, C, ...]
+        x = x.reshape((b, n, c) + x.shape[2:])
+        return jnp.moveaxis(x, 3, 1)
+
+    q, k, v = chunks(q), chunks(k), chunks(v)
+    g, beta = chunks(g.astype(f32)), chunks(beta.astype(f32))
+    gsum = jnp.cumsum(g, axis=-1)                          # [B, H, n, C]
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    gap = gsum[..., :, None] - gsum[..., None, :]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, gap, 0.0)), 0.0)
+
+    k_beta = k.astype(f32) * beta[..., None]
+    a = _mm("bhnid,bhnjd->bhnij", k_beta, k, dt) * decay
+    t = _unit_lower_inverse(jnp.where(jnp.tril(lower, -1), a, 0.0))
+    u = _mm("bhnij,bhnjd->bhnid", t, v.astype(f32) * beta[..., None], dt)
+    w = _mm("bhnij,bhnjd->bhnid", t, k_beta * jnp.exp(gsum)[..., None], dt)
+
+    g_last = gsum[..., -1]                                 # [B, H, n]
+    k_tail = k.astype(f32) * jnp.exp(g_last[..., None] - gsum)[..., None]
+
+    def step(state, xs):
+        w_i, u_i, k_i, decay_i = xs
+        fresh = u_i - _mm("bhcd,bhdv->bhcv", w_i, state, dt)
+        nxt = state * decay_i[..., None, None] \
+            + _mm("bhcd,bhcv->bhdv", k_i, fresh, dt)
+        return nxt, (state.astype(dt), fresh.astype(dt))
+
+    def by_chunk(x):        # chunk axis first, for the scan
+        return jnp.moveaxis(x, 2, 0)
+
+    _, (states, fresh) = lax.scan(
+        step, jnp.zeros((b, h, dk, dv), f32),
+        (by_chunk(w.astype(dt)), by_chunk(u), by_chunk(k_tail.astype(dt)),
+         by_chunk(jnp.exp(g_last))))
+    states, fresh = jnp.moveaxis(states, 0, 2), jnp.moveaxis(fresh, 0, 2)
+
+    within = _mm("bhnid,bhnjd->bhnij", q, k, dt) * decay
+    o = _mm("bhnid,bhndv->bhniv",
+            q.astype(f32) * jnp.exp(gsum)[..., None], states, dt) \
+        + _mm("bhnij,bhnjv->bhniv", within, fresh, dt)
+    o = jnp.moveaxis(o, 1, 3).reshape(b, n * c, h, dv)     # [B, S, H, dv]
+    return o[:, :s].astype(dt)

@@ -12,6 +12,7 @@ from ray_tpu.train.jax_step import (
     make_lm_train_step,
     make_resnet_train_step,
     make_vit_train_step,
+    step_span,
 )
 
 _LAZY = {
@@ -37,7 +38,7 @@ _LAZY = {
     "PipelineStageActor": ("ray_tpu.train.pipeline", "PipelineStageActor"),
 }
 
-__all__ = ["TrainState", "make_lm_train_step", "make_resnet_train_step",
+__all__ = ["TrainState", "step_span", "make_lm_train_step", "make_resnet_train_step",
            "make_vit_train_step",
            *_LAZY]
 

@@ -23,10 +23,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ray_tpu.models.resnet import resnet50, resnet_loss
 from ray_tpu.models.transformer import (TransformerConfig, transformer_init,
                                         transformer_logical_axes,
-                                        transformer_loss)
+                                        transformer_loss_and_stats)
 from ray_tpu.parallel.sharding import (DEFAULT_RULES, LogicalRules,
                                        batch_sharding, pytree_shardings,
                                        replicated, shard_pytree)
+from ray_tpu.util import events
 
 
 @dataclasses.dataclass
@@ -41,6 +42,14 @@ jax.tree_util.register_pytree_node(
     lambda s: ((s.params, s.opt_state, s.step), None),
     lambda _, c: TrainState(*c),
 )
+
+
+def step_span(step: int) -> events.span:
+    """The flight-recorder span a training loop opens around one step, from
+    its dispatch to its metrics on the host. ``sp.set(**counters)`` puts
+    the step's own counters (the ``moe_*`` keys of its metrics) on it once
+    they are fetched, so they cost no transfer of their own."""
+    return events.span("train.step", step=step)
 
 
 def _init_opt_state(tx: optax.GradientTransformation, params, mesh: Mesh):
@@ -59,7 +68,8 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
                        rules: LogicalRules = DEFAULT_RULES,
                        learning_rate: float = 3e-4):
     """Returns (init_fn(key) -> TrainState on-mesh,
-               step_fn(state, batch) -> (state, metrics) jitted)."""
+               step_fn(state, batch) -> (state, metrics) jitted,
+               place_batch)."""
     if tx is None:
         tx = optax.adamw(learning_rate, weight_decay=0.01)
     axes = transformer_logical_axes(cfg)
@@ -76,16 +86,21 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
                                          replicated(mesh)))
 
     def loss_fn(params, batch):
-        return transformer_loss(params, batch, cfg, mesh=mesh, rules=rules)
+        return transformer_loss_and_stats(params, batch, cfg, mesh=mesh,
+                                          rules=rules)
 
     @partial(jax.jit, donate_argnums=(0,))
     def step_fn(state: TrainState, batch) -> Tuple[TrainState, dict]:
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
+        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state.params, batch)
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
         params = optax.apply_updates(state.params, updates)
         gnorm = optax.global_norm(grads)
+        # ``stats``: the expert layers' counters (``moe_*``), none for a
+        # dense model
         return (TrainState(params, opt_state, state.step + 1),
-                {"loss": loss, "grad_norm": gnorm, "step": state.step + 1})
+                {"loss": loss, "grad_norm": gnorm, "step": state.step + 1,
+                 **stats})
 
     def place_batch(batch):
         return jax.tree.map(

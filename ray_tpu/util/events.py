@@ -147,6 +147,11 @@ EVENT_KINDS: Dict[str, str] = {
     "train.loop": "span: value = seconds of the user's loop on one rank",
     "train.report": "span: value = seconds in session.report; attrs carry "
                     "iteration",
+    "train.step": "span: value = seconds from a step's dispatch to its "
+                  "metrics on the host, opened by the user's loop; attrs "
+                  "carry the step's counters (the expert layers' "
+                  "moe_rows_here, moe_rows_dropped, moe_load_max, "
+                  "moe_load_mean)",
     "train.pump": "span: value = seconds of one synchronized report "
                   "round; attrs carry iteration/lag_s",
     # start-up

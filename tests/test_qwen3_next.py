@@ -1,0 +1,408 @@
+"""The hybrid block (gated delta rule, gated attention, dropless expert
+layer over a share) against the plain reference of its family, at a small
+size on the CPU, in float32 so that the comparison is of the mathematics."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.apps import train_qwen3_next as app
+from benchmark.reference import qwen3_next as reference
+from ray_tpu.models import (TransformerConfig, generate, transformer_init,
+                            transformer_loss)
+from ray_tpu.models import moe, transformer
+
+# One period at toy widths, every expert held; Hugging Face key names, as a
+# configuration file gives them.
+CONFIG = {
+    "family": "qwen3_next", "param_dtype": "float32",
+    "hidden_size": 64, "intermediate_size": 128, "head_dim": 32,
+    "num_attention_heads": 4, "num_key_value_heads": 2,
+    "partial_rotary_factor": 0.25, "rope_theta": 10000000,
+    "rms_norm_eps": 1e-6, "full_attention_interval": 4,
+    "linear_num_key_heads": 2, "linear_num_value_heads": 4,
+    "linear_key_head_dim": 16, "linear_value_head_dim": 16,
+    "linear_conv_kernel_dim": 4, "num_experts": 16,
+    "num_experts_published": 16, "first_expert": 0,
+    "num_experts_per_tok": 2, "norm_topk_prob": True,
+    "moe_intermediate_size": 32, "shared_expert_intermediate_size": 32,
+    "num_hidden_layers": 4, "vocab_size": 128, "tie_word_embeddings": False,
+}
+SEQ = 100          # not a multiple of the delta rule's chunk (64)
+
+
+def program_config(config=CONFIG, **overrides) -> TransformerConfig:
+    cfg = app.transformer_config(
+        app.model_kwargs(config, SEQ, "reference"), remat=False)
+    return dataclasses.replace(cfg, dtype=jnp.float32, **overrides)
+
+
+def seeded(cfg, seed=0):
+    """Parameters with every leaf random: the norms' scales too, so that no
+    term drops out of a comparison."""
+    params = transformer_init(jax.random.PRNGKey(seed), cfg)
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
+    return jax.tree.unflatten(tree, [
+        x + 0.05 * jax.random.normal(k, x.shape, x.dtype)
+        for x, k in zip(leaves, keys)])
+
+
+def hidden(seed=3, rows=2, seq=SEQ, d=64):
+    return jax.random.normal(jax.random.PRNGKey(seed), (rows, seq, d))
+
+
+def close(got, want, tol=2e-4):
+    got, want = np.asarray(got), np.asarray(want)
+    scale = max(1e-6, float(np.abs(want).max()))
+    np.testing.assert_allclose(got / scale, want / scale, atol=tol, rtol=0)
+
+
+def layer_of(cfg, params, i):
+    """(the program's layer tree, the reference's matrices) of layer i."""
+    weights = app.reference_weights(params, CONFIG)
+    period = len(cfg.layer_types)
+    stack = params["layers"][i % period]
+    return jax.tree.map(lambda a: a[i // period], stack), weights.layer(i)
+
+
+def test_chunked_delta_rule_matches_the_recurrence_values_and_gradients():
+    cfg = program_config()
+    params = seeded(cfg)
+    layer, w = layer_of(cfg, params, 0)
+    h = hidden()
+
+    def system(p, x):
+        return transformer._gated_delta_mix(cfg, p, x)
+
+    def plain(p, x):
+        with jax.default_matmul_precision("highest"):
+            return reference._gated_delta_rule(x, p, CONFIG)
+
+    close(system(layer["gdn"], h), plain(w, h))
+    probe = jax.random.normal(jax.random.PRNGKey(9), h.shape)
+    got = jax.grad(lambda p, x: (system(p, x) * probe).sum(), (0, 1))(
+        layer["gdn"], h)
+    want = jax.grad(lambda p, x: (plain(p, x) * probe).sum(), (0, 1))(
+        {k: w[k] for k in layer["gdn"]}, h)
+    close(got[1], want[1])
+    for name in layer["gdn"]:
+        # the decay's own parameters: where exp(g) is nearly 0 their
+        # gradient is a difference of nearly equal float32 terms
+        close(got[0][name], want[0][name],
+              tol=5e-3 if name in ("A_log", "dt_bias") else 2e-4)
+
+
+@pytest.mark.parametrize("seq", [64, 65, 130])
+def test_delta_rule_op_at_chunk_edges(seq):
+    """The op alone against a loop over positions, at a whole chunk, one
+    position past it and two chunks and a bit."""
+    from ray_tpu.ops.gated_delta import gated_delta_rule
+    ks = jax.random.split(jax.random.PRNGKey(seq), 5)
+    b, h, dk, dv = 1, 2, 8, 8
+    q = jax.random.normal(ks[0], (b, seq, h, dk)) * dk ** -0.5
+    k = jax.random.normal(ks[1], (b, seq, h, dk))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (b, seq, h, dv))
+    g = -jax.nn.softplus(jax.random.normal(ks[3], (b, seq, h)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, seq, h)))
+    state = np.zeros((b, h, dk, dv))
+    want = []
+    for t in range(seq):
+        state = state * np.exp(np.asarray(g[:, t]))[..., None, None]
+        seen = np.einsum("bhkv,bhk->bhv", state, np.asarray(k[:, t]))
+        delta = np.asarray(beta[:, t])[..., None] \
+            * (np.asarray(v[:, t]) - seen)
+        state = state + np.asarray(k[:, t])[..., :, None] \
+            * delta[..., None, :]
+        want.append(np.einsum("bhkv,bhk->bhv", state, np.asarray(q[:, t])))
+    close(gated_delta_rule(q, k, v, g, beta), np.stack(want, 1))
+
+
+def test_delta_rule_holds_when_keys_align():
+    """Keys that point one way, beta near 1, slow decay: (I + A)^-1 has
+    entries of O(1) but its Neumann series has terms of 1e18; the blocked
+    substitution stays with the recurrence (the series returned NaN)."""
+    from ray_tpu.ops.gated_delta import gated_delta_rule
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    b, seq, h, dk, dv = 1, 192, 2, 16, 16
+    k = 0.01 * jax.random.normal(ks[0], (b, seq, h, dk)) \
+        + jax.random.normal(ks[1], (1, 1, h, dk))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    q = jax.random.normal(ks[2], (b, seq, h, dk)) * dk ** -0.5
+    v = jax.random.normal(ks[3], (b, seq, h, dv))
+    g = jnp.full((b, seq, h), -0.01)
+    beta = jnp.full((b, seq, h), 0.95)
+    got = gated_delta_rule(q, k, v, g, beta)
+
+    def position(state, xs):
+        q_t, k_t, v_t = xs
+        state = state * jnp.exp(-0.01)
+        delta = 0.95 * (v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t))
+        state = state + k_t[..., :, None] * delta[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
+
+    _, want = jax.lax.scan(position, jnp.zeros((b, h, dk, dv)),
+                           tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v)))
+    close(got, jnp.moveaxis(want, 0, 1), tol=1e-4)
+
+
+def test_gated_attention_with_partial_rotary_matches_the_reference():
+    cfg = program_config()
+    params = seeded(cfg)
+    layer, w = layer_of(cfg, params, 3)
+    h = hidden()
+    positions = jnp.broadcast_to(jnp.arange(SEQ), (2, SEQ))
+    got, _ = transformer._full_attention_mix(
+        cfg, layer["attn"], h, positions,
+        lambda q, k, v: (transformer._attention(cfg, q, k, v, None), None))
+    with jax.default_matmul_precision("highest"):
+        want = reference._gated_attention(h, w, CONFIG)
+    close(got, want)
+    # the rotation touches the first quarter of a head and nothing else
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, SEQ, 4, 32))
+    turned = transformer._rope(x, positions, 1e7, cfg.rotary_dim)
+    assert cfg.rotary_dim == 8
+    np.testing.assert_array_equal(turned[..., 8:], x[..., 8:])
+    assert not np.allclose(turned[:, 1:, :, :8], x[:, 1:, :, :8])
+
+
+def test_expert_layer_under_skewed_routing_drops_nothing():
+    cfg = program_config()
+    params = seeded(cfg)
+    layer, w = layer_of(cfg, params, 1)
+    h = jnp.abs(hidden()) + 1.0          # every token scores high where the
+    m = dict(layer["moe"])               # router's column is large
+    m["router"] = m["router"].at[:, 5].set(1.0)
+    w = {**w, "router": m["router"]}
+    got, stats = moe.moe_apply(cfg, m, h)
+    with jax.default_matmul_precision("highest"):
+        want = reference._experts(h, w, CONFIG)
+    close(got, want)
+    tokens = h.shape[0] * h.shape[1]
+    assert int(stats["load"][5]) == tokens          # all of them, kept
+    assert int(stats["rows_here"]) == 2 * tokens
+    assert int(stats["rows_dropped"]) == 0
+
+
+@pytest.mark.parametrize("skewed", [False, True])
+def test_a_share_under_any_routing_value_and_gradients(skewed):
+    """Four of sixteen experts held: under a uniform router most of the
+    buffer's rows are padding, under one that sends every token here none
+    is; either way the value and the gradients for the tokens and the
+    router are the reference's, and nothing is dropped."""
+    cfg = program_config(experts_held=4, first_expert=4)
+    layer, w = layer_of(program_config(), seeded(program_config()), 1)
+    h = jnp.abs(hidden()) + 1.0
+    tokens = h.shape[0] * h.shape[1]
+    router = layer["moe"]["router"]
+    if skewed:
+        router = router.at[:, 4:6].set(1.0)
+    m = {"router": router, "shared": layer["moe"]["shared"],
+         **{k: layer["moe"][k][4:8] for k in ("w1", "w3", "w2")}}
+    share = {**w, **{k: w[k][4:8] for k in ("w1", "w3", "w2")}}
+    (got, stats), vjp = jax.vjp(lambda m, h: moe.moe_apply(cfg, m, h), m, h)
+    with jax.default_matmul_precision("highest"):
+        want, want_vjp = jax.vjp(lambda r, x: reference._experts(
+            x, {**share, "router": r},
+            {**CONFIG, "num_experts": 4, "first_expert": 4}), router, h)
+    close(got, want)
+    assert int(stats["rows_here"]) == int(stats["load"].sum())
+    assert (int(stats["rows_here"]) == 2 * tokens) == skewed
+    assert int(stats["rows_dropped"]) == 0
+    dy = hidden(seed=9)
+    zeros = jax.tree.map(lambda a: np.zeros(a.shape, jax.dtypes.float0),
+                         stats)
+    d_m, d_h = vjp((dy, zeros))
+    want_router, want_h = want_vjp(dy)
+    close(d_h, want_h, tol=2e-3)
+    close(d_m["router"], want_router, tol=2e-3)
+
+
+def test_a_small_share_counts_what_its_buffer_drops():
+    """One of sixteen experts held: the buffer is 4 x the mean of 25 pairs.
+    A router that sends every token there overruns it, and says by how
+    much; holding a quarter or more, the buffer is the most there can be."""
+    cfg = program_config(experts_held=1, first_expert=5)
+    layer, _ = layer_of(program_config(), seeded(program_config()), 1)
+    h = jnp.abs(hidden()) + 1.0
+    tokens = h.shape[0] * h.shape[1]
+    assert moe.buffer_rows(cfg, tokens) == 4 * 25
+    assert moe.buffer_rows(program_config(experts_held=4), tokens) \
+        == 2 * tokens
+    assert moe.buffer_rows(program_config(), tokens) == 2 * tokens
+    m = {"router": layer["moe"]["router"].at[:, 5].set(1.0),
+         **{k: layer["moe"][k][5:6] for k in ("w1", "w3", "w2")}}
+    _, stats = moe.moe_apply(cfg, m, h)
+    assert int(stats["rows_here"]) == tokens
+    assert int(stats["rows_dropped"]) == tokens - 100
+    assert int(stats["load"][0]) == tokens
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four shares of four experts each: their partial results, with the
+    shared expert (which every chip computes alike) counted once, are the
+    reference's layer over all sixteen."""
+    whole = program_config()
+    params = seeded(whole)
+    layer, w = layer_of(whole, params, 2)
+    h = hidden()
+    with jax.default_matmul_precision("highest"):
+        want = reference._experts(h, w, CONFIG)
+    m = layer["moe"]
+    total, rows = 0.0, 0
+    for first in range(0, 16, 4):
+        share_cfg = program_config(experts_held=4, first_expert=first)
+        share = {"router": m["router"],
+                 **{k: m[k][first:first + 4] for k in ("w1", "w3", "w2")}}
+        routed, stats = moe.moe_apply(share_cfg, share, h)
+        total = total + routed
+        rows += int(stats["rows_here"])
+        # the reference, given the same share, gives the same part
+        share_config = {**CONFIG, "num_experts": 4, "first_expert": first}
+        zero_shared = {**w, **{k: w[k][first:first + 4]
+                               for k in ("w1", "w3", "w2")},
+                       "shared_w2": jnp.zeros_like(w["shared_w2"])}
+        with jax.default_matmul_precision("highest"):
+            close(routed, reference._experts(h, zero_shared, share_config))
+    shared_only, _ = moe.moe_apply(
+        program_config(experts_held=1, first_expert=0),
+        {"router": m["router"], "shared": m["shared"],
+         **{k: jnp.zeros_like(m[k][:1]) for k in ("w1", "w3", "w2")}}, h)
+    close(total + shared_only, want)
+    assert rows == 2 * h.shape[0] * h.shape[1]      # every pair, once
+
+
+def reference_arrays(params):
+    weights = app.reference_weights(params, CONFIG)
+    return [weights.layer(i) for i in range(weights.n_layers)]
+
+
+def test_whole_model_loss_and_gradients_over_one_period():
+    cfg = program_config()
+    params = seeded(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, SEQ), 0, 128)
+    loss, grads = jax.value_and_grad(transformer_loss)(
+        params, {"tokens": tokens}, cfg)
+    weights = app.reference_weights(params, CONFIG)
+    assert abs(float(loss) - reference.loss(weights, tokens, CONFIG)) < 1e-5
+
+    def plain(p):
+        return reference.loss_of_arrays(
+            reference_arrays(p), p["embed"], p["final_norm"], p["lm_head"],
+            tokens, CONFIG)
+
+    want_loss, want = jax.value_and_grad(plain)(params)
+    assert abs(float(loss) - float(want_loss)) < 1e-5
+    flat_got = jax.tree_util.tree_leaves_with_path(grads)
+    flat_want = jax.tree.leaves(want)
+    assert len(flat_got) == len(flat_want)
+    for (path, got), wanted in zip(flat_got, flat_want):
+        assert float(jnp.abs(wanted).max()) > 0, path
+        close(got, wanted, tol=2e-3)
+
+
+def test_the_reference_takes_its_gradient_a_layer_at_a_time():
+    """``loss_and_grads``, the form that fits the cell's size (a row at a
+    time, ``jax.vjp`` layer by layer), against ``jax.grad`` of the whole
+    loss."""
+    params = seeded(program_config())
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, SEQ), 0, 128)
+
+    def plain(p):
+        return reference.loss_of_arrays(
+            reference_arrays(p), p["embed"], p["final_norm"], p["lm_head"],
+            tokens, CONFIG)
+
+    want_loss, want = jax.value_and_grad(plain)(params)
+    loss, grads = reference.loss_and_grads(
+        app.reference_weights(params, CONFIG), np.asarray(tokens), CONFIG)
+    assert abs(loss - float(want_loss)) < 1e-5
+    gaps = app.gradient_gaps(
+        app.named_leaves(grads),
+        app.named_leaves(app.reference_weights(want, CONFIG)))
+    assert len(gaps) == 3 + 3 * 17 + 16 + 1      # every leaf, and "all"
+    assert max(gaps.values()) < 2e-4, max(gaps, key=gaps.get)
+
+
+def test_the_steps_gradient_is_read_from_the_state_it_returns():
+    """What the benchmark's ``correct`` leans on: after one step from fresh
+    moments AdamW's first moment is (1 - b1) x the step's own gradient. A
+    state handed back unchanged reads a gap of 1."""
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.train import make_lm_train_step
+    cfg = dataclasses.replace(program_config(), remat=True)
+    init_fn, step_fn, place = make_lm_train_step(
+        cfg, build_mesh(MeshSpec(dp=1)))
+    tokens = np.random.default_rng(0).integers(0, 128, (2, SEQ),
+                                               dtype=np.int32)
+    state = init_fn(jax.random.PRNGKey(0))
+    want = jax.grad(transformer_loss)(state.params, {"tokens": tokens}, cfg)
+    want = {k: np.asarray(v) for k, v in app.named_leaves(
+        app.reference_weights(want, CONFIG)).items()}
+    unchanged = app.gradient_gaps(app.first_moment(state, CONFIG), want,
+                                  1 / (1 - app.ADAM_B1))
+    assert set(unchanged.values()) == {1.0}
+    state, _ = step_fn(state, place({"tokens": tokens}))
+    gaps = app.gradient_gaps(app.first_moment(state, CONFIG), want,
+                             1 / (1 - app.ADAM_B1))
+    assert max(gaps.values()) < 1e-4, max(gaps, key=gaps.get)
+    checks = app.gradient_checks(gaps)
+    assert checks["grad_gap"] == gaps["all"]
+    assert checks["grad_gap_worst"] == gaps[checks["grad_gap_worst_leaf"]]
+
+
+def test_make_lm_train_step_takes_the_hybrid_configuration():
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.train import make_lm_train_step
+    cfg = dataclasses.replace(program_config(), remat=True,
+                              dtype=jnp.bfloat16, attn_impl="auto")
+    mesh = build_mesh(MeshSpec(dp=2))
+    init_fn, step_fn, place = make_lm_train_step(cfg, mesh)
+    state = init_fn(jax.random.PRNGKey(0))
+    batch = place({"tokens": np.random.default_rng(0).integers(
+        0, 128, (4, SEQ), dtype=np.int32)})
+    losses = []
+    for _ in range(4):
+        state, metrics = step_fn(state, batch)
+        losses.append(float(metrics["loss"]))
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+    assert int(metrics["moe_rows_here"]) == 4 * 4 * SEQ * 2   # all held
+    assert int(metrics["moe_rows_dropped"]) == 0
+    assert float(metrics["moe_load_max"]) >= float(metrics["moe_load_mean"])
+    # the llama configuration's metrics carry no expert counters
+    dense = TransformerConfig(vocab_size=128, d_model=64, n_layers=2,
+                              n_heads=4, max_seq=SEQ)
+    init_fn, step_fn, place = make_lm_train_step(dense, mesh)
+    _, metrics = step_fn(init_fn(jax.random.PRNGKey(0)), batch)
+    assert sorted(metrics) == ["grad_norm", "loss", "step"]
+
+
+def test_generate_refuses_recurrent_layers_in_words():
+    cfg = program_config()
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    prompt = jnp.zeros((1, 4), jnp.int32)
+    with pytest.raises(NotImplementedError, match="gated-delta-rule"):
+        generate(params, prompt, cfg, max_new_tokens=2)
+
+
+def test_a_layer_pattern_is_checked_where_it_is_configured():
+    with pytest.raises(ValueError, match="multiple of the period"):
+        program_config(n_layers=6)
+    with pytest.raises(ValueError, match="'full' or 'linear'"):
+        program_config(layer_types=("full", "window"))
+    with pytest.raises(ValueError, match="linear_key_heads"):
+        program_config(linear_key_heads=0)
+
+
+def test_load_balance_loss_is_one_when_uniform():
+    probs = jnp.full((32, 8), 1 / 8)
+    top_e = jnp.stack([jnp.arange(32) % 8, (jnp.arange(32) + 1) % 8], 1)
+    assert float(moe.load_balance_loss(probs, top_e)) == pytest.approx(1.0)
+    onto_one = jnp.zeros((32, 2), jnp.int32)
+    peaked = jax.nn.one_hot(jnp.zeros(32, jnp.int32), 8)
+    assert float(moe.load_balance_loss(peaked, onto_one)) == \
+        pytest.approx(8.0)
