@@ -359,10 +359,13 @@ def _output_gate(o, gate):
     return o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
 
 
-def _gated_delta_mix(cfg: TransformerConfig, p, h):
+def _gated_delta_mix(cfg: TransformerConfig, p, h, mesh=None,
+                     rules: LogicalRules = DEFAULT_RULES):
     """The gated delta rule over the normed input ``h`` [B, S, E]: packed
-    projections, short causal convolution, the chunked rule, gated norm."""
-    from ray_tpu.ops.gated_delta import causal_conv, gated_delta_rule
+    projections, short causal convolution, the chunked rule, gated norm.
+    ``mesh``/``rules``: what ``h`` is laid out by (the rule's kernels run
+    per shard)."""
+    from ray_tpu.ops.gated_delta import causal_conv, gated_delta_rule_over
     dt, f32 = cfg.dtype, jnp.float32
     b, s, _ = h.shape
     hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
@@ -374,30 +377,29 @@ def _gated_delta_mix(cfg: TransformerConfig, p, h):
     qkv = causal_conv(qkvz[..., :2 * kd + vd], p["conv"])
     z = qkvz[..., 2 * kd + vd:].reshape(b, s, hv, dv)
     with jax.named_scope("rt.gdn.scan"):
-        def unit(x):            # L2-normalised over the head, in float32
-            x = x.reshape(b, s, hk, dk).astype(f32)
-            x = x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
-            return jnp.repeat(x, hv // hk, axis=2)
-
-        q = (unit(qkv[..., :kd]) * dk ** -0.5).astype(dt)
-        k = unit(qkv[..., kd:2 * kd]).astype(dt)
+        q = qkv[..., :kd].reshape(b, s, hk, dk)
+        k = qkv[..., kd:2 * kd].reshape(b, s, hk, dk)
         v = qkv[..., 2 * kd:].reshape(b, s, hv, dv)
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
             ba[..., hv:] + p["dt_bias"].astype(f32))
-    o = gated_delta_rule(q, k, v, g, beta)               # [B, S, Hv, dv]
+    # q, k to unit length inside; a key head serves hv / hk value heads
+    o = gated_delta_rule_over(mesh, rules, q, k, v, g, beta)
     with jax.named_scope("rt.gdn.proj"):
         o = _rmsnorm(o, p["norm"]) * jax.nn.silu(z.astype(f32)).astype(dt)
         return o.reshape(b, s, vd) @ p["out"].astype(dt)
 
 
-def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend):
+def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend,
+                 mesh=None, rules: LogicalRules = DEFAULT_RULES):
     """One block: ``x + mixer(norm(x))``, then ``+ feed_forward(norm(.))``.
     The mixer is the layer's own: softmax attention where it holds
     ``attn``, the gated delta rule where it holds ``gdn``. ``attend(q, k,
     v) -> (o, kept)`` is all that differs between training, prefill and
     decode (models/generate.py): what attention does with the rotated k
-    and v, and what it keeps of them. -> (x, kept, expert-layer stats)."""
+    and v, and what it keeps of them; ``mesh``/``rules`` are the gated
+    delta rule's, whose kernels run per shard. -> (x, kept, expert-layer
+    stats)."""
     h = _norm(cfg, x, layer["ln1"])
     if "attn" in layer:
         # the gated variant's device time is found by this scope
@@ -406,7 +408,7 @@ def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend):
             o, kept = _full_attention_mix(cfg, layer["attn"], h, positions,
                                           attend)
     else:
-        o, kept = _gated_delta_mix(cfg, layer["gdn"], h), None
+        o, kept = _gated_delta_mix(cfg, layer["gdn"], h, mesh, rules), None
     x = x + o
     y, stats = _feed_forward(cfg, layer, _norm(cfg, x, layer["ln2"]))
     return x + y, kept, stats
@@ -419,7 +421,7 @@ def _stage_scan(cfg: TransformerConfig, mesh, stage_layers, x, positions,
     ``rules``: what the caller sharded params and batch by over ``mesh``.
     -> (x, the expert layers' stats stacked over the scan, or None)."""
     body = partial(
-        _layer_apply, cfg,
+        _layer_apply, cfg, mesh=mesh, rules=rules,
         attend=lambda q, k, v: (_attention(cfg, q, k, v, mesh, rules), None))
     if cfg.remat:
         body = jax.checkpoint(body)

@@ -1,7 +1,8 @@
 """The gated delta rule (Gated DeltaNet), chunked, and the short causal
 convolution that stands in front of it.
 
-Per head, with ``S`` a ``[dk, dv]`` float32 state that starts at 0:
+Per head, with ``S`` a ``[dk, dv]`` float32 state that starts at 0, q and k
+first taken to unit length over the head (q then times ``dk^-0.5``):
 
     S_t = exp(g_t) S_{t-1}
     S_t = S_t + k_t (beta_t (v_t - S_t^T k_t))^T
@@ -16,15 +17,22 @@ a chunk and ``A = strict_lower(diag(beta) K K^T * exp(G_i - G_j))``,
 
 and then, chunk after chunk, ``v' = u - w S``, ``o = (q e^G) S +
 lower(Q K^T * decay) v'``, ``S <- e^{G_last} S + (k e^{G_last - G})^T v'``.
-Only that last recurrence is a scan (one step a chunk); everything else is
-batched matmuls. ``T`` is made by block forward substitution
-(``_unit_lower_inverse``), in float32 at the highest precision (it is the
-one place where rounding compounds). The large matmuls take their operands
-in the inputs' dtype (bfloat16 in the model) and accumulate in float32.
+Only that last recurrence is sequential (one step a chunk). ``T`` is made by
+forward substitution (rows inside a diagonal block, then blocks, doubling
+the side), in float32 at the highest precision: it is the one place where
+rounding compounds. The large matmuls take their operands in the inputs'
+dtype (bfloat16 in the model) and accumulate in float32.
 
-Backward is XLA's: the scan keeps one state a chunk (``n_chunks x dk x dv``
-a head), which under the model's whole-layer remat lives only while that
-layer's backward runs. Every operation here runs under the scope
+**Two forms, one a backend** (ops/attention.py's rule for flash: no option
+chooses). On a TPU, at head widths that are multiples of 128, the rule is
+two fused Pallas kernels with a ``jax.custom_vjp`` (ops/gated_delta_pallas:
+``rt_gdn_fwd``, ``rt_gdn_bwd``): a chunk's intermediates stay in VMEM, the
+state crosses chunks in VMEM scratch, the backward is a reverse scan of its
+own. Everywhere else (the CPU tests, odd widths) the jnp form below runs,
+batched matmuls and one ``lax.scan`` step a chunk with XLA's backward, which
+keeps one state a chunk; it is also the kernels' oracle. Either way what is
+kept for the backward lives, under the model's whole-layer remat, only while
+that layer's backward runs. Every operation of both runs under the scope
 ``rt.gdn.scan`` (``rt.gdn.conv`` for the convolution), which is how the
 benchmark's reducer finds their device time.
 """
@@ -37,7 +45,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops.flash import _on_tpu
+from ray_tpu.parallel.sharding import LogicalRules
+
 CHUNK = 64
+EPS = 1e-6          # under the root of a head's squared length
 BASE = 8            # side of the diagonal blocks inverted directly
 VPU_SIDE = 32       # blocks up to this side multiply on the VPU
 
@@ -57,12 +69,15 @@ def causal_conv(x, w):
 
 
 def _block_matmul(a, b):
-    """a @ b for stacks of small square float32 blocks. Up to VPU_SIDE a
-    side they are multiplied and summed elementwise, exactly: the MXU pads
-    an 8-wide block to its 128-wide tile, and XLA lowers a batch of such
-    dots as convolutions that take ~1 ms for 17 MB of blocks (on a v5e the
-    rule's forward and backward at [2, 8192, 32, 128] take 39.0 ms this way
-    against 74.1 ms through the MXU; PERF.md, PR 37)."""
+    """a @ b for stacks of small square float32 blocks (the jnp form's; the
+    kernels have their own products). Up to VPU_SIDE a side they are
+    multiplied and summed elementwise, exactly, which is what a CPU does
+    anyway. On a TPU, where this form ran until PR 38 and still runs at
+    head widths the kernels do not take: the MXU pads an 8-wide block to its
+    128-wide tile, and XLA lowers a batch of such dots as convolutions that
+    take ~1 ms for 17 MB of blocks (on a v5e this form's forward and
+    backward at [2, 8192, 32, 128] take 39.0 ms this way against 74.1 ms
+    through the MXU; PERF.md, PR 37)."""
     if a.shape[-1] <= VPU_SIDE:
         return jnp.sum(a[..., :, :, None] * b[..., None, :, :], axis=-2)
     return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
@@ -106,19 +121,66 @@ def _mm(spec, a, b, dt):
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
-    """q, k: [B, S, H, dk] (k of unit length, q already scaled); v: [B, S,
-    H, dv]; g (log decay, <= 0), beta: [B, S, H]. -> o [B, S, H, dv] in v's
-    dtype. Any S: the tail is padded with positions that leave the state
-    alone."""
+    """q, k: [B, S, Hk, dk] as the convolution left them: the rule takes
+    each head's q and k to unit length in float32 (q then times dk^-0.5),
+    as Gated DeltaNet does, and rounds them to v's dtype; v: [B, S, H, dv],
+    each of the Hk key heads shared by H / Hk value heads in a row; g (log
+    decay, <= 0), beta: [B, S, H]. -> o [B, S, H, dv] in v's dtype. Any S:
+    the tail is padded with positions that leave the state alone."""
     with jax.named_scope("rt.gdn.scan"):
+        if _kernels_fit(q, v, chunk):
+            from ray_tpu.ops.gated_delta_pallas import gated_delta_rule_kernels
+            return gated_delta_rule_kernels(q, k, v, g, beta, chunk)
         return _chunked(q, k, v, g, beta, chunk)
 
 
+def gated_delta_rule_over(mesh, rules: LogicalRules, q, k, v, g, beta):
+    """``gated_delta_rule`` of operands laid out by ``rules`` over ``mesh``.
+    A Mosaic call cannot be partitioned by GSPMD, so where the kernels run
+    under a mesh of several devices they run per shard inside shard_map,
+    batch and heads split as ``rules`` say (the rule is independent across
+    both), as ops/flash.py does; the jnp form is GSPMD's to partition."""
+    rule = gated_delta_rule     # as it is named now: the benchmark's sweep
+    #                             plants faults on the name
+    if mesh is None or mesh.size == 1 or not _kernels_fit(q, v, CHUNK) \
+            or jax.sharding.get_abstract_mesh().manual_axes:
+        return rule(q, k, v, g, beta)
+    head_shards = math.prod(
+        mesh.shape[a] for ax in rules.spec(("heads",), mesh)
+        for a in ((ax,) if isinstance(ax, str) else ax or ()))
+    if q.shape[2] % head_shards:
+        # fewer key heads than head shards: one copy a value head
+        q, k = (jnp.repeat(x, v.shape[2] // x.shape[2], axis=2)
+                for x in (q, k))
+    wide = rules.spec(("batch", None, "heads", None), mesh)
+    gate = rules.spec(("batch", None, "heads"), mesh)
+    return jax.shard_map(rule, mesh=mesh, in_specs=(wide, wide, wide, gate,
+                                                    gate),
+                         out_specs=wide, check_vma=False)(q, k, v, g, beta)
+
+
+def _kernels_fit(q, v, chunk) -> bool:
+    """The Pallas kernels run on a TPU at head widths that fill its lanes;
+    everywhere else the jnp form below runs (ops/attention.py's rule for
+    flash)."""
+    return (_on_tpu() and q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
+            and chunk % 16 == 0 and v.shape[2] % q.shape[2] == 0)
+
+
+def unit(x):
+    """x [..., d] at unit length over d, in float32."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + EPS)
+
+
 def _chunked(q, k, v, g, beta, c):
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
+    b, s, h, dv = v.shape
+    dk = q.shape[-1]
     dt = v.dtype
     f32 = jnp.float32
+    q, k = (unit(q) * dk ** -0.5).astype(dt), unit(k).astype(dt)
+    if q.shape[2] != h:
+        q, k = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (q, k))
     pad = -s % c
     if pad:
         q, k, v, g, beta = (

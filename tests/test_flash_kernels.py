@@ -108,6 +108,60 @@ def test_flash_compiles_for_v5e_per_shard(monkeypatch):
     assert "all-gather" not in hlo and "all-to-all" not in hlo
 
 
+def test_delta_rule_kernels_compile_for_v5e_per_shard(monkeypatch):
+    """Mosaic accepts the gated delta rule's forward and backward kernels at
+    the Qwen3-Next cell's shape (2 x 8192, 32 value heads on 16 key heads
+    of 128) within the chip's VMEM, and under a 4-chip mesh each chip runs
+    them on its own rows and heads (tests/test_qwen3_next.py holds their
+    numbers, through the interpreter)."""
+    from jax.experimental import topologies
+    from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+
+    from ray_tpu.ops import gated_delta
+    from ray_tpu.parallel.sharding import DEFAULT_RULES
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu in this install
+        pytest.skip(f"no TPU compiler here: {e!r}")
+    monkeypatch.setattr(gated_delta, "_on_tpu", lambda: True)
+
+    def shapes(b, s, at):
+        def like(shape, dtype, spec):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=at(spec))
+        spec = P("dp", None, "tp")      # rows over dp, heads over tp
+        return (like((b, s, 16, 128), jnp.bfloat16, spec),
+                like((b, s, 16, 128), jnp.bfloat16, spec),
+                like((b, s, 32, 128), jnp.bfloat16, spec),
+                like((b, s, 32), jnp.float32, spec),
+                like((b, s, 32), jnp.float32, spec))
+
+    def kernel_calls(mesh, operands):
+        def loss(*a):
+            out = gated_delta.gated_delta_rule_over(mesh, DEFAULT_RULES, *a)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *operands).compile().as_text()
+        return hlo, [ln for ln in hlo.splitlines()
+                     if 'custom_call_target="tpu_custom_call"' in ln]
+
+    one = SingleDeviceSharding(topo.devices[0])
+    _, calls = kernel_calls(None, shapes(2, 8192, lambda spec: one))
+    assert len(calls) == 2                    # forward (saving), backward
+    assert all("bf16[2,8192,2048]" in ln for ln in calls)   # q, k as made
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 2, 2),
+                ("dcn_dp", "dp", "tp"))
+    hlo, calls = kernel_calls(
+        mesh, shapes(4, 512, lambda spec: NamedSharding(mesh, spec)))
+    assert len(calls) == 2
+    # half of the rows and half of the heads a chip: 8 key heads of 128
+    assert all("bf16[2,512,1024]" in ln for ln in calls)
+    assert "all-gather" not in hlo and "all-to-all" not in hlo
+
+
 @pytest.mark.parametrize("kv_heads", [4, 2, 1])
 def test_sharded_flash_splits_gqa_heads_like_the_reference(monkeypatch,
                                                            kv_heads):

@@ -3,6 +3,7 @@ layer over a share) against the plain reference of its family, at a small
 size on the CPU, in float32 so that the comparison is of the mathematics."""
 
 import dataclasses
+from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -96,47 +97,71 @@ def test_chunked_delta_rule_matches_the_recurrence_values_and_gradients():
               tol=5e-3 if name in ("A_log", "dt_bias") else 2e-4)
 
 
+def delta_rule(form):
+    """The op by its jnp form, or by the Pallas kernels through the
+    interpreter (no platform check ever selects ``interpret``)."""
+    from ray_tpu.ops.gated_delta import CHUNK, gated_delta_rule
+    from ray_tpu.ops.gated_delta_pallas import gated_delta_rule_kernels
+    if form == "jnp":
+        return gated_delta_rule
+    return lambda *operands: gated_delta_rule_kernels(*operands, CHUNK,
+                                                      interpret=True)
+
+
+def delta_rule_operands(seq, hk, hv, dk=8, dv=8, b=1, seed=None):
+    ks = jax.random.split(jax.random.PRNGKey(seq if seed is None else seed),
+                          5)
+    q = jax.random.normal(ks[0], (b, seq, hk, dk))
+    k = 3.0 * jax.random.normal(ks[1], (b, seq, hk, dk))
+    v = jax.random.normal(ks[2], (b, seq, hv, dv))
+    g = -jax.nn.softplus(jax.random.normal(ks[3], (b, seq, hv)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, seq, hv)))
+    return q, k, v, g, beta
+
+
+def as_the_rule_takes_them(q, k):
+    """q, k at unit length over the head, q times dk^-0.5: what the rule
+    makes of them before anything else."""
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+    return unit(q) * q.shape[-1] ** -0.5, unit(k)
+
+
+@pytest.mark.parametrize("form", ["jnp", "kernels"])
 @pytest.mark.parametrize("seq", [64, 65, 130])
-def test_delta_rule_op_at_chunk_edges(seq):
+def test_delta_rule_op_at_chunk_edges(seq, form):
     """The op alone against a loop over positions, at a whole chunk, one
     position past it and two chunks and a bit."""
-    from ray_tpu.ops.gated_delta import gated_delta_rule
-    ks = jax.random.split(jax.random.PRNGKey(seq), 5)
     b, h, dk, dv = 1, 2, 8, 8
-    q = jax.random.normal(ks[0], (b, seq, h, dk)) * dk ** -0.5
-    k = jax.random.normal(ks[1], (b, seq, h, dk))
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (b, seq, h, dv))
-    g = -jax.nn.softplus(jax.random.normal(ks[3], (b, seq, h)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, seq, h)))
+    q, k, v, g, beta = delta_rule_operands(seq, h, h)
+    q_t, k_t = (np.asarray(x) for x in as_the_rule_takes_them(q, k))
     state = np.zeros((b, h, dk, dv))
     want = []
     for t in range(seq):
         state = state * np.exp(np.asarray(g[:, t]))[..., None, None]
-        seen = np.einsum("bhkv,bhk->bhv", state, np.asarray(k[:, t]))
+        seen = np.einsum("bhkv,bhk->bhv", state, k_t[:, t])
         delta = np.asarray(beta[:, t])[..., None] \
             * (np.asarray(v[:, t]) - seen)
-        state = state + np.asarray(k[:, t])[..., :, None] \
-            * delta[..., None, :]
-        want.append(np.einsum("bhkv,bhk->bhv", state, np.asarray(q[:, t])))
-    close(gated_delta_rule(q, k, v, g, beta), np.stack(want, 1))
+        state = state + k_t[:, t][..., :, None] * delta[..., None, :]
+        want.append(np.einsum("bhkv,bhk->bhv", state, q_t[:, t]))
+    close(delta_rule(form)(q, k, v, g, beta), np.stack(want, 1))
 
 
-def test_delta_rule_holds_when_keys_align():
+@pytest.mark.parametrize("form", ["jnp", "kernels"])
+def test_delta_rule_holds_when_keys_align(form):
     """Keys that point one way, beta near 1, slow decay: (I + A)^-1 has
     entries of O(1) but its Neumann series has terms of 1e18; the blocked
     substitution stays with the recurrence (the series returned NaN)."""
-    from ray_tpu.ops.gated_delta import gated_delta_rule
     ks = jax.random.split(jax.random.PRNGKey(2), 4)
     b, seq, h, dk, dv = 1, 192, 2, 16, 16
     k = 0.01 * jax.random.normal(ks[0], (b, seq, h, dk)) \
         + jax.random.normal(ks[1], (1, 1, h, dk))
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    q = jax.random.normal(ks[2], (b, seq, h, dk)) * dk ** -0.5
+    q = jax.random.normal(ks[2], (b, seq, h, dk))
     v = jax.random.normal(ks[3], (b, seq, h, dv))
     g = jnp.full((b, seq, h), -0.01)
     beta = jnp.full((b, seq, h), 0.95)
-    got = gated_delta_rule(q, k, v, g, beta)
+    got = delta_rule(form)(q, k, v, g, beta)
+    q, k = as_the_rule_takes_them(q, k)
 
     def position(state, xs):
         q_t, k_t, v_t = xs
@@ -148,6 +173,82 @@ def test_delta_rule_holds_when_keys_align():
     _, want = jax.lax.scan(position, jnp.zeros((b, h, dk, dv)),
                            tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v)))
     close(got, jnp.moveaxis(want, 0, 1), tol=1e-4)
+
+
+@pytest.mark.parametrize("seq,hk,hv", [
+    (130, 2, 2),        # a padded tail; the heads fill half of the lanes
+    (128, 1, 3),        # three heads share one key head; a lone last head
+    (64, 16, 32),       # the model's heads: dq, dk summed over each pair
+])
+@pytest.mark.parametrize("operand", ["q", "k", "v", "g", "beta"])
+def test_delta_rule_kernels_gradient_matches_the_jnp_forms(operand, seq, hk,
+                                                           hv):
+    """The backward kernel against ``jax.vjp`` of the jnp form. g has its
+    own tolerance: its gradient is a sum through the running sum in which
+    the terms cancel (``A_log`` / ``dt_bias`` read 5e-3 in the layer's
+    test)."""
+    at = ["q", "k", "v", "g", "beta"].index(operand)
+    got, want = (grads[at] for grads in delta_rule_gradients(seq, hk, hv))
+    assert got.shape == want.shape and got.dtype == want.dtype
+    close(got, want, tol=5e-5 if operand == "g" else 1e-5)
+
+
+@cache
+def delta_rule_gradients(seq, hk, hv):
+    """(the kernels', the jnp form's) gradients of all five operands."""
+    operands = delta_rule_operands(seq, hk, hv)
+    probe = jax.random.normal(jax.random.PRNGKey(7), operands[2].shape)
+    return tuple(
+        jax.grad(lambda *a: (delta_rule(form)(*a) * probe).sum(),
+                 argnums=(0, 1, 2, 3, 4))(*operands)
+        for form in ("kernels", "jnp"))
+
+
+def test_delta_rule_kernels_take_bfloat16_as_the_jnp_form_does():
+    """bfloat16 operands, as the model hands them over: both forms round
+    the same products' operands, so they differ by a rounding, not more."""
+    q, k, v, g, beta = delta_rule_operands(128, 2, 4, dk=16, dv=16)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    got = delta_rule("kernels")(q, k, v, g, beta)
+    want = delta_rule("jnp")(q, k, v, g, beta)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    close(got.astype(jnp.float32), want.astype(jnp.float32), tol=1e-2)
+
+
+@pytest.mark.parametrize("hk", [4, 2])
+def test_delta_rule_kernels_run_per_shard_under_a_mesh(monkeypatch, hk):
+    """Where the kernels run, a mesh of several devices gets them per shard
+    (GSPMD cannot partition a Mosaic call): rows over dp, heads over tp, a
+    value head staying with its key head; two key heads over four head
+    shards are first copied once a value head. The kernels themselves go
+    through the interpreter here."""
+    from jax.sharding import Mesh
+
+    from ray_tpu.ops import gated_delta, gated_delta_pallas
+    from ray_tpu.parallel.sharding import DEFAULT_RULES
+
+    monkeypatch.setattr(gated_delta, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        gated_delta_pallas, "gated_delta_rule_kernels",
+        partial(gated_delta_pallas.gated_delta_rule_kernels, interpret=True))
+    mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("dp", "tp"))
+    operands = delta_rule_operands(64, hk, 4, dk=128, dv=128, b=2)
+    got = jax.jit(partial(gated_delta.gated_delta_rule_over, mesh,
+                          DEFAULT_RULES))(*operands)
+    close(got, gated_delta._chunked(*operands, gated_delta.CHUNK))
+
+
+def test_off_the_tpu_the_delta_rule_is_the_jnp_form():
+    """No platform check selects the kernels here: the op traces no Mosaic
+    call on this backend, at the widths that would fit one."""
+    from ray_tpu.ops.gated_delta import gated_delta_rule, gated_delta_rule_over
+    operands = delta_rule_operands(64, 1, 2, dk=128, dv=128)
+    assert jax.default_backend() != "tpu"
+    for rule in (gated_delta_rule, partial(gated_delta_rule_over, None,
+                                           None)):
+        text = jax.jit(jax.grad(
+            lambda *a: rule(*a).sum())).lower(*operands).as_text()
+        assert "tpu_custom_call" not in text and "rt_gdn" not in text
 
 
 def test_gated_attention_with_partial_rotary_matches_the_reference():
