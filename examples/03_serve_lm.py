@@ -25,6 +25,10 @@ class LM:
                                 n_heads=4, n_kv_heads=2, max_seq=128,
                                 attn_impl="reference", dtype=jnp.float32)
         self.params = transformer_init(jax.random.PRNGKey(0), cfg)
+        # generate keeps its KV cache as one stacked pair [loop steps x L,
+        # B, T_max, KVH, D]: a slot a layer, and for a looped stack
+        # (cfg.loop_steps > 1) a slot for every (loop step, layer), since
+        # a step's query sees the keys that step's own state gave.
         self._gen = jax.jit(partial(generate, cfg=cfg, max_new_tokens=16,
                                     temperature=0.8, top_k=40))
 

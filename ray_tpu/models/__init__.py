@@ -5,7 +5,9 @@ configuration: softmax attention (RMSNorm / RoPE, whole or partial / GQA;
 optionally per-head q/k norm, an output gate, any head width) or the gated
 delta rule (linear attention with a short causal convolution), over a SwiGLU
 MLP or a top-k expert layer with no capacity per expert that holds a share
-of the experts, and a shared expert. Pure-functional params pytree with logical-axis
+of the experts, and a shared expert; the stack run once, or ``loop_steps``
+times over the one set of weights with sandwich norms and an exit gate.
+Pure-functional params pytree with logical-axis
 annotations so one definition runs under any MeshSpec (dp/fsdp/tp/pp/sp/ep).
 Plus ResNet-50 (the north-star image benchmark, BASELINE.json) and an MLP.
 
@@ -18,11 +20,13 @@ from ray_tpu.models.transformer import (
     TransformerConfig,
     transformer_init,
     transformer_apply,
+    transformer_apply_and_exits,
     transformer_loss,
     transformer_loss_and_stats,
     transformer_logical_axes,
 )
-from ray_tpu.models.generate import (decode_step, generate, init_cache,
+from ray_tpu.models.generate import (decode_step, generate,
+                                     generate_with_stats, init_cache,
                                      prefill)
 from ray_tpu.models.resnet import resnet50_init, resnet50_apply, resnet_loss
 from ray_tpu.models.mlp import mlp_init, mlp_apply
@@ -30,9 +34,10 @@ from ray_tpu.models.vit import ViTConfig, vit_init, vit_apply, vit_loss
 
 __all__ = [
     "TransformerConfig", "transformer_init", "transformer_apply",
-    "transformer_loss", "transformer_loss_and_stats",
+    "transformer_apply_and_exits", "transformer_loss", "transformer_loss_and_stats",
     "transformer_logical_axes",
-    "generate", "prefill", "decode_step", "init_cache",
+    "generate", "generate_with_stats", "prefill", "decode_step",
+    "init_cache",
     "resnet50_init", "resnet50_apply", "resnet_loss",
     "mlp_init", "mlp_apply",
     "ViTConfig", "vit_init", "vit_apply", "vit_loss",

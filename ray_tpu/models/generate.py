@@ -4,24 +4,32 @@ The reference has no in-tree LM inference; serving there means wrapping an
 external model in Ray Serve. Here decode is a first-class TPU program
 (completing the LM story: train with jax_step, serve with serve/ + this):
 
-- The KV cache is ONE stacked array pair [L, B, T_max, KVH, D] matching the
-  layer-stacked parameter layout. Decode scans over (layers, layer index)
-  with one compiled layer body and CARRIES the stacked cache: a layer
-  writes its new key and value at [l, :, pos] and then reads its slab back
-  from the updated carry. Write first, read second: a read of the
-  pre-update stack after the write would make XLA keep two buffers and
-  copy 2 GB a token. The cache is never a scanned input or output inside
-  the decode loop, so the token loop updates one buffer in place (what a
-  step writes is a few positions a layer, not the whole cache).
+- The KV cache is ONE stacked array pair [loop steps x L, B, T_max, KVH, D]
+  matching the layer-stacked parameter layout: one slot a layer, and for a
+  looped stack (``cfg.loop_steps`` passes over the one set of weights) one
+  slot for every (pass t, layer l), slot ``t * L + l``. The passes share
+  weights, not activations: layer l's keys and values of pass t are
+  projections of pass t's state, so a pass-t query sees pass-t keys only.
+  Decode scans over (layers, layer index), and over the passes around
+  that, with one compiled layer body and CARRIES the stacked cache through
+  both levels: a layer writes its new key and value at [slot, :, pos] and
+  then reads its slab back from the updated carry. Write first, read
+  second: a read of the pre-update stack after the write would make XLA
+  keep two buffers and copy 2 GB a token. The cache is never a scanned
+  input or output inside the decode loop, so the token loop updates one
+  buffer in place (what a step writes is a few positions a slot, not the
+  whole cache).
 - `generate` runs the whole decode loop INSIDE jit via lax.scan: static
   shapes (cache padded to max length, attention masked by position), PRNG
   threaded through the scan — zero host round-trips per token.
 - Prefill and decode run the training forward's one layer
   (transformer._layer_apply) and hand it only the attention step: prefill
-  keeps each layer's rotated K/V as scan outputs (once a call); decode
-  steps attend over the cache with a position mask (S=1 queries are
-  bandwidth-bound; masking the padded tail costs nothing against reading
-  the cache itself).
+  keeps each layer's rotated K/V as scan outputs (once a call; a looped
+  stack's prefill writes them into the carried cache slot by slot, since
+  the scan outputs of a pass, stacked over the passes, would hold a
+  pass's slots twice); decode steps attend over the cache with a position
+  mask (S=1 queries are bandwidth-bound; masking the padded tail costs
+  nothing against reading the cache itself).
 
 GQA (n_kv_heads < n_heads) is supported; pp_stages>1 is not (decode
 pipelining is a different schedule than GPipe microbatching).
@@ -36,7 +44,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.transformer import (TransformerConfig, _attention,
-                                        _head, _layer_apply)
+                                        _head, _layer_apply,
+                                        _over_loop_steps)
+from ray_tpu.util import events
 
 
 def _refuse_recurrent(cfg: TransformerConfig) -> None:
@@ -53,9 +63,15 @@ def _refuse_recurrent(cfg: TransformerConfig) -> None:
             "(layer_types) is not served yet (ROADMAP.md R5)")
 
 
+def cache_slots(cfg: TransformerConfig) -> int:
+    """One slot for every (loop step, layer)."""
+    return cfg.loop_steps * cfg.n_layers
+
+
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
-    """[L, B, T, KVH, D] zeros pair (kv dtype = compute dtype)."""
-    shape = (cfg.n_layers, batch, max_len, cfg.kv_heads, cfg.head_dim)
+    """[loop steps x L, B, T, KVH, D] zeros pair (kv dtype = compute
+    dtype)."""
+    shape = (cache_slots(cfg), batch, max_len, cfg.kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -85,7 +101,7 @@ _WRITE_ROWS = 8
 
 
 def _write_position(cache, l, pos, new):
-    """cache [L, B, T, KVH, D] with new [B, 1, KVH, D] at [l, :, pos]: the
+    """cache [slots, B, T, KVH, D] with new [B, 1, KVH, D] at [l, :, pos]: the
     block of _WRITE_ROWS positions that holds ``pos`` is read, gets the new
     row and is written back where it was. Nothing reads the stack between
     that read and the write, so the update is in place."""
@@ -99,63 +115,139 @@ def _write_position(cache, l, pos, new):
                                     at)
 
 
-def prefill(params, tokens, cfg: TransformerConfig, max_len: int,
-            mesh=None) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+def _write_prompt(cache, l, new):
+    """cache [slots, B, T, KVH, D] with new [B, S, KVH, D] at [l, :, :S],
+    a row of the batch at a time, in a loop of its own: the TPU compiler
+    lays a loop's carry out by what the loop's body does with it. Written
+    straight from the layer's body the stack takes the layout attention
+    wants of its k and v (heads before positions), the decode loop wants
+    positions before heads, and the compiler copies the whole stack from
+    the one to the other: at [192, 16, 384, 16, 128] two copies of 4.8 GB
+    beside the stack, 18.85 of the 15.75 GiB a v5e has. This loop's body
+    holds no matmul, so its carry keeps the default layout, which is the
+    decode loop's, and what is laid out anew is the layer's own 8 MB."""
+    def row(b, cache):
+        return lax.dynamic_update_slice(
+            cache, lax.dynamic_slice_in_dim(new, b, 1)[None],
+            (l, b, 0, 0, 0))
+    return lax.fori_loop(0, new.shape[0], row, cache)
+
+
+def _slots_of_pass(cfg: TransformerConfig, t):
+    """The cache slots of loop step ``t``'s layers, in layer order."""
+    return t * cfg.n_layers + jnp.arange(cfg.n_layers)
+
+
+def _over_the_slots(cfg: TransformerConfig, params, x, positions, cache,
+                    attend_at):
+    """The trunk over a CARRIED cache: every loop step's pass over the
+    layers, layer l of step t owning slot ``t * L + l``. ``attend_at(
+    cache_k, cache_v, slot)`` gives the layer its attention step, which
+    hands back the two stacks with the slot written. -> (x, cache, exit
+    distribution or None)."""
+    def layers(x, kv, t):
+        def step(carry, layer_and_slot):
+            x, kv = carry
+            layer, slot = layer_and_slot
+            return _layer_apply(cfg, layer, x, positions,
+                                attend_at(*kv, slot))[:2], None
+
+        (x, kv), _ = lax.scan(step, (x, kv),
+                              (params["layers"], _slots_of_pass(cfg, t)))
+        return x, kv
+
+    x, (cache_k, cache_v), exits = _over_loop_steps(
+        cfg, params, layers, x, (cache["k"], cache["v"]))
+    return x, {"k": cache_k, "v": cache_v}, exits
+
+
+def prefill_and_exits(params, tokens, cfg: TransformerConfig, max_len: int,
+                      mesh=None):
     """Run the prompt through the trunk, returning (last-position logits
-    [B, vocab], filled cache). tokens [B, S], S <= max_len."""
+    [B, vocab], filled cache, the last position's exit distribution [B,
+    loop_steps] or None where there is no loop). tokens [B, S], S <=
+    max_len."""
     if cfg.pp_stages > 1:
         raise NotImplementedError("decode with pp_stages>1 is not supported")
     _refuse_recurrent(cfg)
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     x = params["embed"].astype(cfg.dtype)[tokens]
-    pad = ((0, 0), (0, max_len - s), (0, 0), (0, 0))
+    if cfg.loop_steps == 1:
+        # One pass: each layer's K and V are the layer scan's outputs,
+        # stacked as the cache. Of a looped stack those outputs would be
+        # stacked once more over the passes, a pass's slots (2.4 GB of 9.7)
+        # held twice; there the cache is the loops' carry, below.
+        pad = ((0, 0), (0, max_len - s), (0, 0), (0, 0))
 
-    def attend(q, k, v):
-        # The training forward's attention; the layer's rotated K and V
-        # are kept, so the cache matches the forward bit for bit.
-        return (_attention(cfg, q, k, v, mesh),
-                {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)})
+        def attend(q, k, v):
+            # The training forward's attention; the layer's rotated K and
+            # V are kept, so the cache matches the forward bit for bit.
+            return (_attention(cfg, q, k, v, mesh),
+                    {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)})
 
-    def step(carry, layer):
-        return _layer_apply(cfg, layer, carry, positions, attend)[:2]
+        def step(carry, layer):
+            return _layer_apply(cfg, layer, carry, positions, attend)[:2]
 
-    x, cache = lax.scan(step, x, params["layers"])
-    return _head(params, x[:, -1:], cfg)[:, 0], cache
+        x, cache = lax.scan(step, x, params["layers"])
+        return _head(params, x[:, -1:], cfg)[:, 0], cache, None
+
+    def write_at(cache_k, cache_v, slot):
+        def attend(q, k, v):
+            # the same attention and the same K and V; each goes straight
+            # into its slot of the carried cache
+            return _attention(cfg, q, k, v, mesh), (
+                _write_prompt(cache_k, slot, k),
+                _write_prompt(cache_v, slot, v))
+        return attend
+
+    x, cache, exits = _over_the_slots(cfg, params, x, positions,
+                                      init_cache(cfg, b, max_len), write_at)
+    return _head(params, x[:, -1:], cfg)[:, 0], cache, exits[:, -1]
 
 
-def decode_step(params, token, pos, cache, cfg: TransformerConfig):
+def prefill(params, tokens, cfg: TransformerConfig, max_len: int,
+            mesh=None) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+    """``prefill_and_exits`` without the exits: (logits, cache)."""
+    return prefill_and_exits(params, tokens, cfg, max_len, mesh)[:2]
+
+
+def decode_step_and_exits(params, token, pos, cache,
+                          cfg: TransformerConfig):
     """One token for the whole batch: token [B] int32, pos scalar int32.
-    -> (logits [B, vocab], updated cache)."""
+    -> (logits [B, vocab], updated cache, exit distribution [B,
+    loop_steps] or None where there is no loop)."""
     _refuse_recurrent(cfg)
     x = params["embed"].astype(cfg.dtype)[token][:, None, :]   # [B, 1, E]
     positions = jnp.full((x.shape[0], 1), pos)
 
-    def step(carry, layer_and_index):
-        x, cache_k, cache_v = carry
-        layer, l = layer_and_index
-
+    def write_and_read_at(cache_k, cache_v, slot):
         def attend(q, k, v):
             # Write, then read the slab from the UPDATED stack: a read of
             # the old stack after the write would make XLA keep two
             # buffers and copy.
-            stack_k = _write_position(cache_k, l, pos, k)
-            stack_v = _write_position(cache_v, l, pos, v)
-            o = _cached_attention(
-                cfg, q,
-                lax.dynamic_index_in_dim(stack_k, l, 0, keepdims=False),
-                lax.dynamic_index_in_dim(stack_v, l, 0, keepdims=False),
-                pos)
+            with jax.named_scope("rt.loop.cache"):
+                stack_k = _write_position(cache_k, slot, pos, k)
+                stack_v = _write_position(cache_v, slot, pos, v)
+                o = _cached_attention(
+                    cfg, q,
+                    lax.dynamic_index_in_dim(stack_k, slot, 0,
+                                             keepdims=False),
+                    lax.dynamic_index_in_dim(stack_v, slot, 0,
+                                             keepdims=False),
+                    pos)
             return o, (stack_k, stack_v)
+        return attend
 
-        x, (cache_k, cache_v), _ = _layer_apply(cfg, layer, x, positions,
-                                                attend)
-        return (x, cache_k, cache_v), None
+    x, cache, exits = _over_the_slots(cfg, params, x, positions, cache,
+                                      write_and_read_at)
+    return (_head(params, x, cfg)[:, 0], cache,
+            None if exits is None else exits[:, 0])
 
-    (x, cache_k, cache_v), _ = lax.scan(
-        step, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(cfg.n_layers)))
-    return _head(params, x, cfg)[:, 0], {"k": cache_k, "v": cache_v}
+
+def decode_step(params, token, pos, cache, cfg: TransformerConfig):
+    """``decode_step_and_exits`` without the exits: (logits, cache)."""
+    return decode_step_and_exits(params, token, pos, cache, cfg)[:2]
 
 
 def _sample(logits, key, temperature: float, top_k: Optional[int]):
@@ -168,11 +260,23 @@ def _sample(logits, key, temperature: float, top_k: Optional[int]):
     return jax.random.categorical(key, logits).astype(jnp.int32)
 
 
-def generate(params, prompt, cfg: TransformerConfig, *,
-             max_new_tokens: int, temperature: float = 0.0,
-             top_k: Optional[int] = None, seed: int = 0,
-             mesh=None) -> jnp.ndarray:
-    """prompt [B, S] int32 -> generated tokens [B, max_new_tokens].
+def _expected_exit_step(exits):
+    """exits [B, loop_steps] -> sum over the rows of ``sum_t (t + 1)
+    p_t``: the loop steps these tokens would have run at a threshold that
+    follows the gate."""
+    return jnp.sum(exits * jnp.arange(1, exits.shape[-1] + 1,
+                                      dtype=exits.dtype))
+
+
+def generate_with_stats(params, prompt, cfg: TransformerConfig, *,
+                        max_new_tokens: int, temperature: float = 0.0,
+                        top_k: Optional[int] = None, seed: int = 0,
+                        mesh=None) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+    """prompt [B, S] int32 -> (generated tokens [B, max_new_tokens],
+    stats). ``stats`` is ``{}`` but for a looped stack: there
+    ``exit_steps_sum``, the sum over the generated tokens of the exit
+    gate's expected loop step ``sum_t (t + 1) p_t``, and ``exit_tokens``,
+    their count: a few floats, accumulated in the decode loop's carry.
 
     The whole decode loop is ONE lax.scan inside the caller's jit scope
     (wrap with jax.jit(partial(generate, ...)) or call under jit): no
@@ -181,21 +285,54 @@ def generate(params, prompt, cfg: TransformerConfig, *,
     _refuse_recurrent(cfg)
     b, s = prompt.shape
     max_len = s + max_new_tokens
+    looped = cfg.loop_steps > 1
     with jax.named_scope("rt.generate.prefill"):
-        logits, cache = prefill(params, prompt, cfg, max_len, mesh=mesh)
+        logits, cache, exits = prefill_and_exits(params, prompt, cfg,
+                                                 max_len, mesh=mesh)
     key = jax.random.PRNGKey(seed)
     key, sub = jax.random.split(key)
     first = _sample(logits, sub, temperature, top_k)
 
     def step(carry, _):
-        token, pos, cache, key = carry
-        logits, cache = decode_step(params, token, pos, cache, cfg)
+        token, pos, cache, key, exits, steps_sum = carry
+        if looped:      # ``exits`` came with the logits ``token`` is from
+            steps_sum = steps_sum + _expected_exit_step(exits)
+        logits, cache, exits = decode_step_and_exits(params, token, pos,
+                                                     cache, cfg)
         key, sub = jax.random.split(key)
         nxt = _sample(logits, sub, temperature, top_k)
-        return (nxt, pos + 1, cache, key), token
+        return (nxt, pos + 1, cache, key, exits, steps_sum), token
 
     with jax.named_scope("rt.generate.decode"):
-        (_, _, _, _), tokens = lax.scan(
-            step, (first, jnp.asarray(s, jnp.int32), cache, key),
+        (*_, steps_sum), tokens = lax.scan(
+            step, (first, jnp.asarray(s, jnp.int32), cache, key, exits,
+                   jnp.zeros((), jnp.float32) if looped else None),
             None, length=max_new_tokens)
-    return jnp.transpose(tokens, (1, 0))   # [B, max_new_tokens]
+    stats = {"exit_steps_sum": steps_sum,
+             "exit_tokens": jnp.asarray(b * max_new_tokens, jnp.float32)} \
+        if looped else {}
+    return jnp.transpose(tokens, (1, 0)), stats   # [B, max_new_tokens]
+
+
+def generate(params, prompt, cfg: TransformerConfig, *, max_new_tokens: int,
+             temperature: float = 0.0, top_k: Optional[int] = None,
+             seed: int = 0, mesh=None) -> jnp.ndarray:
+    """``generate_with_stats`` without the stats: the tokens."""
+    return generate_with_stats(
+        params, prompt, cfg, max_new_tokens=max_new_tokens,
+        temperature=temperature, top_k=top_k, seed=seed, mesh=mesh)[0]
+
+
+def call_span(cfg: TransformerConfig, rows: int, prompt: int,
+              new: int) -> events.span:
+    """The flight-recorder span the caller of a compiled ``generate`` opens
+    around one call, from its dispatch to its tokens on the host.
+    ``sp.set(exit_steps_mean=...)`` puts a looped stack's exit counter
+    (``exit_steps_sum / exit_tokens`` of ``generate_with_stats``) on it
+    once it is fetched with the tokens."""
+    slots = cache_slots(cfg)
+    return events.span(
+        "generate.call", rows=rows, prompt=prompt, new=new,
+        loop_steps=cfg.loop_steps, cache_slots=slots,
+        cache_bytes=2 * slots * rows * (prompt + new) * cfg.kv_heads
+        * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize)

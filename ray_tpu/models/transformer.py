@@ -1,7 +1,9 @@
 """Flagship decoder-only Transformer LM, pure-functional: the llama block
 (RMSNorm / SwiGLU / RoPE / GQA) and, by configuration, the hybrid block of
 Qwen3-Next (a pattern of gated-delta-rule and gated full-attention layers
-over an expert layer without a capacity per expert, with a shared expert).
+over an expert layer without a capacity per expert, with a shared expert)
+or a looped stack (the layers run ``loop_steps`` times over one set of
+weights, a norm on each sublayer's output, an exit gate after each pass).
 
 Design notes (TPU-first):
 - Params are a pytree of jnp arrays; layers are *stacked* on a leading dim
@@ -77,6 +79,13 @@ class TransformerConfig:
     linear_key_dim: int = 128
     linear_value_dim: int = 128
     linear_conv_kernel: int = 4
+    # Looped stack: the n_layers run ``loop_steps`` times over the one set
+    # of weights, the final norm and the exit gate (Linear(d_model -> 1),
+    # sigmoid) after every pass. A position leaves the loop at the first
+    # pass whose cumulative exit probability reaches the threshold.
+    loop_steps: int = 1
+    early_exit_threshold: float = 1.0
+    sandwich_norm: bool = False           # a norm on each sublayer's output
 
     def __post_init__(self):
         kinds = set(self.layer_types)
@@ -93,6 +102,30 @@ class TransformerConfig:
         if self.layer_types and self.pp_stages > 1:
             raise ValueError("a layer pattern with pp_stages > 1 is not "
                              "supported")
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps {self.loop_steps}: at least 1")
+        if self.loop_steps > 1 and self.pp_stages > 1:
+            raise ValueError(
+                "loop_steps > 1 with pp_stages > 1 is not supported: the "
+                "last stage's output would have to go back to the first, "
+                "and the pipeline's schedule has no such edge")
+        if self.loop_steps > 1 and self.layer_types:
+            raise ValueError(
+                "loop_steps > 1 with a layer pattern (layer_types) is not "
+                "supported: a looped stack is one stack of like layers")
+        if self.loop_steps > 1 and self.num_experts:
+            raise ValueError(
+                "loop_steps > 1 with an expert layer is not supported: the "
+                "expert layers' counters of several passes have no place "
+                "in the step's metrics")
+        if self.early_exit_threshold < 1:
+            raise NotImplementedError(
+                f"early_exit_threshold {self.early_exit_threshold} under "
+                "1: rows of one batch would leave the loop at different "
+                "steps, so a step would no longer cost every row the same "
+                "(the batcher and generate assume it does) and the cache "
+                "slots of the skipped steps would stay unwritten; every "
+                "position runs all loop_steps here")
 
     @property
     def kv_heads(self) -> int:
@@ -138,6 +171,9 @@ def _layer_init(key, cfg: TransformerConfig, kind: str = "full"
     pd = cfg.param_dtype
     norm = jnp.zeros if cfg.norm_plus_one else jnp.ones
     layer = {"ln1": norm((d,), pd), "ln2": norm((d,), pd)}
+    if cfg.sandwich_norm:
+        layer["ln1_post"] = norm((d,), pd)
+        layer["ln2_post"] = norm((d,), pd)
     if kind == "full":
         gate = 2 if cfg.attn_output_gate else 1   # per head: query, gate
         layer["attn"] = {
@@ -218,6 +254,11 @@ def transformer_init(key, cfg: TransformerConfig) -> Dict[str, Any]:
     if not cfg.tied_embeddings:
         params["lm_head"] = init(k_head, (cfg.d_model, cfg.vocab_size),
                                  cfg.param_dtype)
+    if cfg.loop_steps > 1:
+        params["exit_gate"] = {
+            "w": init(jax.random.fold_in(k_head, 1), (cfg.d_model,),
+                      cfg.param_dtype),
+            "b": jnp.zeros((), cfg.param_dtype)}
     return params
 
 
@@ -230,6 +271,9 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
 
     def layer_axes(kind: str) -> Dict[str, Any]:
         layer = {"ln1": L("embed"), "ln2": L("embed")}
+        if cfg.sandwich_norm:
+            layer["ln1_post"] = L("embed")
+            layer["ln2_post"] = L("embed")
         if kind == "full":
             layer["attn"] = {
                 "wq": L("embed", "heads", "kv"),
@@ -276,6 +320,8 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     }
     if not cfg.tied_embeddings:
         axes["lm_head"] = ("embed", "vocab")
+    if cfg.loop_steps > 1:
+        axes["exit_gate"] = {"w": ("embed",), "b": ()}
     return axes
 
 
@@ -394,7 +440,9 @@ def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend,
                  mesh=None, rules: LogicalRules = DEFAULT_RULES):
     """One block: ``x + mixer(norm(x))``, then ``+ feed_forward(norm(.))``.
     The mixer is the layer's own: softmax attention where it holds
-    ``attn``, the gated delta rule where it holds ``gdn``. ``attend(q, k,
+    ``attn``, the gated delta rule where it holds ``gdn``; where the layer
+    holds ``ln1_post`` and ``ln2_post`` each sublayer's output is normed
+    before it joins the residual (sandwich norm). ``attend(q, k,
     v) -> (o, kept)`` is all that differs between training, prefill and
     decode (models/generate.py): what attention does with the rotated k
     and v, and what it keeps of them; ``mesh``/``rules`` are the gated
@@ -409,8 +457,12 @@ def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend,
                                           attend)
     else:
         o, kept = _gated_delta_mix(cfg, layer["gdn"], h, mesh, rules), None
+    if "ln1_post" in layer:
+        o = _norm(cfg, o, layer["ln1_post"])
     x = x + o
     y, stats = _feed_forward(cfg, layer, _norm(cfg, x, layer["ln2"]))
+    if "ln2_post" in layer:
+        y = _norm(cfg, y, layer["ln2_post"])
     return x + y, kept, stats
 
 
@@ -442,9 +494,45 @@ def _stage_apply(cfg: TransformerConfig, mesh, stage_layers, x, positions,
     return _stage_scan(cfg, mesh, stage_layers, x, positions, rules)[0]
 
 
+def exit_distribution(gates):
+    """gates [T, ...]: each pass's exit probability given that the position
+    is still in the loop -> [..., T], the probability of leaving after
+    pass t: ``gate_t * prod_{j<t}(1 - gate_j)``, and for the last pass what
+    is left, so that the T of them sum to 1."""
+    stay = jnp.cumprod(1.0 - gates[:-1], axis=0)
+    stay = jnp.concatenate([jnp.ones_like(gates[:1]), stay])
+    p = jnp.concatenate([gates[:-1] * stay[:-1], stay[-1:]])
+    return jnp.moveaxis(p, 0, -1)
+
+
+def _over_loop_steps(cfg: TransformerConfig, params, stack, x, carry=None):
+    """``stack(x, carry, t) -> (x, carry)`` is one pass over the layers;
+    ``t`` counts the passes. Runs it ``cfg.loop_steps`` times over the one
+    set of weights. A looped stack's state is normed by the final norm at
+    the end of EVERY pass (the normed state goes on to the next, and
+    ``_head`` does not norm it again) and read by the exit gate.
+    -> (x, carry, exit distribution [..., loop_steps] float32, or None
+    where there is no loop)."""
+    if cfg.loop_steps == 1:
+        return *stack(x, carry, 0), None
+
+    def step(state, t):
+        x, carry = stack(*state, t)
+        x = _norm(cfg, x, params["final_norm"])
+        gate = params["exit_gate"]
+        z = x.astype(jnp.float32) @ gate["w"].astype(jnp.float32)
+        return (x, carry), jax.nn.sigmoid(z + gate["b"].astype(jnp.float32))
+
+    (x, carry), gates = lax.scan(step, (x, carry),
+                                 jnp.arange(cfg.loop_steps))
+    return x, carry, exit_distribution(gates)
+
+
 def _head(params, x, cfg: TransformerConfig):
-    """Final norm + (tied or untied) head: x [..., E] -> float32 logits."""
-    x = _norm(cfg, x, params["final_norm"])
+    """Final norm + (tied or untied) head: x [..., E] -> float32 logits.
+    A looped stack hands over its state normed (``_over_loop_steps``)."""
+    if cfg.loop_steps == 1:
+        x = _norm(cfg, x, params["final_norm"])
     head = (params["embed"].T if cfg.tied_embeddings else params["lm_head"])
     return (x @ head.astype(cfg.dtype)).astype(jnp.float32)
 
@@ -463,6 +551,8 @@ def _next_token_loss(logits, tokens, mask=None):
 
 def _logits_and_stats(params, tokens, cfg: TransformerConfig, mesh,
                       positions, rules: LogicalRules):
+    """-> (logits, expert-layer stats or None, exit distribution or
+    None)."""
     b, s = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
@@ -484,19 +574,42 @@ def _logits_and_stats(params, tokens, cfg: TransformerConfig, mesh,
 
         x = pipeline_apply(stage_fn, params["layers"], xs, mesh,
                            num_microbatches=m)
-        x, stats = x.reshape(b, s, cfg.d_model), None
+        x, stats, exits = x.reshape(b, s, cfg.d_model), None, None
     else:
-        x, stats = _stage_scan(cfg, mesh, params["layers"], x, positions,
-                               rules)
-    return _head(params, x, cfg), stats
+        x, stats, exits = _over_loop_steps(
+            cfg, params, lambda x, _, t: _stage_scan(
+                cfg, mesh, params["layers"], x, positions, rules), x)
+    return _head(params, x, cfg), stats, exits
+
+
+def _refuse_looped_loss(cfg: TransformerConfig) -> None:
+    if cfg.loop_steps > 1:
+        raise NotImplementedError(
+            "no loss over a looped stack: the published objective weights "
+            "each loop step's next-token loss by the exit gate's "
+            "distribution and adds an entropy term whose weight no key of "
+            "the configuration gives, and each step's loss needs the head "
+            "applied to that step's state; the last step's loss alone "
+            "would train no gate")
 
 
 def transformer_apply(params, tokens, cfg: TransformerConfig, *,
                       mesh=None, positions=None,
                       rules: LogicalRules = DEFAULT_RULES):
     """tokens: [B, S] int32 -> logits [B, S, vocab] (compute in cfg.dtype,
-    logits float32)."""
+    logits float32); of a looped stack, the last pass's."""
     return _logits_and_stats(params, tokens, cfg, mesh, positions, rules)[0]
+
+
+def transformer_apply_and_exits(params, tokens, cfg: TransformerConfig, *,
+                                mesh=None, positions=None,
+                                rules: LogicalRules = DEFAULT_RULES):
+    """-> (logits as ``transformer_apply``, the exit distribution [B, S,
+    loop_steps] float32: for each position the probability of leaving the
+    loop after each pass, summing to 1; None where there is no loop)."""
+    logits, _, exits = _logits_and_stats(params, tokens, cfg, mesh,
+                                         positions, rules)
+    return logits, exits
 
 
 def transformer_loss_and_stats(params, batch, cfg: TransformerConfig, *,
@@ -507,8 +620,10 @@ def transformer_loss_and_stats(params, batch, cfg: TransformerConfig, *,
     ``{}`` for a dense model): ``moe_rows_here`` and ``moe_rows_dropped``
     summed over the layers, ``moe_load_max`` and ``moe_load_mean`` the
     fullest held expert's rows and the mean, over layers and experts."""
+    _refuse_looped_loss(cfg)
     tokens = batch["tokens"]
-    logits, stats = _logits_and_stats(params, tokens, cfg, mesh, None, rules)
+    logits, stats, _ = _logits_and_stats(params, tokens, cfg, mesh, None,
+                                         rules)
     loss = _next_token_loss(logits, tokens, batch.get("mask"))
     if stats is None:
         return loss, {}

@@ -21,7 +21,9 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.resnet import resnet50, resnet_loss
-from ray_tpu.models.transformer import (TransformerConfig, transformer_init,
+from ray_tpu.models.transformer import (TransformerConfig,
+                                        _refuse_looped_loss,
+                                        transformer_init,
                                         transformer_logical_axes,
                                         transformer_loss_and_stats)
 from ray_tpu.parallel.sharding import (DEFAULT_RULES, LogicalRules,
@@ -70,6 +72,7 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
     """Returns (init_fn(key) -> TrainState on-mesh,
                step_fn(state, batch) -> (state, metrics) jitted,
                place_batch)."""
+    _refuse_looped_loss(cfg)      # here, not at the first step's trace
     if tx is None:
         tx = optax.adamw(learning_rate, weight_decay=0.01)
     axes = transformer_logical_axes(cfg)

@@ -152,6 +152,11 @@ EVENT_KINDS: Dict[str, str] = {
                   "carry the step's counters (the expert layers' "
                   "moe_rows_here, moe_rows_dropped, moe_load_max, "
                   "moe_load_mean)",
+    "generate.call": "span: value = seconds of one compiled generate call, "
+                     "dispatch to tokens on the host, opened by its caller "
+                     "(models.generate.call_span); attrs carry rows/prompt/"
+                     "new/loop_steps/cache_slots/cache_bytes and, of a "
+                     "looped stack, exit_steps_mean",
     "train.pump": "span: value = seconds of one synchronized report "
                   "round; attrs carry iteration/lag_s",
     # start-up
