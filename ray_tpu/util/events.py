@@ -155,8 +155,12 @@ EVENT_KINDS: Dict[str, str] = {
     "generate.call": "span: value = seconds of one compiled generate call, "
                      "dispatch to tokens on the host, opened by its caller "
                      "(models.generate.call_span); attrs carry rows/prompt/"
-                     "new/loop_steps/cache_slots/cache_bytes and, of a "
-                     "looped stack, exit_steps_mean",
+                     "new/loop_steps/cache_slots/cache_bytes, the token "
+                     "loop's decode_segments with the cache positions a "
+                     "slot's attention reads over the call's steps and "
+                     "those written by then (cache_positions_read/"
+                     "cache_positions_needed) and, of a looped stack, "
+                     "exit_steps_mean",
     "train.pump": "span: value = seconds of one synchronized report "
                   "round; attrs carry iteration/lag_s",
     # start-up

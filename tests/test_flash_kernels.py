@@ -198,7 +198,11 @@ def test_a_looped_generate_keeps_one_cache_layout_on_v5e(monkeypatch):
     stacks and little more. Written straight from the layer's body
     (``lax.dynamic_update_slice`` in prefill's ``attend``) the compiler
     gives prefill's carry another layout than decode's and copies both
-    stacks whole: 18.85 of a chip's 15.75 GiB at 48 layers."""
+    stacks whole: 18.85 of a chip's 15.75 GiB at 48 layers.
+
+    The token loop's eight segments each read a prefix of a slot (160, 192,
+    ... 384 positions): the slice fuses into the scores' fusion, whose
+    operand is the whole stack, so no slab is written out either."""
     import re
     from functools import partial
 
@@ -233,9 +237,19 @@ def test_a_looped_generate_keeps_one_cache_layout_on_v5e(monkeypatch):
     copied = stack + r"\S* copy\("
     stacks = 2 * 8 * 16 * 384 * 16 * 128 * 2
     sound = compiled()
-    assert re.search(stack, sound.as_text())
-    assert not re.search(copied, sound.as_text())
+    text = sound.as_text()
+    assert re.search(stack, text)
+    assert not re.search(copied, text)
     assert sound.memory_analysis().temp_size_in_bytes < 1.5 * stacks
+    shape_of = dict(re.findall(r"^\s*%(\S+) = (\w+\[[\d,]*\])", text, re.M))
+    for extent in range(160, 385, 32):
+        scores = [ln for ln in text.splitlines()
+                  if re.search(rf"= bf16\[16,{extent},16\]\S* fusion\(", ln)
+                  and "rt.loop.cache/bokgd,btkd->bkgt" in ln]
+        assert len(scores) == 1, extent
+        operands = re.findall(r"%([\w.\-]+)",
+                              scores[0].split(" fusion(")[1].split(")")[0])
+        assert "bf16[8,16,384,16,128]" in [shape_of[o] for o in operands]
     # the guard bites: the straight write brings the copies back
     import sys
     from jax import lax

@@ -107,24 +107,150 @@ def _scanned_over(jaxpr, shape, inside_scan=False):
     return found
 
 
-def test_decode_loop_carries_the_cache():
+@pytest.mark.parametrize("new_tokens", [5, 70])     # one segment, two
+def test_decode_loop_carries_the_cache(new_tokens):
     """Platform-independent statement of "in place": inside the token loop
     no layer scan takes or re-emits the stacked cache (a scanned input and
     a scanned output cannot alias, so XLA would copy the stack a token).
     The prefill's own stacked outputs, at the top level, are allowed."""
     from functools import partial
 
-    cfg = _cfg()
+    cfg = _cfg(max_seq=128)
     params = transformer_init(jax.random.PRNGKey(0), cfg)
     prompt = jnp.zeros((2, 7), jnp.int32)
-    jaxpr = jax.make_jaxpr(partial(generate, cfg=cfg, max_new_tokens=5))(
-        params, prompt)
-    cache_shape = (cfg.n_layers, 2, 12, cfg.kv_heads, cfg.head_dim)
+    jaxpr = jax.make_jaxpr(partial(generate, cfg=cfg,
+                                   max_new_tokens=new_tokens))(params, prompt)
+    cache_shape = (cfg.n_layers, 2, 7 + new_tokens, cfg.kv_heads,
+                   cfg.head_dim)
     # the walker sees the cache at all: prefill stacks it at the top level
     top = [v.aval.shape for eqn in jaxpr.jaxpr.eqns
            if eqn.primitive.name == "scan" for v in eqn.outvars]
     assert top.count(cache_shape) >= 2
     assert _scanned_over(jaxpr.jaxpr, cache_shape) == []
+
+
+# --- the token loop's segments: a step reads the positions written so far ---
+
+def _chain_of_decode_steps(params, prompt, cfg, n):
+    """Greedy tokens by ``prefill`` and ``n`` ``decode_step``s, each over
+    the whole cache."""
+    from functools import partial
+
+    from ray_tpu.models.generate import decode_step
+
+    s = prompt.shape[1]
+    logits, cache = prefill(params, prompt, cfg, max_len=s + n)
+    step = jax.jit(partial(decode_step, cfg=cfg))
+    out = []
+    for i in range(n):
+        out.append(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        logits, cache = step(params, out[-1], jnp.asarray(s + i, jnp.int32),
+                             cache)
+    return jnp.stack(out, axis=1)
+
+
+@pytest.mark.parametrize("overrides, prompt_len, new_tokens", [
+    pytest.param(dict(n_kv_heads=4), 8, 64, id="dense"),
+    pytest.param(dict(n_kv_heads=1), 8, 64, id="gqa"),
+    pytest.param(dict(), 7, 70, id="prompt-no-multiple-of-8"),
+    # 39 + 38 steps: the second segment starts inside a block of 8
+    pytest.param(dict(), 6, 77, id="steps-no-multiple-of-the-segment"),
+])
+def test_segmented_generate_is_a_chain_of_whole_cache_steps(
+        overrides, prompt_len, new_tokens):
+    from ray_tpu.models.generate import _decode_segments
+
+    assert len(_decode_segments(prompt_len, new_tokens)) == 2
+    cfg = _cfg(max_seq=128, **overrides)
+    params = transformer_init(jax.random.PRNGKey(9), cfg)
+    prompt = jax.random.randint(jax.random.PRNGKey(10), (2, prompt_len), 0,
+                                97)
+    want = _chain_of_decode_steps(params, prompt, cfg, new_tokens)
+    got = generate(params, prompt, cfg, max_new_tokens=new_tokens)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert len(np.unique(np.asarray(got))) > 4      # not one token over
+
+
+def test_the_segments_cover_what_their_steps_read():
+    from ray_tpu.models.generate import (_MAX_SEGMENTS, _MIN_NEW_PART,
+                                         _MIN_SEGMENT_STEPS, _WRITE_ROWS,
+                                         _decode_segments)
+
+    for prompt in (1, 5, 8, 127, 128, 512):
+        for new in (1, 8, 31, 32, 33, 63, 64, 70, 77, 128, 255, 256, 257,
+                    1000, 4096):
+            t_max = prompt + new
+            segments = _decode_segments(prompt, new)
+            assert sum(steps for steps, _ in segments) == new
+            assert len(segments) <= _MAX_SEGMENTS
+            if new < 2 * _MIN_SEGMENT_STEPS or new * _MIN_NEW_PART < t_max:
+                assert segments == [(new, t_max)]   # today's one loop
+            else:
+                lengths = [steps for steps, _ in segments]
+                assert min(lengths) >= _MIN_SEGMENT_STEPS
+                assert max(lengths) - min(lengths) <= 1
+            pos = prompt                # the position the next step writes
+            for steps, extent in segments:
+                pos += steps
+                assert pos <= extent <= t_max       # covers pos + 1 of each
+                assert extent % _WRITE_ROWS == 0 or extent == t_max
+                assert extent - pos < _WRITE_ROWS   # and no block more
+            assert segments[-1][1] == t_max
+    # the two serving cells
+    assert _decode_segments(128, 256) == [
+        (32, extent) for extent in range(160, 385, 32)]
+    # a fifth of the cache is new: the tail is a tenth of one loop's reads
+    assert _decode_segments(512, 128) == [(128, 640)]
+    assert _decode_segments(384, 128) == [
+        (32, extent) for extent in (416, 448, 480, 512)]
+
+
+def _attention_operands(jaxpr):
+    """The operand shapes of every dot_general under ``rt.loop.cache``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" \
+                and "rt.loop.cache" in str(eqn.source_info.name_stack):
+            found += [v.aval.shape for v in eqn.invars]
+        for sub in _sub_jaxprs(eqn):
+            found += _attention_operands(sub)
+    return found
+
+
+def test_a_segments_attention_reads_its_extent_and_no_more():
+    """In the jaxpr of ``generate`` the token loop is one scan a segment,
+    and inside segment j both attention einsums take ``extent_j`` positions
+    of keys and values: no operand is longer."""
+    from functools import partial
+
+    from ray_tpu.models.generate import _decode_segments, call_span
+
+    cfg = _cfg(max_seq=256)
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    prompt, new = 7, 130                    # 4 segments: 33, 33, 32, 32
+    segments = _decode_segments(prompt, new)
+    assert [extent for _, extent in segments] == [40, 80, 112, 137]
+    jaxpr = jax.make_jaxpr(partial(generate, cfg=cfg, max_new_tokens=new))(
+        params, jnp.zeros((2, prompt), jnp.int32))
+    loops = [eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "scan"
+             and "rt.generate.decode" in str(eqn.source_info.name_stack)]
+    assert [eqn.params["length"] for eqn in loops] == \
+        [steps for steps, _ in segments]
+    for eqn, (_, extent) in zip(loops, segments):
+        shapes = [s for sub in _sub_jaxprs(eqn)
+                  for s in _attention_operands(sub)]
+        # the keys; the weights and the values
+        assert sorted(s for s in shapes if extent in s) == [
+            (2, cfg.kv_heads, 2, extent), (2, extent, cfg.kv_heads, 16),
+            (2, extent, cfg.kv_heads, 16)]
+        assert max(max(s) for s in shapes) == extent
+    # and the call's span says so, from the same helper
+    sp = call_span(cfg, 2, prompt, new)
+    assert sp.attrs["decode_segments"] == 4
+    assert sp.attrs["cache_positions_read"] == \
+        33 * 40 + 33 * 80 + 32 * 112 + 32 * 137
+    assert sp.attrs["cache_positions_needed"] == \
+        sum(prompt + i + 1 for i in range(new))
 
 
 def test_decode_step_is_functional():

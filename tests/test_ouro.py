@@ -134,6 +134,41 @@ def test_greedy_tokens_pinned_to_reforward(new_tokens):
         generate(params, prompt, cfg, max_new_tokens=new_tokens), got)
 
 
+@pytest.mark.parametrize("prompt_len, new_tokens", [
+    pytest.param(8, 64, id="2x32"),
+    pytest.param(7, 70, id="prompt-no-multiple-of-8"),
+    pytest.param(6, 77, id="steps-no-multiple-of-the-segment"),
+])
+def test_segmented_generate_is_a_chain_of_whole_cache_steps(prompt_len,
+                                                            new_tokens):
+    """The token loop in two segments, the first reading a prefix of every
+    (loop step, layer)'s slot: token for token, and in the exit counter,
+    what ``decode_step_and_exits`` over the whole cache gives."""
+    from functools import partial
+
+    from ray_tpu.models.generate import _decode_segments
+
+    assert len(_decode_segments(prompt_len, new_tokens)) == 2
+    cfg = _cfg(max_seq=128)
+    params = _params(cfg, seed=7)
+    prompt = _tokens(n=prompt_len, seed=8)
+    logits, cache, exits = prefill_and_exits(
+        params, prompt, cfg, max_len=prompt_len + new_tokens)
+    step = jax.jit(partial(decode_step_and_exits, cfg=cfg))
+    want, steps_sum = [], 0.0
+    for i in range(new_tokens):
+        want.append(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        steps_sum += float((exits * jnp.arange(1, T + 1)).sum())
+        logits, cache, exits = step(
+            params, want[-1], jnp.asarray(prompt_len + i, jnp.int32), cache)
+    got, stats = generate_with_stats(params, prompt, cfg,
+                                     max_new_tokens=new_tokens)
+    np.testing.assert_array_equal(got, jnp.stack(want, axis=1))
+    assert float(stats["exit_tokens"]) == 2 * new_tokens
+    assert float(stats["exit_steps_sum"]) == pytest.approx(steps_sum,
+                                                           rel=1e-5)
+
+
 def test_an_unlooped_model_has_no_stats():
     cfg = _cfg(loop_steps=1, sandwich_norm=False)
     params = transformer_init(jax.random.PRNGKey(0), cfg)
