@@ -1,0 +1,9 @@
+import _calls
+
+NEEDS = ("serve.request", "serve.handle.call", "serve.replica.call",
+         "call.return")
+
+
+def read(record, cell):
+    return _calls.median_ms(record, cell, NEEDS,
+                            lambda r: r["call.return"]["value"])

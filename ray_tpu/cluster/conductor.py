@@ -25,6 +25,7 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 from ray_tpu import config
 from ray_tpu.cluster import fault_plane
 from ray_tpu.cluster.protocol import RpcServer, get_client
+from ray_tpu.util import events as _events
 from ray_tpu.util import lockcheck
 
 # Actor FSM states (parity: gcs_actor_manager.h:249 state diagram).
@@ -115,7 +116,6 @@ class Conductor:
         self._spill_del_q: deque = deque()         # spill URLs to delete
         self._free_cv = threading.Condition()
         self._pgs: Dict[bytes, PlacementGroupInfo] = {}
-        self._task_events: List[dict] = []
         # Flight-recorder event store (util/events.py sink; parity role:
         # GcsTaskManager's bounded task-event store). Own lock: batches
         # arrive from every process's flusher/heartbeat and must not
@@ -1486,16 +1486,14 @@ class Conductor:
     # ------------------------------------------------------------------
     # Task events / jobs (parity: gcs_task_manager.h:61, GcsJobManager)
     # ------------------------------------------------------------------
-    def rpc_push_task_events(self, events: List[dict]) -> None:
-        cap = int(config.get("task_event_buffer_size"))
-        with self._lock:
-            self._task_events.extend(events)
-            if len(self._task_events) > cap:
-                del self._task_events[:len(self._task_events) - cap]
-
     def rpc_get_task_events(self) -> List[dict]:
-        with self._lock:
-            return list(self._task_events)
+        """One dict an executed task, actor task or actor creation, oldest
+        first, made from the flight recorder's ``task.exec`` records (a
+        worker stores one as an execution ends; they ship with its ring):
+        what ``state.list_tasks``, the dashboard's task view and
+        ``rt.timeline()`` read. Bounded as the ring store is."""
+        return [_events.task_view(e)
+                for e in self.rpc_get_ring_events(kind="task.exec")]
 
     # Flight-recorder event store (util/events.py sink; GcsTaskManager's
     # bounded-store role for the compact ring events every plane emits).
@@ -1555,7 +1553,6 @@ class Conductor:
                 "refcount_entries": len(self._refcounts),
                 "ref_tombstones": len(self._ref_tombstones),
                 "placement_groups": len(self._pgs),
-                "task_events": len(self._task_events),
             }
         with self._free_cv:
             out["free_queue"] = len(self._free_q)

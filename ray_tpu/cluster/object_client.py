@@ -38,6 +38,8 @@ import weakref
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+from ray_tpu.util import events as _events
+
 OP_CREATE, OP_SEAL, OP_GET, OP_RELEASE, OP_DELETE, OP_CONTAINS, OP_STATS, \
     OP_LIST, OP_GET_COPY, OP_PUT_INLINE, OP_GET_COPY_BATCH, \
     OP_CONTAINS_BATCH, OP_SPILL_CANDIDATES, OP_EVICT = range(1, 15)
@@ -303,7 +305,15 @@ class ShmClient:
 
     # --- framing ---------------------------------------------------------
     def _call(self, payload: bytes) -> bytes:
+        # Under a span that counts it (an actor call's ``call.get`` or
+        # ``call.return``): the seconds this thread stood in line for the
+        # process's one store connection.
+        counts = _events.counters("lock_wait_s")
+        if counts is not None:
+            t0 = time.perf_counter()
         with self._lock:
+            if counts is not None:
+                counts["lock_wait_s"] += time.perf_counter() - t0
             while self._deferred_releases:
                 oid = self._deferred_releases.popleft()
                 self._sock.sendall(struct.pack(     # rtcheck: allow-blocking(wire lock: serializes framing on the local store socket)

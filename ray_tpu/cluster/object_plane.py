@@ -593,8 +593,14 @@ class ObjectPlane:
                         oid.hex(), "all advertised holders unreachable")
                 raise GetTimeoutError(
                     f"timed out waiting for object {oid.hex()}")
+            counts = _events.counters("parked_s")   # a call.get above us
+            t0 = time.perf_counter() if counts is not None else 0.0
             loc = self.conductor.call("locate_object", oid=key,
                                       timeout=min(remaining, 2.0))
+            if counts is not None:
+                counts["parked_s"] += time.perf_counter() - t0
+                counts["woken_ts"] = time.time()
+                counts["lock_wait_s"] = 0.0
             view = self._get_pinned_tolerant(key)
             if view is not None:
                 return view

@@ -31,7 +31,7 @@ from ray_tpu.cluster.protocol import RpcServer, get_client
 from ray_tpu.core import serialization, task_spec
 from ray_tpu.core import refs as _refs_mod
 from ray_tpu.core.exceptions import (GetTimeoutError, ObjectLostError,
-                                     TaskError)
+                                     TaskCancelledError, TaskError)
 from ray_tpu.core.ids import ObjectID, TaskID, WorkerID, store_key
 from ray_tpu.util import events as _events
 
@@ -97,48 +97,66 @@ class _LazySealer:
                         self.plane.put_blob(oid, blob)
                     except Exception:
                         pass
-            if batch:
-                _events.emit("inline.seal", value=float(len(batch)))
-
-
-class TaskEventLog:
-    """Buffered task-event shipping (parity: task_event_buffer.h:188)."""
-
-    def __init__(self, conductor_address: str, node_id: bytes, pid: int):
-        self._events = []
-        self._lock = threading.Lock()
-        self._cli = get_client(conductor_address)
-        self._node_hex = node_id.hex()
-        self._pid = pid
-        self._flusher = threading.Thread(target=self._loop, daemon=True,
-                                         name="task-event-flusher")
-        self._flusher.start()
-
-    def record(self, task_id: bytes, name: str, kind: str,
-               start: float, end: float, error: str = "") -> None:
-        with self._lock:
-            self._events.append({
-                "task_id": task_id.hex(), "name": name, "kind": kind,
-                "start": start, "end": end, "node_id": self._node_hex,
-                "pid": self._pid, "error": error,
-            })
-
-    def _loop(self) -> None:
-        while True:
-            time.sleep(1.0)
-            self.flush()
-
-    def flush(self) -> None:
-        with self._lock:
-            events, self._events = self._events, []
-        if events:
-            try:
-                self._cli.call("push_task_events", events=events)
-            except Exception:
-                pass
 
 
 _NO_SPAN = contextlib.nullcontext()
+
+
+def _task_done(task_id_hex: str, name: str, kind: str, start: float,
+               error: str = "") -> None:
+    """One ``task.exec`` record as an execution ends (``start`` by
+    ``time.time()``): what the operator's task views are made of (the
+    conductor's ``get_task_events``), and the executed-tasks metrics."""
+    attrs = {"task": name, "kind": kind}
+    if error:
+        attrs["error"] = error
+    _events.emit("task.exec", task_id_hex, value=time.time() - start,
+                 attrs=attrs)
+
+
+class _Turn:
+    """``call.turn`` of an actor call whose spec carries a ``trace_ctx``:
+    from ``rpc_push_actor_task``'s first line to the user's method's first
+    line, over the threads it crosses; recorded once, as the method is
+    about to be called."""
+
+    __slots__ = ("ctx", "ts", "t0", "turn_wait_s", "handed", "pool_wait_s",
+                 "resolve_s")
+
+    def __init__(self, ctx: dict):
+        self.ctx, self.ts, self.t0 = ctx, time.time(), time.perf_counter()
+        self.turn_wait_s = self.pool_wait_s = self.resolve_s = 0.0
+        self.handed = None
+
+    def taken(self) -> None:
+        """``_wait_turn`` returned: the seqno's turn is this call's."""
+        self.turn_wait_s = time.perf_counter() - self.t0
+
+    def queued(self) -> None:
+        """About to be handed to a pool thread or the actor's loop."""
+        self.handed = time.perf_counter()
+
+    def started(self) -> None:
+        """First line on the thread or loop that runs the call."""
+        if self.handed is not None:
+            self.pool_wait_s = time.perf_counter() - self.handed
+
+    def record(self) -> None:
+        _events.span_record(
+            "call.turn", self.ts, time.perf_counter() - self.t0,
+            ident=self.ctx.get("ident"), parent=self.ctx.get("span"),
+            turn_wait_s=self.turn_wait_s, pool_wait_s=self.pool_wait_s,
+            resolve_s=self.resolve_s)
+
+
+def _returning(ctx: Optional[dict]):
+    """``call.return`` around the storing of a traced actor call's returns:
+    the store's put adds its wait for the connection (``lock_wait_s``,
+    object_client), ``_emit_return`` the rest."""
+    if ctx is None:
+        return _NO_SPAN
+    return _events.span("call.return", ctx=ctx, bytes=0, inline=0,
+                        seal_wait_s=0.0, lock_wait_s=0.0)
 
 
 def _execution(ctx: Optional[dict], name: str, task_id: bytes):
@@ -179,7 +197,6 @@ class WorkerService:
         self._ilim_v = -1
         self._ftmo_gen = None       # arg-fetch timeout, config-cached
         self._ftmo_v = 30.0
-        self.events = TaskEventLog(conductor_address, node_id, os.getpid())
         self._fn_cache: Dict[str, Any] = {}
         self._exec_lock = threading.Lock()   # serial normal-task execution
         self._cancelled: set = set()
@@ -293,22 +310,28 @@ class WorkerService:
             self._ilim_gen = config.generation
         return self._ilim_v
 
-    def _emit_return(self, oid: ObjectID, value: Any, collect) -> None:
+    def _emit_return(self, oid: ObjectID, value: Any, collect,
+                     counts: Optional[dict] = None) -> None:
         """Store one return value. With ``collect`` (reply-carried mode),
         results at or below max_inline_object_bytes ride the push reply as
         {"data": blob} entries and seal into the store lazily; larger ones
         seal now and reply {"stored": True}. collect=None keeps the
         classic store-now behavior (async/pool actor paths, whose acks
-        predate execution)."""
-        if collect is None:
-            self.plane.put_value(oid, value)
-            return
-        limit = self._inline_limit()
+        predate execution). ``counts``: the counters of the ``call.return``
+        span open around a traced actor call's returns."""
         total, segments, refs = serialization.serialize_segments(value)
-        if total > limit:
+        if counts is not None:
+            counts["bytes"] += total
+        if collect is None or total > self._inline_limit():
+            t0 = time.perf_counter()
             self.plane.put_segments(oid, total, segments, refs)
-            collect.append({"stored": True})
+            if counts is not None:
+                counts["seal_wait_s"] += time.perf_counter() - t0
+            if collect is not None:
+                collect.append({"stored": True})
             return
+        if counts is not None:
+            counts["inline"] = 1
         blob = segments[0] if len(segments) == 1 else b"".join(segments)
         if refs:
             t = _refs_mod._tracker
@@ -327,10 +350,11 @@ class WorkerService:
         collect.append({"data": blob, "_oid": oid})
 
     def _store_returns(self, task_id: bytes, num_returns: int, result: Any,
-                       collect=None):
+                       collect=None, counts: Optional[dict] = None):
         tid = TaskID(task_id)
         if num_returns == 1:
-            self._emit_return(tid.object_id_for_return(0), result, collect)
+            self._emit_return(tid.object_id_for_return(0), result, collect,
+                              counts)
             return
         vals = list(result)
         if len(vals) != num_returns:
@@ -340,24 +364,28 @@ class WorkerService:
             if collect is not None:
                 collect[:] = []
             for i in range(num_returns):
-                self._emit_return(tid.object_id_for_return(i), err, collect)
+                self._emit_return(tid.object_id_for_return(i), err, collect,
+                                  counts)
             return
         for i, v in enumerate(vals):
-            self._emit_return(tid.object_id_for_return(i), v, collect)
+            self._emit_return(tid.object_id_for_return(i), v, collect,
+                              counts)
 
     def _fail_returns(self, task_id: bytes, num_returns: int, exc, desc: str,
-                      collect=None):
+                      collect=None, counts: Optional[dict] = None):
         err = exc if isinstance(exc, TaskError) else TaskError.from_exception(
             exc, desc)
         tid = TaskID(task_id)
         for i in range(num_returns):
             try:
-                self._emit_return(tid.object_id_for_return(i), err, collect)
+                self._emit_return(tid.object_id_for_return(i), err, collect,
+                                  counts)
             except BaseException:  # noqa: BLE001 - fallback error report; caller must unblock
                 # The error object itself failed to serialize/store: fall
                 # back to a bare TaskError so the caller still unblocks.
                 self._emit_return(tid.object_id_for_return(i),
-                                  TaskError(repr(err), desc), collect)
+                                  TaskError(repr(err), desc), collect,
+                                  counts)
 
     def _queue_seals(self, per_task_entries) -> None:
         """Strip the private _oid markers from reply entries and hand the
@@ -388,7 +416,6 @@ class WorkerService:
         start = time.time()
         if task_id in self._cancelled:
             self._cancelled.discard(task_id)
-            from ray_tpu.core.exceptions import TaskCancelledError
             self._fail_returns(task_id, num_returns,
                                TaskCancelledError("task cancelled"), name,
                                collect)
@@ -415,11 +442,7 @@ class WorkerService:
             except BaseException:  # noqa: BLE001 - injected double fault
                 if collect is not None:
                     collect[:] = []
-        end = time.time()
-        self.events.record(task_id, name, "task", start, end, error)
-        _events.emit("task.exec", task_id.hex(), value=end - start,
-                     attrs={"task": name, "error": error} if error
-                     else {"task": name})
+        _task_done(task_id.hex(), name, "task", start, error)
 
     def rpc_push_task(self, task_id: bytes, function_id: str,
                       function_blob: Optional[bytes], args_blob: bytes,
@@ -493,9 +516,9 @@ class WorkerService:
         get_client(self.conductor_address).call(
             "actor_started", actor_id=actor_id, address=self.address,
             node_id=self.node_id, incarnation=incarnation)
-        self.events.record(actor_id + b"\x00" * 4,
-                           self.actor_class_name + ".__init__",
-                           "actor_creation", start, time.time())
+        _task_done((actor_id + b"\x00" * 4).hex(),
+                   self.actor_class_name + ".__init__", "actor_creation",
+                   start)
         return {"ok": True}
 
     def _wait_turn(self, caller_id: bytes, seqno: int) -> bool:
@@ -527,6 +550,7 @@ class WorkerService:
         ``actor_id`` guards against a stale address: a recycled worker may
         host a DIFFERENT actor at the address a slow caller cached, and a
         push for the dead tenant must fail, not hit the new instance."""
+        turn = _Turn(trace_ctx) if trace_ctx is not None else None
         if actor_id is not None and actor_id != self.actor_id:
             raise RuntimeError("actor no longer hosted on this worker "
                                "(stale address after recycle)")
@@ -538,7 +562,7 @@ class WorkerService:
             return self._push_actor_task(task_id, caller_id, seqno,
                                          method_name, args_blob,
                                          num_returns, arg_pins, inline_args,
-                                         trace_ctx)
+                                         trace_ctx, turn)
         finally:
             with self._seq_lock:
                 self._active_calls -= 1
@@ -548,10 +572,16 @@ class WorkerService:
                          num_returns: int,
                          arg_pins: Optional[list] = None,
                          inline_args: Optional[dict] = None,
-                         trace_ctx: Optional[dict] = None) -> dict:
+                         trace_ctx: Optional[dict] = None,
+                         turn: Optional["_Turn"] = None) -> dict:
+        """A call runs on whichever thread or loop its kind of actor gives
+        it: its turn and its arguments up to the user's method, then
+        ``store`` (its returns or its error into the store or the reply).
+        A call whose spec carries a ``trace_ctx`` records the first as
+        ``call.turn`` (``turn``, begun at the RPC's first line) and the
+        second as ``call.return``."""
         name = f"{self.actor_class_name}.{method_name}"
         start = time.time()
-        error = ""
 
         def unpin_args():
             if not arg_pins:
@@ -566,18 +596,34 @@ class WorkerService:
                     else:
                         self._taken_pins.pop(k, None)
 
-        def run_sync(collect=None):
-            err = ""
-            if task_id in self._cancelled:
-                # Cancelled before execution started (rt.cancel on an
-                # actor-task ref — e.g. a serve deadline): store the
-                # cancellation error, never run user code.
-                self._cancelled.discard(task_id)
-                from ray_tpu.core.exceptions import TaskCancelledError
-                self._fail_returns(task_id, num_returns,
-                                   TaskCancelledError("actor task cancelled"),
-                                   name, collect)
-                return "cancelled"
+        def cancelled():
+            """Cancelled before execution started (rt.cancel on an
+            actor-task ref — e.g. a serve deadline): its returns become
+            the cancellation error, user code never runs."""
+            if task_id not in self._cancelled:
+                return None
+            self._cancelled.discard(task_id)
+            return TaskCancelledError("actor task cancelled")
+
+        def prepare():
+            """-> (the bound method, args, kwargs), the arguments fetched
+            and unpickled."""
+            t0 = time.perf_counter()
+            args, kwargs = self._resolve(args_blob, inline_args)
+            m = getattr(self.actor_instance, method_name)
+            if turn is not None:
+                turn.resolve_s = time.perf_counter() - t0
+            return m, args, kwargs
+
+        def run():
+            """-> (result, None) or (None, what was raised). The caller's
+            span is current around the method, so the callee's spans are
+            its children."""
+            if turn is not None:
+                turn.started()
+            exc = cancelled()
+            if exc is not None:
+                return None, exc
             try:
                 # Fault point: kill/fail mid-actor-task — after the seqno
                 # turn was taken, before the result stores. Exercises the
@@ -586,22 +632,46 @@ class WorkerService:
                 # unwieldy for match filters).
                 fault_plane.fire("worker.actor.exec", name=name,
                                  method=method_name)
-                with _events.adopt(trace_ctx):   # callee's spans: children
-                    args, kwargs = self._resolve(args_blob, inline_args)
-                    m = getattr(self.actor_instance, method_name)
-                    result = m(*args, **kwargs)
-                self._store_returns(task_id, num_returns, result, collect)
+                with _events.adopt(trace_ctx):
+                    m, args, kwargs = prepare()
+                    if turn is not None:
+                        turn.record()
+                    return m(*args, **kwargs), None
             except BaseException as e:  # noqa: BLE001
-                err = repr(e)
+                return None, e
+
+        def store(result, exc, collect, ret) -> str:
+            """The call's returns (or, with ``exc``, its error) into the
+            store or the reply, counted on ``ret`` (the open
+            ``call.return`` span, or None); -> the task event's error."""
+            counts = None if ret is None else ret.attrs
+            if exc is None:
+                try:
+                    self._store_returns(task_id, num_returns, result,
+                                        collect, counts)
+                    return ""
+                except BaseException as e:  # noqa: BLE001
+                    exc = e
+            if collect is not None:
+                collect[:] = []
+            try:
+                self._fail_returns(task_id, num_returns, exc, name,
+                                   collect, counts)
+            except BaseException:  # noqa: BLE001 - injected dbl fault
                 if collect is not None:
                     collect[:] = []
-                try:
-                    self._fail_returns(task_id, num_returns, e, name,
-                                       collect)
-                except BaseException:  # noqa: BLE001 - injected dbl fault
-                    if collect is not None:
-                        collect[:] = []
-            return err
+            return "cancelled" if isinstance(exc, TaskCancelledError) \
+                else repr(exc)
+
+        def store_now(result, exc) -> None:
+            """The enqueue-ack paths' end: the returns into the store,
+            then the task's event and the taken-over pins back."""
+            try:
+                with _returning(trace_ctx) as ret:
+                    error = store(result, exc, None, ret)
+                _task_done(task_id.hex(), name, "actor_task", start, error)
+            finally:
+                unpin_args()
 
         def take_over_pins():
             """Enqueue-ack paths: the caller unpins its in-flight argument
@@ -618,39 +688,34 @@ class WorkerService:
                 for k in arg_pins:
                     self._taken_pins[k] = self._taken_pins.get(k, 0) + 1
 
+        if not self._wait_turn(caller_id, seqno):
+            return {"ok": True, "duplicate": True}
+        if turn is not None:
+            turn.taken()
         if self.actor_is_async:
             # Ordered start, concurrent awaits (parity: async actors).
             async def run_async():
-                err = ""
-                if task_id in self._cancelled:
-                    self._cancelled.discard(task_id)
-                    from ray_tpu.core.exceptions import TaskCancelledError
-                    self._fail_returns(
-                        task_id, num_returns,
-                        TaskCancelledError("actor task cancelled"), name)
-                    unpin_args()
-                    return "cancelled"
+                if turn is not None:
+                    turn.started()
+                result, exc = None, cancelled()
                 try:
-                    loop = asyncio.get_running_loop()
-                    with _events.adopt(trace_ctx):
-                        args, kwargs = await loop.run_in_executor(
-                            None,
-                            lambda: self._resolve(args_blob, inline_args))
-                        m = getattr(self.actor_instance, method_name)
-                        result = m(*args, **kwargs)
-                        if inspect.isawaitable(result):
-                            result = await result
-                    self._store_returns(task_id, num_returns, result)
+                    if exc is None:
+                        loop = asyncio.get_running_loop()
+                        with _events.adopt(trace_ctx):
+                            m, args, kwargs = await loop.run_in_executor(
+                                None, prepare)
+                            if turn is not None:
+                                turn.record()
+                            result = m(*args, **kwargs)
+                            if inspect.isawaitable(result):
+                                result = await result
                 except BaseException as e:  # noqa: BLE001
-                    err = repr(e)
-                    self._fail_returns(task_id, num_returns, e, name)
-                finally:
-                    unpin_args()
-                return err
+                    exc = e
+                store_now(result, exc)
 
-            if not self._wait_turn(caller_id, seqno):
-                return {"ok": True, "duplicate": True}
             take_over_pins()
+            if turn is not None:
+                turn.queued()
             asyncio.run_coroutine_threadsafe(run_async(), self.actor_loop)
             self._done_turn(caller_id, seqno)
             # Ack on enqueue: concurrent awaits must overlap, so completion
@@ -659,36 +724,28 @@ class WorkerService:
         elif self.actor_pool is not None:
             # max_concurrency > 1: out-of-order execution is allowed
             # (parity: out_of_order_actor_scheduling_queue.h).
-            if not self._wait_turn(caller_id, seqno):
-                return {"ok": True, "duplicate": True}
             take_over_pins()
 
-            def run_and_unpin():
-                try:
-                    run_sync()
-                finally:
-                    unpin_args()
-
-            self.actor_pool.submit(run_and_unpin)
+            if turn is not None:
+                turn.queued()
+            self.actor_pool.submit(lambda: store_now(*run()))
             self._done_turn(caller_id, seqno)
             return {"ok": True, "enqueued": True}
-        else:
-            # Sync actors ack AFTER execution, so the reply can carry the
-            # small returns inline (same contract as push_task_batch); the
-            # caller's call_async future completes with the value in hand.
-            # enqueued/duplicate acks above carry NO returns — the caller
-            # falls back to observing the store.
-            if not self._wait_turn(caller_id, seqno):
-                return {"ok": True, "duplicate": True}
-            entries: list = []
+        # Sync actors ack AFTER execution, so the reply can carry the
+        # small returns inline (same contract as push_task_batch); the
+        # caller's call_async future completes with the value in hand.
+        # enqueued/duplicate acks above carry NO returns — the caller
+        # falls back to observing the store.
+        entries: list = []
+        result, exc = run()
+        with _returning(trace_ctx) as ret:
             try:
-                error = run_sync(entries)
+                error = store(result, exc, entries, ret)
             finally:
                 self._done_turn(caller_id, seqno)
             self._flush_refs()
             self._queue_seals([entries])
-        self.events.record(task_id, name, "actor_task", start, time.time(),
-                           error)
+        _task_done(task_id.hex(), name, "actor_task", start, error)
         return {"ok": True, "node_id": self.node_id, "returns": entries}
 
     def _release_taken_pins(self) -> None:
@@ -729,7 +786,6 @@ class WorkerService:
             # the state was already reset: nothing to do, and killing the
             # process now could take down an innocent new tenant.
             return {"ok": True, "stale": True}
-        self.events.flush()
         try:
             _events.flush_now()     # the ring's tail would die with us
         except Exception:

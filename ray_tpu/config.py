@@ -325,15 +325,17 @@ define("tpu_probe_timeout_s", float, 120.0,
        "probe's stderr instead of hanging.")
 
 # Observability
-define("task_event_buffer_size", int, 100_000,
-       "Task lifecycle events the conductor retains (oldest dropped "
-       "first; state.list_tasks / dashboard timeline source).")
 define("metrics_export_period_s", float, 5.0, "Metrics flush period.")
 define("events_enabled", bool, True,
        "Flight-recorder event ring (util/events.py): per-process "
        "lifecycle events across all planes, shipped to the conductor in "
        "background batches. Always-on by design — the hot-path cost is "
-       "one cached flag check plus a ring-slot store.")
+       "one cached flag check plus a ring-slot store. The task views "
+       "(state.list_tasks, the dashboard's task list, rt.timeline()) are "
+       "made of the ring's task.exec records: with this off they are "
+       "empty, and they share the ring's and the conductor's bounds "
+       "(event_ring_size a process, 200 000 records cluster-wide) with "
+       "every other kind.")
 define("event_ring_size", int, 16384,
        "Flight-recorder ring capacity per process; overwrites oldest "
        "(dropped counts ship with the next batch).")

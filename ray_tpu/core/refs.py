@@ -21,13 +21,17 @@ _tracker = None
 
 
 class ObjectRef:
-    __slots__ = ("_id", "_owner", "_tracked", "__weakref__")
+    # ``_trace``: the trace_ctx of the actor call this ref is the return
+    # of, where that call was made under a span; cleared by the get that
+    # resolves it (runtime_cluster ``call.get``). None on every other ref.
+    __slots__ = ("_id", "_owner", "_tracked", "_trace", "__weakref__")
 
     def __init__(self, object_id: ObjectID, owner: Optional[str] = None):
         self._id = object_id
         # Owner address string ("host:port" of the owning worker/driver) —
         # lets any holder resolve the object's location via the owner.
         self._owner = owner
+        self._trace = None
         t = _tracker
         self._tracked = t is not None
         if t is not None:
@@ -96,6 +100,7 @@ class ChannelResolvedRef(ObjectRef):
         # has no store entry for the conductor ledger to count.
         self._id = object_id
         self._owner = None
+        self._trace = None
         self._tracked = False
 
     def _resolve(self, timeout: Optional[float] = None):

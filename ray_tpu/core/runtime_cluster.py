@@ -819,10 +819,11 @@ class _ActorClient:
             cli = get_client(self.address)
             base = self.seqno
             futs = []
-            _events.emit("actor.window", self.actor_id.hex()[:16],
-                         value=len(batch))
             try:
                 for i, task in enumerate(batch):
+                    stamps = task.pop("_call_span", None)
+                    if stamps is not None:
+                        sent_t0 = time.perf_counter()
                     f = cli.call_async(
                         "push_actor_task", task_id=task["task_id"],
                         caller_id=self.rt.caller_id, seqno=base + i,
@@ -834,6 +835,16 @@ class _ActorClient:
                         actor_id=self.actor_id,
                         **({"trace_ctx": task["trace_ctx"]}
                            if "trace_ctx" in task else {}))
+                    if stamps is not None:
+                        # the frame is on the socket: the caller's station
+                        # ends (a resend after a failure records none)
+                        _events.span_record(
+                            "call.submit", stamps[0],
+                            time.perf_counter() - stamps[1],
+                            ident=task["trace_ctx"]["ident"],
+                            parent=task["trace_ctx"]["span"],
+                            bytes=len(task["args_blob"]),
+                            window_wait_s=sent_t0 - stamps[2])
                     f.add_done_callback(
                         lambda f, t=task: self._ack_one(t, f))
                     futs.append(f)
@@ -1261,12 +1272,24 @@ class ClusterRuntime:
         # resolves every reply-carried or locally sealed small object (the
         # dominant shape — a get() over many task results) with zero
         # conductor traffic. Misses fall through to the per-object path.
+        start, t0 = time.time(), time.perf_counter()
         try:
             results = self.plane.get_values_local_inline(
                 [r.id for r in refs])
         except Exception:
             results = [MISS] * len(refs)
         missing = [i for i, v in enumerate(results) if v is MISS]
+        if len(missing) < len(refs):
+            # the traced actor calls among the refs this one round trip
+            # resolved: a call.get each, of the batch's seconds
+            took = time.perf_counter() - t0
+            for r, v in zip(refs, results):
+                if r._trace is not None and v is not MISS:
+                    ctx, r._trace = r._trace, None
+                    _events.span_record(
+                        "call.get", start, took, ident=ctx["ident"],
+                        parent=ctx["span"], parked_s=0.0, woken_ts=start,
+                        lock_wait_s=0.0)
         if missing:
             # Directory prewait only helps refs that are NOT parked on a
             # push reply (pending refs resolve from the reply, and their
@@ -1298,6 +1321,26 @@ class ClusterRuntime:
         return results
 
     def _get_one(self, ref: ObjectRef, deadline: Optional[float]) -> Any:
+        """Resolve one ref. The return of an actor call made under a span
+        (``ref._trace``) is resolved under a ``call.get`` span, that
+        span's child: the first get of the ref only."""
+        ctx = ref._trace
+        if ctx is None:
+            return self._resolve_one(ref, deadline, None)
+        ref._trace = None
+        with _events.span("call.get", ctx=ctx, parked_s=0.0,
+                          woken_ts=time.time(), lock_wait_s=0.0) as sp:
+            return self._resolve_one(ref, deadline, sp.attrs)
+
+    def _resolve_one(self, ref: ObjectRef, deadline: Optional[float],
+                     counts: Optional[dict]) -> Any:
+        """``counts``: the ``call.get`` span's counters. Parked here
+        (``wait_inline``) and below (``locate_object``, which counts
+        itself) is ``parked_s``; as a park ends ``woken_ts`` is stamped and
+        ``lock_wait_s`` (object_client's: in line for the store connection)
+        starts again from 0, so that it reads the round that found the
+        value and not the rounds before the value existed (a long call's
+        getter looks in the store every 2 s)."""
         waited = 0.0
         key = self.plane._key(ref.id)
         while True:
@@ -1309,9 +1352,15 @@ class ClusterRuntime:
             # wait, woken by seed/resolve) instead of polling the store
             # and long-polling the directory for a location that may not
             # exist until the producer's lazy seal.
-            if self.plane.is_pending(key) and \
-                    not self.plane.wait_inline(key, step):
-                continue
+            if self.plane.is_pending(key):
+                t0 = time.perf_counter()
+                resolved = self.plane.wait_inline(key, step)
+                if counts is not None:
+                    counts["parked_s"] += time.perf_counter() - t0
+                    counts["woken_ts"] = time.time()
+                    counts["lock_wait_s"] = 0.0
+                if not resolved:
+                    continue
             try:
                 value = self.plane.get_value(ref.id, timeout=step)
             except (GetTimeoutError, ObjectLostError) as e:
@@ -1713,6 +1762,14 @@ class ClusterRuntime:
 
     def submit_actor_task(self, handle: ActorHandle, method_name: str, args,
                           kwargs, opts: TaskOptions) -> List[ObjectRef]:
+        # A call made under an open span carries it (``trace_ctx``): the
+        # callee's spans are its children, and the call's own stations are
+        # timed (call.submit here and in the pusher, call.turn and
+        # call.return in the worker, call.get where the ref is resolved). A
+        # call outside any span pays this one context-variable read.
+        ctx = _events.current()
+        if ctx is not None:
+            span_ts, span_t0 = time.time(), time.perf_counter()
         actor_id = handle._rt_actor_id.binary()
         task_id = TaskID.from_random()
         args_blob, all_refs = serialization.dumps_with_refs(
@@ -1732,12 +1789,15 @@ class ClusterRuntime:
         }
         if inline_args:
             task["inline_args"] = inline_args
-        ctx = _events.current()
-        if ctx is not None:
-            task["trace_ctx"] = ctx
         self.plane.add_pending([store_key(ob) for ob in return_oids])
         refs = [ObjectRef(task_id.object_id_for_return(i), owner=self.address)
                 for i in range(opts.num_returns)]
+        if ctx is not None:
+            task["trace_ctx"] = ctx
+            if refs:
+                refs[0]._trace = ctx
+            # (start, its perf_counter, handed to the pusher's queue)
+            task["_call_span"] = (span_ts, span_t0, time.perf_counter())
         with self._lock:
             for r in refs:
                 self._oid_actor[r.id.binary()] = actor_id
@@ -1860,37 +1920,17 @@ class ClusterRuntime:
         return self.conductor.call("available_resources")
 
     def timeline_events(self) -> List[dict]:
-        """Merged cluster-wide Chrome-trace events (ray.timeline parity):
-        execution X slices from the task-event store, submit/reply instants
-        from the flight-recorder ring, flow events ("s"/"t"/"f", joined on
-        the task id) linking submit -> execute -> reply across processes,
-        and an object-transfer view from the pull/push ring events. Every
-        event carries ts + dur (flow/instant events use dur 0)."""
+        """Merged cluster-wide Chrome-trace events (ray.timeline parity),
+        all from the flight-recorder ring: execution X slices, submit/reply
+        instants, flow events ("s"/"t"/"f", joined on the task id) linking
+        submit -> execute -> reply across processes, and an object-transfer
+        view from the pull/push events. Every event carries ts + dur
+        (flow/instant events use dur 0)."""
         try:
             _events.flush_now()   # this process's tail rides along
         except Exception:
             pass
-        out: List[dict] = []
-        for e in self.conductor.call("get_task_events"):
-            tid = e.get("task_id", "")
-            out.append({
-                "cat": e["kind"], "name": e["name"], "ph": "X",
-                "ts": e["start"] * 1e6,
-                "dur": (e["end"] - e["start"]) * 1e6,
-                "pid": e["node_id"][:8], "tid": e["pid"],
-                "args": {"error": e["error"], "task_id": tid},
-            })
-            if e["kind"] == "task" and tid:
-                # flow step at execution start, bound by task id
-                out.append({"cat": "task_flow", "name": "task", "ph": "t",
-                            "id": tid, "ts": e["start"] * 1e6, "dur": 0,
-                            "bp": "e", "pid": e["node_id"][:8],
-                            "tid": e["pid"]})
-        try:
-            ring = self.conductor.call("get_ring_events")
-        except Exception:
-            ring = []
-        return out + ring_timeline(ring)
+        return ring_timeline(self.conductor.call("get_ring_events"))
 
     def debug_state(self) -> dict:
         """Driver-side slice of the cluster debug dump (the conductor and
@@ -1972,8 +2012,9 @@ class ClusterRuntime:
 
 def ring_timeline(ring: List[dict]) -> List[dict]:
     """Chrome-trace events from flight-recorder records (the conductor's
-    dicts): spans as nested X slices per process, task submit/reply
-    instants and their flow arrows, pipeline lanes, object transfers."""
+    dicts): spans as nested X slices per process, task executions,
+    submit/reply instants and their flow arrows, pipeline lanes, object
+    transfers."""
     out: List[dict] = []
     for e in ring:
         kind, ident = e["kind"], e["ident"]
@@ -1987,6 +2028,19 @@ def ring_timeline(ring: List[dict]) -> List[dict]:
                         "ts": ts_us, "dur": (e["value"] or 0.0) * 1e6,
                         "pid": pid_, "tid": tid_,
                         "args": {"ident": ident, **attrs}})
+        elif kind == "task.exec":
+            # an execution slice; a plain task's is a step of its flow,
+            # bound by the task id
+            v = _events.task_view(e)
+            out.append({"cat": v["kind"], "name": v["name"], "ph": "X",
+                        "ts": v["start"] * 1e6,
+                        "dur": (v["end"] - v["start"]) * 1e6,
+                        "pid": pid_, "tid": tid_,
+                        "args": {"error": v["error"], "task_id": ident}})
+            if v["kind"] == "task" and ident:
+                out.append({"cat": "task_flow", "name": "task", "ph": "t",
+                            "id": ident, "ts": v["start"] * 1e6, "dur": 0,
+                            "bp": "e", "pid": pid_, "tid": tid_})
         elif kind == "task.submit" and ident:
             out.append({"cat": "task", "name": "task.submit", "ph": "X",
                         "ts": ts_us, "dur": 0, "pid": pid_, "tid": tid_,

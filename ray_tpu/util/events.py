@@ -77,15 +77,47 @@ from ray_tpu import config
 EVENT_KINDS: Dict[str, str] = {
     # task plane
     "task.submit": "value unused; ident = task id",
-    "task.exec": "value = execution seconds",
+    "task.exec": "value = execution seconds, ts = the end; ident = task id "
+                 "(an actor creation: the actor id); attrs carry task (the "
+                 "name), kind (task / actor_task / actor_creation) and, "
+                 "of a failed one, error: the conductor answers "
+                 "get_task_events from these (rt.timeline(), "
+                 "state.list_tasks(), the dashboard's task view)",
     "task.reply": "value = end-to-end seconds",
     "task.retry": "value = retries remaining",
     "task.execute": "span: value = seconds; a task whose spec carried a "
                     "trace_ctx, parent = the caller's span",
     "lease.grant": "span: value = seconds from the request to the grant "
                    "(or to the actor alive); attrs carry TPU",
-    "actor.window": "value = ordered-push window occupancy",
-    "inline.seal": "value = sealed inline bytes",
+    # an actor call's four stations, recorded only for a call whose spec
+    # carries a trace_ctx (made under an open span); all four are children
+    # of that span and carry its ident. One host, one clock: the wire is
+    # call.turn.ts - end(call.submit), the wake end(call.get) -
+    # end(call.return); across hosts the difference is skewed by the
+    # hosts' clocks and the readers refuse it.
+    "call.submit": "span: value = seconds from submit_actor_task's first "
+                   "line to the push frame handed to the socket; attrs "
+                   "carry bytes (the args blob) and window_wait_s (queued "
+                   "behind the per-actor ordered send window)",
+    "call.turn": "span: value = seconds from rpc_push_actor_task's first "
+                 "line to the user's method's first line; attrs carry "
+                 "turn_wait_s (the seqno turn), pool_wait_s (the hand-off "
+                 "to a pool thread or the actor's loop) and resolve_s (the "
+                 "arguments fetched and unpickled)",
+    "call.return": "span: value = seconds from the user's method's return "
+                   "to its returns stored and sealed (or, inline, handed "
+                   "to the reply); attrs carry bytes, inline (1: rode the "
+                   "reply, its seal is the lazy sealer's, after the ack), "
+                   "seal_wait_s (in the store's put) and lock_wait_s (of "
+                   "that, waiting for the process's store connection)",
+    "call.get": "span: value = seconds from the first line of the get that "
+                "resolves the ref to the value in hand; attrs carry "
+                "parked_s (in wait_inline and the conductor's "
+                "locate_object long poll), woken_ts (time.time() as the "
+                "last of those parks ended: woken to value in hand is the "
+                "span's end less this) and lock_wait_s (of woken to value "
+                "in hand, in line for the process's store connection: it "
+                "starts again from 0 as a park ends)",
     # rpc plane
     "rpc.frame": "value = frame round-trip seconds; attrs carry bytes",
     # object plane
@@ -270,8 +302,10 @@ def _renonce() -> None:
 
 os.register_at_fork(after_in_child=_renonce)
 
-# (ident, span id) of the innermost open span of this thread or task.
-_current: "contextvars.ContextVar[Optional[Tuple[str, str]]]" = \
+# (ident, span id) of the innermost open span of this thread or task, and
+# behind them, of a ``span`` opened here (not of an adopted context), its
+# attrs: what ``counters`` hands to the code below it.
+_current: "contextvars.ContextVar[Optional[tuple]]" = \
     contextvars.ContextVar("span", default=None)
 _last_session: List[dict] = []
 
@@ -285,6 +319,18 @@ def current() -> Optional[dict]:
     submit outside any span attaches nothing."""
     cur = _current.get()
     return None if cur is None else {"ident": cur[0], "span": cur[1]}
+
+
+def counters(key: str) -> Optional[dict]:
+    """The attrs of the span open in this thread if it counts ``key`` (it
+    was opened with ``key=0.0``), else None: how a layer below the span (the
+    store connection, the locate long poll) adds seconds to the caller's
+    span without being handed it. One context-variable read where nothing
+    counts."""
+    cur = _current.get()
+    if cur is None or len(cur) < 3 or key not in cur[2]:
+        return None
+    return cur[2]
 
 
 def _annotation(kind: str):
@@ -334,7 +380,7 @@ class span:
         self.id = new_span_id()
         if self.ident is None:
             self.ident = self.id
-        self._token = _current.set((self.ident, self.id))
+        self._token = _current.set((self.ident, self.id, self.attrs))
         self.ts = time.time()
         self._ann = _annotation(self.kind)
         if self._ann is not None:
@@ -398,6 +444,18 @@ def keep_session(records: List[dict]) -> None:
     """``rt.shutdown()`` leaves the session's span records here."""
     global _last_session
     _last_session = list(records)
+
+
+def task_view(record: dict) -> dict:
+    """A ``task.exec`` record (the conductor's dict) as the task views show
+    it: ``state.list_tasks``, the dashboard's task list, ``rt.timeline()``'s
+    execution slices."""
+    attrs = record["attrs"] or {}
+    return {"task_id": record["ident"] or "", "name": attrs.get("task", ""),
+            "kind": attrs.get("kind", "task"),
+            "start": record["ts"] - (record["value"] or 0.0),
+            "end": record["ts"], "node_id": record["node_id"],
+            "pid": record["pid"], "error": attrs.get("error", "")}
 
 
 def last_session() -> List[dict]:
@@ -547,6 +605,8 @@ def _fold_metrics(evs: List[tuple], dropped: int) -> None:
         if kind == "task.submit":
             m.builtin(C, "rt_tasks_submitted_total").inc()
         elif kind == "task.exec":
+            if attrs and attrs.get("kind", "task") != "task":
+                continue    # an actor's call or creation: a task view only
             m.builtin(C, "rt_tasks_executed_total").inc()
             m.builtin(H, "rt_task_exec_s").observe(value)
         elif kind == "task.reply":
@@ -607,10 +667,6 @@ def _fold_metrics(evs: List[tuple], dropped: int) -> None:
             m.builtin(C, "rt_inline_cache_hits_total").inc(value or 1)
         elif kind == "inline.miss":
             m.builtin(C, "rt_inline_cache_misses_total").inc(value or 1)
-        elif kind == "inline.seal":
-            m.builtin(C, "rt_inline_seals_total").inc(value)
-        elif kind == "actor.window":
-            m.builtin(m.Gauge, "rt_actor_push_window").set(value)
         elif kind == "fault.fired":
             m.builtin(C, "rt_faults_fired_total").inc()
         elif kind == "cgraph.execute":

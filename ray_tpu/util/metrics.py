@@ -44,12 +44,12 @@ _builtin_lock = threading.Lock()
 METRICS: Dict[str, str] = {
     # task plane
     "rt_tasks_submitted_total": "tasks submitted by this driver",
-    "rt_tasks_executed_total": "tasks executed by this worker",
-    "rt_task_exec_s": "task execution wall time",
+    "rt_tasks_executed_total": "plain tasks executed by this worker "
+                               "(actor calls and creations are not counted)",
+    "rt_task_exec_s": "plain-task execution wall time",
     "rt_task_replies_total": "task replies observed by the driver",
     "rt_task_retries_total": "task retries scheduled after failures",
     "rt_lease_latency_s": "worker-lease grant latency",
-    "rt_actor_push_window": "actor ordered-push window occupancy",
     # rpc plane
     "rt_rpc_frame_latency_s": "rpc frame round-trip latency",
     "rt_rpc_frames_total": "rpc frames sent",
@@ -70,7 +70,6 @@ METRICS: Dict[str, str] = {
     "rt_inline_cache_entries": "inline cache entries resident",
     "rt_inline_cache_bytes": "inline cache bytes resident",
     "rt_inline_pending_returns": "inline returns awaiting seal",
-    "rt_inline_seals_total": "inline returns sealed",
     "rt_location_batch_backlog": "location-update batches queued",
     # device-native array objects (r16)
     "rt_array_puts_total": "array objects stored via the zero-copy path",

@@ -127,6 +127,31 @@ def test_fold_metrics_counts_batched_hits():
         events.reset_for_tests()
 
 
+def test_fold_metrics_counts_plain_tasks_as_executed():
+    """``task.exec`` is recorded for actor calls and creations too (the
+    task views read them); the executed-tasks series count plain tasks, as
+    they did when only those were recorded."""
+    events.reset_for_tests()
+    try:
+        reg = metrics_mod._registry
+
+        def executed():
+            m = reg.get("rt_tasks_executed_total")
+            return sum(p[1] for p in m._points()) if m else 0.0
+        before = executed()
+        now = time.time()
+        events._fold_metrics(
+            [(now, "task.exec", "a", 0.01, {"task": "f", "kind": "task"}),
+             (now, "task.exec", "b", 0.01, None),
+             (now, "task.exec", "c", 0.01,
+              {"task": "A.m", "kind": "actor_task"}),
+             (now, "task.exec", "d", 0.01,
+              {"task": "A.__init__", "kind": "actor_creation"})], dropped=0)
+        assert executed() - before == 2
+    finally:
+        events.reset_for_tests()
+
+
 def test_histogram_snapshot_series_shape():
     """Histogram snapshots carry per-tag bucket counts + sums so the
     exposition can render cumulative _bucket/_sum/_count lines."""

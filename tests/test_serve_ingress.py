@@ -164,6 +164,16 @@ def test_actor_task_cancel_before_start():
         assert rt.get(first, timeout=30) == 1
         # user code for the cancelled call never ran
         assert rt.get(a.log.remote(), timeout=30) == [1]
+        # and the task views name it so
+        from ray_tpu import state
+        deadline = time.time() + 30
+        while time.time() < deadline:
+            errors = [t["error_message"] for t in state.list_tasks()
+                      if t["name"].endswith("Slow.work")]
+            if len(errors) == 2:
+                break
+            time.sleep(0.2)
+        assert sorted(errors) == ["", "cancelled"]
 
 
 # ---------------------------------------------------------------------------

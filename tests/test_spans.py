@@ -259,6 +259,210 @@ def test_context_crosses_a_task_and_an_actor_call():
             rt.kill(a)
 
 
+STATIONS = ("call.submit", "call.turn", "call.return", "call.get")
+
+
+def _end(s):
+    return s["ts"] + s["value"]
+
+
+def _actor_of(how):
+    if how == "sync":
+        @rt.remote
+        class Sync:
+            def call(self, x):
+                return x + 1
+        return Sync
+    if how == "pooled":
+        @rt.remote(max_concurrency=2)
+        class Pooled:
+            def call(self, x):
+                return x + 1
+        return Pooled
+
+    @rt.remote
+    class Async:
+        async def call(self, x):
+            return x + 1
+    return Async
+
+
+@pytest.mark.parametrize("how", ["sync", "pooled", "async"])
+def test_an_actor_call_records_its_four_stations(how):
+    """One call under an open span: one each of call.submit (caller),
+    call.turn and call.return (callee), call.get (caller), children of the
+    caller's span, in order, inside [start(call.submit), end(call.get)]; a
+    call outside any span records none."""
+    with _runtime():
+        actor = _actor_of(how).remote()
+        assert rt.get(actor.call.remote(0), timeout=60) == 1   # no span
+        with events.span("test.job") as job:
+            ref = actor.call.remote(1)
+            assert rt.get(ref, timeout=60) == 2
+            assert rt.get(ref, timeout=60) == 2     # a second get: no span
+        spans = _cluster_spans(set(STATIONS), ident=job.ident)
+        by_kind = {k: _kind(spans, k) for k in STATIONS}
+        assert {k: len(v) for k, v in by_kind.items()} == \
+            dict.fromkeys(STATIONS, 1)
+        submit, turn, ret, get = (by_kind[k][0] for k in STATIONS)
+        for s in (submit, turn, ret, get):
+            assert s["attrs"]["parent"] == job.id and s["ident"] == job.ident
+        assert submit["pid"] == get["pid"] == os.getpid()
+        assert turn["pid"] == ret["pid"] != os.getpid()
+        # ordered (one host, one clock; the slack is time.time() against
+        # perf_counter() across two processes)
+        slack = 0.005
+        assert submit["ts"] <= turn["ts"] + slack
+        assert _end(turn) <= ret["ts"] + slack
+        # the value is in the store a moment before call.return's end is
+        # stamped: a getter on a busy host can have it by then
+        seen = 0.05
+        assert _end(ret) <= _end(get) + seen
+        for s in (turn, ret):
+            assert submit["ts"] - slack <= s["ts"] and \
+                _end(s) <= _end(get) + seen
+        # the counters: what each station says it waited for
+        assert submit["attrs"]["bytes"] > 0
+        assert submit["attrs"]["window_wait_s"] <= submit["value"]
+        t = turn["attrs"]
+        assert min(t["turn_wait_s"], t["pool_wait_s"], t["resolve_s"]) >= 0
+        assert t["turn_wait_s"] + t["pool_wait_s"] + t["resolve_s"] \
+            <= turn["value"] + 1e-6
+        assert (t["pool_wait_s"] > 0) == (how != "sync")
+        r, g = ret["attrs"], get["attrs"]
+        assert r["bytes"] > 0 and r["inline"] == (1 if how == "sync" else 0)
+        assert r["lock_wait_s"] <= r["seal_wait_s"] <= ret["value"]
+        assert (r["seal_wait_s"] > 0) == (how != "sync")
+        assert g["parked_s"] + g["lock_wait_s"] <= get["value"] + 1e-6
+        assert min(g["parked_s"], g["lock_wait_s"]) >= 0
+        assert get["ts"] - slack <= g["woken_ts"] <= _end(get) + slack
+        assert _end(ret) <= g["woken_ts"] + seen    # woken by the return
+        # nothing of the calls made outside the span
+        every = _cluster_spans(set(), timeout=0)
+        assert all(s["ident"] == job.ident
+                   for k in STATIONS for s in _kind(every, k))
+        rt.kill(actor)
+
+
+def test_a_batched_get_records_a_call_get_a_traced_ref():
+    with _runtime():
+        actor = _actor_of("sync").remote()
+        with events.span("test.job") as job:
+            refs = [actor.call.remote(i) for i in range(3)]
+            rt.wait(refs, num_returns=3, timeout=60)
+            assert rt.get(refs, timeout=60) == [1, 2, 3]
+        spans = _cluster_spans(set(STATIONS), ident=job.ident)
+        assert [len(_kind(spans, k)) for k in STATIONS] == [3, 3, 3, 3]
+        assert all(s["attrs"]["parent"] == job.id
+                   for s in _kind(spans, "call.get"))
+        rt.kill(actor)
+
+
+def _largest(attrs, keys):
+    return max(keys, key=lambda k: attrs[k])
+
+
+def test_a_delay_before_the_turn_shows_in_turn_wait_s(ring, monkeypatch):
+    """The callee's half in this process: a worker service beside the
+    driver's runtime, its ``_wait_turn`` 0.2 s late."""
+    from ray_tpu.cluster.worker_main import WorkerService
+    from ray_tpu.core import serialization
+    from ray_tpu.core.ids import TaskID
+    with _runtime():
+        runtime = core_api._runtime
+        daemon = runtime._owned_daemon
+        svc = WorkerService(runtime.conductor_address,
+                            runtime.daemon_address, daemon.store_socket,
+                            daemon.store_prefix, runtime.node_id)
+        try:
+            class Target:
+                def call(self, x):
+                    return x + 1
+            svc.actor_id, svc.actor_instance = b"a" * 16, Target()
+            svc.actor_class_name = "Target"
+            wait_turn = svc._wait_turn
+
+            def late(caller_id, seqno):
+                time.sleep(0.2)
+                return wait_turn(caller_id, seqno)
+            monkeypatch.setattr(svc, "_wait_turn", late)
+            reply = svc.rpc_push_actor_task(
+                task_id=TaskID.from_random().binary(), caller_id=b"c",
+                seqno=0, method_name="call",
+                args_blob=serialization.dumps(([1], {})), num_returns=1,
+                actor_id=svc.actor_id,
+                trace_ctx={"ident": "req", "span": "parent"})
+            assert reply["ok"] and len(reply["returns"]) == 1
+        finally:
+            svc._shutdown.set()         # its watchdog must not outlive us
+        spans = _spans(events.snapshot())
+        (turn,), (ret,) = _kind(spans, "call.turn"), _kind(spans,
+                                                          "call.return")
+        assert turn["ident"] == ret["ident"] == "req"
+        assert turn["attrs"]["parent"] == ret["attrs"]["parent"] == "parent"
+        t = turn["attrs"]
+        assert t["turn_wait_s"] >= 0.2 and turn["value"] >= 0.2
+        assert _largest(t, ("turn_wait_s", "pool_wait_s", "resolve_s")) \
+            == "turn_wait_s"
+        assert ret["value"] < turn["value"]
+
+
+def test_a_delay_in_storing_the_returns_shows_in_call_return():
+    """A fault rule sleeps 0.2 s where a return is about to ride the
+    reply (``_store_returns`` -> ``_emit_return``): call.return takes it,
+    not call.turn, and call.get is parked for it."""
+    from ray_tpu.cluster import fault_plane
+    rt.shutdown()
+    fault_plane.load_plan([{"site": "task.reply.inline", "action": "delay",
+                            "delay_s": 0.2}])
+    try:
+        with _runtime():
+            actor = _actor_of("sync").remote()
+            assert rt.get(actor.call.remote(0), timeout=60) == 1   # alive
+            with events.span("test.job") as job:
+                assert rt.get(actor.call.remote(1), timeout=60) == 2
+            spans = _cluster_spans(set(STATIONS), ident=job.ident)
+            by_kind = {k: _kind(spans, k)[0] for k in STATIONS}
+            assert by_kind["call.return"]["value"] >= 0.2
+            assert by_kind["call.get"]["attrs"]["parked_s"] >= 0.2
+            assert max(("call.submit", "call.turn", "call.return"),
+                       key=lambda k: by_kind[k]["value"]) == "call.return"
+            rt.kill(actor)
+    finally:
+        fault_plane.clear_plan()
+
+
+def test_a_held_store_connection_shows_in_lock_wait_s():
+    """The process's one store connection held for 0.2 s while a get
+    fetches a store-backed return: ``lock_wait_s`` takes it."""
+    with _runtime():
+        actor = _actor_of("pooled").remote()
+        store = core_api._runtime.plane.store
+        with events.span("test.job") as job:
+            ref = actor.call.remote(1)
+            done, _ = rt.wait([ref], num_returns=1, timeout=60)
+            assert done                 # stored: nothing left to park for
+            held, go = threading.Event(), threading.Event()
+
+            def hold():
+                with store._lock:
+                    held.set()
+                    go.wait(10)
+                    time.sleep(0.2)
+            holder = threading.Thread(target=hold)
+            holder.start()
+            assert held.wait(10)
+            go.set()
+            assert rt.get(ref, timeout=60) == 2
+            holder.join(10)
+        spans = _cluster_spans(set(STATIONS), ident=job.ident)
+        (get,) = _kind(spans, "call.get")
+        g = get["attrs"]
+        assert g["lock_wait_s"] >= 0.2 - 0.01 and get["value"] >= 0.2 - 0.01
+        assert _largest(g, ("parked_s", "lock_wait_s")) == "lock_wait_s"
+        rt.kill(actor)
+
+
 SLOW_S = 0.4
 
 
@@ -308,7 +512,13 @@ def test_serve_chain_through_proxy_and_batcher():
         for req in requests:
             assert req["attrs"]["code"] == 200
             mine = {s["kind"]: s for s in spans if s["ident"] == req["ident"]}
-            assert set(mine) == chain, sorted(mine)
+            assert set(mine) == chain | set(STATIONS), sorted(mine)
+            # another actor call under the request's ident (the handle
+            # refreshing its routes under slot_wait) has stations too: the
+            # request's own are the children of its serve.handle.call
+            mine.update({s["kind"]: s for s in spans
+                         if s["kind"] in STATIONS and s["attrs"]["parent"]
+                         == mine["serve.handle.call"]["attrs"]["span"]})
             rid = req["attrs"]["span"]
             for kind in ("serve.proxy.admit", "serve.proxy.thread_wait",
                          "serve.handle.slot_wait", "serve.handle.call"):
@@ -321,6 +531,16 @@ def test_serve_chain_through_proxy_and_batcher():
             assert replica["pid"] != call["pid"] and _inside(replica, call)
             assert call["attrs"]["retries"] == 0
             assert 1 <= replica["attrs"]["inflight"] <= n
+            # the actor call's own stations: the handle's call is their
+            # parent too, the way in before the replica's method, the way
+            # out after it
+            for kind in STATIONS:
+                assert mine[kind]["attrs"]["parent"] == \
+                    call["attrs"]["span"], kind
+                assert _inside(mine[kind], call), kind
+            assert _end(mine["call.turn"]) <= replica["ts"] + 0.005
+            assert _end(replica) <= mine["call.return"]["ts"] + 0.005
+            assert mine["call.return"]["attrs"]["inline"] == 0
             wait = mine["serve.batch.wait"]
             assert wait["attrs"]["parent"] == replica["attrs"]["span"]
             assert _inside(wait, replica)

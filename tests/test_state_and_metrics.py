@@ -55,6 +55,81 @@ def test_state_lists(cluster):
     rt.kill(a)
 
 
+# What an operator's task views return for one execution, as they did when
+# a second recorder in every worker fed them (pinned before that recorder
+# was folded into the flight-recorder ring, PR 43).
+LIST_TASKS_FIELDS = {"task_id", "name", "type", "state", "start_time_s",
+                     "end_time_s", "duration_s", "node_id", "worker_pid",
+                     "error_message"}
+TIMELINE_SLICE_FIELDS = {"cat", "name", "ph", "ts", "dur", "pid", "tid",
+                         "args"}
+
+
+@pytest.mark.parametrize("what", ["task", "actor_task", "failed_task"])
+def test_task_views_keep_their_fields(cluster, what):
+    import os
+    import time
+    from ray_tpu import state
+    from ray_tpu.core.exceptions import TaskError
+
+    @rt.remote
+    def viewed_task():
+        return os.getpid()
+
+    @rt.remote
+    def viewed_failure():
+        raise ValueError("viewed")
+
+    @rt.remote
+    class ViewedActor:
+        def viewed_call(self):
+            return os.getpid()
+
+    actor = None
+    if what == "task":
+        pid, name, kind = rt.get(viewed_task.remote(), timeout=60), \
+            "viewed_task", "task"
+    elif what == "actor_task":
+        actor = ViewedActor.remote()
+        pid, name, kind = rt.get(actor.viewed_call.remote(), timeout=60), \
+            "ViewedActor.viewed_call", "actor_task"
+    else:
+        with pytest.raises(TaskError):
+            rt.get(viewed_failure.remote(), timeout=60)
+        pid, name, kind = None, "viewed_failure", "task"
+    deadline = time.time() + 30
+    while True:
+        rows = [t for t in state.list_tasks() if name in t["name"]]
+        if rows or time.time() > deadline:
+            break
+        time.sleep(0.2)
+    assert len(rows) == 1
+    row = rows[0]
+    assert set(row) == LIST_TASKS_FIELDS
+    assert row["type"] == kind and len(row["task_id"]) == 32
+    assert row["state"] == ("FAILED" if what == "failed_task"
+                            else "FINISHED")
+    assert ("viewed" in row["error_message"]) == (what == "failed_task")
+    assert row["duration_s"] == row["end_time_s"] - row["start_time_s"] >= 0
+    assert abs(row["end_time_s"] - time.time()) < 120
+    assert pid is None or row["worker_pid"] == pid
+    assert row["node_id"] == core_api._runtime.node_id.hex()
+    assert state.summarize_tasks()[row["name"]]["count"] == 1
+    slices = [e for e in rt.timeline()
+              if e["ph"] == "X" and e["cat"] == kind and name in e["name"]]
+    assert len(slices) == 1
+    assert set(slices[0]) == TIMELINE_SLICE_FIELDS
+    assert set(slices[0]["args"]) == {"error", "task_id"}
+    assert slices[0]["args"]["task_id"] == row["task_id"]
+    assert slices[0]["dur"] == pytest.approx(row["duration_s"] * 1e6,
+                                             abs=1.0)
+    flows = [e for e in rt.timeline() if e.get("cat") == "task_flow"
+             and e["ph"] == "t" and e["id"] == row["task_id"]]
+    assert len(flows) == (1 if kind == "task" else 0)
+    if actor is not None:
+        rt.kill(actor)
+
+
 def test_timeline_dump(cluster, tmp_path):
     @rt.remote
     def traced():
