@@ -8,7 +8,12 @@ weights, a norm on each sublayer's output, an exit gate after each pass).
 Design notes (TPU-first):
 - Params are a pytree of jnp arrays; layers are *stacked* on a leading dim
   and applied with `lax.scan` so XLA compiles one layer body regardless of
-  depth; `jax.checkpoint` remats each layer (HBM <-> FLOPs trade).
+  depth; `jax.checkpoint` remats each layer (HBM <-> FLOPs trade). A remat'd
+  layer keeps its input and, where its mixer is the gated delta rule run by
+  the Pallas kernels, the forward kernel's four outputs (by their checkpoint
+  name, ops/gated_delta.py ``KEPT``: 603,979,776 bytes a layer at 2 rows x
+  8,192 in bfloat16), so that kernel is not run again for the backward; a
+  softmax-attention layer and the rule's jnp form keep nothing else.
 - Every weight carries logical axis names (transformer_logical_axes) mapped
   to mesh axes by parallel/sharding.py: tp shards heads/mlp/vocab, fsdp
   shards the embed dim (ZeRO-3), sp shards the sequence (ring/Ulysses
@@ -33,6 +38,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops.attention import mha
+from ray_tpu.ops.gated_delta import KEPT
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
 from ray_tpu.parallel.sharding import DEFAULT_RULES, LogicalRules
@@ -476,7 +482,10 @@ def _stage_scan(cfg: TransformerConfig, mesh, stage_layers, x, positions,
         _layer_apply, cfg, mesh=mesh, rules=rules,
         attend=lambda q, k, v: (_attention(cfg, q, k, v, mesh, rules), None))
     if cfg.remat:
-        body = jax.checkpoint(body)
+        # a layer without the rule's kernels holds nothing under the name,
+        # and is remat'd whole as by a bare jax.checkpoint
+        body = jax.checkpoint(
+            body, policy=jax.checkpoint_policies.save_only_these_names(KEPT))
 
     def step(carry, period):
         stats = []

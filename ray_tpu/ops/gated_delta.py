@@ -30,11 +30,22 @@ two fused Pallas kernels with a ``jax.custom_vjp`` (ops/gated_delta_pallas:
 state crosses chunks in VMEM scratch, the backward is a reverse scan of its
 own. Everywhere else (the CPU tests, odd widths) the jnp form below runs,
 batched matmuls and one ``lax.scan`` step a chunk with XLA's backward, which
-keeps one state a chunk; it is also the kernels' oracle. Either way what is
-kept for the backward lives, under the model's whole-layer remat, only while
-that layer's backward runs. Every operation of both runs under the scope
-``rt.gdn.scan`` (``rt.gdn.conv`` for the convolution), which is how the
-benchmark's reducer finds their device time.
+keeps one state a chunk; it is also the kernels' oracle.
+
+**What outlives the forward pass.** The forward kernel's four outputs, ``o``
+and what ``rt_gdn_bwd`` reads (each chunk's starting state, ``T`` and
+``v'``), carry the checkpoint name ``KEPT``, and the model's whole-layer
+remat keeps what carries it (models/transformer.py ``_stage_scan``): the
+kernel runs once a layer, not again inside the layer's backward. At 2 rows x
+8,192, 32 value heads of 128 on chunks of 64, bfloat16, that is 268,435,456
++ 67,108,864 + 134,217,728 + 134,217,728 = 603,979,776 bytes a layer from
+its forward to its backward. The operands (q, k, v, g, beta) are recomputed
+with the projections and the convolution they come out of. The jnp form
+carries no name and keeps nothing: under remat its forward is recomputed
+whole, and what XLA's backward reads lives only while that layer's backward
+runs. Every operation of both runs under the scope ``rt.gdn.scan``
+(``rt.gdn.conv`` for the convolution), which is how the benchmark's reducer
+finds their device time.
 """
 
 from __future__ import annotations
@@ -49,6 +60,9 @@ from ray_tpu.ops.flash import _on_tpu
 from ray_tpu.parallel.sharding import LogicalRules
 
 CHUNK = 64
+# the name (jax.ad_checkpoint.checkpoint_name) on what the kernels' forward
+# hands to their backward: a remat policy that saves it spares the second run
+KEPT = "gdn_kept"
 EPS = 1e-6          # under the root of a head's squared length
 BASE = 8            # side of the diagonal blocks inverted directly
 VPU_SIDE = 32       # blocks up to this side multiply on the VPU

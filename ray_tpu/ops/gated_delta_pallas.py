@@ -45,10 +45,11 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.gated_delta import EPS
+from ray_tpu.ops.gated_delta import EPS, KEPT
 
 BASE = 8        # side of the blocks the VPU inverts row by row: a sublane tile
 # Value heads a grid step. On a v5e at [2, 8192, 32 on 16 key heads, 128],
@@ -568,6 +569,13 @@ def _rule(q, k, v, g, beta, hk, hv, heads, c, interpret):
 def _rule_fwd(q, k, v, g, beta, hk, hv, heads, c, interpret):
     o, saved = _forward(q, k, v, g, beta, hk=hk, hv=hv, heads=heads, c=c,
                         save=True, interpret=interpret)
+    # All four of the kernel's outputs carry the name a remat'd layer's
+    # policy keeps (models/transformer.py ``_stage_scan``): with one left
+    # out the backward pass would run the whole kernel again for it (``o``
+    # feeds the gated norm, whose backward the layer's tail recomputes). The
+    # operands come out of the projections and the convolution, and are
+    # recomputed with them.
+    o, saved = checkpoint_name((o, saved), KEPT)
     return o, (q, k, v, g, beta, saved)
 
 

@@ -3,6 +3,8 @@ layer over a share) against the plain reference of its family, at a small
 size on the CPU, in float32 so that the comparison is of the mathematics."""
 
 import dataclasses
+import re
+from collections import Counter
 from functools import cache, partial
 
 import jax
@@ -215,8 +217,19 @@ def test_delta_rule_kernels_take_bfloat16_as_the_jnp_form_does():
     close(got.astype(jnp.float32), want.astype(jnp.float32), tol=1e-2)
 
 
+@pytest.fixture
+def kernels_interpreted(monkeypatch):
+    """The delta rule as on a TPU, its kernels through the interpreter."""
+    from ray_tpu.ops import gated_delta, gated_delta_pallas
+    monkeypatch.setattr(gated_delta, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        gated_delta_pallas, "gated_delta_rule_kernels",
+        partial(gated_delta_pallas.gated_delta_rule_kernels, interpret=True))
+
+
 @pytest.mark.parametrize("hk", [4, 2])
-def test_delta_rule_kernels_run_per_shard_under_a_mesh(monkeypatch, hk):
+def test_delta_rule_kernels_run_per_shard_under_a_mesh(kernels_interpreted,
+                                                       hk):
     """Where the kernels run, a mesh of several devices gets them per shard
     (GSPMD cannot partition a Mosaic call): rows over dp, heads over tp, a
     value head staying with its key head; two key heads over four head
@@ -224,13 +237,9 @@ def test_delta_rule_kernels_run_per_shard_under_a_mesh(monkeypatch, hk):
     through the interpreter here."""
     from jax.sharding import Mesh
 
-    from ray_tpu.ops import gated_delta, gated_delta_pallas
+    from ray_tpu.ops import gated_delta
     from ray_tpu.parallel.sharding import DEFAULT_RULES
 
-    monkeypatch.setattr(gated_delta, "_on_tpu", lambda: True)
-    monkeypatch.setattr(
-        gated_delta_pallas, "gated_delta_rule_kernels",
-        partial(gated_delta_pallas.gated_delta_rule_kernels, interpret=True))
     mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("dp", "tp"))
     operands = delta_rule_operands(64, hk, 4, dk=128, dv=128, b=2)
     got = jax.jit(partial(gated_delta.gated_delta_rule_over, mesh,
@@ -249,6 +258,147 @@ def test_off_the_tpu_the_delta_rule_is_the_jnp_form():
         text = jax.jit(jax.grad(
             lambda *a: rule(*a).sum())).lower(*operands).as_text()
         assert "tpu_custom_call" not in text and "rt_gdn" not in text
+
+
+# The toy period at head widths the kernels take (multiples of 128): three
+# delta-rule layers and one attention layer, a key head on two value heads.
+KERNEL_WIDTHS = {**CONFIG, "linear_num_key_heads": 1,
+                 "linear_num_value_heads": 2, "linear_key_head_dim": 128,
+                 "linear_value_head_dim": 128}
+GDN_LAYERS = 3
+
+
+@pytest.fixture
+def bare_checkpoint(monkeypatch):
+    """``_stage_scan``'s remat as the parent of PR 44 had it: a
+    ``jax.checkpoint`` with no policy, which keeps a layer's input alone."""
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+
+
+def equations(jaxpr, into=None) -> Counter:
+    """How often a jaxpr, with every jaxpr inside it, holds each primitive
+    and, by their own names, each Pallas kernel and each checkpoint name.
+    (The printed form will not do: it writes a kernel's body once however
+    often it is called.)"""
+    into = Counter() if into is None else into
+    for eqn in jaxpr.eqns:
+        into[eqn.primitive.name] += 1
+        if eqn.primitive.name in ("pallas_call", "name"):
+            into[eqn.params["name"]] += 1
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            equations(inner, into)
+    return into
+
+
+def period_loss_and_grads(cfg, mesh=None):
+    """value_and_grad of a loss over ``_stage_scan`` of one period, by the
+    layers' parameters and the input."""
+    def loss(layers, x):
+        positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
+        y, _ = transformer._stage_scan(cfg, mesh, layers, x, positions)
+        return jnp.mean(y.astype(jnp.float32) ** 2)
+
+    return jax.value_and_grad(loss, argnums=(0, 1))
+
+
+def devices_as(shape):
+    from jax.sharding import Mesh
+    if shape is None:
+        return None
+    count = int(np.prod(shape))
+    return Mesh(np.array(jax.devices()[:count]).reshape(shape), ("dp", "tp"))
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (2, 1), (2, 2)])
+@pytest.mark.parametrize("remat,policy,forwards", [
+    (True, "kept", 1), (False, "kept", 1), (True, "bare", 2)])
+def test_the_rules_forward_kernel_runs_once_a_layer(
+        request, kernels_interpreted, mesh_shape, remat, policy, forwards):
+    """What ``rt_gdn_bwd`` and the layer's remat'd tail read of
+    ``rt_gdn_fwd`` outlives the forward pass: the gradient of a period
+    under whole-layer remat holds one forward and one backward kernel a
+    delta-rule layer, as without remat; under a bare ``jax.checkpoint``
+    (the parent's) the forward is there twice. Under a mesh the kernels,
+    and the names on their outputs, sit inside shard_map, and the policy
+    reaches them there (two head shards: the one key head is first copied
+    once a value head)."""
+    from ray_tpu.ops.gated_delta import KEPT
+    if policy == "bare":
+        request.getfixturevalue("bare_checkpoint")
+    cfg = dataclasses.replace(program_config(KERNEL_WIDTHS), remat=remat)
+    layers = seeded(cfg)["layers"]
+    mesh = devices_as(mesh_shape)
+    found = equations(jax.make_jaxpr(period_loss_and_grads(cfg, mesh))(
+        layers, hidden(seq=64)).jaxpr)
+    assert found["rt_gdn_fwd"] == forwards * GDN_LAYERS
+    assert found["rt_gdn_bwd"] == GDN_LAYERS
+    # o, states, t and v' of each forward that is kept
+    assert found[KEPT] >= 4 * GDN_LAYERS
+    assert (found["shard_map"] > 0) == (mesh is not None)
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (2, 2)])
+def test_what_is_kept_is_what_the_second_run_would_have_made(
+        request, kernels_interpreted, mesh_shape):
+    """Loss and every gradient leaf, bit for bit, between the policy and a
+    bare ``jax.checkpoint`` of the same body."""
+    cfg = dataclasses.replace(program_config(KERNEL_WIDTHS), remat=True)
+    layers, x = seeded(cfg)["layers"], hidden(seq=64)
+    mesh = devices_as(mesh_shape)
+    kept = jax.jit(period_loss_and_grads(cfg, mesh))(layers, x)
+    request.getfixturevalue("bare_checkpoint")
+    bare = jax.jit(period_loss_and_grads(cfg, mesh))(layers, x)
+    got = jax.tree_util.tree_leaves_with_path(kept)
+    want = jax.tree.leaves(bare)
+    assert len(got) == len(want) == 2 + 3 * 17 + 16
+    for (path, leaf), wanted in zip(got, want):
+        assert float(jnp.abs(wanted).max()) > 0, path
+        np.testing.assert_array_equal(leaf, wanted, err_msg=str(path))
+
+
+def test_off_the_tpu_a_period_keeps_nothing_and_runs_no_kernel():
+    """No patch: the jnp form runs at the widths that would fit the
+    kernels, nothing carries the name, and the policy finds nothing."""
+    cfg = dataclasses.replace(program_config(KERNEL_WIDTHS), remat=True)
+    assert jax.default_backend() != "tpu"
+    traced = jax.make_jaxpr(period_loss_and_grads(cfg))(
+        seeded(cfg)["layers"], hidden(seq=64))
+    found = equations(traced.jaxpr)
+    assert found["pallas_call"] == found["name"] == 0
+    assert not [k for k in found if k.startswith("rt_gdn")]
+    assert "rt_gdn" not in str(traced)
+
+
+def test_a_dense_models_step_is_the_bare_checkpoints(request):
+    """A layer without ``gdn`` holds nothing under the name: the jaxpr of a
+    llama configuration's whole train step is the one a bare
+    ``jax.checkpoint`` gives, but for the line that prints the policy's
+    own address, and holds no ``name`` equation."""
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.train import make_lm_train_step
+    dense = TransformerConfig(vocab_size=128, d_model=64, n_layers=2,
+                              n_heads=4, n_kv_heads=2, max_seq=64)
+    assert dense.remat
+    tokens = {"tokens": jnp.zeros((2, 64), jnp.int32)}
+
+    def step_jaxpr():
+        init_fn, step_fn, _ = make_lm_train_step(
+            dense, build_mesh(MeshSpec(dp=1)))
+        return jax.make_jaxpr(step_fn)(init_fn(jax.random.PRNGKey(0)),
+                                       tokens)
+
+    def text(jaxpr):
+        return re.sub(r"policy=.*", "policy=", str(jaxpr))
+
+    kept = step_jaxpr()
+    found = equations(kept.jaxpr)
+    assert found["name"] == 0
+    assert "save_only_these_names" in str(kept)      # the remat is there
+    request.getfixturevalue("bare_checkpoint")
+    bare = step_jaxpr()
+    assert "policy=None" in str(bare)
+    assert text(kept) == text(bare)
 
 
 def test_gated_attention_with_partial_rotary_matches_the_reference():
