@@ -1,0 +1,5 @@
+from _dots3 import part_roofline
+
+
+def read(record, cell):
+    return part_roofline(record, cell, "index", "rt.dsa.index")

@@ -6,7 +6,13 @@ optionally per-head q/k norm, an output gate, any head width) or the gated
 delta rule (linear attention with a short causal convolution), over a SwiGLU
 MLP or a top-k expert layer with no capacity per expert that holds a share
 of the experts, and a shared expert; the stack run once, or ``loop_steps``
-times over the one set of weights with sandwich norms and an exit gate.
+times over the one set of weights with sandwich norms and an exit gate; or
+latent attention (models/latent.py: keys and values as one low-rank latent
+a position, in full layers with a learned sparse indexer and in window
+layers, a head-wise output gate) after leading dense layers, over expert
+layers routed by sigmoid with a correction bias. ``generate`` serves the
+softmax stacks from a K/V cache and the latent stacks from a cache by kind
+(latents, indexer keys, window rings), the prompt in chunks.
 Pure-functional params pytree with logical-axis
 annotations so one definition runs under any MeshSpec (dp/fsdp/tp/pp/sp/ep).
 Plus ResNet-50 (the north-star image benchmark, BASELINE.json) and an MLP.
@@ -17,6 +23,7 @@ ray.train; here models are jax pytrees + pure apply fns, jit/pjit-ready.
 """
 
 from ray_tpu.models.transformer import (
+    LatentDims,
     TransformerConfig,
     transformer_init,
     transformer_apply,
@@ -26,6 +33,7 @@ from ray_tpu.models.transformer import (
     transformer_logical_axes,
 )
 from ray_tpu.models.generate import (decode_step, generate,
+                                     generate_and_cache,
                                      generate_with_stats, init_cache,
                                      prefill)
 from ray_tpu.models.resnet import resnet50_init, resnet50_apply, resnet_loss
@@ -33,10 +41,12 @@ from ray_tpu.models.mlp import mlp_init, mlp_apply
 from ray_tpu.models.vit import ViTConfig, vit_init, vit_apply, vit_loss
 
 __all__ = [
-    "TransformerConfig", "transformer_init", "transformer_apply",
+    "LatentDims", "TransformerConfig", "transformer_init",
+    "transformer_apply",
     "transformer_apply_and_exits", "transformer_loss", "transformer_loss_and_stats",
     "transformer_logical_axes",
-    "generate", "generate_with_stats", "prefill", "decode_step",
+    "generate", "generate_and_cache", "generate_with_stats", "prefill",
+    "decode_step",
     "init_cache",
     "resnet50_init", "resnet50_apply", "resnet_loss",
     "mlp_init", "mlp_apply",

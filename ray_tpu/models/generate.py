@@ -47,6 +47,7 @@ pipelining is a different schedule than GPipe microbatching).
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -54,13 +55,25 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models.latent import ring_positions
 from ray_tpu.models.transformer import (TransformerConfig, _attention,
                                         _head, _layer_apply,
                                         _over_loop_steps)
+from ray_tpu.ops.attention import auto_path
 from ray_tpu.util import events
+
+LATENT_KINDS = ("latent", "window")
+
+
+def _by_kind(cfg: TransformerConfig) -> bool:
+    """A stack of latent-attention layers, served from a cache by kind."""
+    return bool(cfg.layer_types) and \
+        set(cfg.layer_types) <= set(LATENT_KINDS)
 
 
 def _refuse_recurrent(cfg: TransformerConfig) -> None:
+    if _by_kind(cfg):
+        return
     if "linear" in cfg.layer_types:
         raise NotImplementedError(
             "generate serves softmax-attention layers only: this "
@@ -70,8 +83,9 @@ def _refuse_recurrent(cfg: TransformerConfig) -> None:
             "(ROADMAP.md R8)")
     if cfg.layer_types:
         raise NotImplementedError(
-            "generate scans one stack of like layers; a layer pattern "
-            "(layer_types) is not served yet (ROADMAP.md R5)")
+            "generate serves one stack of like softmax-attention layers, or "
+            "a pattern of latent and window layers; this pattern "
+            f"{cfg.layer_types} is not served")
 
 
 def cache_slots(cfg: TransformerConfig) -> int:
@@ -79,9 +93,62 @@ def cache_slots(cfg: TransformerConfig) -> int:
     return cfg.loop_steps * cfg.n_layers
 
 
+def window_rows(cfg: TransformerConfig) -> int:
+    """Positions a window layer's cache holds: the window rounded up to the
+    block a step writes. A ring: position p lives in slot ``p mod rows``."""
+    return -(-cfg.window // _WRITE_ROWS) * _WRITE_ROWS
+
+
+def _lead_slots(cfg: TransformerConfig, kind: str) -> int:
+    """The kind's slots that the leading dense layers (of the period's
+    first kind) hold: its first."""
+    return cfg.first_dense_layers if cfg.kinds[0] == kind else 0
+
+
+def kind_slots(cfg: TransformerConfig) -> Dict[str, int]:
+    """Cache slots by layer kind: a layer of a kind has one."""
+    return {kind: _lead_slots(cfg, kind)
+            + cfg.periods * cfg.layer_types.count(kind)
+            for kind in LATENT_KINDS}
+
+
+def _slot(cfg: TransformerConfig, kind: str, period, j: int):
+    """The slot, among its kind's, of the layer at position ``j`` of
+    period ``period`` (the leading dense layers hold their kind's
+    first)."""
+    return _lead_slots(cfg, kind) + period * cfg.layer_types.count(kind) \
+        + cfg.layer_types[:j].count(kind)
+
+
+def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int
+                 ) -> Dict[str, Tuple[int, ...]]:
+    """The cache's arrays. A stack by kind holds, a latent layer, the
+    latent and shared key of every position (``latent``) and the indexer's
+    key (``index``), and a window layer a ring of `window_rows` positions
+    (``window``): [slots of the kind, B, positions, 1, width]."""
+    if not _by_kind(cfg):
+        shape = (cache_slots(cfg), batch, max_len, cfg.kv_heads,
+                 cfg.head_dim)
+        return {"k": shape, "v": shape}
+    slots, shapes = kind_slots(cfg), {}
+    if slots["latent"]:
+        shapes["latent"] = (slots["latent"], batch, max_len, 1,
+                            cfg.latent.cached)
+        if cfg.index_topk:
+            shapes["index"] = (slots["latent"], batch, max_len, 1,
+                               cfg.index_head_dim)
+    if slots["window"]:
+        shapes["window"] = (slots["window"], batch, window_rows(cfg), 1,
+                            cfg.window_latent.cached)
+    return shapes
+
+
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
-    """[loop steps x L, B, T, KVH, D] zeros pair (kv dtype = compute
-    dtype)."""
+    """Zeros of `cache_shapes` (kv dtype = compute dtype): the
+    [loop steps x L, B, T, KVH, D] pair, or the arrays by kind."""
+    if _by_kind(cfg):
+        return {name: jnp.zeros(shape, cfg.dtype) for name, shape
+                in cache_shapes(cfg, batch, max_len).items()}
     shape = (cache_slots(cfg), batch, max_len, cfg.kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype)}
@@ -214,6 +281,173 @@ def _over_the_slots(cfg: TransformerConfig, params, x, positions, cache,
     return x, {"k": cache_k, "v": cache_v}, exits
 
 
+# A stack by kind (latent and window layers, models/latent.py). The prompt
+# goes through the whole stack in chunks of queries, each chunk writing its
+# entries into the carried cache and attending to what stands: at 32,768
+# positions one row's queries of 128 heads x 192 are 1.6 GB and the expert
+# layer's buffer grows with the tokens of a step.
+PREFILL_CHUNK = 2048
+
+
+def prefill_chunk(prompt: int, most: int = PREFILL_CHUNK) -> int:
+    """Queries a chunk: the largest divisor of the prompt up to ``most``."""
+    return max(c for c in range(1, min(most, prompt) + 1) if prompt % c == 0)
+
+
+def _slot_rows(stack, slot):
+    """stack [slots, B, T, 1, W] -> the slot's [B, T, W]."""
+    return lax.dynamic_index_in_dim(stack, slot, 0, keepdims=False)[:, :, 0]
+
+
+def _over_the_kinds(cfg: TransformerConfig, params, x, positions, cache,
+                    attend_at):
+    """The trunk over a CARRIED cache by kind: the leading dense layers,
+    then the periods. ``attend_at(kind, cache, slot)`` gives a layer its
+    ``attend(new) -> (keys, key positions, cache)``, which hands back the
+    cache with the slot written. -> (x, cache, [rows routed to held
+    experts, rows dropped] summed over the layers, taps: the indexed
+    layers' selections and the window layers' key counts, ``lead`` stacked over the leading layers and
+    ``periods`` a list over the period's positions, stacked over the
+    periods)."""
+    kinds = cfg.layer_types
+
+    def run(kind, slot, layer, x, cache, counts):
+        x, cache, stats = _layer_apply(cfg, layer, x, positions,
+                                       attend_at(kind, cache, slot))
+        stats = stats or {}
+        if "rows_here" in stats:
+            counts = counts + jnp.stack([stats["rows_here"],
+                                         stats["rows_dropped"]])
+        return (x, cache, counts), {k: v for k, v in stats.items()
+                                    if k.startswith(("selected", "window"))}
+
+    carry, lead_taps = (x, cache, jnp.zeros((2,), jnp.int32)), None
+    if cfg.first_dense_layers:
+        carry, lead_taps = lax.scan(
+            lambda carry, at: run(kinds[0], at[1], at[0], *carry),
+            carry,
+            (params["dense_layers"], jnp.arange(cfg.first_dense_layers)))
+
+    def period(carry, layers_and_index):
+        layers, i = layers_and_index
+        taps = []
+        for j, (kind, layer) in enumerate(zip(kinds, layers)):
+            carry, tap = run(kind, _slot(cfg, kind, i, j), layer, *carry)
+            taps.append(tap)
+        return carry, taps
+
+    (x, cache, counts), taps = lax.scan(
+        period, carry, (params["layers"], jnp.arange(cfg.periods)))
+    return x, cache, counts, {"lead": lead_taps, "periods": taps}
+
+
+def _write_chunk_at(cfg: TransformerConfig, start, chunk: int):
+    """``attend_at`` of a prefill chunk of ``chunk`` positions from
+    ``start``. A latent layer writes the chunk's entries at their positions
+    and attends to its whole slot (the mask leaves out what is not written
+    yet); a window layer attends to its ring as it stood and the chunk's
+    own entries, then writes the chunk's last `window_rows` into the
+    ring."""
+    def attend_at(kind, cache, slot):
+        def latent(new):
+            out = dict(cache)
+            for name, rows in new.items():
+                out[name] = lax.dynamic_update_slice(
+                    cache[name], rows[None, :, :, None, :],
+                    (slot, 0, start, 0, 0))
+            keys = {name: _slot_rows(out[name], slot) for name in new}
+            return keys, jnp.arange(cache["latent"].shape[2])[None], out
+
+        def window(new):
+            rows = window_rows(cfg)
+            ring = _slot_rows(cache["window"], slot)
+            keys = {"latent": jnp.concatenate([ring, new["latent"]], 1)}
+            kpos = jnp.concatenate([ring_positions(start - 1, rows),
+                                    start + jnp.arange(chunk)])[None]
+            tail = min(chunk, rows)
+            at = (start + chunk - tail + jnp.arange(tail)) % rows
+            ring = ring.at[:, at].set(new["latent"][:, chunk - tail:])
+            return keys, kpos, dict(cache, window=lax.dynamic_update_slice(
+                cache["window"], ring[None, :, :, None, :],
+                (slot, 0, 0, 0, 0)))
+
+        return latent if kind == "latent" else window
+    return attend_at
+
+
+def _write_and_read_at(cfg: TransformerConfig, pos, extent: int):
+    """``attend_at`` of a decode step at position ``pos``: write the entry,
+    then read the slot from the UPDATED stack (as `decode_step_and_exits`):
+    a latent layer its first ``extent`` positions, a window layer its
+    ring."""
+    def attend_at(kind, cache, slot):
+        def latent(new):
+            out = dict(cache)
+            for name, row in new.items():
+                out[name] = _write_position(cache[name], slot, pos,
+                                            row[:, :, None, :])
+            keys = {name: _slot_prefix(out[name], slot, extent)[:, :, 0]
+                    for name in new}
+            return keys, jnp.arange(extent)[None], out
+
+        def window(new):
+            rows = window_rows(cfg)
+            stack = _write_position(cache["window"], slot, pos % rows,
+                                    new["latent"][:, :, None, :])
+            return ({"latent": _slot_rows(stack, slot)},
+                    ring_positions(pos, rows)[None],
+                    dict(cache, window=stack))
+
+        return latent if kind == "latent" else window
+    return attend_at
+
+
+def prefill_and_taps(params, tokens, cfg: TransformerConfig, max_len: int,
+                     chunk: Optional[int] = None):
+    """A stack by kind: the prompt [B, S] through the trunk in chunks of
+    ``chunk`` queries (`prefill_chunk` of S where None; S is a multiple)
+    -> (last-position logits [B, vocab], filled cache, taps of the last
+    chunk as `_over_the_kinds` gives them, with ``moe_rows``: [rows routed
+    to held experts, rows dropped] over the whole prompt)."""
+    b, s = tokens.shape
+    chunk = chunk or prefill_chunk(s)
+    if s % chunk:
+        raise ValueError(f"a prompt of {s} is not a multiple of the chunk "
+                         f"{chunk}")
+    embed = params["embed"].astype(cfg.dtype)
+
+    def step(carry, c):
+        cache, counts = carry
+        start = c * chunk
+        positions = start + jnp.broadcast_to(jnp.arange(chunk), (b, chunk))
+        x = embed[lax.dynamic_slice_in_dim(tokens, start, chunk, axis=1)]
+        x, cache, n, taps = _over_the_kinds(
+            cfg, params, x, positions, cache,
+            _write_chunk_at(cfg, start, chunk))
+        return (cache, counts + n), (x[:, -1], taps)
+
+    (cache, counts), (last, taps) = lax.scan(
+        step, (init_cache(cfg, b, max_len), jnp.zeros((2,), jnp.int32)),
+        jnp.arange(s // chunk))
+    taps = dict(jax.tree.map(lambda a: a[-1], taps), moe_rows=counts)
+    return _head(params, last[-1][:, None], cfg)[:, 0], cache, taps
+
+
+def decode_step_and_taps(params, token, pos, cache, cfg: TransformerConfig,
+                         *, extent: Optional[int] = None):
+    """A stack by kind, one token for the whole batch (as
+    `decode_step_and_exits`) -> (logits [B, vocab], updated cache, taps
+    with ``moe_rows``)."""
+    if extent is None:
+        extent = cache["latent"].shape[2] if "latent" in cache else 0
+    x = params["embed"].astype(cfg.dtype)[token][:, None, :]
+    positions = jnp.full((x.shape[0], 1), pos)
+    x, cache, counts, taps = _over_the_kinds(
+        cfg, params, x, positions, cache,
+        _write_and_read_at(cfg, pos, extent))
+    return _head(params, x, cfg)[:, 0], cache, dict(taps, moe_rows=counts)
+
+
 def prefill_and_exits(params, tokens, cfg: TransformerConfig, max_len: int,
                       mesh=None):
     """Run the prompt through the trunk, returning (last-position logits
@@ -223,6 +457,9 @@ def prefill_and_exits(params, tokens, cfg: TransformerConfig, max_len: int,
     if cfg.pp_stages > 1:
         raise NotImplementedError("decode with pp_stages>1 is not supported")
     _refuse_recurrent(cfg)
+    if _by_kind(cfg):
+        logits, cache, _ = prefill_and_taps(params, tokens, cfg, max_len)
+        return logits, cache, None
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     x = params["embed"].astype(cfg.dtype)[tokens]
@@ -274,6 +511,10 @@ def decode_step_and_exits(params, token, pos, cache,
     first ``extent`` positions of a slot (static; the caller's word that
     ``pos < extent``), all of them where it is None."""
     _refuse_recurrent(cfg)
+    if _by_kind(cfg):
+        logits, cache, _ = decode_step_and_taps(params, token, pos, cache,
+                                                cfg, extent=extent)
+        return logits, cache, None
     extent = cache["k"].shape[2] if extent is None else extent
     x = params["embed"].astype(cfg.dtype)[token][:, None, :]   # [B, 1, E]
     positions = jnp.full((x.shape[0], 1), pos)
@@ -323,15 +564,21 @@ def _expected_exit_step(exits):
                                       dtype=exits.dtype))
 
 
-def generate_with_stats(params, prompt, cfg: TransformerConfig, *,
-                        max_new_tokens: int, temperature: float = 0.0,
-                        top_k: Optional[int] = None, seed: int = 0,
-                        mesh=None) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+def generate_and_cache(params, prompt, cfg: TransformerConfig, *,
+                       max_new_tokens: int, temperature: float = 0.0,
+                       top_k: Optional[int] = None, seed: int = 0,
+                       mesh=None
+                       ) -> Tuple[jnp.ndarray, Dict[str, Any], Dict[str, Any]]:
     """prompt [B, S] int32 -> (generated tokens [B, max_new_tokens],
-    stats). ``stats`` is ``{}`` but for a looped stack: there
+    stats, the cache as the call's last step left it: what a caller that
+    holds the call to a reference reads, and the token loop's carry, so
+    handing it back costs no copy). ``stats`` is ``{}`` but for a looped
+    stack: there
     ``exit_steps_sum``, the sum over the generated tokens of the exit
     gate's expected loop step ``sum_t (t + 1) p_t``, and ``exit_tokens``,
-    their count: a few floats, accumulated in the decode loop's carry.
+    their count: a few floats, accumulated in the decode loop's carry; and
+    for a stack by kind, whose expert layers must drop nothing:
+    ``moe_rows_here`` and ``moe_rows_dropped``, summed over the call.
 
     The whole decode loop runs inside the caller's jit scope (wrap with
     jax.jit(partial(generate, ...)) or call under jit), one lax.scan for
@@ -340,38 +587,62 @@ def generate_with_stats(params, prompt, cfg: TransformerConfig, *,
     _refuse_recurrent(cfg)
     b, s = prompt.shape
     max_len = s + max_new_tokens
-    looped = cfg.loop_steps > 1
+    looped, by_kind = cfg.loop_steps > 1, _by_kind(cfg)
+    rows = None
     with jax.named_scope("rt.generate.prefill"):
-        logits, cache, exits = prefill_and_exits(params, prompt, cfg,
-                                                 max_len, mesh=mesh)
+        if by_kind:
+            logits, cache, taps = prefill_and_taps(params, prompt, cfg,
+                                                   max_len)
+            exits, rows = None, taps["moe_rows"]
+        else:
+            logits, cache, exits = prefill_and_exits(params, prompt, cfg,
+                                                     max_len, mesh=mesh)
     key = jax.random.PRNGKey(seed)
     key, sub = jax.random.split(key)
     first = _sample(logits, sub, temperature, top_k)
 
     def step(extent, carry, _):
-        token, pos, cache, key, exits, steps_sum = carry
+        token, pos, cache, key, exits, steps_sum, rows = carry
         if looped:      # ``exits`` came with the logits ``token`` is from
             steps_sum = steps_sum + _expected_exit_step(exits)
-        logits, cache, exits = decode_step_and_exits(
-            params, token, pos, cache, cfg, extent=extent)
+        if by_kind:
+            logits, cache, taps = decode_step_and_taps(
+                params, token, pos, cache, cfg, extent=extent)
+            rows = rows + taps["moe_rows"]
+        else:
+            logits, cache, exits = decode_step_and_exits(
+                params, token, pos, cache, cfg, extent=extent)
         key, sub = jax.random.split(key)
         nxt = _sample(logits, sub, temperature, top_k)
-        return (nxt, pos + 1, cache, key, exits, steps_sum), token
+        return (nxt, pos + 1, cache, key, exits, steps_sum, rows), token
 
     carry = (first, jnp.asarray(s, jnp.int32), cache, key, exits,
-             jnp.zeros((), jnp.float32) if looped else None)
+             jnp.zeros((), jnp.float32) if looped else None, rows)
     tokens = []
     with jax.named_scope("rt.generate.decode"):
         for steps, extent in _decode_segments(s, max_new_tokens):
             carry, emitted = lax.scan(partial(step, extent), carry, None,
                                       length=steps)
             tokens.append(emitted)
-    steps_sum = carry[-1]
+    steps_sum, rows = carry[-2:]
     tokens = jnp.concatenate(tokens)
     stats = {"exit_steps_sum": steps_sum,
              "exit_tokens": jnp.asarray(b * max_new_tokens, jnp.float32)} \
         if looped else {}
-    return jnp.transpose(tokens, (1, 0)), stats   # [B, max_new_tokens]
+    if by_kind:
+        stats = {"moe_rows_here": rows[0], "moe_rows_dropped": rows[1]}
+    return jnp.transpose(tokens, (1, 0)), stats, carry[2]
+
+
+def generate_with_stats(params, prompt, cfg: TransformerConfig, *,
+                        max_new_tokens: int, temperature: float = 0.0,
+                        top_k: Optional[int] = None, seed: int = 0,
+                        mesh=None) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+    """``generate_and_cache`` without the cache: (tokens [B,
+    max_new_tokens], stats)."""
+    return generate_and_cache(
+        params, prompt, cfg, max_new_tokens=max_new_tokens,
+        temperature=temperature, top_k=top_k, seed=seed, mesh=mesh)[:2]
 
 
 def generate(params, prompt, cfg: TransformerConfig, *, max_new_tokens: int,
@@ -393,13 +664,35 @@ def call_span(cfg: TransformerConfig, rows: int, prompt: int,
     over the call's decode steps of their segment's extent, what a slot's
     attention reads, ``cache_positions_needed`` that of ``pos + 1``, what
     it has to."""
-    slots = cache_slots(cfg)
     segments = _decode_segments(prompt, new)
-    return events.span(
-        "generate.call", rows=rows, prompt=prompt, new=new,
-        loop_steps=cfg.loop_steps, cache_slots=slots,
-        cache_bytes=2 * slots * rows * (prompt + new) * cfg.kv_heads
-        * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize,
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    shapes = cache_shapes(cfg, rows, prompt + new)
+    attrs = dict(
+        rows=rows, prompt=prompt, new=new, loop_steps=cfg.loop_steps,
+        attention_path="latent" if _by_kind(cfg)
+        else auto_path(prompt, prompt, cfg.head_dim)
+        if cfg.attn_impl == "auto" else cfg.attn_impl,
+        cache_slots=sum(shape[0] for shape in shapes.values())
+        if _by_kind(cfg) else cache_slots(cfg),
+        cache_bytes=sum(math.prod(shape) for shape in shapes.values())
+        * itemsize,
         decode_segments=len(segments),
         cache_positions_read=sum(n * extent for n, extent in segments),
         cache_positions_needed=new * prompt + new * (new + 1) // 2)
+    if _by_kind(cfg):
+        # a latent layer's queries, each over the keys up to its own: all
+        # of them scored by the indexer, index_topk of them attended to
+        layers = rows * kind_slots(cfg)["latent"]
+        k = cfg.index_topk if 0 < cfg.index_topk < prompt + new else 0
+        total = prompt + new - 1        # queries at positions 0 .. total-1
+        causal = total * (total + 1) // 2
+        few = min(total, k)             # positions with no more than k keys
+        attrs.update(
+            {"cache_bytes_" + name: math.prod(shape) * itemsize
+             for name, shape in shapes.items()},
+            prefill_chunks=prompt // prefill_chunk(prompt),
+            index_topk=cfg.index_topk,
+            keys_scored=layers * causal if k else 0,
+            keys_attended=layers * (few * (few + 1) // 2 + (total - few) * k
+                                    if k else causal))
+    return events.span("generate.call", **attrs)

@@ -1,0 +1,309 @@
+"""Latent attention: keys and values of all heads kept as one low-rank
+latent a position (DeepSeek-V2's MLA), in two kinds of layer.
+
+A "latent" layer attends to every earlier position or, where
+``cfg.index_topk`` is set and more than that many keys stand, to the
+``index_topk`` of them that a learned indexer scores highest (DeepSeek-V3.2's
+sparse attention): ``I[t, s] = sum_j w[t, j] relu(qI_j[t] . kI[s])`` over
+``index_heads`` small heads, the ``index_topk`` largest ``s <= t`` kept. A
+"window" layer is the same mixer at widths of its own
+(``cfg.window_latent``), without indexer, over the last ``cfg.window``
+positions, the query's own included. Both can gate each head's output by
+``sigmoid(w_g . x)`` (``attn_gate = "headwise"``) and scale the two latents
+by ``sqrt(d_model / rank)`` (``lora_rescale``).
+
+What is cached for a position is ``[c_kv ; k_rope]`` (``dims.cached`` wide:
+the normed latent and the one rotated key all heads share) and, in an
+indexed layer, the indexer's key ``kI``. The mixer makes a position's
+entries, hands them to ``attend(new) -> (keys, key positions, kept)`` and
+attends to what comes back: the sequence's own entries in the training
+forward, a cache with the entries written in prefill and decode
+(models/generate.py). Nothing else differs between the three.
+
+Two forms of one product. *Expanded*: every key's ``k_nope`` and ``v`` are
+made from its latent (``W_ukv``), ``(nope + v)`` multiply-adds a head and
+pair: for many queries over keys they share. *Absorbed*: ``W_uk`` is folded
+into the query and ``W_uv`` applied to the attended latents, ``(2 kv_rank +
+rope)`` a head and pair and nothing per key: for one query (a decode step),
+and wherever each query has keys of its own (the selection), whose expanded
+keys nothing could hold. Queries go through either in blocks, so that no
+``[heads, queries, keys]`` score tensor is whole at once.
+
+Scopes in the compiled program: ``rt.mla.project`` (the projections and the
+output), ``rt.dsa.index`` (the indexer's scores and the top-k),
+``rt.mla.sparse`` (the gather and the attention over the selection),
+``rt.mla.window`` (a window layer's attention), ``rt.mla.dense`` (a latent
+layer's attention over all keys, where there are no more than
+``index_topk``).
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models.transformer import (LatentDims, TransformerConfig,
+                                        _output_gate, _rmsnorm, _rope)
+
+PARAMS_KEY = {"latent": "mla", "window": "swa"}
+# Queries a block: of the selection (each query gathers index_topk rows of
+# the cache: 128 x 2,048 x 576 x 2 B = 302 MB a row of the batch) and of
+# attention over shared keys.
+SPARSE_QUERY_BLOCK = 128
+DENSE_QUERY_BLOCK = 512
+# Indexer heads scored at once: [B, group, block, keys] float32 is held.
+INDEX_HEAD_GROUP = 16
+LAYER_NORM_EPS = 1e-6
+_NEVER = jnp.iinfo(jnp.int32).max     # the position of a slot never written
+
+
+def _indexed(cfg: TransformerConfig, kind: str) -> bool:
+    return kind == "latent" and cfg.index_topk > 0
+
+
+def latent_init(key, cfg: TransformerConfig, kind: str) -> dict:
+    dims, d, pd = cfg.latent_dims(kind), cfg.d_model, cfg.param_dtype
+    init = jax.nn.initializers.normal(0.02)
+    ks = jax.random.split(key, 9)
+    p = {
+        "wdq": init(ks[0], (d, dims.q_rank), pd),
+        "q_norm": jnp.ones((dims.q_rank,), pd),
+        "wuq": init(ks[1], (dims.q_rank, dims.heads,
+                            dims.nope + dims.rope), pd),
+        "wdkv": init(ks[2], (d, dims.cached), pd),
+        "kv_norm": jnp.ones((dims.kv_rank,), pd),
+        "wukv": init(ks[3], (dims.kv_rank, dims.heads,
+                             dims.nope + dims.v), pd),
+        "wo": init(ks[4], (dims.heads, dims.v, d), pd),
+    }
+    if cfg.attn_gate == "headwise":
+        p["wg"] = init(ks[5], (d, dims.heads), pd)
+    if _indexed(cfg, kind):
+        p["index"] = {
+            "wq": init(ks[6], (dims.q_rank, cfg.index_heads,
+                               cfg.index_head_dim), pd),
+            "wk": init(ks[7], (d, cfg.index_head_dim), pd),
+            "k_norm_w": jnp.ones((cfg.index_head_dim,), pd),
+            "k_norm_b": jnp.zeros((cfg.index_head_dim,), pd),
+            "ww": init(ks[8], (d, cfg.index_heads), pd),
+        }
+    return p
+
+
+def latent_axes(cfg: TransformerConfig, kind: str, L) -> dict:
+    """Logical axes of ``latent_init``'s tree (``L`` adds the stacked
+    dims): heads over tp, the rest whole."""
+    p = {"wdq": L("embed", None), "q_norm": L(None),
+         "wuq": L(None, "heads", "kv"), "wdkv": L("embed", None),
+         "kv_norm": L(None), "wukv": L(None, "heads", "kv"),
+         "wo": L("heads", "kv", "embed")}
+    if cfg.attn_gate == "headwise":
+        p["wg"] = L("embed", "heads")
+    if _indexed(cfg, kind):
+        p["index"] = {"wq": L(None, None, None), "wk": L("embed", None),
+                      "k_norm_w": L(None), "k_norm_b": L(None),
+                      "ww": L("embed", None)}
+    return p
+
+
+def _layer_norm(x, w, b):
+    x32 = x.astype(jnp.float32)
+    mean = x32.mean(-1, keepdims=True)
+    var = jnp.square(x32 - mean).mean(-1, keepdims=True)
+    return ((x32 - mean) * lax.rsqrt(var + LAYER_NORM_EPS)).astype(x.dtype) \
+        * w.astype(x.dtype) + b.astype(x.dtype)
+
+
+def _over_query_blocks(fn, block: int, *per_query):
+    """``fn(*blocks) -> tree of [B, block, ...]`` over blocks of the
+    arrays' second dim (queries), one block at a time; the whole at once
+    where the queries are no more than a block or do not divide."""
+    s = per_query[0].shape[1]
+    if s <= block or s % block:
+        return fn(*per_query)
+    n = s // block
+    split = [jnp.moveaxis(a.reshape(a.shape[0], n, block, *a.shape[2:]),
+                          1, 0) for a in per_query]
+    out = lax.map(lambda blocks: fn(*blocks), split)
+    return jax.tree.map(
+        lambda a: jnp.moveaxis(a, 0, 1).reshape(
+            a.shape[1], n * block, *a.shape[3:]), out)
+
+
+def _softmax(scores, mask):
+    """float32 softmax over the last dim of the masked scores; a row with
+    no key (never under a causal mask that keeps the query's own position)
+    would come out uniform."""
+    return jax.nn.softmax(jnp.where(mask, scores.astype(jnp.float32), -1e30),
+                          axis=-1)
+
+
+def _mask(qpos, kpos, window: int):
+    """[B, S, T]: key position <= query position, and inside the window."""
+    q, k = qpos[:, :, None], kpos[:, None, :]
+    return (k <= q) & (q - k < window) if window else (k <= q)
+
+
+def _expanded(dims, wukv, q_nope, q_rope, qpos, keys, kpos, window):
+    """Every key's k_nope and v from its latent; queries in blocks."""
+    r = dims.kv_rank
+    kv = jnp.einsum("btr,rhd->bthd", keys[..., :r], wukv)
+    k_nope, v, k_rope = kv[..., :dims.nope], kv[..., dims.nope:], \
+        keys[..., r:]
+    scale = 1.0 / math.sqrt(dims.nope + dims.rope)
+
+    def block(q_nope, q_rope, qpos):
+        scores = (jnp.einsum("bshd,bthd->bhst", q_nope, k_nope)
+                  + jnp.einsum("bshd,btd->bhst", q_rope, k_rope)) * scale
+        w = _softmax(scores, _mask(qpos, kpos, window)[:, None])
+        return jnp.einsum("bhst,bthd->bshd", w.astype(v.dtype), v)
+
+    return _over_query_blocks(block, DENSE_QUERY_BLOCK, q_nope, q_rope, qpos)
+
+
+def _absorb(dims, wukv, q_nope):
+    """q_nope [B, S, H, nope] -> the query against latents [B, S, H, r]."""
+    return jnp.einsum("bshd,rhd->bshr", q_nope, wukv[..., :dims.nope])
+
+
+def _unabsorb(dims, wukv, o_latent):
+    """Attended latents [B, S, H, r] -> values [B, S, H, v]."""
+    return jnp.einsum("bshr,rhd->bshd", o_latent, wukv[..., dims.nope:])
+
+
+def _absorbed(dims, wukv, q_nope, q_rope, qpos, keys, kpos, window):
+    """The latents themselves as keys and values of every head."""
+    r = dims.kv_rank
+    latent, k_rope = keys[..., :r], keys[..., r:]
+    scale = 1.0 / math.sqrt(dims.nope + dims.rope)
+    scores = (jnp.einsum("bshr,btr->bhst", _absorb(dims, wukv, q_nope),
+                         latent)
+              + jnp.einsum("bshd,btd->bhst", q_rope, k_rope)) * scale
+    w = _softmax(scores, _mask(qpos, kpos, window)[:, None])
+    return _unabsorb(dims, wukv, jnp.einsum(
+        "bhst,btr->bshr", w.astype(latent.dtype), latent))
+
+
+def index_scores(qi, w, ki):
+    """qi [B, S, J, D], w [B, S, J] float32, ki [B, T, D] -> ``sum_j w_j
+    relu(qi_j . ki)`` [B, S, T] float32, INDEX_HEAD_GROUP heads at a
+    time."""
+    b, s, j, _ = qi.shape
+    g = math.gcd(j, INDEX_HEAD_GROUP)
+
+    def group(acc, q_w):
+        q, wj = q_w                         # [B, S, g, D], [B, S, g]
+        dots = jnp.einsum("bsjd,btd->bsjt", q, ki,
+                          preferred_element_type=jnp.float32)
+        return acc + jnp.einsum("bsjt,bsj->bst", jax.nn.relu(dots), wj), None
+
+    groups = (jnp.moveaxis(qi.reshape(b, s, j // g, g, -1), 2, 0),
+              jnp.moveaxis(w.reshape(b, s, j // g, g), 2, 0))
+    return lax.scan(group, jnp.zeros((b, s, ki.shape[1]), jnp.float32),
+                    groups)[0]
+
+
+def select(topk: int, qi, w, ki, qpos, kpos):
+    """-> (positions in the keys [B, S, topk] int32, which of them are
+    real [B, S, topk]): the ``topk`` keys ``s <= t`` of largest index
+    score; a query with fewer keys than that selects them all, and the
+    rest of its row is marked not real."""
+    scores = jnp.where(_mask(qpos, kpos, 0), index_scores(qi, w, ki),
+                       -jnp.inf)
+    top, at = lax.top_k(scores, topk)
+    return at, top > -jnp.inf
+
+
+def _sparse(cfg, dims, wukv, q_nope, q_rope, qpos, keys, kpos, index_keys,
+            index_query):
+    """Each query over its own selection, absorbed, in blocks of queries.
+    -> (o [B, S, H, v], the last query's selection)."""
+    r = dims.kv_rank
+    scale = 1.0 / math.sqrt(dims.nope + dims.rope)
+    gather = jax.vmap(lambda rows, at: rows[at])    # over the batch
+
+    def block(q_nope, q_rope, qpos, qi, w):
+        with jax.named_scope("rt.dsa.index"):
+            at, real = select(cfg.index_topk, qi, w, index_keys, qpos, kpos)
+        with jax.named_scope("rt.mla.sparse"):
+            rows = gather(keys, at)                 # [B, S, topk, cached]
+            latent, k_rope = rows[..., :r], rows[..., r:]
+            scores = (jnp.einsum("bshr,bskr->bshk",
+                                 _absorb(dims, wukv, q_nope), latent)
+                      + jnp.einsum("bshd,bskd->bshk", q_rope, k_rope)) \
+                * scale
+            p = _softmax(scores, real[:, :, None, :])
+            o = _unabsorb(dims, wukv, jnp.einsum(
+                "bshk,bskr->bshr", p.astype(latent.dtype), latent))
+        return o, at, real
+
+    o, at, real = _over_query_blocks(block, SPARSE_QUERY_BLOCK, q_nope,
+                                     q_rope, qpos, *index_query)
+    return o, {"selected": at[:, -1], "selected_real": real[:, -1]}
+
+
+def latent_mix(cfg: TransformerConfig, layer, h, positions, attend):
+    """The latent-attention mixer over the normed input ``h`` [B, S, E] of
+    the layer's kind (``mla``: a latent layer, ``swa``: a window layer).
+    -> (o [B, S, E], what ``attend`` kept, taps: an indexed layer's
+    selection for its last query, ``selected`` [B, topk] key positions and
+    ``selected_real``, where it selected; a window layer's ``window_keys``
+    [B], the keys its last query attended to)."""
+    kind = "latent" if "mla" in layer else "window"
+    p, dims, dt = layer[PARAMS_KEY[kind]], cfg.latent_dims(kind), cfg.dtype
+    window = cfg.window if kind == "window" else 0
+    r, eps = dims.kv_rank, cfg.norm_eps
+    wukv = p["wukv"].astype(dt)
+    with jax.named_scope("rt.mla.project"):
+        c_q = _rmsnorm(h @ p["wdq"].astype(dt), p["q_norm"], eps)
+        down = h @ p["wdkv"].astype(dt)
+        c_kv = _rmsnorm(down[..., :r], p["kv_norm"], eps)
+        if cfg.lora_rescale:
+            c_q = c_q * jnp.asarray(math.sqrt(cfg.d_model / dims.q_rank), dt)
+            c_kv = c_kv * jnp.asarray(math.sqrt(cfg.d_model / r), dt)
+        k_rope = _rope(down[:, :, None, r:], positions, dims.rope_theta)
+        q = jnp.einsum("bsr,rhd->bshd", c_q, p["wuq"].astype(dt))
+        q_nope = q[..., :dims.nope]
+        q_rope = _rope(q[..., dims.nope:], positions, dims.rope_theta)
+        new = {"latent": jnp.concatenate([c_kv, k_rope[:, :, 0]], -1)}
+    if "index" in p:
+        with jax.named_scope("rt.dsa.index"):
+            ix = p["index"]
+            qi = _rope(jnp.einsum("bsr,rjd->bsjd", c_q, ix["wq"].astype(dt)),
+                       positions, dims.rope_theta, dims.rope)
+            ki = _layer_norm(h @ ix["wk"].astype(dt), ix["k_norm_w"],
+                             ix["k_norm_b"])
+            new["index"] = _rope(ki[:, :, None], positions, dims.rope_theta,
+                                 dims.rope)[:, :, 0]
+            w = (h @ ix["ww"].astype(dt)).astype(jnp.float32) \
+                * (cfg.index_heads ** -0.5 * cfg.index_head_dim ** -0.5)
+    keys, kpos, kept = attend(new)
+    cached = keys["latent"]
+    taps = {}
+    if "index" in p and cached.shape[1] > cfg.index_topk:
+        o, taps = _sparse(cfg, dims, wukv, q_nope, q_rope, positions, cached,
+                          kpos, keys["index"], (qi, w))
+    else:
+        form = _expanded if h.shape[1] > 1 else _absorbed
+        with jax.named_scope("rt.mla.window" if window else "rt.mla.dense"):
+            o = form(dims, wukv, q_nope, q_rope, positions, cached, kpos,
+                     window)
+        if window:      # how many keys the last query's window held
+            taps = {"window_keys": _mask(positions[:, -1:], kpos,
+                                         window).sum(-1)[:, 0]}
+    with jax.named_scope("rt.mla.project"):
+        if "wg" in p:
+            o = _output_gate(o, h @ p["wg"].astype(dt))
+        return jnp.einsum("bshd,hde->bse", o, p["wo"].astype(dt)), kept, taps
+
+
+def ring_positions(last, rows: int):
+    """The positions a ring of ``rows`` slots holds once position ``last``
+    is written (slot = position mod rows) -> [rows] int32; a slot nothing
+    was written to reads as a position no query reaches."""
+    slot = jnp.arange(rows)
+    held = last - (last - slot) % rows
+    return jnp.where(held >= 0, held, _NEVER)

@@ -1,9 +1,10 @@
 """The expert layer: top-k routing with no capacity per expert over a share
 of the experts, with an optional shared expert.
 
-The router scores every published expert (``cfg.num_experts`` wide,
-softmax in float32), keeps the ``expert_top_k`` largest and, under
-``norm_topk_prob``, divides them by their sum. This program holds
+The router scores every published expert (``cfg.num_experts`` wide, in
+float32: softmax, or sigmoid with a correction bias that enters the
+selection and not the weights), keeps the ``expert_top_k`` largest and,
+under ``norm_topk_prob``, divides them by their sum. This program holds
 ``cfg.held`` experts from ``cfg.first_expert`` on: the (token, expert) pairs
 that fall on them are sorted by expert, their rows gathered, run through the
 experts as one grouped matmul a weight (``lax.ragged_dot``), weighted and
@@ -13,8 +14,9 @@ held elsewhere would add is left out, and nothing stands in for them or for
 their exchange: under expert parallelism that partial result is what this
 member of the group contributes (the all-to-all itself is not built; the
 ``expert`` logical axis still places the weights over an ``ep`` mesh axis,
-where GSPMD gathers them). The shared expert, ``sigmoid(w_g . x) *
-swiglu(x)``, is computed for every token.
+where GSPMD gathers them). The shared expert, ``swiglu(x)``, gated by
+``sigmoid(w_g . x)`` where it holds a ``gate``, is computed for every
+token.
 
 The buffer of routed rows is static: ``BUFFER_OVER_MEAN`` x the mean of the
 pairs routed here (tokens x top_k x held / experts), and never more than
@@ -46,20 +48,40 @@ from jax import lax
 BUFFER_OVER_MEAN = 4
 
 
+# A step of this few tokens or fewer (a decode step's rows) gets the most
+# any routing can send: the mean of so few draws says nothing about their
+# largest, and in serving a dropped row is a wrong answer.
+FEW_TOKENS = 64
+
+
 def buffer_rows(cfg, tokens: int) -> int:
     """Rows of the routed-pairs buffer for ``tokens`` tokens."""
     most = tokens * min(cfg.expert_top_k, cfg.held)
     mean = -(-tokens * cfg.expert_top_k * cfg.held // cfg.num_experts)
-    return min(most, BUFFER_OVER_MEAN * mean)
+    return most if tokens <= FEW_TOKENS \
+        else min(most, BUFFER_OVER_MEAN * mean)
 
 
-def route(cfg, router, x):
-    """x [N, D] -> (weights [N, k] float32, experts [N, k] int32)."""
-    logits = (x @ router.astype(x.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = lax.top_k(probs, cfg.expert_top_k)
+def route(cfg, moe_params, x):
+    """x [N, D] -> (weights [N, k] float32, experts [N, k] int32). Softmax
+    scoring: the k largest probabilities. Sigmoid scoring: the k experts of
+    largest ``sigmoid + router_bias``, weighted by the sigmoid alone (the
+    bias corrects the load, not the mixture), times
+    ``routed_scaling_factor``."""
+    logits = (x @ moe_params["router"].astype(x.dtype)).astype(jnp.float32)
+    if cfg.router_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_e = lax.top_k(
+            scores + moe_params["router_bias"].astype(jnp.float32),
+            cfg.expert_top_k)
+        top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+    else:
+        top_p, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                 cfg.expert_top_k)
     if cfg.norm_topk_prob:
         top_p = top_p / top_p.sum(-1, keepdims=True)
+    if cfg.routed_scaling_factor != 1.0:
+        top_p = top_p * cfg.routed_scaling_factor
     return top_p, top_e
 
 
@@ -72,7 +94,7 @@ def moe_apply(cfg, moe_params, h):
     rows = buffer_rows(cfg, n)
 
     with jax.named_scope("rt.moe.route"):
-        top_p, top_e = route(cfg, moe_params["router"], x)
+        top_p, top_e = route(cfg, moe_params, x)
         local = top_e - cfg.first_expert
         here = (local >= 0) & (local < held)
         # pairs held elsewhere sort behind every held expert
@@ -110,9 +132,12 @@ def moe_apply(cfg, moe_params, h):
             sh = moe_params["shared"]
             mid = jax.nn.silu(x @ sh["w1"].astype(dt)) \
                 * (x @ sh["w3"].astype(dt))
-            open_ = jax.nn.sigmoid(
-                (x @ sh["gate"].astype(dt)).astype(jnp.float32))
-            y = y + (mid @ sh["w2"].astype(dt)) * open_[:, None].astype(dt)
+            out = mid @ sh["w2"].astype(dt)
+            if "gate" in sh:
+                open_ = jax.nn.sigmoid(
+                    (x @ sh["gate"].astype(dt)).astype(jnp.float32))
+                out = out * open_[:, None].astype(dt)
+            y = y + out
     return y.reshape(b, s, d), stats
 
 

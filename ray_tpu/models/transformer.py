@@ -45,6 +45,23 @@ from ray_tpu.parallel.sharding import DEFAULT_RULES, LogicalRules
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentDims:
+    """Widths of one kind of latent-attention layer (models/latent.py)."""
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int                             # a head's q/k dims without RoPE
+    rope: int                             # ... with RoPE (the key's shared)
+    v: int
+    rope_theta: float = 10000.0
+
+    @property
+    def cached(self) -> int:
+        """Width of a cached position: the latent and the shared key."""
+        return self.kv_rank + self.rope
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 512
@@ -72,9 +89,33 @@ class TransformerConfig:
     expert_ff: Optional[int] = None       # None -> ff_dim
     shared_expert_ff: int = 0             # 0 = no shared expert
     tied_embeddings: bool = False
-    # Hybrid block. ``layer_types``: one period of "full" | "linear", () =
-    # every layer full attention; n_layers is a multiple of its length.
+    router_scoring: str = "softmax"       # | "sigmoid": selection by score
+    #                                       + a correction bias, weights by
+    #                                       the score alone
+    routed_scaling_factor: float = 1.0    # on the routed experts' weights
+    shared_expert_gate: bool = True       # sigmoid(w_g . x) on the shared
+    # Hybrid block. ``layer_types``: one period of "full" | "linear" |
+    # "latent" | "window", () = every layer full attention; after
+    # ``first_dense_layers`` leading layers of the period's first kind, with
+    # a dense MLP whatever ``num_experts`` says, n_layers is a multiple of
+    # its length.
     layer_types: Tuple[str, ...] = ()
+    first_dense_layers: int = 0
+    norm_eps: float = 1e-6                # RMSNorm epsilon
+    # Latent attention (models/latent.py): "latent" layers attend to every
+    # earlier position or, where ``index_topk`` is set and there are more,
+    # to the ``index_topk`` that a learned indexer of ``index_heads`` heads
+    # scores highest; "window" layers to the last ``window`` positions, the
+    # query's own included.
+    latent: Optional[LatentDims] = None
+    window_latent: Optional[LatentDims] = None
+    window: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 128
+    index_topk: int = 0
+    attn_gate: str = ""                   # "headwise": sigmoid(w_g . x), one
+    #                                       scalar a head, on a latent layer
+    lora_rescale: bool = False            # latents times sqrt(d / rank)
     head_width: Optional[int] = None      # None -> d_model / n_heads
     partial_rotary_factor: float = 1.0    # share of a head RoPE rotates
     qk_norm: bool = False                 # RMSNorm of q and k per head
@@ -94,14 +135,35 @@ class TransformerConfig:
     sandwich_norm: bool = False           # a norm on each sublayer's output
 
     def __post_init__(self):
-        kinds = set(self.layer_types)
-        if kinds - {"full", "linear"}:
+        if set(self.layer_types) - {"full", "linear", "latent", "window"}:
             raise ValueError(f"layer_types {self.layer_types}: each is "
-                             "'full' or 'linear'")
-        if self.layer_types and self.n_layers % len(self.layer_types):
-            raise ValueError(f"n_layers {self.n_layers} is not a multiple "
-                             f"of the period {len(self.layer_types)}")
-        if "linear" in kinds and not (self.linear_key_heads
+                             "'full', 'linear', 'latent' or 'window'")
+        if self.layer_types and \
+                (self.n_layers - self.first_dense_layers) \
+                % len(self.layer_types):
+            raise ValueError(
+                f"n_layers {self.n_layers} less the {self.first_dense_layers}"
+                " leading dense layers is not a multiple of the period "
+                f"{len(self.layer_types)}")
+        if self.first_dense_layers and not (self.layer_types
+                                            and self.num_experts):
+            raise ValueError("first_dense_layers needs a layer pattern and "
+                             "an expert layer to precede")
+        if "latent" in self.layer_types and self.latent is None:
+            raise ValueError("latent layers need their widths (latent)")
+        if "window" in self.layer_types and \
+                not (self.window_latent and self.window):
+            raise ValueError("window layers need their widths "
+                             "(window_latent) and a window")
+        if self.index_topk and not self.index_heads:
+            raise ValueError("index_topk needs index_heads")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_scoring {self.router_scoring!r}: "
+                             "'softmax' or 'sigmoid'")
+        if self.attn_gate not in ("", "headwise"):
+            raise ValueError(f"attn_gate {self.attn_gate!r}: '' or "
+                             "'headwise'")
+        if "linear" in self.layer_types and not (self.linear_key_heads
                                       and self.linear_value_heads):
             raise ValueError("linear layers need linear_key_heads and "
                              "linear_value_heads")
@@ -132,6 +194,20 @@ class TransformerConfig:
                 "(the batcher and generate assume it does) and the cache "
                 "slots of the skipped steps would stay unwritten; every "
                 "position runs all loop_steps here")
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """One period's layer kinds; the leading dense layers are of the
+        first."""
+        return self.layer_types or ("full",)
+
+    @property
+    def periods(self) -> int:
+        return (self.n_layers - self.first_dense_layers) \
+            // max(len(self.layer_types), 1)
+
+    def latent_dims(self, kind: str) -> LatentDims:
+        return self.latent if kind == "latent" else self.window_latent
 
     @property
     def kv_heads(self) -> int:
@@ -168,8 +244,9 @@ class TransformerConfig:
 # init
 # ---------------------------------------------------------------------------
 
-def _layer_init(key, cfg: TransformerConfig, kind: str = "full"
-                ) -> Dict[str, Any]:
+def _layer_init(key, cfg: TransformerConfig, kind: str = "full",
+                dense: bool = False) -> Dict[str, Any]:
+    """``dense``: a dense MLP whatever ``cfg.num_experts`` says."""
     d, h, hk, hd, f = (cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim,
                        cfg.ff_dim)
     ks = jax.random.split(key, 8)
@@ -191,6 +268,9 @@ def _layer_init(key, cfg: TransformerConfig, kind: str = "full"
         if cfg.qk_norm:
             layer["attn"]["q_norm"] = norm((hd,), pd)
             layer["attn"]["k_norm"] = norm((hd,), pd)
+    elif kind in ("latent", "window"):
+        from ray_tpu.models.latent import PARAMS_KEY, latent_init
+        layer[PARAMS_KEY[kind]] = latent_init(ks[0], cfg, kind)
     else:
         gk = jax.random.split(ks[0], 5)
         hv = cfg.linear_value_heads
@@ -207,7 +287,7 @@ def _layer_init(key, cfg: TransformerConfig, kind: str = "full"
             "norm": jnp.ones((cfg.linear_value_dim,), pd),
             "out": init(gk[4], (vd, d), pd),
         }
-    if cfg.num_experts:
+    if cfg.num_experts and not dense:
         ek = jax.random.split(ks[4], 8)
         e, ef, sf = cfg.held, cfg.expert_ff_dim, cfg.shared_expert_ff
         layer["moe"] = {
@@ -216,13 +296,18 @@ def _layer_init(key, cfg: TransformerConfig, kind: str = "full"
             "w3": init(ek[2], (e, d, ef), pd),
             "w2": init(ek[3], (e, ef, d), pd),
         }
+        if cfg.router_scoring == "sigmoid":
+            # the correction bias of aux-loss-free balancing: trained by
+            # the balancer, not the loss, from zero
+            layer["moe"]["router_bias"] = jnp.zeros((cfg.num_experts,), pd)
         if sf:
             layer["moe"]["shared"] = {
                 "w1": init(ek[4], (d, sf), pd),
                 "w3": init(ek[5], (d, sf), pd),
                 "w2": init(ek[6], (sf, d), pd),
-                "gate": init(ek[7], (d,), pd),
             }
+            if cfg.shared_expert_gate:
+                layer["moe"]["shared"]["gate"] = init(ek[7], (d,), pd)
     else:
         layer["mlp"] = {
             "w1": init(ks[5], (d, f), pd),
@@ -235,10 +320,16 @@ def _layer_init(key, cfg: TransformerConfig, kind: str = "full"
 def transformer_init(key, cfg: TransformerConfig) -> Dict[str, Any]:
     """``params["layers"]``: the layer tree stacked on a leading dim, or,
     under a layer pattern, a tuple of such stacks, one a position of the
-    period, each stacked over the periods."""
+    period, each stacked over the periods; the leading dense layers are a
+    stack of their own before it, ``params["dense_layers"]``."""
     k_emb, k_layers, k_head = jax.random.split(key, 3)
     init = jax.nn.initializers.normal(0.02)
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
+    lead = cfg.first_dense_layers
+    dense_layers = jax.vmap(lambda k: _layer_init(
+        k, cfg, cfg.kinds[0], dense=True))(layer_keys[:lead]) \
+        if lead else None
+    layer_keys = layer_keys[lead:]
     if cfg.layer_types:
         period = len(cfg.layer_types)
         stacked = tuple(
@@ -257,6 +348,8 @@ def transformer_init(key, cfg: TransformerConfig) -> Dict[str, Any]:
         "layers": stacked,
         "final_norm": norm((cfg.d_model,), cfg.param_dtype),
     }
+    if lead:
+        params["dense_layers"] = dense_layers
     if not cfg.tied_embeddings:
         params["lm_head"] = init(k_head, (cfg.d_model, cfg.vocab_size),
                                  cfg.param_dtype)
@@ -275,7 +368,7 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     def L(*axes):  # layer leaf: leading stacked dim(s)
         return stage + axes
 
-    def layer_axes(kind: str) -> Dict[str, Any]:
+    def layer_axes(kind: str, dense: bool = False) -> Dict[str, Any]:
         layer = {"ln1": L("embed"), "ln2": L("embed")}
         if cfg.sandwich_norm:
             layer["ln1_post"] = L("embed")
@@ -290,6 +383,9 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
             if cfg.qk_norm:
                 layer["attn"]["q_norm"] = L(None)
                 layer["attn"]["k_norm"] = L(None)
+        elif kind in ("latent", "window"):
+            from ray_tpu.models.latent import PARAMS_KEY, latent_axes
+            layer[PARAMS_KEY[kind]] = latent_axes(cfg, kind, L)
         else:
             # the packed projections keep their columns whole: q, k, v and
             # z blocks of unequal width do not split over tp
@@ -298,18 +394,22 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                 "conv": L(None, None), "dt_bias": L(None), "A_log": L(None),
                 "norm": L(None), "out": L(None, "embed"),
             }
-        if cfg.num_experts:
+        if cfg.num_experts and not dense:
             layer["moe"] = {
                 "router": L("embed", None),
                 "w1": L("expert", "embed", "expert_mlp"),
                 "w3": L("expert", "embed", "expert_mlp"),
                 "w2": L("expert", "expert_mlp", "embed"),
             }
+            if cfg.router_scoring == "sigmoid":
+                layer["moe"]["router_bias"] = L(None)
             if cfg.shared_expert_ff:
                 layer["moe"]["shared"] = {
                     "w1": L("embed", "mlp"), "w3": L("embed", "mlp"),
-                    "w2": L("mlp", "embed"), "gate": L("embed"),
+                    "w2": L("mlp", "embed"),
                 }
+                if cfg.shared_expert_gate:
+                    layer["moe"]["shared"]["gate"] = L("embed")
         else:
             layer["mlp"] = {
                 "w1": L("embed", "mlp"),
@@ -324,6 +424,8 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         if cfg.layer_types else layer_axes("full"),
         "final_norm": ("embed",),
     }
+    if cfg.first_dense_layers:
+        axes["dense_layers"] = layer_axes(cfg.kinds[0], dense=True)
     if not cfg.tied_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     if cfg.loop_steps > 1:
@@ -343,7 +445,8 @@ def _rmsnorm(x, scale, eps=1e-6):
 def _norm(cfg: TransformerConfig, x, w):
     """RMSNorm over the last dim with the configuration's scale: ``w``, or
     ``1 + w`` (``norm_plus_one``)."""
-    return _rmsnorm(x, 1.0 + w if cfg.norm_plus_one else w)
+    return _rmsnorm(x, 1.0 + w if cfg.norm_plus_one else w,
+                    cfg.norm_eps)
 
 
 def _rope(x, positions, theta: float, rotary_dim: Optional[int] = None):
@@ -378,7 +481,7 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh,
 def _feed_forward(cfg: TransformerConfig, layer, h):
     """-> (y, stats): ``stats`` is the expert layer's counters (None for
     the dense MLP)."""
-    if cfg.num_experts:
+    if "moe" in layer:
         from ray_tpu.models.moe import moe_apply
         return moe_apply(cfg, layer["moe"], h)
     dt = cfg.dtype
@@ -408,6 +511,10 @@ def _full_attention_mix(cfg: TransformerConfig, a, h, positions, attend):
 
 
 def _output_gate(o, gate):
+    """o [B, S, H, D] times ``sigmoid(gate)``: element-wise where the gate
+    has o's shape, head-wise where it is [B, S, H], one scalar a head."""
+    if gate.ndim == o.ndim - 1:
+        gate = gate[..., None]
     return o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
 
 
@@ -446,51 +553,78 @@ def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend,
                  mesh=None, rules: LogicalRules = DEFAULT_RULES):
     """One block: ``x + mixer(norm(x))``, then ``+ feed_forward(norm(.))``.
     The mixer is the layer's own: softmax attention where it holds
-    ``attn``, the gated delta rule where it holds ``gdn``; where the layer
+    ``attn``, the gated delta rule where it holds ``gdn``, latent attention
+    where it holds ``mla`` or ``swa`` (models/latent.py); where the layer
     holds ``ln1_post`` and ``ln2_post`` each sublayer's output is normed
-    before it joins the residual (sandwich norm). ``attend(q, k,
-    v) -> (o, kept)`` is all that differs between training, prefill and
-    decode (models/generate.py): what attention does with the rotated k
-    and v, and what it keeps of them; ``mesh``/``rules`` are the gated
-    delta rule's, whose kernels run per shard. -> (x, kept, expert-layer
-    stats)."""
+    before it joins the residual (sandwich norm). ``attend`` is all that
+    differs between training, prefill and decode (models/generate.py):
+    ``attend(q, k, v) -> (o, kept)`` is what softmax attention does with
+    the rotated k and v, and what it keeps of them; ``attend(new) -> (keys,
+    key positions, kept)`` is what a latent layer's new cached entries
+    join, and what is kept of them. ``mesh``/``rules`` are the gated delta
+    rule's, whose kernels run per shard. -> (x, kept, stats: the expert
+    layer's counters and a latent layer's selection, or None)."""
     h = _norm(cfg, x, layer["ln1"])
+    taps = {}
     if "attn" in layer:
         # the gated variant's device time is found by this scope
         with jax.named_scope("rt.attn.gated") if cfg.attn_output_gate \
                 else contextlib.nullcontext():
             o, kept = _full_attention_mix(cfg, layer["attn"], h, positions,
                                           attend)
-    else:
+    elif "gdn" in layer:
         o, kept = _gated_delta_mix(cfg, layer["gdn"], h, mesh, rules), None
+    else:
+        from ray_tpu.models.latent import latent_mix
+        o, kept, taps = latent_mix(cfg, layer, h, positions, attend)
     if "ln1_post" in layer:
         o = _norm(cfg, o, layer["ln1_post"])
     x = x + o
     y, stats = _feed_forward(cfg, layer, _norm(cfg, x, layer["ln2"]))
     if "ln2_post" in layer:
         y = _norm(cfg, y, layer["ln2_post"])
+    if taps:
+        stats = {**(stats or {}), **taps}
     return x + y, kept, stats
 
 
 def _stage_scan(cfg: TransformerConfig, mesh, stage_layers, x, positions,
-                rules: LogicalRules = DEFAULT_RULES):
+                rules: LogicalRules = DEFAULT_RULES, dense_layers=None):
     """Apply a stack of layers (leading dim = layers, or under a layer
-    pattern a tuple of stacks with leading dim = periods) with lax.scan.
-    ``rules``: what the caller sharded params and batch by over ``mesh``.
+    pattern a tuple of stacks with leading dim = periods, after the stack
+    of leading ``dense_layers``) with lax.scan. ``rules``: what the caller
+    sharded params and batch by over ``mesh``.
     -> (x, the expert layers' stats stacked over the scan, or None)."""
-    body = partial(
-        _layer_apply, cfg, mesh=mesh, rules=rules,
-        attend=lambda q, k, v: (_attention(cfg, q, k, v, mesh, rules), None))
-    if cfg.remat:
-        # a layer without the rule's kernels holds nothing under the name,
-        # and is remat'd whole as by a bare jax.checkpoint
-        body = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.save_only_these_names(KEPT))
+    def softmax(q, k, v):
+        return _attention(cfg, q, k, v, mesh, rules), None
+
+    def whole(new):             # a latent layer's keys: the sequence's own
+        return new, positions, None
+
+    def body_of(kind: str):
+        body = partial(_layer_apply, cfg, mesh=mesh, rules=rules,
+                       attend=whole if kind in ("latent", "window")
+                       else softmax)
+        if cfg.remat:
+            # a layer without the rule's kernels holds nothing under the
+            # name, and is remat'd whole as by a bare jax.checkpoint
+            body = jax.checkpoint(
+                body,
+                policy=jax.checkpoint_policies.save_only_these_names(KEPT))
+        return body
+
+    kinds = cfg.kinds
+    bodies = {kind: body_of(kind) for kind in kinds}
+    if dense_layers is not None:
+        x, _ = lax.scan(
+            lambda carry, layer: (bodies[kinds[0]](layer, carry,
+                                                   positions)[0], None),
+            x, dense_layers)
 
     def step(carry, period):
         stats = []
-        for layer in period:
-            carry, _, layer_stats = body(layer, carry, positions)
+        for kind, layer in zip(kinds, period):
+            carry, _, layer_stats = bodies[kind](layer, carry, positions)
             stats.append(layer_stats)
         return carry, stats if cfg.num_experts else None
 
@@ -587,7 +721,8 @@ def _logits_and_stats(params, tokens, cfg: TransformerConfig, mesh,
     else:
         x, stats, exits = _over_loop_steps(
             cfg, params, lambda x, _, t: _stage_scan(
-                cfg, mesh, params["layers"], x, positions, rules), x)
+                cfg, mesh, params["layers"], x, positions, rules,
+                params.get("dense_layers")), x)
     return _head(params, x, cfg), stats, exits
 
 

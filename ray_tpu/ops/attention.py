@@ -112,6 +112,18 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
     return out.astype(q.dtype)
 
 
+def auto_path(sq: int, sk: int, d: int) -> str:
+    """The path ``impl="auto"`` takes for ``sq`` queries over ``sk`` keys of
+    head width ``d``: ``flash`` on a TPU when all three are multiples of
+    128 (a 192-wide head is not), else ``blockwise`` from 2,048 queries on,
+    else ``reference``. The caller of a compiled program puts it on its
+    span (``generate.call_span``'s ``attention_path``), so that leaving the
+    flash path is seen."""
+    if _on_tpu() and sq % 128 == 0 and sk % 128 == 0 and d % 128 == 0:
+        return "flash"
+    return "blockwise" if sq >= 2048 else "reference"
+
+
 def mha(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
         block_size: int = 512, impl: str = "auto", mesh=None,
         rules: LogicalRules = DEFAULT_RULES):
@@ -131,12 +143,11 @@ def mha(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     if impl == "flash":
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                mesh=mesh, rules=rules)
-    # auto
-    sq, d = q.shape[1], q.shape[3]
-    if _on_tpu() and sq % 128 == 0 and k.shape[1] % 128 == 0 and d % 128 == 0:
+    path = auto_path(q.shape[1], k.shape[1], q.shape[3])
+    if path == "flash":
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                mesh=mesh, rules=rules)
-    if sq >= 2048:
+    if path == "blockwise":
         return blockwise_attention(q, k, v, causal=causal, scale=scale,
                                    block_size=block_size)
     return attention_reference(q, k, v, causal=causal, scale=scale)

@@ -191,8 +191,13 @@ EVENT_KINDS: Dict[str, str] = {
                      "loop's decode_segments with the cache positions a "
                      "slot's attention reads over the call's steps and "
                      "those written by then (cache_positions_read/"
-                     "cache_positions_needed) and, of a looped stack, "
-                     "exit_steps_mean",
+                     "cache_positions_needed), attention_path (the path "
+                     "the prompt's attention took: flash/blockwise/"
+                     "reference as ops.attention.auto_path chose, or "
+                     "latent), of a looped stack exit_steps_mean, and of "
+                     "a stack by kind cache_bytes_latent/_index/_window, "
+                     "prefill_chunks, index_topk, keys_scored, "
+                     "keys_attended, moe_rows_here, moe_rows_dropped",
     "train.pump": "span: value = seconds of one synchronized report "
                   "round; attrs carry iteration/lag_s",
     # start-up

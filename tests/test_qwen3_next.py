@@ -493,38 +493,93 @@ def test_a_small_share_counts_what_its_buffer_drops():
     assert int(stats["load"][0]) == tokens
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """Four shares of four experts each: their partial results, with the
-    shared expert (which every chip computes alike) counted once, are the
-    reference's layer over all sixteen."""
+def softmax_shares():
+    """Four shares of four experts each, of the hybrid block's layer: (the
+    uncut configuration, a share's, the layer's ``moe`` tree, the shares'
+    first experts, top_k)."""
     whole = program_config()
-    params = seeded(whole)
-    layer, w = layer_of(whole, params, 2)
+    layer, _ = layer_of(whole, seeded(whole), 2)
+    return (whole, lambda first: program_config(experts_held=4,
+                                                first_expert=first),
+            layer["moe"], range(0, 16, 4), 2)
+
+
+def sigmoid_shares():
+    """Eight shares of 32 experts each, routed by sigmoid with a correction
+    bias that moves the selection, an ungated shared expert, top 8 of 256:
+    the routing of the ``dots3`` family at toy widths."""
+    def cfg(held, first=0):
+        return TransformerConfig(
+            d_model=64, num_experts=256, experts_held=held,
+            first_expert=first, expert_top_k=8, norm_topk_prob=True,
+            expert_ff=16, shared_expert_ff=16, shared_expert_gate=False,
+            router_scoring="sigmoid", routed_scaling_factor=1.5,
+            dtype=jnp.float32)
+    whole = cfg(256)
+    m = seeded_tree(transformer._layer_init(jax.random.PRNGKey(4), whole)[
+        "moe"], 5)
+    return whole, lambda first: cfg(32, first), m, range(0, 256, 32), 8
+
+
+def seeded_tree(tree, seed):
+    leaves, struct = jax.tree.flatten(tree)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return jax.tree.unflatten(struct, [
+        x + 0.05 * jax.random.normal(k, x.shape, x.dtype)
+        for x, k in zip(leaves, keys)])
+
+
+@pytest.mark.parametrize("shares", [softmax_shares, sigmoid_shares])
+def test_the_shares_add_up_to_the_uncut_layer(shares):
+    """The shares' partial results, with the shared expert (which every
+    chip computes alike) counted once, are the uncut layer over all the
+    experts; under softmax routing each is also the reference's."""
+    whole, share_cfg_at, m, firsts, top_k = shares()
+    n = len(firsts)
+    held = whole.num_experts // n
     h = hidden()
-    with jax.default_matmul_precision("highest"):
-        want = reference._experts(h, w, CONFIG)
-    m = layer["moe"]
+    want, _ = moe.moe_apply(whole, m, h)
+    if shares is softmax_shares:
+        _, w = layer_of(whole, seeded(whole), 2)
+        with jax.default_matmul_precision("highest"):
+            close(want, reference._experts(h, w, CONFIG))
+    routing = {k: v for k, v in m.items() if k.startswith("router")}
     total, rows = 0.0, 0
-    for first in range(0, 16, 4):
-        share_cfg = program_config(experts_held=4, first_expert=first)
-        share = {"router": m["router"],
-                 **{k: m[k][first:first + 4] for k in ("w1", "w3", "w2")}}
-        routed, stats = moe.moe_apply(share_cfg, share, h)
+    for first in firsts:
+        share = {**routing, **{k: m[k][first:first + held]
+                               for k in ("w1", "w3", "w2")}}
+        routed, stats = moe.moe_apply(share_cfg_at(first), share, h)
         total = total + routed
         rows += int(stats["rows_here"])
-        # the reference, given the same share, gives the same part
-        share_config = {**CONFIG, "num_experts": 4, "first_expert": first}
-        zero_shared = {**w, **{k: w[k][first:first + 4]
-                               for k in ("w1", "w3", "w2")},
-                       "shared_w2": jnp.zeros_like(w["shared_w2"])}
-        with jax.default_matmul_precision("highest"):
-            close(routed, reference._experts(h, zero_shared, share_config))
+        if shares is softmax_shares:
+            # the reference, given the same share, gives the same part
+            share_config = {**CONFIG, "num_experts": 4,
+                            "first_expert": first}
+            zero_shared = {**w, **{k: w[k][first:first + 4]
+                                   for k in ("w1", "w3", "w2")},
+                           "shared_w2": jnp.zeros_like(w["shared_w2"])}
+            with jax.default_matmul_precision("highest"):
+                close(routed,
+                      reference._experts(h, zero_shared, share_config))
     shared_only, _ = moe.moe_apply(
-        program_config(experts_held=1, first_expert=0),
-        {"router": m["router"], "shared": m["shared"],
-         **{k: jnp.zeros_like(m[k][:1]) for k in ("w1", "w3", "w2")}}, h)
+        share_cfg_at(0),
+        {**routing, "shared": m["shared"],
+         **{k: jnp.zeros_like(m[k][:held]) for k in ("w1", "w3", "w2")}}, h)
     close(total + shared_only, want)
-    assert rows == 2 * h.shape[0] * h.shape[1]      # every pair, once
+    assert rows == top_k * h.shape[0] * h.shape[1]      # every pair, once
+
+
+def test_the_correction_bias_moves_the_selection_and_not_the_weights():
+    whole, _, m, _, _ = sigmoid_shares()
+    x = hidden().reshape(-1, 64)
+    w, e = moe.route(whole, m, x)
+    scores = jax.nn.sigmoid(x @ m["router"])
+    picked = jnp.take_along_axis(scores, e, -1)
+    close(w, 1.5 * picked / picked.sum(-1, keepdims=True))
+    _, unbiased = moe.route(whole, {**m, "router_bias": jnp.zeros(256)}, x)
+    assert not np.array_equal(np.sort(e, -1), np.sort(unbiased, -1))
+    want = jax.lax.top_k(scores + m["router_bias"], 8)[1]
+    np.testing.assert_array_equal(np.sort(e, -1), np.sort(want, -1))
 
 
 def reference_arrays(params):
@@ -643,7 +698,9 @@ def test_generate_refuses_recurrent_layers_in_words():
 def test_a_layer_pattern_is_checked_where_it_is_configured():
     with pytest.raises(ValueError, match="multiple of the period"):
         program_config(n_layers=6)
-    with pytest.raises(ValueError, match="'full' or 'linear'"):
+    with pytest.raises(ValueError, match="'full', 'linear', 'latent' or"):
+        program_config(layer_types=("full", "banded"))
+    with pytest.raises(ValueError, match="window layers need their widths"):
         program_config(layer_types=("full", "window"))
     with pytest.raises(ValueError, match="linear_key_heads"):
         program_config(linear_key_heads=0)
