@@ -29,8 +29,20 @@ and wherever each query has keys of its own (the selection), whose expanded
 keys nothing could hold. Queries go through either in blocks, so that no
 ``[heads, queries, keys]`` score tensor is whole at once.
 
+The selection is a SET: ``select`` hands a query's ``index_topk`` key
+positions to a gather and a softmax that sums over them, and nothing reads
+their order. So no row of scores is sorted (XLA lowers ``lax.top_k`` on a
+TPU to a full sort of the row, 32,896 keys padded to 65,536: 45 us a row).
+The row's k-th largest score is found exactly, by bisection on the float32
+bits folded into integer order (32 counts along the row, one loop), the
+keys above it and the lowest-positioned of those equal to it are marked,
+and the marks are compacted into ``index_topk`` slots a tile of 128 keys at
+a time with three small matrix products: 3 us a row on a v5e at 32,896 keys
+(``_top_set``). Exact in float32, to the last key and tie what
+``lax.top_k`` selects.
+
 Scopes in the compiled program: ``rt.mla.project`` (the projections and the
-output), ``rt.dsa.index`` (the indexer's scores and the top-k),
+output), ``rt.dsa.index`` (the indexer's scores and the selection),
 ``rt.mla.sparse`` (the gather and the attention over the selection),
 ``rt.mla.window`` (a window layer's attention), ``rt.mla.dense`` (a latent
 layer's attention over all keys, where there are no more than
@@ -56,6 +68,8 @@ SPARSE_QUERY_BLOCK = 128
 DENSE_QUERY_BLOCK = 512
 # Indexer heads scored at once: [B, group, block, keys] float32 is held.
 INDEX_HEAD_GROUP = 16
+# Keys a tile of the selection's compaction: one row of lanes.
+SELECT_TILE = 128
 LAYER_NORM_EPS = 1e-6
 _NEVER = jnp.iinfo(jnp.int32).max     # the position of a slot never written
 
@@ -206,15 +220,97 @@ def index_scores(qi, w, ki):
                     groups)[0]
 
 
+def _ordered_bits(x):
+    """float32 -> uint32 whose integer order is the floats' total order
+    (``-inf`` below every finite value, ``-0.0`` below ``+0.0``)."""
+    u = lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+_LEAST_KEY = 0x007FFFFF         # _ordered_bits(-inf): a masked key
+
+
+def _kth_largest(keys, k: int):
+    """keys [..., T] uint32, T >= k -> the k-th largest of each row [...]:
+    the largest v with ``count(keys >= v) >= k``, a bit a pass from the
+    top (32 counts along the row; no order among the keys is made)."""
+    def bit(i, thr):
+        cand = thr | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = (keys >= cand[..., None]).sum(-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, thr)
+
+    return lax.fori_loop(0, 32, bit, jnp.zeros(keys.shape[:-1], jnp.uint32))
+
+
+def _running_count(mask):
+    """mask [..., SELECT_TILE] of 0 / 1 -> how many are set up to and with
+    each lane (int32), as a product with a triangle of ones: 0 / 1 in
+    bfloat16 and sums of at most SELECT_TILE of them in float32 are
+    exact."""
+    lane = jnp.arange(SELECT_TILE)
+    upto = (lane[:, None] <= lane[None, :]).astype(jnp.bfloat16)
+    return jnp.einsum("...l,lm->...m", mask.astype(jnp.bfloat16), upto,
+                      preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _top_set(scores, topk: int):
+    """scores [B, S, T] float32 -> (at [B, S, topk] int32, real [B, S,
+    topk]): the positions ``lax.top_k(scores, topk)`` returns, as a set,
+    ascending; ``-inf`` is never selected, and the slots a row does not
+    fill are not real and point at key 0."""
+    b, s, t = scores.shape
+    tiles = -(-max(t, topk) // SELECT_TILE)
+    keys = _ordered_bits(jnp.pad(
+        scores, ((0, 0), (0, 0), (0, tiles * SELECT_TILE - t)),
+        constant_values=-jnp.inf)).reshape(b, s, tiles, SELECT_TILE)
+    thr = _kth_largest(keys.reshape(b, s, -1), topk)[..., None, None]
+    above, tie = keys > thr, keys == thr
+    # the k-th value's ties, lowest positions first, fill what is left
+    tie_upto = _running_count(tie)
+    tie_before = jnp.cumsum(tie_upto[..., -1], -1) - tie_upto[..., -1]
+    need = topk - above.sum((-1, -2), dtype=jnp.int32)
+    chosen = (above | (tie & (tie_before[..., None] + tie_upto
+                              <= need[..., None, None]))) \
+        & (keys > _LEAST_KEY)
+    # compaction: slot j holds the (j + 1)-th chosen key of the row. Its
+    # tile, by the running sum of the tiles' counts; that tile's lanes
+    # fetched by a one-hot product, each chosen lane reading its rank in
+    # the row modulo the tile (no two alike within a tile, and 0 / 1 and
+    # ranks up to SELECT_TILE are exact in bfloat16); the lane that reads
+    # j's.
+    upto = _running_count(chosen)
+    count = upto[..., -1]                               # [B, S, tiles]
+    end = jnp.cumsum(count, -1)
+    start = end - count
+    rank = jnp.where(chosen, (start[..., None] + upto) % SELECT_TILE + 1, 0)
+    slot = jnp.arange(topk)
+    # summed over the tiles with the slots along the lanes: 9 x faster on
+    # the chip than with the tiles along them
+    tile = (end[..., :, None] <= slot).sum(-2)
+    here = tile[..., None] == jnp.arange(tiles)
+    fetched = jnp.einsum("bsjt,bstl->bsjl", here.astype(jnp.bfloat16),
+                         rank.astype(jnp.bfloat16),
+                         preferred_element_type=jnp.bfloat16)
+    wanted = ((slot + 1) % SELECT_TILE + 1).astype(jnp.bfloat16)
+    lane = ((fetched == wanted[:, None]) * jnp.arange(SELECT_TILE)).sum(-1)
+    real = slot < end[..., -1:]
+    return jnp.where(real, tile * SELECT_TILE + lane, 0).astype(jnp.int32), \
+        real
+
+
 def select(topk: int, qi, w, ki, qpos, kpos):
     """-> (positions in the keys [B, S, topk] int32, which of them are
     real [B, S, topk]): the ``topk`` keys ``s <= t`` of largest index
     score; a query with fewer keys than that selects them all, and the
-    rest of its row is marked not real."""
+    rest of its row is marked not real. What is guaranteed is the set
+    ``{at[real]}``: it is the set ``lax.top_k`` of the masked float32
+    scores returns (ties at the k-th value go to the lowest positions;
+    ``-0.0`` counts as under ``+0.0``). The order within a row is free
+    (ascending positions today) and nothing downstream reads it; a slot
+    that is not real holds position 0. The module comment has the cost."""
     scores = jnp.where(_mask(qpos, kpos, 0), index_scores(qi, w, ki),
                        -jnp.inf)
-    top, at = lax.top_k(scores, topk)
-    return at, top > -jnp.inf
+    return _top_set(scores, topk)
 
 
 def _sparse(cfg, dims, wukv, q_nope, q_rope, qpos, keys, kpos, index_keys,
