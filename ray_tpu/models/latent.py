@@ -17,7 +17,8 @@ the normed latent and the one rotated key all heads share) and, in an
 indexed layer, the indexer's key ``kI``. The mixer makes a position's
 entries, hands them to ``attend(new) -> (keys, key positions, kept)`` and
 attends to what comes back: the sequence's own entries in the training
-forward, a cache with the entries written in prefill and decode
+forward (key positions None: index i holds position i, and a gradient may
+be taken), a cache with the entries written in prefill and decode
 (models/generate.py). Nothing else differs between the three.
 
 Two forms of one product. *Expanded*: every key's ``k_nope`` and ``v`` are
@@ -27,7 +28,12 @@ into the query and ``W_uv`` applied to the attended latents, ``(2 kv_rank +
 rope)`` a head and pair and nothing per key: for one query (a decode step),
 and wherever each query has keys of its own (the selection), whose expanded
 keys nothing could hold. Queries go through either in blocks, so that no
-``[heads, queries, keys]`` score tensor is whole at once.
+``[heads, queries, keys]`` score tensor is whole at once. Over the
+sequence's own keys the expanded form is plain multi-head attention with k =
+``[k_nope ; k_rope]`` (``nope + rope`` wide) and v (``v`` wide): there it
+runs in the flash kernels, which take a value width of its own, or in query
+blocks that the backward pass makes again, so that a latent layer trains at
+8k and holds no block's scores across its backward.
 
 The selection is a SET: ``select`` hands a query's ``index_topk`` key
 positions to a gather and a softmax that sums over them, and nothing reads
@@ -52,6 +58,7 @@ layer's attention over all keys, where there are no more than
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +66,7 @@ from jax import lax
 
 from ray_tpu.models.transformer import (LatentDims, TransformerConfig,
                                         _output_gate, _rmsnorm, _rope)
+from ray_tpu.ops.flash import _on_tpu, flash_attention
 
 PARAMS_KEY = {"latent": "mla", "window": "swa"}
 # Queries a block: of the selection (each query gathers index_topk rows of
@@ -66,6 +74,9 @@ PARAMS_KEY = {"latent": "mla", "window": "swa"}
 # attention over shared keys.
 SPARSE_QUERY_BLOCK = 128
 DENSE_QUERY_BLOCK = 512
+# A sequence over its own keys is filled up to a multiple of this for the
+# flash kernels: the larger of their default blocks (ops/flash.py).
+FLASH_MULTIPLE = 1024
 # Indexer heads scored at once: [B, group, block, keys] float32 is held.
 INDEX_HEAD_GROUP = 16
 # Keys a tile of the selection's compaction: one row of lanes.
@@ -161,13 +172,40 @@ def _mask(qpos, kpos, window: int):
     return (k <= q) & (q - k < window) if window else (k <= q)
 
 
-def _expanded(dims, wukv, q_nope, q_rope, qpos, keys, kpos, window):
-    """Every key's k_nope and v from its latent; queries in blocks."""
+def _flash_own(impl: str, s: int) -> bool:
+    """Whether a sequence of ``s`` positions over its own keys goes through
+    the flash kernels: ``"flash"`` is the kernel or an error, ``"auto"``
+    the kernel on a TPU from a length on that fills its blocks."""
+    return impl == "flash" or (impl == "auto" and _on_tpu()
+                               and s >= FLASH_MULTIPLE)
+
+
+def _expanded(dims, wukv, q_nope, q_rope, qpos, keys, kpos, window,
+              own: str = ""):
+    """Every key's k_nope and v from its latent; queries in blocks.
+    ``own``: the keys are the queries' own sequence, position i at index i
+    (the training forward), and a gradient may be taken, under the
+    configuration's ``attn_impl``: the expanded form is plain multi-head
+    attention with k = [k_nope ; k_rope] and a value width of its own, so
+    it runs in the flash kernels (ops/flash.py: no score leaves the chip's
+    fast memory, causal blocks above the diagonal skipped) or, where they
+    do not run, in query blocks that a backward pass makes again."""
     r = dims.kv_rank
     kv = jnp.einsum("btr,rhd->bthd", keys[..., :r], wukv)
     k_nope, v, k_rope = kv[..., :dims.nope], kv[..., dims.nope:], \
         keys[..., r:]
     scale = 1.0 / math.sqrt(dims.nope + dims.rope)
+    s = q_nope.shape[1]
+    if own and not window and _flash_own(own, s):
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rope[:, :, None], k_nope.shape[:3] + (dims.rope,))], -1)
+        # up to a length the kernels' blocks divide (a multi-token-
+        # prediction module runs S - 1 positions): the keys added lie
+        # behind every query, the queries added are cut off again
+        fill = ((0, 0), (0, -s % FLASH_MULTIPLE), (0, 0), (0, 0))
+        q, k, v = (jnp.pad(a, fill) for a in (q, k, v))
+        return flash_attention(q, k, v, causal=True, scale=scale)[:, :s]
 
     def block(q_nope, q_rope, qpos):
         scores = (jnp.einsum("bshd,bthd->bhst", q_nope, k_nope)
@@ -175,7 +213,9 @@ def _expanded(dims, wukv, q_nope, q_rope, qpos, keys, kpos, window):
         w = _softmax(scores, _mask(qpos, kpos, window)[:, None])
         return jnp.einsum("bhst,bthd->bshd", w.astype(v.dtype), v)
 
-    return _over_query_blocks(block, DENSE_QUERY_BLOCK, q_nope, q_rope, qpos)
+    # under a gradient a block's scores are made again, not kept
+    return _over_query_blocks(jax.checkpoint(block) if own else block,
+                              DENSE_QUERY_BLOCK, q_nope, q_rope, qpos)
 
 
 def _absorb(dims, wukv, q_nope):
@@ -377,6 +417,9 @@ def latent_mix(cfg: TransformerConfig, layer, h, positions, attend):
             w = (h @ ix["ww"].astype(dt)).astype(jnp.float32) \
                 * (cfg.index_heads ** -0.5 * cfg.index_head_dim ** -0.5)
     keys, kpos, kept = attend(new)
+    own = kpos is None          # the training forward: the sequence's own
+    if own:
+        kpos = positions
     cached = keys["latent"]
     taps = {}
     if "index" in p and cached.shape[1] > cfg.index_topk:
@@ -384,6 +427,8 @@ def latent_mix(cfg: TransformerConfig, layer, h, positions, attend):
                           kpos, keys["index"], (qi, w))
     else:
         form = _expanded if h.shape[1] > 1 else _absorbed
+        if own and form is _expanded:
+            form = partial(_expanded, own=cfg.attn_impl)
         with jax.named_scope("rt.mla.window" if window else "rt.mla.dense"):
             o = form(dims, wukv, q_nope, q_rope, positions, cached, kpos,
                      window)

@@ -26,7 +26,17 @@ that holds a smaller share drops, AND counts, what a router sends past 4 x
 its mean: at a sixteenth with top-10, the pairs of a router collapsed onto
 three or more of this share's experts at once. The counters go out with the
 result: ``rows_here`` (pairs routed to held experts), ``rows_dropped``,
-``load`` (rows of each held expert).
+``load`` (rows of each held expert) and, of a router with a correction
+bias, ``counts``: the pairs of each of the ``cfg.num_experts`` published
+experts, which is what the balancer moves the bias by (``balance_bias``).
+
+The bias is trained by no gradient (it enters a top-k's selection alone):
+after the optimizer's update each expert's bias goes up by
+``cfg.router_bias_update_rate`` where the expert took fewer pairs than the
+mean over the experts this step, and down where it took more (DeepSeek-V3's
+aux-loss-free balancing, arXiv:2412.19437 section 2.1.2), over this
+program's own tokens: a deployment sums the counts over its data-parallel
+group first, and no exchange runs here.
 
 Scopes ``rt.moe.route``, ``rt.moe.experts``, ``rt.moe.shared`` name the three
 parts in the compiled program.
@@ -52,6 +62,14 @@ BUFFER_OVER_MEAN = 4
 # any routing can send: the mean of so few draws says nothing about their
 # largest, and in serving a dropped row is a wrong answer.
 FEW_TOKENS = 64
+
+# The buffer's arrays are laid out at a multiple of this many rows, whatever
+# it takes: the TPU's grouped matmul (``lax.ragged_dot``) computes wrong
+# values and gradients over a row count that 8 does not divide (measured on
+# a v5e, PR 47: 32,764 rows, what 2 x 8,191 tokens of a multi-token-
+# prediction module ask for, read a sum of squares of 187,224 for 2,882,809
+# and a weight gradient wholly off; 32,760 and 32,768 rows were right).
+ROW_MULTIPLE = 8
 
 
 def buffer_rows(cfg, tokens: int) -> int:
@@ -91,7 +109,8 @@ def moe_apply(cfg, moe_params, h):
     b, s, d = h.shape
     n, k, held = b * s, cfg.expert_top_k, cfg.held
     x = h.reshape(n, d)
-    rows = buffer_rows(cfg, n)
+    rows = buffer_rows(cfg, n)              # what the buffer takes
+    laid = -(-rows // ROW_MULTIPLE) * ROW_MULTIPLE    # what it is laid out at
 
     with jax.named_scope("rt.moe.route"):
         top_p, top_e = route(cfg, moe_params, x)
@@ -105,17 +124,24 @@ def moe_apply(cfg, moe_params, h):
         load = starts[1:] - starts[:-1]                     # [held]
         ends = jnp.minimum(starts, rows)                    # fit the buffer
         sizes = ends[1:] - ends[:-1]
-        order = order[:rows]
+        if laid > n * k:    # rows added to the layout lie past every group
+            order = jnp.pad(order, (0, laid - n * k))
+        order = order[:laid]
         token = order // k
         # Rows past the last group are no expert's: ragged_dot leaves them
         # unwritten, forward and transposed (on the TPU they hold whatever
         # the memory held). Masked where they come in and where they go
         # out, so that neither a value nor a gradient of theirs reaches a
         # token.
-        kept = (jnp.arange(rows) < ends[-1])[:, None]
+        kept = (jnp.arange(laid) < ends[-1])[:, None]
         weight = top_p.reshape(n * k)[order][:, None]
         stats = {"rows_here": starts[-1], "load": load,
                  "rows_dropped": starts[-1] - ends[-1]}
+        if "router_bias" in moe_params:
+            # what moves the bias (train/jax_step.py): the step's (token,
+            # expert) pairs of every published expert, held here or not
+            stats["counts"] = jnp.bincount(
+                top_e.reshape(n * k), length=cfg.num_experts)
 
     with jax.named_scope("rt.moe.experts"):
         xs = jnp.where(kept, x[token], 0)                   # [rows, D]
@@ -139,6 +165,15 @@ def moe_apply(cfg, moe_params, h):
                 out = out * open_[:, None].astype(dt)
             y = y + out
     return y.reshape(b, s, d), stats
+
+
+def balance_bias(cfg, counts):
+    """counts [..., E]: a step's pairs of each published expert -> what the
+    step adds to the router's correction bias, ``rate x sign(mean -
+    count)``, float32."""
+    counts = counts.astype(jnp.float32)
+    return cfg.router_bias_update_rate * jnp.sign(
+        counts.mean(-1, keepdims=True) - counts)
 
 
 def load_balance_loss(probs, top_e):

@@ -94,6 +94,9 @@ class TransformerConfig:
     #                                       the score alone
     routed_scaling_factor: float = 1.0    # on the routed experts' weights
     shared_expert_gate: bool = True       # sigmoid(w_g . x) on the shared
+    # what a step adds to or takes from a sigmoid router's correction bias
+    # by each expert's load (models/moe.py balance_bias)
+    router_bias_update_rate: float = 0.001
     # Hybrid block. ``layer_types``: one period of "full" | "linear" |
     # "latent" | "window", () = every layer full attention; after
     # ``first_dense_layers`` leading layers of the period's first kind, with
@@ -133,6 +136,14 @@ class TransformerConfig:
     loop_steps: int = 1
     early_exit_threshold: float = 1.0
     sandwich_norm: bool = False           # a norm on each sublayer's output
+    # Multi-token prediction (DeepSeek-V3, arXiv:2412.19437 section 2.2):
+    # ``mtp_layers`` modules (0 or 1) after the stack, ``params["mtp"]``,
+    # each one block of the period's last kind over ``W_eh [norm(Emb(t_{i+1}
+    # )) ; norm(h_i)]``, read by the model's own head: a second loss, of
+    # ``t_{i+2}``, added with weight ``mtp_loss_weight``. Training only:
+    # ``generate`` serves the main stack and does not read the module.
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
     def __post_init__(self):
         if set(self.layer_types) - {"full", "linear", "latent", "window"}:
@@ -170,6 +181,18 @@ class TransformerConfig:
         if self.layer_types and self.pp_stages > 1:
             raise ValueError("a layer pattern with pp_stages > 1 is not "
                              "supported")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(
+                f"mtp_layers {self.mtp_layers}: 0 or 1 (a module of depth k "
+                "reads the module before it, and one depth is built)")
+        if self.mtp_layers and self.pp_stages > 1:
+            raise ValueError(
+                "mtp_layers with pp_stages > 1 is not supported: the module "
+                "reads the last stage's output and the first stage's "
+                "embedding, and the pipeline's schedule has no such edge")
+        if self.mtp_layers and self.loop_steps > 1:
+            raise ValueError("mtp_layers with loop_steps > 1 is not "
+                             "supported: a looped stack has no loss")
         if self.loop_steps < 1:
             raise ValueError(f"loop_steps {self.loop_steps}: at least 1")
         if self.loop_steps > 1 and self.pp_stages > 1:
@@ -358,6 +381,14 @@ def transformer_init(key, cfg: TransformerConfig) -> Dict[str, Any]:
             "w": init(jax.random.fold_in(k_head, 1), (cfg.d_model,),
                       cfg.param_dtype),
             "b": jnp.zeros((), cfg.param_dtype)}
+    if cfg.mtp_layers:
+        k_proj, k_block = jax.random.split(jax.random.fold_in(k_head, 2))
+        d, pd = cfg.d_model, cfg.param_dtype
+        params["mtp"] = {
+            "enorm": norm((d,), pd), "hnorm": norm((d,), pd),
+            "eh_proj": init(k_proj, (2 * d, d), pd),      # [Emb ; h] -> d
+            "block": _layer_init(k_block, cfg, cfg.kinds[-1]),
+            "final_norm": norm((d,), pd)}
     return params
 
 
@@ -365,10 +396,12 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     """Pytree mirroring params: per-leaf logical dim names (see
     parallel/sharding.py DEFAULT_RULES)."""
     stage = ("stage", "layers") if cfg.pp_stages > 1 else ("layers",)
-    def L(*axes):  # layer leaf: leading stacked dim(s)
+
+    def stacked(*axes):  # layer leaf: leading stacked dim(s)
         return stage + axes
 
-    def layer_axes(kind: str, dense: bool = False) -> Dict[str, Any]:
+    def layer_axes(kind: str, dense: bool = False,
+                   L=stacked) -> Dict[str, Any]:
         layer = {"ln1": L("embed"), "ln2": L("embed")}
         if cfg.sandwich_norm:
             layer["ln1_post"] = L("embed")
@@ -430,6 +463,12 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         axes["lm_head"] = ("embed", "vocab")
     if cfg.loop_steps > 1:
         axes["exit_gate"] = {"w": ("embed",), "b": ()}
+    if cfg.mtp_layers:
+        axes["mtp"] = {
+            "enorm": ("embed",), "hnorm": ("embed",),
+            "eh_proj": (None, "embed"),
+            "block": layer_axes(cfg.kinds[-1], L=lambda *axes: axes),
+            "final_norm": ("embed",)}
     return axes
 
 
@@ -561,7 +600,8 @@ def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend,
     ``attend(q, k, v) -> (o, kept)`` is what softmax attention does with
     the rotated k and v, and what it keeps of them; ``attend(new) -> (keys,
     key positions, kept)`` is what a latent layer's new cached entries
-    join, and what is kept of them. ``mesh``/``rules`` are the gated delta
+    join (key positions None: the sequence's own, index i at position i),
+    and what is kept of them. ``mesh``/``rules`` are the gated delta
     rule's, whose kernels run per shard. -> (x, kept, stats: the expert
     layer's counters and a latent layer's selection, or None)."""
     h = _norm(cfg, x, layer["ln1"])
@@ -588,18 +628,15 @@ def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend,
     return x + y, kept, stats
 
 
-def _stage_scan(cfg: TransformerConfig, mesh, stage_layers, x, positions,
-                rules: LogicalRules = DEFAULT_RULES, dense_layers=None):
-    """Apply a stack of layers (leading dim = layers, or under a layer
-    pattern a tuple of stacks with leading dim = periods, after the stack
-    of leading ``dense_layers``) with lax.scan. ``rules``: what the caller
-    sharded params and batch by over ``mesh``.
-    -> (x, the expert layers' stats stacked over the scan, or None)."""
+def _layer_bodies(cfg: TransformerConfig, mesh, rules: LogicalRules):
+    """{kind: ``body(layer, x, positions) -> (x, kept, stats)``}: the
+    training forward's ``_layer_apply`` of each kind of the period, over
+    the sequence's own keys, remat'd where the configuration says."""
     def softmax(q, k, v):
         return _attention(cfg, q, k, v, mesh, rules), None
 
     def whole(new):             # a latent layer's keys: the sequence's own
-        return new, positions, None
+        return new, None, None
 
     def body_of(kind: str):
         body = partial(_layer_apply, cfg, mesh=mesh, rules=rules,
@@ -613,8 +650,18 @@ def _stage_scan(cfg: TransformerConfig, mesh, stage_layers, x, positions,
                 policy=jax.checkpoint_policies.save_only_these_names(KEPT))
         return body
 
+    return {kind: body_of(kind) for kind in cfg.kinds}
+
+
+def _stage_scan(cfg: TransformerConfig, mesh, stage_layers, x, positions,
+                rules: LogicalRules = DEFAULT_RULES, dense_layers=None):
+    """Apply a stack of layers (leading dim = layers, or under a layer
+    pattern a tuple of stacks with leading dim = periods, after the stack
+    of leading ``dense_layers``) with lax.scan. ``rules``: what the caller
+    sharded params and batch by over ``mesh``.
+    -> (x, the expert layers' stats stacked over the scan, or None)."""
     kinds = cfg.kinds
-    bodies = {kind: body_of(kind) for kind in kinds}
+    bodies = _layer_bodies(cfg, mesh, rules)
     if dense_layers is not None:
         x, _ = lax.scan(
             lambda carry, layer: (bodies[kinds[0]](layer, carry,
@@ -692,10 +739,10 @@ def _next_token_loss(logits, tokens, mask=None):
     return nll.mean()
 
 
-def _logits_and_stats(params, tokens, cfg: TransformerConfig, mesh,
+def _hidden_and_stats(params, tokens, cfg: TransformerConfig, mesh,
                       positions, rules: LogicalRules):
-    """-> (logits, expert-layer stats or None, exit distribution or
-    None)."""
+    """-> (the stack's output [B, S, E] as ``_head`` takes it, expert-layer
+    stats or None, exit distribution or None)."""
     b, s = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
@@ -723,7 +770,52 @@ def _logits_and_stats(params, tokens, cfg: TransformerConfig, mesh,
             cfg, params, lambda x, _, t: _stage_scan(
                 cfg, mesh, params["layers"], x, positions, rules,
                 params.get("dense_layers")), x)
+    return x, stats, exits
+
+
+def _logits_and_stats(params, tokens, cfg: TransformerConfig, mesh,
+                      positions, rules: LogicalRules):
+    """-> (logits, expert-layer stats or None, exit distribution or
+    None)."""
+    x, stats, exits = _hidden_and_stats(params, tokens, cfg, mesh, positions,
+                                        rules)
     return _head(params, x, cfg), stats, exits
+
+
+def _mtp_loss(params, h, tokens, cfg: TransformerConfig, mesh,
+              rules: LogicalRules, mask=None):
+    """The multi-token-prediction module's loss. ``h`` [B, S, E]: the main
+    stack's output before the final norm. For i = 0 .. S-2: ``u_i = W_eh
+    [norm_e(Emb(t_{i+1})) ; norm_h(h_i)]``, one block over u at positions
+    i, the module's own final norm, the model's own head: logits of
+    ``t_{i+2}``. ``Emb`` and the head are the main model's arrays, so each
+    gets the sum of both losses' gradients. -> (mean cross-entropy over i =
+    0 .. S-3, the block's expert-layer stats or None). The logits are made
+    again in the backward pass and not kept beside the main head's. Scopes
+    ``rt.mtp.combine`` (the two norms and ``W_eh``) inside ``rt.mtp`` (the
+    whole module)."""
+    m, dt = params["mtp"], cfg.dtype
+    b, s = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(s - 1), (b, s - 1))
+    with jax.named_scope("rt.mtp"):
+        with jax.named_scope("rt.mtp.combine"):
+            e = _norm(cfg, params["embed"].astype(dt)[tokens[:, 1:]],
+                      m["enorm"])
+            u = jnp.concatenate([e, _norm(cfg, h[:, :-1], m["hnorm"])], -1) \
+                @ m["eh_proj"].astype(dt)
+        body = _layer_bodies(cfg, mesh, rules)[cfg.kinds[-1]]
+        v, _, stats = body(m["block"], u, positions)
+
+        @jax.checkpoint
+        def head_loss(head_params, v):
+            return _next_token_loss(
+                _head(head_params, v, cfg), tokens[:, 1:],
+                None if mask is None else mask[:, 1:])
+
+        head_params = {k: params[k] for k in ("embed", "lm_head")
+                       if k in params}
+        head_params["final_norm"] = m["final_norm"]
+        return head_loss(head_params, v), stats
 
 
 def _refuse_looped_loss(cfg: TransformerConfig) -> None:
@@ -759,23 +851,51 @@ def transformer_apply_and_exits(params, tokens, cfg: TransformerConfig, *,
 def transformer_loss_and_stats(params, batch, cfg: TransformerConfig, *,
                                mesh=None,
                                rules: LogicalRules = DEFAULT_RULES):
-    """batch: {"tokens": [B, S]} -> (next-token cross-entropy, mean over
-    non-final positions; the step's expert-layer counters as scalars,
-    ``{}`` for a dense model): ``moe_rows_here`` and ``moe_rows_dropped``
+    """batch: {"tokens": [B, S]} -> (the loss; the step's counters, ``{}``
+    for a dense model without a module). The loss is the next-token
+    cross-entropy, mean over non-final positions, and with a
+    multi-token-prediction module ``loss_main + mtp_loss_weight x
+    loss_mtp``, both among the counters. The expert layers' (the module's
+    among them), as scalars: ``moe_rows_here`` and ``moe_rows_dropped``
     summed over the layers, ``moe_load_max`` and ``moe_load_mean`` the
-    fullest held expert's rows and the mean, over layers and experts."""
+    fullest held expert's rows and the mean, over layers and experts; of a
+    router with a correction bias also ``moe_count_max_over_mean`` (the
+    fullest published expert's pairs over the mean, the worst layer's) and,
+    not a scalar, ``moe_counts``: each router's pairs of every published
+    expert, [periods, E] a stack and [E] the module's, in a tree that holds
+    them where ``params`` holds the ``router_bias`` they move
+    (train/jax_step.py)."""
     _refuse_looped_loss(cfg)
-    tokens = batch["tokens"]
-    logits, stats, _ = _logits_and_stats(params, tokens, cfg, mesh, None,
-                                         rules)
-    loss = _next_token_loss(logits, tokens, batch.get("mask"))
+    tokens, mask = batch["tokens"], batch.get("mask")
+    x, stats, _ = _hidden_and_stats(params, tokens, cfg, mesh, None, rules)
+    loss = _next_token_loss(_head(params, x, cfg), tokens, mask)
+    out, module = {}, None
+    if cfg.mtp_layers:
+        loss_mtp, module = _mtp_loss(params, x, tokens, cfg, mesh, rules,
+                                     mask)
+        out.update(loss_main=loss, loss_mtp=loss_mtp)
+        loss = loss + cfg.mtp_loss_weight * loss_mtp
     if stats is None:
-        return loss, {}
-    load = jnp.stack([s["load"] for s in stats])     # [period, periods, held]
-    return loss, {
-        "moe_rows_here": sum(s["rows_here"].sum() for s in stats),
-        "moe_rows_dropped": sum(s["rows_dropped"].sum() for s in stats),
-        "moe_load_max": load.max(), "moe_load_mean": load.mean()}
+        return loss, out
+    # the module's block is one more layer: a stack of one
+    stacks = list(stats) + ([jax.tree.map(lambda a: a[None], module)]
+                            if module else [])
+    load = jnp.concatenate([s["load"] for s in stacks])     # [layers, held]
+    out.update(
+        moe_rows_here=sum(s["rows_here"].sum() for s in stacks),
+        moe_rows_dropped=sum(s["rows_dropped"].sum() for s in stacks),
+        moe_load_max=load.max(), moe_load_mean=load.mean())
+    if "counts" in stacks[0]:
+        counts = jnp.concatenate([s["counts"] for s in stacks])
+        out["moe_count_max_over_mean"] = (
+            counts.max(-1) / counts.astype(jnp.float32).mean(-1)).max()
+        at_bias = lambda s: {"moe": {"router_bias": s["counts"]}}
+        layers = [at_bias(s) for s in stats]
+        out["moe_counts"] = {"layers": tuple(layers) if cfg.layer_types
+                             else layers[0]}
+        if module:
+            out["moe_counts"]["mtp"] = {"block": at_bias(module)}
+    return loss, out
 
 
 def transformer_loss(params, batch, cfg: TransformerConfig, *, mesh=None,

@@ -104,10 +104,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k,
                interpret=False):
-    """q,k,v: [BH, S, D] -> (out [BH, Sq, D], lse [BH, Sq, 128]).
-    ``interpret`` runs the Pallas interpreter: only tests pass it."""
+    """q, k: [BH, S, D], v: [BH, Sk, Dv] -> (out [BH, Sq, Dv], lse [BH, Sq,
+    128]). ``interpret`` runs the Pallas interpreter: only tests pass it."""
     bh, sq, d = q.shape
     _, sk, _ = k.shape
+    d_v = v.shape[-1]
     bq, bk = min(block_q, sq), min(block_k, sk)
     assert sq % bq == 0 and sk % bk == 0
     grid = (bh, sq // bq, sk // bk)
@@ -121,18 +122,18 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, d_v), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, d_v), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 128), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 128), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, d_v), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
@@ -229,6 +230,7 @@ def _flash_bwd(res, g, *, causal, scale, block_q, block_k,
     q, k, v, out, lse = res
     bh, sq, d = q.shape
     _, sk, _ = k.shape
+    d_v = v.shape[-1]
     bq, bk = min(block_q, sq), min(block_k, sk)
     q_offset = sk - sq
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), -1)
@@ -242,22 +244,22 @@ def _flash_bwd(res, g, *, causal, scale, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),   # q
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),   # k
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),   # v
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),   # do
+            pl.BlockSpec((1, bk, d_v), lambda b, j, i: (b, j, 0)),  # v
+            pl.BlockSpec((1, bq, d_v), lambda b, j, i: (b, i, 0)),  # do
             pl.BlockSpec((1, bq, 128), lambda b, j, i: (b, i, 0)),  # lse
             pl.BlockSpec((1, bq, 128), lambda b, j, i: (b, i, 0)),  # delta
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, bk, d_v), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, d_v), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, g, lse, delta)
@@ -271,8 +273,8 @@ def _flash_bwd(res, g, *, causal, scale, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, d_v), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bq, d_v), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 128), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 128), lambda b, i, j: (b, i, 0)),
         ],
@@ -423,7 +425,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     mesh=None, rules: LogicalRules = DEFAULT_RULES):
-    """Fused attention; q,k,v: [B, S, H, D] -> [B, Sq, H, D].
+    """Fused attention; q, k: [B, S, H, D], v: [B, Sk, H, Dv] (a value
+    width of its own, as expanded latent attention has: D 192, Dv 128) ->
+    [B, Sq, H, Dv].
 
     Default block sizes come from the per-generation table (refined by
     autotune_blocks on the live chip); blocks shrink to fit/divide the
@@ -475,11 +479,11 @@ def _flash_bshd(q, k, v, *, causal, scale, block_q, block_k):
     scale_ = scale if scale is not None else d ** -0.5
 
     def to_bhsd(x, s):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[-1])
 
     out = _flash_bhsd(to_bhsd(q, sq), to_bhsd(k, sk), to_bhsd(v, sk),
                       causal, scale_, block_q, block_k)
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, sq, v.shape[-1]).transpose(0, 2, 1, 3)
 
 
 @functools.cache

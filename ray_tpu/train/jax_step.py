@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu.models.moe import balance_bias
 from ray_tpu.models.resnet import resnet50, resnet_loss
 from ray_tpu.models.transformer import (TransformerConfig,
                                         _refuse_looped_loss,
@@ -65,6 +66,20 @@ def _init_opt_state(tx: optax.GradientTransformation, params, mesh: Mesh):
     return jax.jit(tx.init, out_shardings=shardings)(params)
 
 
+def _balance_routers(updates, counts, cfg: TransformerConfig):
+    """``updates`` with every router's correction bias moved by its
+    layer's load (models/moe.py ``balance_bias``) in place of whatever the
+    optimizer gave it: no gradient reaches the bias, and neither AdamW nor
+    its weight decay is to move it. ``counts``: the step's ``moe_counts``,
+    a tree that holds each router's counts where ``updates`` holds its
+    ``router_bias``."""
+    moved = dict(jax.tree_util.tree_leaves_with_path(
+        jax.tree.map(partial(balance_bias, cfg), counts)))
+    return jax.tree_util.tree_map_with_path(
+        lambda path, u: moved[path].astype(u.dtype) if path in moved else u,
+        updates)
+
+
 def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
                        tx: Optional[optax.GradientTransformation] = None,
                        rules: LogicalRules = DEFAULT_RULES,
@@ -97,10 +112,22 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
         (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch)
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
+        # a sigmoid router's bias is the balancer's, by the step's counts
+        counts = stats.pop("moe_counts", None)
+        if counts is not None:
+            updates = _balance_routers(updates, counts, cfg)
         params = optax.apply_updates(state.params, updates)
+        if counts is not None:
+            at = {path for path, _ in
+                  jax.tree_util.tree_leaves_with_path(counts)}
+            stats["router_bias_abs_mean"] = jnp.abs(jnp.concatenate([
+                b.reshape(-1) for path, b in
+                jax.tree_util.tree_leaves_with_path(params)
+                if path in at])).mean()
         gnorm = optax.global_norm(grads)
-        # ``stats``: the expert layers' counters (``moe_*``), none for a
-        # dense model
+        # ``stats``: the expert layers' counters (``moe_*``) and a
+        # multi-token-prediction module's two losses, none for a dense
+        # model without one
         return (TrainState(params, opt_state, state.step + 1),
                 {"loss": loss, "grad_norm": gnorm, "step": state.step + 1,
                  **stats})
