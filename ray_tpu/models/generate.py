@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.latent import ring_positions
+from ray_tpu.models.latent import ring_positions, sparse_in_kernel
 from ray_tpu.models.transformer import (TransformerConfig, _attention,
                                         _head, _layer_apply,
                                         _over_loop_steps)
@@ -694,5 +694,10 @@ def call_span(cfg: TransformerConfig, rows: int, prompt: int,
             index_topk=cfg.index_topk,
             keys_scored=layers * causal if k else 0,
             keys_attended=layers * (few * (few + 1) // 2 + (total - few) * k
-                                    if k else causal))
+                                    if k else causal),
+            # the prompt's queries, where a block of them over the whole
+            # cache went through rt_sparse_attend (no decode step does)
+            sparse_kernel_queries=layers * prompt if k and sparse_in_kernel(
+                cfg.latent_dims("latent"), k, prefill_chunk(prompt),
+                prompt + new) else 0)
     return events.span("generate.call", **attrs)

@@ -28,7 +28,17 @@ into the query and ``W_uv`` applied to the attended latents, ``(2 kv_rank +
 rope)`` a head and pair and nothing per key: for one query (a decode step),
 and wherever each query has keys of its own (the selection), whose expanded
 keys nothing could hold. Queries go through either in blocks, so that no
-``[heads, queries, keys]`` score tensor is whole at once. Over the
+``[heads, queries, keys]`` score tensor is whole at once. Over the selection
+the absorbed form runs in one of two ways, by what the code sees of its
+input (``sparse_in_kernel``): where a block of queries fetches more rows
+than the cache holds, on a TPU (every prefill block of a long prompt), in
+the Pallas kernel ``rt_sparse_attend`` (ops/sparse_attend.py), which holds
+one batch row's cache in VMEM, fetches each selected row from there once
+and hands out the attended latents alone, the scores float32 all the way;
+otherwise (a decode step, whose one query a row fetches a sixteenth of the
+cache; the CPU; small sizes) as a gather of the rows into a copy and three
+``jnp`` passes over it, the kernel's reference in the tests. One algorithm,
+two sizes: no knob chooses. Over the
 sequence's own keys the expanded form is plain multi-head attention with k =
 ``[k_nope ; k_rope]`` (``nope + rope`` wide) and v (``v`` wide): there it
 runs in the flash kernels, which take a value width of its own, or in query
@@ -49,7 +59,9 @@ a time with three small matrix products: 3 us a row on a v5e at 32,896 keys
 
 Scopes in the compiled program: ``rt.mla.project`` (the projections and the
 output), ``rt.dsa.index`` (the indexer's scores and the selection),
-``rt.mla.sparse`` (the gather and the attention over the selection),
+``rt.mla.sparse`` (the attention over the selection: the cache packed for
+the kernel once a layer and chunk, the query absorbed, the kernel's Mosaic
+call or the gather and the three passes, the values unabsorbed),
 ``rt.mla.window`` (a window layer's attention), ``rt.mla.dense`` (a latent
 layer's attention over all keys, where there are no more than
 ``index_topk``).
@@ -66,12 +78,14 @@ from jax import lax
 
 from ray_tpu.models.transformer import (LatentDims, TransformerConfig,
                                         _output_gate, _rmsnorm, _rope)
+from ray_tpu.ops import sparse_attend
 from ray_tpu.ops.flash import _on_tpu, flash_attention
 
 PARAMS_KEY = {"latent": "mla", "window": "swa"}
-# Queries a block: of the selection (each query gathers index_topk rows of
-# the cache: 128 x 2,048 x 576 x 2 B = 302 MB a row of the batch) and of
-# attention over shared keys.
+# Queries a block: of the selection (the indexer's float32 scores of a block
+# over every key are held, 128 x 32,896 x 4 B = 17 MB a row of the batch at
+# 32k; off the kernel's path each query's index_topk rows of the cache too,
+# 128 x 2,048 x 576 x 2 B = 302 MB a row) and of attention over shared keys.
 SPARSE_QUERY_BLOCK = 128
 DENSE_QUERY_BLOCK = 512
 # A sequence over its own keys is filled up to a multiple of this for the
@@ -142,12 +156,19 @@ def _layer_norm(x, w, b):
         * w.astype(x.dtype) + b.astype(x.dtype)
 
 
+def _block_queries(queries: int, block: int) -> int:
+    """Queries that go through at once, of ``queries`` in blocks of
+    ``block``: all of them where they are no more than a block or do not
+    divide."""
+    return queries if queries <= block or queries % block else block
+
+
 def _over_query_blocks(fn, block: int, *per_query):
     """``fn(*blocks) -> tree of [B, block, ...]`` over blocks of the
     arrays' second dim (queries), one block at a time; the whole at once
     where the queries are no more than a block or do not divide."""
     s = per_query[0].shape[1]
-    if s <= block or s % block:
+    if _block_queries(s, block) == s:
         return fn(*per_query)
     n = s // block
     split = [jnp.moveaxis(a.reshape(a.shape[0], n, block, *a.shape[2:]),
@@ -353,6 +374,18 @@ def select(topk: int, qi, w, ki, qpos, kpos):
     return _top_set(scores, topk)
 
 
+def sparse_in_kernel(dims: LatentDims, topk: int, queries: int,
+                     keys: int) -> bool:
+    """Whether ``queries`` a call, each over a selection of ``topk`` of
+    ``keys`` cached rows, run in ``rt_sparse_attend``: on a TPU, where a
+    block of them fetches more rows than the cache holds (so that holding
+    one batch row's cache in VMEM pays: a prefill block, never a decode
+    step) and the kernel takes the sizes. The ``jnp`` form otherwise."""
+    return _on_tpu() \
+        and _block_queries(queries, SPARSE_QUERY_BLOCK) * topk > keys \
+        and sparse_attend.takes(keys, dims.cached, dims.kv_rank, topk)
+
+
 def _sparse(cfg, dims, wukv, q_nope, q_rope, qpos, keys, kpos, index_keys,
             index_query):
     """Each query over its own selection, absorbed, in blocks of queries.
@@ -360,20 +393,31 @@ def _sparse(cfg, dims, wukv, q_nope, q_rope, qpos, keys, kpos, index_keys,
     r = dims.kv_rank
     scale = 1.0 / math.sqrt(dims.nope + dims.rope)
     gather = jax.vmap(lambda rows, at: rows[at])    # over the batch
+    packed = None
+    if sparse_in_kernel(dims, cfg.index_topk, q_nope.shape[1],
+                        keys.shape[1]):
+        with jax.named_scope("rt.mla.sparse"):
+            packed = sparse_attend.pack(keys, r)    # once for every block
 
     def block(q_nope, q_rope, qpos, qi, w):
         with jax.named_scope("rt.dsa.index"):
             at, real = select(cfg.index_topk, qi, w, index_keys, qpos, kpos)
         with jax.named_scope("rt.mla.sparse"):
-            rows = gather(keys, at)                 # [B, S, topk, cached]
-            latent, k_rope = rows[..., :r], rows[..., r:]
-            scores = (jnp.einsum("bshr,bskr->bshk",
-                                 _absorb(dims, wukv, q_nope), latent)
-                      + jnp.einsum("bshd,bskd->bshk", q_rope, k_rope)) \
-                * scale
-            p = _softmax(scores, real[:, :, None, :])
-            o = _unabsorb(dims, wukv, jnp.einsum(
-                "bshk,bskr->bshr", p.astype(latent.dtype), latent))
+            q_latent = _absorb(dims, wukv, q_nope)
+            if packed is not None:
+                o_latent = sparse_attend.sparse_attend(
+                    jnp.concatenate([q_latent, q_rope], -1), packed, at,
+                    real, v=r, scale=scale)
+            else:
+                rows = gather(keys, at)             # [B, S, topk, cached]
+                latent, k_rope = rows[..., :r], rows[..., r:]
+                scores = (jnp.einsum("bshr,bskr->bshk", q_latent, latent)
+                          + jnp.einsum("bshd,bskd->bshk", q_rope, k_rope)) \
+                    * scale
+                p = _softmax(scores, real[:, :, None, :])
+                o_latent = jnp.einsum("bshk,bskr->bshr",
+                                      p.astype(latent.dtype), latent)
+            o = _unabsorb(dims, wukv, o_latent)
         return o, at, real
 
     o, at, real = _over_query_blocks(block, SPARSE_QUERY_BLOCK, q_nope,

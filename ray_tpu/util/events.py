@@ -197,7 +197,9 @@ EVENT_KINDS: Dict[str, str] = {
                      "latent), of a looped stack exit_steps_mean, and of "
                      "a stack by kind cache_bytes_latent/_index/_window, "
                      "prefill_chunks, index_topk, keys_scored, "
-                     "keys_attended, moe_rows_here, moe_rows_dropped",
+                     "keys_attended, sparse_kernel_queries (of its queries, "
+                     "those attended in rt_sparse_attend), moe_rows_here, "
+                     "moe_rows_dropped",
     "train.pump": "span: value = seconds of one synchronized report "
                   "round; attrs carry iteration/lag_s",
     # start-up

@@ -260,3 +260,42 @@ def test_a_looped_generate_keeps_one_cache_layout_on_v5e(monkeypatch):
     straight = compiled()
     assert len(re.findall(copied, straight.as_text())) == 2
     assert straight.memory_analysis().temp_size_in_bytes > 1.9 * stacks
+
+
+def test_sparse_attend_compiles_for_v5e_at_the_cells_block():
+    """Mosaic accepts the kernel at the dots3 cell's prefill block (2 x 128
+    queries of 128 heads over 2,048 of 32,896 rows of 576) and grants it
+    the VMEM it asks for: one batch row's cache and the working set."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops import sparse_attend
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu in this install
+        pytest.skip(f"no TPU compiler here: {e!r}")
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    b, s, h, t, topk = 2, 128, 128, 32896, 2048
+    compiled = jax.jit(
+        lambda q, keys, at, real: sparse_attend.sparse_attend(
+            q, sparse_attend.pack(keys, 512), at, real, v=512,
+            scale=192 ** -0.5)).lower(
+        shape((b, s, h, 576), jnp.bfloat16),
+        shape((b, t, 576), jnp.bfloat16), shape((b, s, topk), jnp.int32),
+        shape((b, s, topk), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    call, = [ln for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert "bf16[2,128,128,512]" in call            # only o_latent leaves
+    assert "bf16[2,128,2048,576]" not in hlo        # no copy of the rows
+    asked = t * 384 * 4 + sparse_attend.WORK_VMEM_BYTES
+    assert asked <= 100 << 20                       # of a core's 128 MiB
+    # no score and no row among the program's temporaries: the packed
+    # cache (2 x 50.5 MB) and what packing it holds
+    assert compiled.memory_analysis().temp_size_in_bytes < 320 << 20
