@@ -9,11 +9,15 @@ Design notes (TPU-first):
 - Params are a pytree of jnp arrays; layers are *stacked* on a leading dim
   and applied with `lax.scan` so XLA compiles one layer body regardless of
   depth; `jax.checkpoint` remats each layer (HBM <-> FLOPs trade). A remat'd
-  layer keeps its input and, where its mixer is the gated delta rule run by
-  the Pallas kernels, the forward kernel's four outputs (by their checkpoint
-  name, ops/gated_delta.py ``KEPT``: 603,979,776 bytes a layer at 2 rows x
-  8,192 in bfloat16), so that kernel is not run again for the backward; a
-  softmax-attention layer and the rule's jnp form keep nothing else.
+  layer keeps its input and what its mixer's Pallas forward kernel hands
+  the backward kernels under a checkpoint name, so that kernel is not run
+  again for the backward: the gated delta rule's four outputs
+  (ops/gated_delta.py ``KEPT``: 603,979,776 bytes a layer at 2 rows x 8,192
+  in bfloat16), and flash attention's ``out`` and log-sum-exp column where
+  a kept byte spares enough of a second run (ops/flash.py ``KEPT``,
+  ``KEEP_FROM``: 136,314,880 bytes a latent layer at 2 rows x 8,192 x 32
+  heads, nothing at S = 2,048 with heads of 128); XLA's attention forms and
+  the rule's jnp form keep nothing else.
 - Every weight carries logical axis names (transformer_logical_axes) mapped
   to mesh axes by parallel/sharding.py: tp shards heads/mlp/vocab, fsdp
   shards the embed dim (ZeRO-3), sp shards the sequence (ring/Ulysses
@@ -37,8 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops import flash, gated_delta
 from ray_tpu.ops.attention import mha
-from ray_tpu.ops.gated_delta import KEPT
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
 from ray_tpu.parallel.sharding import DEFAULT_RULES, LogicalRules
@@ -643,11 +647,14 @@ def _layer_bodies(cfg: TransformerConfig, mesh, rules: LogicalRules):
                        attend=whole if kind in ("latent", "window")
                        else softmax)
         if cfg.remat:
-            # a layer without the rule's kernels holds nothing under the
-            # name, and is remat'd whole as by a bare jax.checkpoint
+            # what the delta rule's and flash's forward kernels name for
+            # their backward kernels outlives the forward pass (flash names
+            # its own only from a size on: ops/flash.py KEEP_FROM); a layer
+            # that holds nothing under either name is remat'd whole as by a
+            # bare jax.checkpoint
             body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies.save_only_these_names(KEPT))
+                body, policy=jax.checkpoint_policies.save_only_these_names(
+                    gated_delta.KEPT, flash.KEPT))
         return body
 
     return {kind: body_of(kind) for kind in cfg.kinds}

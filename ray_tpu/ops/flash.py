@@ -15,6 +15,28 @@ other backend (ops/attention.py mha(impl="auto") picks a CPU form there).
 A Mosaic call cannot be partitioned by GSPMD, so under a multi-device mesh
 the kernel runs per shard inside shard_map, q/k/v split the way the
 caller's LogicalRules lay out a [batch, seq, heads, kv] activation.
+
+What outlives the forward pass. The backward kernels read, beside q, k, v
+and the output's gradient, two things only the forward kernel makes: ``out``
+(for delta = sum(dout * out)) and the log-sum-exp of each query's scores.
+Where ``_worth_keeping`` says so, the forward rule marks both with the
+checkpoint name ``KEPT`` (jax.ad_checkpoint.checkpoint_name), the
+log-sum-exp as a column ``[BH, S]`` float32: the kernel writes it over 128
+lanes for the TPU's tiling, and ``_flash_bwd`` spreads a column over the
+lanes again, as it does delta. A caller that remats the layer around the
+kernel under ``save_only_these_names(KEPT)`` (models/transformer.py
+``_layer_bodies``) then runs the forward kernel once, not a second time in
+the backward pass; under any other policy, and without remat, the name does
+nothing. Both are named, or the kernel would run again for the one left
+out; q, k and v are not: they come out of the caller's projections and
+transposes and are made again with them. At 2 rows x 32 heads, S = 8,192,
+Dv = 128 in bfloat16 a layer keeps 134,217,728 + 2,097,152 bytes (the
+log-sum-exp over its lanes would be 268,435,456). Whether that is worth it
+is the trade ``KEEP_FROM`` states: the multiply-adds a second run of the
+forward would execute for each byte kept. Below it nothing is named and the
+residuals are the kernel's outputs as they are (taking the column and
+spreading it again is three passes over ``[BH, S, 128]`` a layer, 1.3 ms
+at that size, which only what is kept repays).
 """
 
 from __future__ import annotations
@@ -28,6 +50,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -35,6 +58,16 @@ from ray_tpu.parallel.sharding import DEFAULT_RULES, LogicalRules
 from ray_tpu.tpu.topology import generation
 
 NEG_INF = -1e30
+# the name on what the backward kernels read of the forward kernel, where
+# it is worth keeping across a caller's remat
+KEPT = "flash_kept"
+# Multiply-adds that a second run of the forward kernel executes for each
+# byte that keeping ``out`` and the log-sum-exp holds until the backward
+# pass: they are kept from here on. The v5e's kernels execute 46-56 T
+# multiply-adds a second, so at 2,000 a gigabyte kept buys about 40 ms a
+# step. A causal S = 8,192 reads 5,041 at q/k 192 and v 128 and 4,064 at a
+# head of 256; S = 2,048 at a head of 128 reads 1,008 and keeps nothing.
+KEEP_FROM = 2000
 
 
 def _dot(a, b):
@@ -225,16 +258,25 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
+def _over_lanes(column):
+    """[BH, S] -> [BH, S, 128]: the blocks the backward kernels read."""
+    return jnp.broadcast_to(column[..., None], column.shape + (128,))
+
+
 def _flash_bwd(res, g, *, causal, scale, block_q, block_k,
                interpret=False):
+    """``res``: (q, k, v, out, the log-sum-exp as the forward kernel wrote
+    it, [BH, Sq, 128], or as the column [BH, Sq] that is kept by name)."""
     q, k, v, out, lse = res
     bh, sq, d = q.shape
     _, sk, _ = k.shape
     d_v = v.shape[-1]
     bq, bk = min(block_q, sq), min(block_k, sk)
     q_offset = sk - sq
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), -1)
-    delta = jnp.broadcast_to(delta[..., None], delta.shape + (128,))
+    delta = _over_lanes(
+        jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), -1))
+    if lse.ndim == 2:             # kept as a column (_flash_bhsd_fwd)
+        lse = _over_lanes(lse)
 
     dkv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -297,9 +339,22 @@ def _flash_bhsd(q, k, v, causal, scale, block_q, block_k):
     return out
 
 
+def _worth_keeping(q, k, v, causal: bool) -> bool:
+    """Whether a head's ``out`` [Sq, Dv] and log-sum-exp column are kept
+    for the backward pass by name, from the kernel's shapes alone: the
+    multiply-adds a second run of the forward executes (D + Dv a (query,
+    key) pair it visits) over the bytes kept reach ``KEEP_FROM``."""
+    (_, sq, d), sk, d_v = q.shape, k.shape[1], v.shape[2]
+    pairs = sq * (sk - sq) + sq * (sq + 1) // 2 if causal else sq * sk
+    return pairs * (d + d_v) >= KEEP_FROM * sq * (q.dtype.itemsize * d_v + 4)
+
+
 def _flash_bhsd_fwd(q, k, v, causal, scale, block_q, block_k):
     out, lse = _flash_fwd(q, k, v, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k)
+    if _worth_keeping(q, k, v, causal):
+        # the log-sum-exp's 128 lanes hold one number: the column is kept
+        out, lse = checkpoint_name((out, lse[..., 0]), KEPT)
     return out, (q, k, v, out, lse)
 
 

@@ -8,6 +8,8 @@ that the comparison is of the mathematics."""
 import dataclasses
 import json
 import os
+import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +21,7 @@ from benchmark.apps import lm, train_joyai as app
 from benchmark.reference import joyai as reference
 from ray_tpu.models import (TransformerConfig, generate, transformer_apply,
                             transformer_init)
-from ray_tpu.models import latent, moe
+from ray_tpu.models import latent, moe, transformer
 from ray_tpu.models.transformer import (transformer_logical_axes,
                                         transformer_loss_and_stats,
                                         transformer_num_params,
@@ -27,6 +29,7 @@ from ray_tpu.models.transformer import (transformer_logical_axes,
 from ray_tpu.ops import flash
 from ray_tpu.parallel import MeshSpec, build_mesh
 from ray_tpu.train import make_lm_train_step
+from test_qwen3_next import bare_checkpoint, equations  # noqa: F401 - a fixture
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(CHECKOUT, "benchmark", "configs",
@@ -426,13 +429,10 @@ def test_over_its_own_keys_a_latent_layer_is_the_same_and_keeps_no_scores(
     assert not scores(residual_shapes(True))
 
 
-@pytest.mark.parametrize("s", [256, 255])
-def test_flash_with_a_value_width_of_its_own_matches_attention(s,
-                                                               monkeypatch):
-    """q/k 192 wide, v 128: the kernels through the interpreter against
-    plain attention, value and gradient; a length the blocks do not divide
-    (a module's S - 1) is filled up and cut."""
-    from functools import partial
+@pytest.fixture
+def flash_interpreted(monkeypatch):
+    """flash as on a v5e, its three kernels through the interpreter, a
+    sequence over its own keys filled up to 128."""
     monkeypatch.setattr(flash, "_on_tpu", lambda: True)
     monkeypatch.setattr(flash, "generation", lambda: "v5e")
     monkeypatch.setattr(latent, "FLASH_MULTIPLE", 128)
@@ -440,6 +440,14 @@ def test_flash_with_a_value_width_of_its_own_matches_attention(s,
                         partial(flash._flash_fwd, interpret=True))
     monkeypatch.setattr(flash, "_flash_bwd",
                         partial(flash._flash_bwd, interpret=True))
+
+
+@pytest.mark.parametrize("s", [256, 255])
+def test_flash_with_a_value_width_of_its_own_matches_attention(
+        s, flash_interpreted):
+    """q/k 192 wide, v 128: the kernels through the interpreter against
+    plain attention, value and gradient; a length the blocks do not divide
+    (a module's S - 1) is filled up and cut."""
     dims = dataclasses.replace(program_config().latent, heads=2, kv_rank=16,
                                nope=128, rope=64, v=128)
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -462,10 +470,10 @@ def test_flash_with_a_value_width_of_its_own_matches_attention(s,
         close(a, b, 2e-3)
 
 
-def test_the_cells_attention_compiles_for_v5e(monkeypatch):
-    """Mosaic accepts the three kernels at the cell's shape: 2 rows x 32
-    heads, S = 8192, q/k 192 wide, v 128 (and the module's 8191 positions
-    filled up to 8192)."""
+@pytest.fixture
+def one_v5e_chip(monkeypatch):
+    """-> the sharding of one chip of a described v5e 2x2, which libtpu
+    compiles for without a device; flash takes its TPU branch."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     try:
@@ -475,13 +483,24 @@ def test_the_cells_attention_compiles_for_v5e(monkeypatch):
         pytest.skip(f"no TPU compiler here: {e!r}")
     monkeypatch.setattr(flash, "_on_tpu", lambda: True)
     monkeypatch.setattr(flash, "generation", lambda: "v5e")
-    one = SingleDeviceSharding(topo.devices[0])
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def mosaic_calls(compiled) -> list:
+    return [ln for ln in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
+def test_the_cells_attention_compiles_for_v5e(one_v5e_chip):
+    """Mosaic accepts the three kernels at the cell's shape: 2 rows x 32
+    heads, S = 8192, q/k 192 wide, v 128 (and the module's 8191 positions
+    filled up to 8192)."""
     dims = app.transformer_config(
         app.model_kwargs(PUBLISHED, 8192, "flash"), remat=True).latent
 
     def shapes(s):
         like = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
-                                                   sharding=one)
+                                                   sharding=one_v5e_chip)
         return (like(512, 32, 256), like(2, s, 32, 128), like(2, s, 32, 64),
                 like(2, s, 576))
 
@@ -492,10 +511,169 @@ def test_the_cells_attention_compiles_for_v5e(monkeypatch):
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
     for s in (8192, 8191):
-        hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
-            *shapes(s)).compile().as_text()
-        calls = [ln for ln in hlo.splitlines()
-                 if 'custom_call_target="tpu_custom_call"' in ln]
+        calls = mosaic_calls(jax.jit(jax.grad(
+            loss, argnums=(0, 1, 2, 3))).lower(*shapes(s)).compile())
         assert len(calls) == 3                  # forward, dkv, dq
         assert all("bf16[64,8192,192]" in ln and "bf16[64,8192,128]" in ln
                    for ln in calls)
+
+
+# ---------------------------------------------------------------------------
+# what flash's forward kernel hands its backward kernels outlives a remat'd
+# layer, where a kept byte spares enough of a second run (ops/flash.py)
+# ---------------------------------------------------------------------------
+
+# the toy widths with the cell's heads: q/k 128 + 64 wide, v 128
+KERNEL_TOY = {**TOY, "num_attention_heads": 2, "qk_nope_head_dim": 128,
+              "qk_rope_head_dim": 64, "qk_head_dim": 192, "v_head_dim": 128}
+# the bodies a step differentiates: the stack of leading dense layers, the
+# scanned period and the module's block, one latent layer each
+BODIES = 3
+
+
+def step_gradient(cfg):
+    return jax.value_and_grad(lambda params, tokens: (
+        transformer_loss_and_stats(params, {"tokens": tokens}, cfg)[0]))
+
+
+def shapes_of(cfg, rows, seq):
+    return (jax.eval_shape(partial(transformer_init, cfg=cfg),
+                           jax.random.PRNGKey(0)),
+            jax.ShapeDtypeStruct((rows, seq), jnp.int32))
+
+
+@pytest.mark.parametrize("remat,policy,forwards", [
+    (True, "kept", 1), (False, "kept", 1), (True, "bare", 2)])
+def test_the_flash_forward_kernel_runs_once_a_layer(
+        request, flash_interpreted, remat, policy, forwards):
+    """At the cell's S = 8,192 and head widths (traced, not run) the whole
+    loss's gradient, dense layer, scanned layers and module, holds one
+    ``rt_flash_fwd`` a latent layer under whole-layer remat, as without
+    remat; under a bare ``jax.checkpoint`` (the parent's) two. The backward
+    kernels are there once either way."""
+    if policy == "bare":
+        request.getfixturevalue("bare_checkpoint")
+    cfg = dataclasses.replace(
+        program_config(KERNEL_TOY, seq=8192, attn_impl="flash", remat=remat),
+        dtype=jnp.bfloat16)
+    assert (cfg.first_dense_layers, cfg.mtp_layers) == (1, 1)
+    found = equations(jax.make_jaxpr(step_gradient(cfg))(
+        *shapes_of(cfg, 2, 8192)).jaxpr)
+    assert found["rt_flash_fwd"] == forwards * BODIES
+    assert found["rt_flash_dkv"] == found["rt_flash_dq"] == BODIES
+    # out and the log-sum-exp of each forward that is traced
+    assert found[flash.KEPT] >= 2 * BODIES
+
+
+def test_what_flash_keeps_is_what_the_second_run_would_have_made(
+        request, monkeypatch, flash_interpreted):
+    """Loss and every gradient leaf of the whole step's loss, bit for bit,
+    between the policy and a bare ``jax.checkpoint`` of the same bodies (at
+    a size the interpreter runs, which the rule is told to keep)."""
+    monkeypatch.setattr(flash, "KEEP_FROM", 0)
+    cfg = program_config(KERNEL_TOY, seq=256, attn_impl="flash", remat=True)
+    params, tokens = seeded(cfg), tokens_of(seq=256)
+    forwards = lambda: equations(jax.make_jaxpr(step_gradient(cfg))(
+        params, tokens).jaxpr)["rt_flash_fwd"]
+    assert forwards() == BODIES
+    kept = jax.jit(step_gradient(cfg))(params, tokens)
+    request.getfixturevalue("bare_checkpoint")
+    assert forwards() == 2 * BODIES
+    bare = jax.jit(step_gradient(cfg))(params, tokens)
+    got = jax.tree_util.tree_leaves_with_path(kept)
+    want = jax.tree.leaves(bare)
+    assert len(got) == len(want) > 40
+    moved = 0
+    for (path, leaf), wanted in zip(got, want):
+        moved += float(jnp.abs(wanted).max()) > 0
+        np.testing.assert_array_equal(leaf, wanted, err_msg=str(path))
+    assert moved >= len(want) - 3         # the routers' biases get none
+
+
+@pytest.mark.parametrize("s,d,d_v,spared,keeps", [
+    (8192, 192, 128, 5041, True),         # this cell's latent layers
+    (8192, 256, 256, 4064, True),         # Qwen3-Next's gated attention
+    (2048, 128, 128, 1008, False)])       # both llama cells
+def test_who_keeps_is_read_from_the_kernels_shapes(
+        flash_interpreted, s, d, d_v, spared, keeps):
+    """The forward rule names ``out`` and the log-sum-exp where the
+    multiply-adds a second run would execute for each byte kept reach
+    ``KEEP_FROM``, the log-sum-exp as a column, and nowhere else: below it
+    the residuals are the kernel's outputs as they are."""
+    assert 1100 < flash.KEEP_FROM < 4000
+    pairs = s * (s + 1) // 2
+    assert pairs * (d + d_v) // (s * (2 * d_v + 4)) == spared
+    assert (spared >= flash.KEEP_FROM) == keeps
+    like = lambda width: jax.ShapeDtypeStruct((4, s, width), jnp.bfloat16)
+    rule = lambda q, k, v: flash._flash_bhsd_fwd(q, k, v, True, 1.0, 512,
+                                                 1024)
+    found = equations(jax.make_jaxpr(rule)(like(d), like(d), like(d_v)).jaxpr)
+    assert found["name"] == found[flash.KEPT] == (2 if keeps else 0)
+    assert found["rt_flash_fwd"] == 1
+    out, (_, _, _, kept_out, lse) = jax.eval_shape(rule, like(d), like(d),
+                                                   like(d_v))
+    assert out.shape == kept_out.shape == (4, s, d_v)
+    assert (lse.shape, lse.dtype) == ((4, s) if keeps else (4, s, 128),
+                                      jnp.float32)
+
+
+def llama_config(**widths):
+    return TransformerConfig(vocab_size=128, max_seq=2048, attn_impl="flash",
+                             **widths)
+
+
+def test_under_the_line_a_llama_step_is_the_bare_checkpoints(
+        request, flash_interpreted):
+    """S = 2,048 at a head of 128 through the flash kernels: nothing
+    carries a name, the policy finds nothing, and the step's gradient is
+    the jaxpr a bare ``jax.checkpoint`` gives (but for the line that prints
+    the policy's own address), the forward kernel twice a layer."""
+    cfg = llama_config(d_model=256, n_layers=2, n_heads=2, n_kv_heads=1,
+                       d_ff=512)
+    assert cfg.remat and cfg.head_dim == 128
+
+    def traced():
+        return jax.make_jaxpr(step_gradient(cfg))(*shapes_of(cfg, 2, 2048))
+
+    text = lambda jaxpr: re.sub(r"policy=.*", "policy=", str(jaxpr))
+    kept = traced()
+    found = equations(kept.jaxpr)
+    assert found["name"] == 0
+    assert (found["rt_flash_fwd"], found["rt_flash_dkv"],
+            found["rt_flash_dq"]) == (2, 1, 1)
+    assert "save_only_these_names" in str(kept)
+    request.getfixturevalue("bare_checkpoint")
+    bare = traced()
+    assert "policy=None" in str(bare)
+    assert text(kept) == text(bare)
+
+
+@pytest.mark.parametrize("which,calls", [("latent", 3), ("llama", 4)])
+def test_a_rematted_layers_gradient_compiled_for_v5e(one_v5e_chip, which,
+                                                     calls):
+    """One remat'd layer's gradient as the chip's compiler leaves it: the
+    cell's dense latent layer (2 rows x 8,192, 32 heads of 192 / 128) holds
+    three Mosaic calls, forward, dkv and dq, and no second forward; a llama
+    layer at S = 2,048 with heads of 128 holds four, as on the parent."""
+    from ray_tpu.parallel.sharding import DEFAULT_RULES
+    if which == "latent":
+        cfg = app.transformer_config(
+            app.model_kwargs(PUBLISHED, 8192, "flash"), remat=True)
+        stack, rows, seq = "dense_layers", 2, 8192
+    else:
+        cfg = llama_config(d_model=1024, n_layers=1, n_heads=8, n_kv_heads=2,
+                           d_ff=2048)
+        stack, rows, seq = "layers", 2, 2048
+    like = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_v5e_chip)
+    layer = jax.tree.map(lambda a: like(a.shape[1:], a.dtype),
+                         shapes_of(cfg, rows, seq)[0][stack])
+    body = transformer._layer_bodies(cfg, None, DEFAULT_RULES)[cfg.kinds[0]]
+
+    def loss(layer, x):
+        positions = jnp.broadcast_to(jnp.arange(seq), (rows, seq))
+        return jnp.sum(body(layer, x, positions)[0].astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        layer, like((rows, seq, cfg.d_model), jnp.bfloat16)).compile()
+    assert len(mosaic_calls(compiled)) == calls

@@ -338,6 +338,33 @@ def test_the_rules_forward_kernel_runs_once_a_layer(
     assert (found["shard_map"] > 0) == (mesh is not None)
 
 
+@pytest.mark.parametrize("mesh_shape", [None, (2, 1)])
+@pytest.mark.parametrize("policy,forwards", [("kept", 1), ("bare", 2)])
+def test_gated_attentions_flash_forward_runs_once_a_period(
+        request, monkeypatch, kernels_interpreted, mesh_shape, policy,
+        forwards):
+    """The period's one gated-attention layer through the flash kernels at
+    the cell's S = 8,192 and head of 256 (traced, not run): what its
+    backward kernels read of ``rt_flash_fwd`` carries flash's own name,
+    inside shard_map under a mesh, and the same policy keeps it beside the
+    delta rule's."""
+    from ray_tpu.ops import flash
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash, "generation", lambda: "v5e")
+    if policy == "bare":
+        request.getfixturevalue("bare_checkpoint")
+    cfg = dataclasses.replace(
+        program_config({**KERNEL_WIDTHS, "head_dim": 256}),
+        remat=True, attn_impl="flash", dtype=jnp.bfloat16)
+    found = equations(jax.make_jaxpr(period_loss_and_grads(
+        cfg, devices_as(mesh_shape)))(
+            seeded(cfg)["layers"], hidden(seq=8192)).jaxpr)
+    assert found["rt_flash_fwd"] == forwards
+    assert found["rt_flash_dkv"] == found["rt_flash_dq"] == 1
+    assert found["rt_gdn_fwd"] == forwards * GDN_LAYERS
+    assert found[flash.KEPT] >= 2
+
+
 @pytest.mark.parametrize("mesh_shape", [None, (2, 2)])
 def test_what_is_kept_is_what_the_second_run_would_have_made(
         request, kernels_interpreted, mesh_shape):
