@@ -717,6 +717,17 @@ class _ActorClient:
             with self.cv:
                 while not self.queue and not self.dead:
                     self.cv.wait(1.0)
+            # A call stays on the queue, where rt.cancel finds it, until
+            # there is a worker to send it (and its cancel) to: one taken
+            # off while the actor is still starting would be in neither
+            # place, and its cancel would stop nothing.
+            if self.address is None and not self.dead:
+                try:
+                    if not self._resolve_address() and not self.dead:
+                        continue
+                except BaseException:  # noqa: BLE001 - must not kill pusher
+                    pass    # _push_window meets it again and fails the batch
+            with self.cv:
                 if self.dead:
                     pending = list(self.queue)
                     self.queue.clear()
@@ -1869,10 +1880,14 @@ class ClusterRuntime:
                     cli.queue.remove(t)
                     break
         if task is not None:
+            name = f"{cli.class_name}.{task['method_name']}"
             self._store_error_returns(task, TaskError.from_exception(
-                TaskCancelledError("actor task cancelled"),
-                f"{cli.class_name}.{task['method_name']}"))
+                TaskCancelledError("actor task cancelled"), name))
             self._unpin_task(task)
+            # the task views name it, as they do a call its worker skipped
+            _events.emit("task.exec", task["task_id"].hex(), value=0.0,
+                         attrs={"task": name, "kind": "actor_task",
+                                "error": "cancelled"})
             return
         # Already pushed: the return oid is task_id + 4-byte index
         # (ids.py object_id_for_return), so the worker keys off oid[:-4].

@@ -1,45 +1,48 @@
-"""Autoregressive generation with a KV cache for the flagship Transformer.
+"""Autoregressive generation through a cache, for every stack `generate`
+serves (the reference has no in-tree LM inference; here decode is a TPU
+program: train with jax_step, serve with serve/ and this).
 
-The reference has no in-tree LM inference; serving there means wrapping an
-external model in Ray Serve. Here decode is a first-class TPU program
-(completing the LM story: train with jax_step, serve with serve/ + this):
+One cache, one trunk, two makers of a layer's attention step.
 
-- The KV cache is ONE stacked array pair [loop steps x L, B, T_max, KVH, D]
-  matching the layer-stacked parameter layout: one slot a layer, and for a
-  looped stack (``cfg.loop_steps`` passes over the one set of weights) one
-  slot for every (pass t, layer l), slot ``t * L + l``. The passes share
-  weights, not activations: layer l's keys and values of pass t are
-  projections of pass t's state, so a pass-t query sees pass-t keys only.
-  Decode scans over (layers, layer index), and over the passes around
-  that, with one compiled layer body and CARRIES the stacked cache through
-  both levels: a layer writes its new key and value at [slot, :, pos] and
-  then reads its slot's written prefix back from the updated carry. Write
-  first, read second: a read of the pre-update stack after the write would
-  make XLA keep two buffers and copy 2 GB a token. The cache is never a
-  scanned input or output inside the decode loop, so the token loop updates
-  one buffer in place (what a step writes is a few positions a slot, not
-  the whole cache).
+- The cache is a dict of arrays by layer kind, and `cache_shapes` alone
+  knows them: a softmax layer (kind ``"full"``) has a slot of ``k`` and of
+  ``v`` [slots, B, T_max, KVH, D], a latent layer one of ``latent`` (and of
+  the indexer's ``index``) over every position, a window layer one of
+  ``window``, a ring of `window_rows` positions. A looped stack
+  (``cfg.loop_steps`` passes over the one set of weights) has a slot for
+  every (pass t, layer l), slot ``t * L + l``: the passes share weights, not
+  activations, so a pass-t query sees pass-t keys only.
+- `_over_the_layers` is the trunk of prefill and decode alike: the leading
+  dense layers and then the periods of ``cfg.kinds``, under
+  ``transformer._over_loop_steps`` for the passes, each layer the training
+  forward's own (``transformer._layer_apply``) and handed only its attention
+  step ``attend_at(kind, cache, slot)``. The cache is the CARRY of every
+  level (passes, periods, and the token loop around them), never a scanned
+  input or output: a layer writes its slot and reads it back from the
+  updated carry, so the token loop updates one buffer in place. Write first,
+  read second: a read of the pre-update stack after the write would make
+  XLA keep two buffers and copy the cache a token.
+- `_write_chunk_at` makes the attention step of a prefill chunk,
+  `_write_and_read_at` that of a decode step; each has the three kinds.
+  `prefill_and_taps` and `decode_step_and_taps` are the two entry points
+  (``prefill``, ``prefill_and_exits``, ``decode_step`` and
+  ``decode_step_and_exits`` are views of them). The prompt of a stack by
+  kind goes through in chunks of queries, each attending to what the cache
+  holds; a softmax layer attends to its chunk's own keys, the training
+  forward's attention, so its stack goes through in one chunk.
 - `generate` runs the whole decode loop INSIDE jit via lax.scan: static
   shapes (cache padded to max length, attention masked by position), PRNG
-  threaded through the scan — zero host round-trips per token.
-- The token loop runs in SEGMENTS: consecutive lax.scans over the one
-  carried cache, same carry and same body, each compiled for a static
-  ``extent``: the positions its last step will have written, rounded up to
-  the block a step writes. A step's attention reads ``[B, extent, KVH, D]``
-  of its slot, not all of T_max (`_decode_segments`; the slice fuses into
-  the two attention fusions, no slab is written out). A short loop, or one
-  whose steps are a small part of the cache, stays one segment of T_max.
-- Prefill and decode run the training forward's one layer
-  (transformer._layer_apply) and hand it only the attention step: prefill
-  keeps each layer's rotated K/V as scan outputs (once a call; a looped
-  stack's prefill writes them into the carried cache slot by slot, since
-  the scan outputs of a pass, stacked over the passes, would hold a
-  pass's slots twice); decode steps attend over the cache with a position
-  mask. S=1 queries are bandwidth-bound, so a masked position costs what a
-  read one costs: where the cache is most of what a step reads (a slot for
-  every (pass, layer): 9.7 GB a token at 16 x 384 beside 19.9 GB of
-  weights) the never-written tail of T_max is a third of the cache's
-  bytes, which is what the segments' extents are for.
+  threaded through the scan, no host round trip a token. The token loop
+  runs in SEGMENTS: consecutive scans over the one carried cache, same
+  carry and same body, each compiled for a static ``extent``: the positions
+  its last step will have written, rounded up to the block a step writes. A
+  step's attention reads ``[B, extent, ...]`` of its slot, not all of T_max
+  (`_decode_segments`; the slice fuses into the attention fusions, no slab
+  is written out). S=1 queries are bandwidth-bound, so a masked position
+  costs what a read one costs: where the cache is most of what a step reads
+  (9.7 GB a token at 16 x 384 over 192 slots beside 19.9 GB of weights) the
+  never-written tail of T_max is a third of the cache's bytes. A short
+  loop, or one whose steps are a small part of the cache, stays one segment.
 
 GQA (n_kv_heads < n_heads) is supported; pp_stages>1 is not (decode
 pipelining is a different schedule than GPipe microbatching).
@@ -65,14 +68,8 @@ from ray_tpu.util import events
 LATENT_KINDS = ("latent", "window")
 
 
-def _by_kind(cfg: TransformerConfig) -> bool:
-    """A stack of latent-attention layers, served from a cache by kind."""
-    return bool(cfg.layer_types) and \
-        set(cfg.layer_types) <= set(LATENT_KINDS)
-
-
 def _refuse_recurrent(cfg: TransformerConfig) -> None:
-    if _by_kind(cfg):
+    if set(cfg.layer_types) <= set(LATENT_KINDS):
         return
     if "linear" in cfg.layer_types:
         raise NotImplementedError(
@@ -81,11 +78,10 @@ def _refuse_recurrent(cfg: TransformerConfig) -> None:
             "state [B, Hv, dk, dv] and convolution tail would have to live "
             "beside the keys and values, and the cache here holds one kind "
             "(ROADMAP.md R8)")
-    if cfg.layer_types:
-        raise NotImplementedError(
-            "generate serves one stack of like softmax-attention layers, or "
-            "a pattern of latent and window layers; this pattern "
-            f"{cfg.layer_types} is not served")
+    raise NotImplementedError(
+        "generate serves one stack of like softmax-attention layers, or "
+        "a pattern of latent and window layers; this pattern "
+        f"{cfg.layer_types} is not served")
 
 
 def cache_slots(cfg: TransformerConfig) -> int:
@@ -106,31 +102,38 @@ def _lead_slots(cfg: TransformerConfig, kind: str) -> int:
 
 
 def kind_slots(cfg: TransformerConfig) -> Dict[str, int]:
-    """Cache slots by layer kind: a layer of a kind has one."""
-    return {kind: _lead_slots(cfg, kind)
-            + cfg.periods * cfg.layer_types.count(kind)
-            for kind in LATENT_KINDS}
+    """Cache slots by layer kind: a layer of a kind has one a loop step."""
+    return {kind: cfg.loop_steps * (
+        _lead_slots(cfg, kind) + cfg.periods * cfg.kinds.count(kind))
+        for kind in ("full",) + LATENT_KINDS}
+
+
+def _slots_of_pass(cfg: TransformerConfig, t):
+    """The periods of loop step ``t``, in the order they run, numbered
+    over all the passes. A plain stack's period is one layer: its layers'
+    cache slots ``t * L + l``."""
+    return t * cfg.periods + jnp.arange(cfg.periods)
 
 
 def _slot(cfg: TransformerConfig, kind: str, period, j: int):
     """The slot, among its kind's, of the layer at position ``j`` of
-    period ``period`` (the leading dense layers hold their kind's
-    first)."""
-    return _lead_slots(cfg, kind) + period * cfg.layer_types.count(kind) \
-        + cfg.layer_types[:j].count(kind)
+    period ``period`` (as `_slots_of_pass` numbers them; the leading dense
+    layers hold their kind's first)."""
+    return _lead_slots(cfg, kind) + period * cfg.kinds.count(kind) \
+        + cfg.kinds[:j].count(kind)
 
 
 def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int
                  ) -> Dict[str, Tuple[int, ...]]:
-    """The cache's arrays. A stack by kind holds, a latent layer, the
-    latent and shared key of every position (``latent``) and the indexer's
-    key (``index``), and a window layer a ring of `window_rows` positions
-    (``window``): [slots of the kind, B, positions, 1, width]."""
-    if not _by_kind(cfg):
-        shape = (cache_slots(cfg), batch, max_len, cfg.kv_heads,
-                 cfg.head_dim)
-        return {"k": shape, "v": shape}
+    """The cache's arrays, [slots of the kind, B, positions, heads, width]:
+    a softmax layer holds the rotated keys and the values of every position
+    (``k``, ``v``), a latent layer the latent and shared key of every
+    position (``latent``) and the indexer's key (``index``), a window layer
+    a ring of `window_rows` positions (``window``)."""
     slots, shapes = kind_slots(cfg), {}
+    if slots["full"]:
+        shapes["k"] = shapes["v"] = (slots["full"], batch, max_len,
+                                     cfg.kv_heads, cfg.head_dim)
     if slots["latent"]:
         shapes["latent"] = (slots["latent"], batch, max_len, 1,
                             cfg.latent.cached)
@@ -144,14 +147,9 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
-    """Zeros of `cache_shapes` (kv dtype = compute dtype): the
-    [loop steps x L, B, T, KVH, D] pair, or the arrays by kind."""
-    if _by_kind(cfg):
-        return {name: jnp.zeros(shape, cfg.dtype) for name, shape
-                in cache_shapes(cfg, batch, max_len).items()}
-    shape = (cache_slots(cfg), batch, max_len, cfg.kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype)}
+    """Zeros of `cache_shapes` (kv dtype = compute dtype)."""
+    return {name: jnp.zeros(shape, cfg.dtype) for name, shape
+            in cache_shapes(cfg, batch, max_len).items()}
 
 
 def _cached_attention(cfg: TransformerConfig, q, k_cache, v_cache, pos):
@@ -244,8 +242,11 @@ def _write_prompt(cache, l, new):
     positions before heads, and the compiler copies the whole stack from
     the one to the other: at [192, 16, 384, 16, 128] two copies of 4.8 GB
     beside the stack, 18.85 of the 15.75 GiB a v5e has. This loop's body
-    holds no matmul, so its carry keeps the default layout, which is the
-    decode loop's, and what is laid out anew is the layer's own 8 MB."""
+    holds no matmul, so its carry keeps the default layout, which is that
+    decode loop's, and what is laid out anew is the layer's own 8 MB.
+    Where the decode loop wants the other (8 KV heads under 32 query heads,
+    [24, 32, 640, 8, 128]) the stack is copied once a call, 2 x 1.0 GB,
+    11 ms of a 3,372 ms call on a v5e: ROADMAP.md S11."""
     def row(b, cache):
         return lax.dynamic_update_slice(
             cache, lax.dynamic_slice_in_dim(new, b, 1)[None],
@@ -253,63 +254,33 @@ def _write_prompt(cache, l, new):
     return lax.fori_loop(0, new.shape[0], row, cache)
 
 
-def _slots_of_pass(cfg: TransformerConfig, t):
-    """The cache slots of loop step ``t``'s layers, in layer order."""
-    return t * cfg.n_layers + jnp.arange(cfg.n_layers)
-
-
-def _over_the_slots(cfg: TransformerConfig, params, x, positions, cache,
-                    attend_at):
-    """The trunk over a CARRIED cache: every loop step's pass over the
-    layers, layer l of step t owning slot ``t * L + l``. ``attend_at(
-    cache_k, cache_v, slot)`` gives the layer its attention step, which
-    hands back the two stacks with the slot written. -> (x, cache, exit
-    distribution or None)."""
-    def layers(x, kv, t):
-        def step(carry, layer_and_slot):
-            x, kv = carry
-            layer, slot = layer_and_slot
-            return _layer_apply(cfg, layer, x, positions,
-                                attend_at(*kv, slot))[:2], None
-
-        (x, kv), _ = lax.scan(step, (x, kv),
-                              (params["layers"], _slots_of_pass(cfg, t)))
-        return x, kv
-
-    x, (cache_k, cache_v), exits = _over_loop_steps(
-        cfg, params, layers, x, (cache["k"], cache["v"]))
-    return x, {"k": cache_k, "v": cache_v}, exits
-
-
-# A stack by kind (latent and window layers, models/latent.py). The prompt
-# goes through the whole stack in chunks of queries, each chunk writing its
-# entries into the carried cache and attending to what stands: at 32,768
-# positions one row's queries of 128 heads x 192 are 1.6 GB and the expert
-# layer's buffer grows with the tokens of a step.
-PREFILL_CHUNK = 2048
-
-
-def prefill_chunk(prompt: int, most: int = PREFILL_CHUNK) -> int:
-    """Queries a chunk: the largest divisor of the prompt up to ``most``."""
-    return max(c for c in range(1, min(most, prompt) + 1) if prompt % c == 0)
-
-
 def _slot_rows(stack, slot):
     """stack [slots, B, T, 1, W] -> the slot's [B, T, W]."""
     return lax.dynamic_index_in_dim(stack, slot, 0, keepdims=False)[:, :, 0]
 
 
-def _over_the_kinds(cfg: TransformerConfig, params, x, positions, cache,
-                    attend_at):
-    """The trunk over a CARRIED cache by kind: the leading dense layers,
-    then the periods. ``attend_at(kind, cache, slot)`` gives a layer its
-    ``attend(new) -> (keys, key positions, cache)``, which hands back the
-    cache with the slot written. -> (x, cache, [rows routed to held
-    experts, rows dropped] summed over the layers, taps: the indexed
-    layers' selections and the window layers' key counts, ``lead`` stacked over the leading layers and
-    ``periods`` a list over the period's positions, stacked over the
-    periods)."""
-    kinds = cfg.layer_types
+def _over_the_layers(cfg: TransformerConfig, params, x, positions, cache,
+                     attend_at):
+    """The trunk over the CARRIED cache: every loop step's pass over the
+    leading dense layers and then the periods of ``cfg.kinds`` (a plain
+    stack's period is its one ``"full"`` layer). ``attend_at(kind, cache,
+    slot)`` gives a layer its attention step (``transformer._layer_apply``'s
+    ``attend`` of that kind), which hands back the cache with the slot
+    written. -> (x, cache, exit distribution or None where there is no
+    loop, [rows routed to held experts, rows dropped] summed over the
+    layers, taps: the indexed layers' selections and the window layers' key
+    counts, ``lead`` stacked over the leading layers and ``periods`` a list
+    over the period's positions, stacked over the periods).
+
+    Only latent kinds have taps and only a stack of like ``"full"`` layers
+    loops (``TransformerConfig`` refuses a loop over a pattern or an expert
+    layer), so the taps ride out on the carry of ``_over_loop_steps``, whose
+    scanned step may not change its carry's shape and has no outputs of its
+    own: a looped stack's are as empty as they went in. The rows' counter
+    rides with them; where no layer adds to it, it is a loop invariant that
+    XLA drops."""
+    kinds = cfg.kinds
+    periods = params["layers"] if cfg.layer_types else (params["layers"],)
 
     def run(kind, slot, layer, x, cache, counts):
         x, cache, stats = _layer_apply(cfg, layer, x, positions,
@@ -321,34 +292,63 @@ def _over_the_kinds(cfg: TransformerConfig, params, x, positions, cache,
         return (x, cache, counts), {k: v for k, v in stats.items()
                                     if k.startswith(("selected", "window"))}
 
-    carry, lead_taps = (x, cache, jnp.zeros((2,), jnp.int32)), None
-    if cfg.first_dense_layers:
-        carry, lead_taps = lax.scan(
-            lambda carry, at: run(kinds[0], at[1], at[0], *carry),
-            carry,
-            (params["dense_layers"], jnp.arange(cfg.first_dense_layers)))
+    def stack(x, carry, t):
+        cache, counts, _ = carry
+        carry, lead_taps = (x, cache, counts), None
+        if cfg.first_dense_layers:
+            carry, lead_taps = lax.scan(
+                lambda carry, at: run(kinds[0], at[1], at[0], *carry),
+                carry,
+                (params["dense_layers"], jnp.arange(cfg.first_dense_layers)))
 
-    def period(carry, layers_and_index):
-        layers, i = layers_and_index
-        taps = []
-        for j, (kind, layer) in enumerate(zip(kinds, layers)):
-            carry, tap = run(kind, _slot(cfg, kind, i, j), layer, *carry)
-            taps.append(tap)
-        return carry, taps
+        def period(carry, layers_and_index):
+            layers, i = layers_and_index
+            taps = []
+            for j, (kind, layer) in enumerate(zip(kinds, layers)):
+                carry, tap = run(kind, _slot(cfg, kind, i, j), layer, *carry)
+                taps.append(tap)
+            return carry, taps
 
-    (x, cache, counts), taps = lax.scan(
-        period, carry, (params["layers"], jnp.arange(cfg.periods)))
-    return x, cache, counts, {"lead": lead_taps, "periods": taps}
+        (x, cache, counts), taps = lax.scan(
+            period, carry, (periods, _slots_of_pass(cfg, t)))
+        return x, (cache, counts, {"lead": lead_taps, "periods": taps})
+
+    x, (cache, counts, taps), exits = _over_loop_steps(
+        cfg, params, stack, x,
+        (cache, jnp.zeros((2,), jnp.int32),
+         {"lead": None, "periods": [{} for _ in kinds]}))
+    return x, cache, exits, counts, taps
 
 
-def _write_chunk_at(cfg: TransformerConfig, start, chunk: int):
+# A stack by kind (latent and window layers, models/latent.py) takes the
+# prompt in chunks of queries, each chunk writing its entries into the
+# carried cache and attending to what stands: at 32,768 positions one row's
+# queries of 128 heads x 192 are 1.6 GB and the expert layer's buffer grows
+# with the tokens of a step.
+PREFILL_CHUNK = 2048
+
+
+def prefill_chunk(prompt: int, most: int = PREFILL_CHUNK) -> int:
+    """Queries a chunk: the largest divisor of the prompt up to ``most``."""
+    return max(c for c in range(1, min(most, prompt) + 1) if prompt % c == 0)
+
+
+def _write_chunk_at(cfg: TransformerConfig, start, chunk: int, mesh=None):
     """``attend_at`` of a prefill chunk of ``chunk`` positions from
-    ``start``. A latent layer writes the chunk's entries at their positions
-    and attends to its whole slot (the mask leaves out what is not written
+    ``start``. A softmax layer runs the training forward's attention over
+    the chunk's own keys (the whole prompt: `prefill_and_taps`) and keeps
+    the rotated K and V it ran on, so the cache matches the forward bit for
+    bit; a latent layer writes the chunk's entries at their positions and
+    attends to its whole slot (the mask leaves out what is not written
     yet); a window layer attends to its ring as it stood and the chunk's
     own entries, then writes the chunk's last `window_rows` into the
     ring."""
     def attend_at(kind, cache, slot):
+        def full(q, k, v):
+            return _attention(cfg, q, k, v, mesh), dict(
+                cache, k=_write_prompt(cache["k"], slot, k),
+                v=_write_prompt(cache["v"], slot, v))
+
         def latent(new):
             out = dict(cache)
             for name, rows in new.items():
@@ -371,16 +371,26 @@ def _write_chunk_at(cfg: TransformerConfig, start, chunk: int):
                 cache["window"], ring[None, :, :, None, :],
                 (slot, 0, 0, 0, 0)))
 
-        return latent if kind == "latent" else window
+        return {"full": full, "latent": latent, "window": window}[kind]
     return attend_at
 
 
 def _write_and_read_at(cfg: TransformerConfig, pos, extent: int):
     """``attend_at`` of a decode step at position ``pos``: write the entry,
-    then read the slot from the UPDATED stack (as `decode_step_and_exits`):
-    a latent layer its first ``extent`` positions, a window layer its
+    then read the slot from the UPDATED stack (a read of the old stack
+    after the write would make XLA keep two buffers and copy): a softmax
+    and a latent layer its first ``extent`` positions, a window layer its
     ring."""
     def attend_at(kind, cache, slot):
+        def full(q, k, v):
+            with jax.named_scope("rt.loop.cache"):
+                stack_k = _write_position(cache["k"], slot, pos, k)
+                stack_v = _write_position(cache["v"], slot, pos, v)
+                o = _cached_attention(
+                    cfg, q, _slot_prefix(stack_k, slot, extent),
+                    _slot_prefix(stack_v, slot, extent), pos)
+            return o, dict(cache, k=stack_k, v=stack_v)
+
         def latent(new):
             out = dict(cache)
             for name, row in new.items():
@@ -398,22 +408,39 @@ def _write_and_read_at(cfg: TransformerConfig, pos, extent: int):
                     ring_positions(pos, rows)[None],
                     dict(cache, window=stack))
 
-        return latent if kind == "latent" else window
+        return {"full": full, "latent": latent, "window": window}[kind]
     return attend_at
 
 
+def _last_exits(exits):
+    """The exit distribution [B, S, loop_steps] -> the last position's [B,
+    loop_steps]; None where there is no loop."""
+    return None if exits is None else exits[:, -1]
+
+
 def prefill_and_taps(params, tokens, cfg: TransformerConfig, max_len: int,
-                     chunk: Optional[int] = None):
-    """A stack by kind: the prompt [B, S] through the trunk in chunks of
-    ``chunk`` queries (`prefill_chunk` of S where None; S is a multiple)
+                     chunk: Optional[int] = None, mesh=None):
+    """The prompt [B, S] (S <= max_len) through the trunk in chunks of
+    ``chunk`` queries (S is a multiple; where None, `prefill_chunk` of S)
     -> (last-position logits [B, vocab], filled cache, taps of the last
-    chunk as `_over_the_kinds` gives them, with ``moe_rows``: [rows routed
-    to held experts, rows dropped] over the whole prompt)."""
+    chunk as `_over_the_layers` gives them, with ``moe_rows``: [rows routed
+    to held experts, rows dropped] over the whole prompt, and ``exits``:
+    the last position's exit distribution [B, loop_steps], None where
+    there is no loop)."""
+    if cfg.pp_stages > 1:
+        raise NotImplementedError("decode with pp_stages>1 is not supported")
+    _refuse_recurrent(cfg)
     b, s = tokens.shape
-    chunk = chunk or prefill_chunk(s)
+    # A softmax layer's prefill attends to its chunk's own keys and no
+    # others, so a stack of them goes through in one chunk: the prompt.
+    whole = "full" in cfg.kinds
+    chunk = chunk or (s if whole else prefill_chunk(s))
     if s % chunk:
         raise ValueError(f"a prompt of {s} is not a multiple of the chunk "
                          f"{chunk}")
+    if whole and chunk != s:
+        raise ValueError("a stack of softmax layers takes its prompt in "
+                         f"one chunk, not {s} in chunks of {chunk}")
     embed = params["embed"].astype(cfg.dtype)
 
     def step(carry, c):
@@ -421,129 +448,70 @@ def prefill_and_taps(params, tokens, cfg: TransformerConfig, max_len: int,
         start = c * chunk
         positions = start + jnp.broadcast_to(jnp.arange(chunk), (b, chunk))
         x = embed[lax.dynamic_slice_in_dim(tokens, start, chunk, axis=1)]
-        x, cache, n, taps = _over_the_kinds(
+        x, cache, exits, n, taps = _over_the_layers(
             cfg, params, x, positions, cache,
-            _write_chunk_at(cfg, start, chunk))
-        return (cache, counts + n), (x[:, -1], taps)
+            _write_chunk_at(cfg, start, chunk, mesh))
+        return (cache, counts + n), (x[:, -1], _last_exits(exits), taps)
 
-    (cache, counts), (last, taps) = lax.scan(
+    (cache, counts), last = lax.scan(
         step, (init_cache(cfg, b, max_len), jnp.zeros((2,), jnp.int32)),
         jnp.arange(s // chunk))
-    taps = dict(jax.tree.map(lambda a: a[-1], taps), moe_rows=counts)
-    return _head(params, last[-1][:, None], cfg)[:, 0], cache, taps
+    x, exits, taps = jax.tree.map(lambda a: a[-1], last)
+    return (_head(params, x[:, None], cfg)[:, 0], cache,
+            dict(taps, moe_rows=counts, exits=exits))
 
 
 def decode_step_and_taps(params, token, pos, cache, cfg: TransformerConfig,
                          *, extent: Optional[int] = None):
-    """A stack by kind, one token for the whole batch (as
-    `decode_step_and_exits`) -> (logits [B, vocab], updated cache, taps
-    with ``moe_rows``)."""
+    """One token for the whole batch: token [B] int32, pos scalar int32.
+    -> (logits [B, vocab], updated cache, taps with ``moe_rows`` and
+    ``exits`` as `prefill_and_taps` gives them). Attention reads the first
+    ``extent`` positions of a slot (static; the caller's word that ``pos <
+    extent``), all of them where it is None; a window layer reads its
+    ring."""
+    _refuse_recurrent(cfg)
     if extent is None:
-        extent = cache["latent"].shape[2] if "latent" in cache else 0
-    x = params["embed"].astype(cfg.dtype)[token][:, None, :]
+        extent = max((a.shape[2] for name, a in cache.items()
+                      if name != "window"), default=0)
+    x = params["embed"].astype(cfg.dtype)[token][:, None, :]   # [B, 1, E]
     positions = jnp.full((x.shape[0], 1), pos)
-    x, cache, counts, taps = _over_the_kinds(
+    x, cache, exits, counts, taps = _over_the_layers(
         cfg, params, x, positions, cache,
         _write_and_read_at(cfg, pos, extent))
-    return _head(params, x, cfg)[:, 0], cache, dict(taps, moe_rows=counts)
+    return (_head(params, x, cfg)[:, 0], cache,
+            dict(taps, moe_rows=counts, exits=_last_exits(exits)))
 
 
 def prefill_and_exits(params, tokens, cfg: TransformerConfig, max_len: int,
                       mesh=None):
-    """Run the prompt through the trunk, returning (last-position logits
-    [B, vocab], filled cache, the last position's exit distribution [B,
-    loop_steps] or None where there is no loop). tokens [B, S], S <=
-    max_len."""
-    if cfg.pp_stages > 1:
-        raise NotImplementedError("decode with pp_stages>1 is not supported")
-    _refuse_recurrent(cfg)
-    if _by_kind(cfg):
-        logits, cache, _ = prefill_and_taps(params, tokens, cfg, max_len)
-        return logits, cache, None
-    b, s = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    if cfg.loop_steps == 1:
-        # One pass: each layer's K and V are the layer scan's outputs,
-        # stacked as the cache. Of a looped stack those outputs would be
-        # stacked once more over the passes, a pass's slots (2.4 GB of 9.7)
-        # held twice; there the cache is the loops' carry, below.
-        pad = ((0, 0), (0, max_len - s), (0, 0), (0, 0))
-
-        def attend(q, k, v):
-            # The training forward's attention; the layer's rotated K and
-            # V are kept, so the cache matches the forward bit for bit.
-            return (_attention(cfg, q, k, v, mesh),
-                    {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)})
-
-        def step(carry, layer):
-            return _layer_apply(cfg, layer, carry, positions, attend)[:2]
-
-        x, cache = lax.scan(step, x, params["layers"])
-        return _head(params, x[:, -1:], cfg)[:, 0], cache, None
-
-    def write_at(cache_k, cache_v, slot):
-        def attend(q, k, v):
-            # the same attention and the same K and V; each goes straight
-            # into its slot of the carried cache
-            return _attention(cfg, q, k, v, mesh), (
-                _write_prompt(cache_k, slot, k),
-                _write_prompt(cache_v, slot, v))
-        return attend
-
-    x, cache, exits = _over_the_slots(cfg, params, x, positions,
-                                      init_cache(cfg, b, max_len), write_at)
-    return _head(params, x[:, -1:], cfg)[:, 0], cache, exits[:, -1]
+    """``prefill_and_taps`` with the exits for the taps: (logits, cache,
+    the last position's exit distribution or None)."""
+    logits, cache, taps = prefill_and_taps(params, tokens, cfg, max_len,
+                                           mesh=mesh)
+    return logits, cache, taps["exits"]
 
 
 def prefill(params, tokens, cfg: TransformerConfig, max_len: int,
             mesh=None) -> Tuple[jnp.ndarray, Dict[str, Any]]:
-    """``prefill_and_exits`` without the exits: (logits, cache)."""
-    return prefill_and_exits(params, tokens, cfg, max_len, mesh)[:2]
+    """``prefill_and_taps`` without the taps: (logits, cache)."""
+    return prefill_and_taps(params, tokens, cfg, max_len, mesh=mesh)[:2]
 
 
 def decode_step_and_exits(params, token, pos, cache,
                           cfg: TransformerConfig, *,
                           extent: Optional[int] = None):
-    """One token for the whole batch: token [B] int32, pos scalar int32.
-    -> (logits [B, vocab], updated cache, exit distribution [B,
-    loop_steps] or None where there is no loop). Attention reads the
-    first ``extent`` positions of a slot (static; the caller's word that
-    ``pos < extent``), all of them where it is None."""
-    _refuse_recurrent(cfg)
-    if _by_kind(cfg):
-        logits, cache, _ = decode_step_and_taps(params, token, pos, cache,
-                                                cfg, extent=extent)
-        return logits, cache, None
-    extent = cache["k"].shape[2] if extent is None else extent
-    x = params["embed"].astype(cfg.dtype)[token][:, None, :]   # [B, 1, E]
-    positions = jnp.full((x.shape[0], 1), pos)
-
-    def write_and_read_at(cache_k, cache_v, slot):
-        def attend(q, k, v):
-            # Write, then read the slot from the UPDATED stack: a read of
-            # the old stack after the write would make XLA keep two
-            # buffers and copy.
-            with jax.named_scope("rt.loop.cache"):
-                stack_k = _write_position(cache_k, slot, pos, k)
-                stack_v = _write_position(cache_v, slot, pos, v)
-                o = _cached_attention(
-                    cfg, q, _slot_prefix(stack_k, slot, extent),
-                    _slot_prefix(stack_v, slot, extent), pos)
-            return o, (stack_k, stack_v)
-        return attend
-
-    x, cache, exits = _over_the_slots(cfg, params, x, positions, cache,
-                                      write_and_read_at)
-    return (_head(params, x, cfg)[:, 0], cache,
-            None if exits is None else exits[:, 0])
+    """``decode_step_and_taps`` with the exits for the taps: (logits,
+    cache, exit distribution [B, loop_steps] or None)."""
+    logits, cache, taps = decode_step_and_taps(params, token, pos, cache,
+                                               cfg, extent=extent)
+    return logits, cache, taps["exits"]
 
 
 def decode_step(params, token, pos, cache, cfg: TransformerConfig, *,
                 extent: Optional[int] = None):
-    """``decode_step_and_exits`` without the exits: (logits, cache)."""
-    return decode_step_and_exits(params, token, pos, cache, cfg,
-                                 extent=extent)[:2]
+    """``decode_step_and_taps`` without the taps: (logits, cache)."""
+    return decode_step_and_taps(params, token, pos, cache, cfg,
+                                extent=extent)[:2]
 
 
 def _sample(logits, key, temperature: float, top_k: Optional[int]):
@@ -559,7 +527,9 @@ def _sample(logits, key, temperature: float, top_k: Optional[int]):
 def _expected_exit_step(exits):
     """exits [B, loop_steps] -> sum over the rows of ``sum_t (t + 1)
     p_t``: the loop steps these tokens would have run at a threshold that
-    follows the gate."""
+    follows the gate; 0 where there is no loop (None)."""
+    if exits is None:
+        return 0.0
     return jnp.sum(exits * jnp.arange(1, exits.shape[-1] + 1,
                                       dtype=exits.dtype))
 
@@ -572,66 +542,53 @@ def generate_and_cache(params, prompt, cfg: TransformerConfig, *,
     """prompt [B, S] int32 -> (generated tokens [B, max_new_tokens],
     stats, the cache as the call's last step left it: what a caller that
     holds the call to a reference reads, and the token loop's carry, so
-    handing it back costs no copy). ``stats`` is ``{}`` but for a looped
-    stack: there
+    handing it back costs no copy). ``stats`` holds, of a looped stack,
     ``exit_steps_sum``, the sum over the generated tokens of the exit
     gate's expected loop step ``sum_t (t + 1) p_t``, and ``exit_tokens``,
-    their count: a few floats, accumulated in the decode loop's carry; and
-    for a stack by kind, whose expert layers must drop nothing:
-    ``moe_rows_here`` and ``moe_rows_dropped``, summed over the call.
+    their count; and of a stack with expert layers, which must drop
+    nothing, ``moe_rows_here`` and ``moe_rows_dropped``, summed over the
+    call: a few numbers, accumulated in the decode loop's carry.
 
     The whole decode loop runs inside the caller's jit scope (wrap with
     jax.jit(partial(generate, ...)) or call under jit), one lax.scan for
     each of `_decode_segments`' segments: no per-token host round trips.
     """
-    _refuse_recurrent(cfg)
     b, s = prompt.shape
-    max_len = s + max_new_tokens
-    looped, by_kind = cfg.loop_steps > 1, _by_kind(cfg)
-    rows = None
     with jax.named_scope("rt.generate.prefill"):
-        if by_kind:
-            logits, cache, taps = prefill_and_taps(params, prompt, cfg,
-                                                   max_len)
-            exits, rows = None, taps["moe_rows"]
-        else:
-            logits, cache, exits = prefill_and_exits(params, prompt, cfg,
-                                                     max_len, mesh=mesh)
+        logits, cache, taps = prefill_and_taps(
+            params, prompt, cfg, s + max_new_tokens, mesh=mesh)
     key = jax.random.PRNGKey(seed)
     key, sub = jax.random.split(key)
     first = _sample(logits, sub, temperature, top_k)
 
     def step(extent, carry, _):
         token, pos, cache, key, exits, steps_sum, rows = carry
-        if looped:      # ``exits`` came with the logits ``token`` is from
-            steps_sum = steps_sum + _expected_exit_step(exits)
-        if by_kind:
-            logits, cache, taps = decode_step_and_taps(
-                params, token, pos, cache, cfg, extent=extent)
-            rows = rows + taps["moe_rows"]
-        else:
-            logits, cache, exits = decode_step_and_exits(
-                params, token, pos, cache, cfg, extent=extent)
+        # ``exits`` came with the logits ``token`` is from
+        steps_sum = steps_sum + _expected_exit_step(exits)
+        logits, cache, taps = decode_step_and_taps(
+            params, token, pos, cache, cfg, extent=extent)
         key, sub = jax.random.split(key)
         nxt = _sample(logits, sub, temperature, top_k)
-        return (nxt, pos + 1, cache, key, exits, steps_sum, rows), token
+        return (nxt, pos + 1, cache, key, taps["exits"], steps_sum,
+                rows + taps["moe_rows"]), token
 
-    carry = (first, jnp.asarray(s, jnp.int32), cache, key, exits,
-             jnp.zeros((), jnp.float32) if looped else None, rows)
+    carry = (first, jnp.asarray(s, jnp.int32), cache, key, taps["exits"],
+             jnp.zeros((), jnp.float32), taps["moe_rows"])
     tokens = []
     with jax.named_scope("rt.generate.decode"):
         for steps, extent in _decode_segments(s, max_new_tokens):
             carry, emitted = lax.scan(partial(step, extent), carry, None,
                                       length=steps)
             tokens.append(emitted)
-    steps_sum, rows = carry[-2:]
-    tokens = jnp.concatenate(tokens)
-    stats = {"exit_steps_sum": steps_sum,
-             "exit_tokens": jnp.asarray(b * max_new_tokens, jnp.float32)} \
-        if looped else {}
-    if by_kind:
-        stats = {"moe_rows_here": rows[0], "moe_rows_dropped": rows[1]}
-    return jnp.transpose(tokens, (1, 0)), stats, carry[2]
+    _, _, cache, _, exits, steps_sum, rows = carry
+    stats = {}
+    if exits is not None:
+        stats.update(
+            exit_steps_sum=steps_sum,
+            exit_tokens=jnp.asarray(b * max_new_tokens, jnp.float32))
+    if cfg.num_experts:
+        stats.update(moe_rows_here=rows[0], moe_rows_dropped=rows[1])
+    return jnp.transpose(jnp.concatenate(tokens), (1, 0)), stats, cache
 
 
 def generate_with_stats(params, prompt, cfg: TransformerConfig, *,
@@ -667,19 +624,21 @@ def call_span(cfg: TransformerConfig, rows: int, prompt: int,
     segments = _decode_segments(prompt, new)
     itemsize = jnp.dtype(cfg.dtype).itemsize
     shapes = cache_shapes(cfg, rows, prompt + new)
+    by_kind = "k" not in shapes         # latent and window layers
     attrs = dict(
         rows=rows, prompt=prompt, new=new, loop_steps=cfg.loop_steps,
-        attention_path="latent" if _by_kind(cfg)
+        attention_path="latent" if by_kind
         else auto_path(prompt, prompt, cfg.head_dim)
         if cfg.attn_impl == "auto" else cfg.attn_impl,
-        cache_slots=sum(shape[0] for shape in shapes.values())
-        if _by_kind(cfg) else cache_slots(cfg),
+        # a softmax layer's slot is its pair of k and v
+        cache_slots=sum(shape[0] for name, shape in shapes.items()
+                        if name != "v"),
         cache_bytes=sum(math.prod(shape) for shape in shapes.values())
         * itemsize,
         decode_segments=len(segments),
         cache_positions_read=sum(n * extent for n, extent in segments),
         cache_positions_needed=new * prompt + new * (new + 1) // 2)
-    if _by_kind(cfg):
+    if by_kind:
         # a latent layer's queries, each over the keys up to its own: all
         # of them scored by the indexer, index_topk of them attended to
         layers = rows * kind_slots(cfg)["latent"]

@@ -338,28 +338,52 @@ def _last_logits(params, tokens, cfg):
     }
 
 
-def _halved(fn):
-    """``fn`` with its result halved (of ``_feed_forward``'s pair, the
-    result and not the expert layer's counters)."""
-    def half(*a, **kw):
+def _scaled(fn, by):
+    """``fn`` with its result times ``by`` (of ``_feed_forward``'s pair,
+    the result and not the expert layer's counters; of the trunk's, the
+    state and not the cache)."""
+    def scaled(*a, **kw):
         out = fn(*a, **kw)
-        return (0.5 * out[0],) + out[1:] if isinstance(out, tuple) \
-            else 0.5 * out
-    return half
+        return (by * out[0],) + out[1:] if isinstance(out, tuple) \
+            else by * out
+    return scaled
 
 
-@pytest.mark.parametrize("overrides", [
-    pytest.param(dict(), id="dense"),
-    pytest.param(dict(num_experts=4, expert_top_k=2), id="moe"),
+def _latent_dims():
+    from ray_tpu.models.transformer import LatentDims
+    return LatentDims(heads=4, q_rank=16, kv_rank=16, nope=8, rope=8, v=8)
+
+
+# the three kinds of stack `generate` serves, through its one trunk and its
+# one cache: like softmax layers, the same looped, and a pattern by kind
+STACKS = {
+    "llama": dict(),
+    "looped": dict(loop_steps=2, sandwich_norm=True),
+    "by-kind": dict(n_layers=4, layer_types=("latent", "window"),
+                    latent=_latent_dims(), window_latent=_latent_dims(),
+                    window=5, index_topk=4, index_heads=2, index_head_dim=8),
+}
+
+
+@pytest.mark.parametrize("path, shared, overrides", [
+    pytest.param(path, shared, overrides, id=f"{path}-{shared}-{name}")
+    for shared in ("_feed_forward", "_head")
+    for path in ("train", "prefill", "decode")
+    for name, overrides in (("dense", dict()),
+                            ("moe", dict(num_experts=4, expert_top_k=2)))
+] + [
+    pytest.param(path, "_over_the_layers", overrides,
+                 id=f"{path}-the-trunk-{name}")
+    for path in ("prefill", "decode") for name, overrides in STACKS.items()
 ])
-@pytest.mark.parametrize("shared", ["_feed_forward", "_head"])
-@pytest.mark.parametrize("path", ["train", "prefill", "decode"])
 def test_every_path_runs_the_one_definition(monkeypatch, path, shared,
                                             overrides):
     """The feed-forward and the head are written once: a marked stand-in
     put in the place of the one function moves the full forward, prefill's
     last-position logits and decode_step's logits alike, and the three go
-    on agreeing as they do untouched."""
+    on agreeing as they do untouched. The trunk over the cache is written
+    once too: a stand-in in its place moves prefill and decode of a llama
+    stack, a looped stack and a stack by kind, and leaves training be."""
     import sys
 
     from ray_tpu.models import transformer
@@ -370,7 +394,19 @@ def test_every_path_runs_the_one_definition(monkeypatch, path, shared,
     params = transformer_init(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 9), 0, 97)
     plain = _last_logits(params, tokens, cfg)
-    stand_in = _halved(getattr(transformer, shared))
+    if shared == "_over_the_layers":
+        # the state's sign: the final norm would undo a factor
+        monkeypatch.setattr(generate_module, shared, _scaled(
+            generate_module._over_the_layers, -1.0))
+        marked = _last_logits(params, tokens, cfg)
+        np.testing.assert_array_equal(np.asarray(marked["train"]),
+                                      np.asarray(plain["train"]))
+        np.testing.assert_allclose(
+            np.asarray(marked[path]), -np.asarray(plain["train"]),
+            rtol=2e-4, atol=2e-4)
+        assert np.abs(np.asarray(plain[path])).max() > 0.1
+        return
+    stand_in = _scaled(getattr(transformer, shared), 0.5)
     monkeypatch.setattr(transformer, shared, stand_in)
     if hasattr(generate_module, shared):        # imported by name there
         monkeypatch.setattr(generate_module, shared, stand_in)
@@ -419,3 +455,67 @@ def test_generate_names_no_weight_of_the_layer():
              and isinstance(node.slice, ast.Constant)
              and node.slice.value in weights]
     assert named == []
+
+
+# --- one cache by layer kind -------------------------------------------------
+
+@pytest.mark.parametrize("overrides", [
+    pytest.param(overrides, id=name) for name, overrides in STACKS.items()])
+def test_init_cache_is_zeros_of_cache_shapes(overrides):
+    """`cache_shapes` alone knows the cache's arrays: `init_cache` is zeros
+    of them, `prefill` fills arrays of those shapes, and `call_span` counts
+    their bytes."""
+    from ray_tpu.models.generate import (cache_shapes, cache_slots,
+                                         call_span, init_cache)
+
+    cfg = _cfg(**overrides)
+    shapes = cache_shapes(cfg, 2, 12)
+    cache = init_cache(cfg, 2, 12)
+    assert {k: v.shape for k, v in cache.items()} == shapes
+    assert all(v.dtype == cfg.dtype and not np.asarray(v).any()
+               for v in cache.values())
+    if "k" in shapes:       # a slot for every (loop step, layer)
+        assert shapes["k"] == shapes["v"] == (
+            cache_slots(cfg), 2, 12, cfg.kv_heads, cfg.head_dim)
+    else:                   # 2 periods of a latent and a window layer
+        assert sorted(shapes) == ["index", "latent", "window"]
+        assert shapes["latent"] == (2, 2, 12, 1, 24)
+        assert shapes["window"] == (2, 2, 8, 1, 24)
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    _, filled = prefill(params, jnp.zeros((2, 9), jnp.int32), cfg, max_len=12)
+    assert {k: v.shape for k, v in filled.items()} == shapes
+    assert call_span(cfg, 2, 9, 3).attrs["cache_bytes"] == 4 * sum(
+        int(np.prod(shape)) for shape in shapes.values())
+
+
+def test_unlooped_prefill_cache_is_the_forwards_keys_and_values(monkeypatch):
+    """An unlooped stack's prefill goes through the carried cache and leaves
+    in it, bit for bit, the rotated K and V that `transformer_apply`'s own
+    layers attend with (and zeros after the prompt); its logits are the
+    forward's last."""
+    from ray_tpu.models import transformer
+
+    cfg = _cfg(n_kv_heads=1)
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 9), 0, 97)
+    seen = []
+    attention = transformer._attention
+
+    def keeping(cfg, q, k, v, *a, **kw):
+        # out of the layer scan, a layer at a time and in their order
+        jax.debug.callback(lambda k, v: seen.append((k, v)), k, v,
+                           ordered=True)
+        return attention(cfg, q, k, v, *a, **kw)
+
+    monkeypatch.setattr(transformer, "_attention", keeping)
+    want = transformer_apply(params, tokens, cfg)[:, -1]
+    jax.effects_barrier()
+    monkeypatch.undo()
+    assert len(seen) == cfg.n_layers
+    logits, cache = prefill(params, tokens, cfg, max_len=14)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
+    for i, name in enumerate(("k", "v")):
+        got = np.asarray(cache[name])
+        np.testing.assert_array_equal(
+            got[:, :, :9], np.stack([np.asarray(kv[i]) for kv in seen]))
+        assert got[:, :, :9].any() and not got[:, :, 9:].any()
