@@ -10,9 +10,13 @@ times over the one set of weights with sandwich norms and an exit gate; or
 latent attention (models/latent.py: keys and values as one low-rank latent
 a position, in full layers with a learned sparse indexer and in window
 layers, a head-wise output gate) after leading dense layers, over expert
-layers routed by sigmoid with a correction bias. ``generate`` serves the
-softmax stacks from a K/V cache and the latent stacks from a cache by kind
-(latents, indexer keys, window rings), the prompt in chunks.
+layers routed by sigmoid with a correction bias; a block's norms on its
+sublayers' inputs, on inputs and outputs, or on the outputs only.
+``generate`` serves, from one cache by layer kind, the softmax stacks (keys
+and values), the latent stacks (latents, indexer keys, window rings; the
+prompt in chunks) and patterns of gated-delta-rule and softmax layers (a
+float32 recurrent state and the convolution's last inputs beside the keys
+and values).
 Pure-functional params pytree with logical-axis
 annotations so one definition runs under any MeshSpec (dp/fsdp/tp/pp/sp/ep).
 Plus ResNet-50 (the north-star image benchmark, BASELINE.json) and an MLP.

@@ -8,7 +8,12 @@ One cache, one trunk, two makers of a layer's attention step.
   knows them: a softmax layer (kind ``"full"``) has a slot of ``k`` and of
   ``v`` [slots, B, T_max, KVH, D], a latent layer one of ``latent`` (and of
   the indexer's ``index``) over every position, a window layer one of
-  ``window``, a ring of `window_rows` positions. A looped stack
+  ``window``, a ring of `window_rows` positions, a gated-delta-rule layer
+  (kind ``"linear"``) one of ``state``, the recurrent state after the last
+  position in float32 whatever the compute dtype, packed so that its minor
+  dimension fills the TPU's lanes (ops/gated_delta.py ``pack_state``), and
+  one of ``tail``, the convolution's last K - 1 inputs: neither grows with
+  the positions, and a step rewrites the whole of both. A looped stack
   (``cfg.loop_steps`` passes over the one set of weights) has a slot for
   every (pass t, layer l), slot ``t * L + l``: the passes share weights, not
   activations, so a pass-t query sees pass-t keys only.
@@ -23,13 +28,22 @@ One cache, one trunk, two makers of a layer's attention step.
   read second: a read of the pre-update stack after the write would make
   XLA keep two buffers and copy the cache a token.
 - `_write_chunk_at` makes the attention step of a prefill chunk,
-  `_write_and_read_at` that of a decode step; each has the three kinds.
+  `_write_and_read_at` that of a decode step; each has the four kinds. A
+  linear layer's is the rule itself (``transformer._gated_delta_mix``'s
+  ``rule``): the chunked rule from a zero state, whose final state and last
+  inputs prefill writes, and one position of the recurrence on the carried
+  state, read from its slot and written back to it, no second copy.
   `prefill_and_taps` and `decode_step_and_taps` are the two entry points
   (``prefill``, ``prefill_and_exits``, ``decode_step`` and
   ``decode_step_and_exits`` are views of them). The prompt of a stack by
   kind goes through in chunks of queries, each attending to what the cache
   holds; a softmax layer attends to its chunk's own keys, the training
-  forward's attention, so its stack goes through in one chunk.
+  forward's attention, and a linear layer starts from a zero state, so a
+  stack that has either goes through in one chunk.
+
+Served: one stack of like softmax layers (looped or not), a pattern of
+latent and window layers, a pattern of softmax and linear layers. Not
+served: linear layers beside latent or window ones (`_refuse_unserved`).
 - `generate` runs the whole decode loop INSIDE jit via lax.scan: static
   shapes (cache padded to max length, attention masked by position), PRNG
   threaded through the scan, no host round trip a token. The token loop
@@ -61,27 +75,41 @@ from jax import lax
 from ray_tpu.models.latent import ring_positions, sparse_in_kernel
 from ray_tpu.models.transformer import (TransformerConfig, _attention,
                                         _head, _layer_apply,
-                                        _over_loop_steps)
+                                        _over_loop_steps, _rule_operands)
+from ray_tpu.ops import gated_delta
 from ray_tpu.ops.attention import auto_path
 from ray_tpu.util import events
 
 LATENT_KINDS = ("latent", "window")
 
 
-def _refuse_recurrent(cfg: TransformerConfig) -> None:
-    if set(cfg.layer_types) <= set(LATENT_KINDS):
-        return
-    if "linear" in cfg.layer_types:
+RECURRENT_KINDS = ("full", "linear")
+# the cache's arrays over positions, [slots, B, T, ...]: what a decode
+# step's ``extent`` cuts
+BY_POSITION = ("k", "v", "latent", "index")
+
+
+def _refuse_unserved(cfg: TransformerConfig, mesh=None) -> None:
+    kinds = set(cfg.layer_types)
+    if "linear" in kinds and mesh is not None and mesh.size > 1:
         raise NotImplementedError(
-            "generate serves softmax-attention layers only: this "
-            "configuration has gated-delta-rule layers, whose recurrent "
-            "state [B, Hv, dk, dv] and convolution tail would have to live "
-            "beside the keys and values, and the cache here holds one kind "
+            "generate serves gated-delta-rule layers on one device: the "
+            "rule's kernels cannot be partitioned, and the state's layout "
+            "over a mesh is not built (ROADMAP.md R8)")
+    if kinds <= set(LATENT_KINDS) or kinds <= set(RECURRENT_KINDS):
+        return
+    if "linear" in kinds:
+        raise NotImplementedError(
+            "generate serves gated-delta-rule layers beside softmax layers "
+            f"only: this pattern {cfg.layer_types} has them beside latent "
+            "or window layers, whose prompt goes through in chunks of "
+            "queries, and the rule's prefill starts from a zero state "
             "(ROADMAP.md R8)")
     raise NotImplementedError(
-        "generate serves one stack of like softmax-attention layers, or "
-        "a pattern of latent and window layers; this pattern "
-        f"{cfg.layer_types} is not served")
+        "generate serves one stack of like softmax-attention layers, a "
+        "pattern of softmax and gated-delta-rule layers, or a pattern of "
+        f"latent and window layers; this pattern {cfg.layer_types} is not "
+        "served")
 
 
 def cache_slots(cfg: TransformerConfig) -> int:
@@ -105,7 +133,7 @@ def kind_slots(cfg: TransformerConfig) -> Dict[str, int]:
     """Cache slots by layer kind: a layer of a kind has one a loop step."""
     return {kind: cfg.loop_steps * (
         _lead_slots(cfg, kind) + cfg.periods * cfg.kinds.count(kind))
-        for kind in ("full",) + LATENT_KINDS}
+        for kind in RECURRENT_KINDS + LATENT_KINDS}
 
 
 def _slots_of_pass(cfg: TransformerConfig, t):
@@ -129,7 +157,14 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int
     a softmax layer holds the rotated keys and the values of every position
     (``k``, ``v``), a latent layer the latent and shared key of every
     position (``latent``) and the indexer's key (``index``), a window layer
-    a ring of `window_rows` positions (``window``)."""
+    a ring of `window_rows` positions (``window``); a linear layer holds no
+    positions: its packed state [slots, B, Hv / r, dk, r dv] (``state``:
+    r = ``gated_delta.state_pack`` heads beside each other on the minor
+    dimension, 2 x 192 = 384 = 3 x 128 lanes, so nothing is padded) and the
+    convolution's last K - 1 inputs, oldest first, [slots, K - 1, B, 2 kd
+    + vd] (``tail``: positions before rows, the only array here whose
+    second dimension is not the batch, so that a tile holds rows x channels
+    and the 3 positions pad nothing)."""
     slots, shapes = kind_slots(cfg), {}
     if slots["full"]:
         shapes["k"] = shapes["v"] = (slots["full"], batch, max_len,
@@ -143,12 +178,27 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int
     if slots["window"]:
         shapes["window"] = (slots["window"], batch, window_rows(cfg), 1,
                             cfg.window_latent.cached)
+    if slots["linear"]:
+        hv, dv = cfg.linear_value_heads, cfg.linear_value_dim
+        r = gated_delta.state_pack(hv, dv)
+        shapes["state"] = (slots["linear"], batch, hv // r,
+                           cfg.linear_key_dim, r * dv)
+        shapes["tail"] = (
+            slots["linear"], cfg.linear_conv_kernel - 1, batch,
+            2 * cfg.linear_key_heads * cfg.linear_key_dim + hv * dv)
     return shapes
 
 
+def cache_dtype(cfg: TransformerConfig, name: str):
+    """The recurrent state is float32 whatever the compute dtype (every
+    step adds to it: bfloat16's 8 bits would be lost under what it holds);
+    every other array is in the compute dtype."""
+    return jnp.float32 if name == "state" else cfg.dtype
+
+
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
-    """Zeros of `cache_shapes` (kv dtype = compute dtype)."""
-    return {name: jnp.zeros(shape, cfg.dtype) for name, shape
+    """Zeros of `cache_shapes`, each in its `cache_dtype`."""
+    return {name: jnp.zeros(shape, cache_dtype(cfg, name)) for name, shape
             in cache_shapes(cfg, batch, max_len).items()}
 
 
@@ -254,6 +304,12 @@ def _write_prompt(cache, l, new):
     return lax.fori_loop(0, new.shape[0], row, cache)
 
 
+def _write_slot(stack, slot, new):
+    """stack [slots, ...] with new [...] as the whole of ``slot``."""
+    return lax.dynamic_update_slice(stack, new[None].astype(stack.dtype),
+                                    (slot,) + (0,) * new.ndim)
+
+
 def _slot_rows(stack, slot):
     """stack [slots, B, T, 1, W] -> the slot's [B, T, W]."""
     return lax.dynamic_index_in_dim(stack, slot, 0, keepdims=False)[:, :, 0]
@@ -342,7 +398,9 @@ def _write_chunk_at(cfg: TransformerConfig, start, chunk: int, mesh=None):
     attends to its whole slot (the mask leaves out what is not written
     yet); a window layer attends to its ring as it stood and the chunk's
     own entries, then writes the chunk's last `window_rows` into the
-    ring."""
+    ring; a linear layer runs the chunked rule from a zero state (the whole
+    prompt too) and writes the state it ends in and the convolution's last
+    K - 1 inputs."""
     def attend_at(kind, cache, slot):
         def full(q, k, v):
             return _attention(cfg, q, k, v, mesh), dict(
@@ -371,7 +429,22 @@ def _write_chunk_at(cfg: TransformerConfig, start, chunk: int, mesh=None):
                 cache["window"], ring[None, :, :, None, :],
                 (slot, 0, 0, 0, 0)))
 
-        return {"full": full, "latent": latent, "window": window}[kind]
+        def linear(u, ba, p):
+            qkv = gated_delta.causal_conv(u, p["conv"])
+            with jax.named_scope("rt.gdn.scan"):
+                operands = _rule_operands(cfg, p, qkv, ba)
+            o, state = gated_delta.gated_delta_rule(*operands,
+                                                    final_state=True)
+            kept = cfg.linear_conv_kernel - 1       # zeros before position 0
+            tail = jnp.swapaxes(
+                jnp.pad(u, ((0, 0), (kept, 0), (0, 0)))[:, chunk:], 0, 1)
+            return o, dict(
+                cache, tail=_write_slot(cache["tail"], slot, tail),
+                state=_write_slot(cache["state"], slot,
+                                  gated_delta.pack_state(state)))
+
+        return {"full": full, "latent": latent, "window": window,
+                "linear": linear}[kind]
     return attend_at
 
 
@@ -380,7 +453,12 @@ def _write_and_read_at(cfg: TransformerConfig, pos, extent: int):
     then read the slot from the UPDATED stack (a read of the old stack
     after the write would make XLA keep two buffers and copy): a softmax
     and a latent layer its first ``extent`` positions, a window layer its
-    ring."""
+    ring. A linear layer's entry is made FROM what its slot holds: the new
+    column is convolved against the tail, one position of the rule taken on
+    the state, and both written back where they were read, each by one
+    operation on the stack (``gated_delta.conv_step_at``,
+    ``gated_delta_step_at``: on a TPU kernels whose output is the stack
+    they read)."""
     def attend_at(kind, cache, slot):
         def full(q, k, v):
             with jax.named_scope("rt.loop.cache"):
@@ -408,7 +486,18 @@ def _write_and_read_at(cfg: TransformerConfig, pos, extent: int):
                     ring_positions(pos, rows)[None],
                     dict(cache, window=stack))
 
-        return {"full": full, "latent": latent, "window": window}[kind]
+        def linear(u, ba, p):
+            qkv, tails = gated_delta.conv_step_at(cache["tail"], slot,
+                                                  u[:, 0], p["conv"])
+            with jax.named_scope("rt.gdn.step"):
+                operands = [x[:, 0] for x in
+                            _rule_operands(cfg, p, qkv[:, None], ba)]
+            o, states = gated_delta.gated_delta_step_at(cache["state"], slot,
+                                                        *operands)
+            return o[:, None], dict(cache, tail=tails, state=states)
+
+        return {"full": full, "latent": latent, "window": window,
+                "linear": linear}[kind]
     return attend_at
 
 
@@ -429,18 +518,19 @@ def prefill_and_taps(params, tokens, cfg: TransformerConfig, max_len: int,
     there is no loop)."""
     if cfg.pp_stages > 1:
         raise NotImplementedError("decode with pp_stages>1 is not supported")
-    _refuse_recurrent(cfg)
+    _refuse_unserved(cfg, mesh)
     b, s = tokens.shape
     # A softmax layer's prefill attends to its chunk's own keys and no
-    # others, so a stack of them goes through in one chunk: the prompt.
-    whole = "full" in cfg.kinds
+    # others, and a linear layer's starts from a zero state, so a stack
+    # with either goes through in one chunk: the prompt.
+    whole = bool(set(cfg.kinds) & set(RECURRENT_KINDS))
     chunk = chunk or (s if whole else prefill_chunk(s))
     if s % chunk:
         raise ValueError(f"a prompt of {s} is not a multiple of the chunk "
                          f"{chunk}")
     if whole and chunk != s:
-        raise ValueError("a stack of softmax layers takes its prompt in "
-                         f"one chunk, not {s} in chunks of {chunk}")
+        raise ValueError("a stack with softmax or linear layers takes its "
+                         f"prompt in one chunk, not {s} in chunks of {chunk}")
     embed = params["embed"].astype(cfg.dtype)
 
     def step(carry, c):
@@ -469,10 +559,10 @@ def decode_step_and_taps(params, token, pos, cache, cfg: TransformerConfig,
     ``extent`` positions of a slot (static; the caller's word that ``pos <
     extent``), all of them where it is None; a window layer reads its
     ring."""
-    _refuse_recurrent(cfg)
+    _refuse_unserved(cfg)
     if extent is None:
         extent = max((a.shape[2] for name, a in cache.items()
-                      if name != "window"), default=0)
+                      if name in BY_POSITION), default=0)
     x = params["embed"].astype(cfg.dtype)[token][:, None, :]   # [B, 1, E]
     positions = jnp.full((x.shape[0], 1), pos)
     x, cache, exits, counts, taps = _over_the_layers(
@@ -620,24 +710,34 @@ def call_span(cfg: TransformerConfig, rows: int, prompt: int,
     once it is fetched with the tokens. ``cache_positions_read`` is the sum
     over the call's decode steps of their segment's extent, what a slot's
     attention reads, ``cache_positions_needed`` that of ``pos + 1``, what
-    it has to."""
+    it has to. Of a stack with linear layers the cache's bytes by what
+    holds them (``cache_bytes_state``, ``_tail``, ``_kv``) and its slots by
+    kind."""
     segments = _decode_segments(prompt, new)
-    itemsize = jnp.dtype(cfg.dtype).itemsize
     shapes = cache_shapes(cfg, rows, prompt + new)
-    by_kind = "k" not in shapes         # latent and window layers
+    nbytes = {name: math.prod(shape)
+              * jnp.dtype(cache_dtype(cfg, name)).itemsize
+              for name, shape in shapes.items()}
+    by_kind = "latent" in shapes or "window" in shapes
     attrs = dict(
         rows=rows, prompt=prompt, new=new, loop_steps=cfg.loop_steps,
         attention_path="latent" if by_kind
         else auto_path(prompt, prompt, cfg.head_dim)
         if cfg.attn_impl == "auto" else cfg.attn_impl,
-        # a softmax layer's slot is its pair of k and v
+        # a softmax layer's slot is its pair of k and v, a linear layer's
+        # its state and tail
         cache_slots=sum(shape[0] for name, shape in shapes.items()
-                        if name != "v"),
-        cache_bytes=sum(math.prod(shape) for shape in shapes.values())
-        * itemsize,
+                        if name not in ("v", "tail")),
+        cache_bytes=sum(nbytes.values()),
         decode_segments=len(segments),
         cache_positions_read=sum(n * extent for n, extent in segments),
         cache_positions_needed=new * prompt + new * (new + 1) // 2)
+    if "state" in shapes:
+        slots = kind_slots(cfg)
+        attrs.update(
+            cache_bytes_state=nbytes["state"], cache_bytes_tail=nbytes["tail"],
+            cache_bytes_kv=nbytes.get("k", 0) + nbytes.get("v", 0),
+            linear_slots=slots["linear"], full_slots=slots["full"])
     if by_kind:
         # a latent layer's queries, each over the keys up to its own: all
         # of them scored by the indexer, index_topk of them attended to
@@ -647,8 +747,7 @@ def call_span(cfg: TransformerConfig, rows: int, prompt: int,
         causal = total * (total + 1) // 2
         few = min(total, k)             # positions with no more than k keys
         attrs.update(
-            {"cache_bytes_" + name: math.prod(shape) * itemsize
-             for name, shape in shapes.items()},
+            {"cache_bytes_" + name: n for name, n in nbytes.items()},
             prefill_chunks=prompt // prefill_chunk(prompt),
             index_topk=cfg.index_topk,
             keys_scored=layers * causal if k else 0,

@@ -1,8 +1,10 @@
 """Flagship decoder-only Transformer LM, pure-functional: the llama block
 (RMSNorm / SwiGLU / RoPE / GQA) and, by configuration, the hybrid block of
 Qwen3-Next (a pattern of gated-delta-rule and gated full-attention layers
-over an expert layer without a capacity per expert, with a shared expert)
-or a looped stack (the layers run ``loop_steps`` times over one set of
+over an expert layer without a capacity per expert, with a shared expert),
+the post-normed hybrid block of OLMo (the same rule with beta up to 2 beside
+plain softmax layers, a norm on each sublayer's output only, q and k normed
+over the whole projection, no rotation) or a looped stack (the layers run ``loop_steps`` times over one set of
 weights, a norm on each sublayer's output, an exit gate after each pass).
 
 Design notes (TPU-first):
@@ -126,6 +128,8 @@ class TransformerConfig:
     head_width: Optional[int] = None      # None -> d_model / n_heads
     partial_rotary_factor: float = 1.0    # share of a head RoPE rotates
     qk_norm: bool = False                 # RMSNorm of q and k per head
+    qk_norm_whole: bool = False           # ... over the whole projection, all
+    #                                       heads together (OLMo 2 / 3)
     attn_output_gate: bool = False        # wq also gives a sigmoid gate
     norm_plus_one: bool = False           # norms scale by 1 + w, w from 0
     linear_key_heads: int = 0             # gated delta rule (ops/gated_delta)
@@ -133,6 +137,8 @@ class TransformerConfig:
     linear_key_dim: int = 128
     linear_value_dim: int = 128
     linear_conv_kernel: int = 4
+    linear_beta_scale: float = 1.0        # beta = scale x sigmoid(b): 2 lets
+    #                                       a state's eigenvalue go negative
     # Looped stack: the n_layers run ``loop_steps`` times over the one set
     # of weights, the final norm and the exit gate (Linear(d_model -> 1),
     # sigmoid) after every pass. A position leaves the loop at the first
@@ -140,6 +146,8 @@ class TransformerConfig:
     loop_steps: int = 1
     early_exit_threshold: float = 1.0
     sandwich_norm: bool = False           # a norm on each sublayer's output
+    post_norm_only: bool = False          # ... and none on its input: ``x +
+    #                                       norm(mixer(x))`` (OLMo 2 / 3)
     # Multi-token prediction (DeepSeek-V3, arXiv:2412.19437 section 2.2):
     # ``mtp_layers`` modules (0 or 1) after the stack, ``params["mtp"]``,
     # each one block of the period's last kind over ``W_eh [norm(Emb(t_{i+1}
@@ -175,6 +183,12 @@ class TransformerConfig:
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"router_scoring {self.router_scoring!r}: "
                              "'softmax' or 'sigmoid'")
+        if self.qk_norm and self.qk_norm_whole:
+            raise ValueError("qk_norm (per head) and qk_norm_whole (over "
+                             "the projection) are two norms of one place")
+        if self.sandwich_norm and self.post_norm_only:
+            raise ValueError("sandwich_norm norms a sublayer's input too, "
+                             "post_norm_only does not: one of the two")
         if self.attn_gate not in ("", "headwise"):
             raise ValueError(f"attn_gate {self.attn_gate!r}: '' or "
                              "'headwise'")
@@ -271,6 +285,16 @@ class TransformerConfig:
 # init
 # ---------------------------------------------------------------------------
 
+def _layer_norms(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The block's norm scales: on each sublayer's input (``ln1``, ``ln2``)
+    unless ``post_norm_only``, on its output (``ln1_post``, ``ln2_post``)
+    under that or ``sandwich_norm``."""
+    before = () if cfg.post_norm_only else ("ln1", "ln2")
+    after = ("ln1_post", "ln2_post") \
+        if cfg.sandwich_norm or cfg.post_norm_only else ()
+    return before + after
+
+
 def _layer_init(key, cfg: TransformerConfig, kind: str = "full",
                 dense: bool = False) -> Dict[str, Any]:
     """``dense``: a dense MLP whatever ``cfg.num_experts`` says."""
@@ -280,10 +304,7 @@ def _layer_init(key, cfg: TransformerConfig, kind: str = "full",
     init = jax.nn.initializers.normal(0.02)
     pd = cfg.param_dtype
     norm = jnp.zeros if cfg.norm_plus_one else jnp.ones
-    layer = {"ln1": norm((d,), pd), "ln2": norm((d,), pd)}
-    if cfg.sandwich_norm:
-        layer["ln1_post"] = norm((d,), pd)
-        layer["ln2_post"] = norm((d,), pd)
+    layer = {name: norm((d,), pd) for name in _layer_norms(cfg)}
     if kind == "full":
         gate = 2 if cfg.attn_output_gate else 1   # per head: query, gate
         layer["attn"] = {
@@ -295,6 +316,9 @@ def _layer_init(key, cfg: TransformerConfig, kind: str = "full",
         if cfg.qk_norm:
             layer["attn"]["q_norm"] = norm((hd,), pd)
             layer["attn"]["k_norm"] = norm((hd,), pd)
+        if cfg.qk_norm_whole:
+            layer["attn"]["q_norm"] = norm((h * hd,), pd)
+            layer["attn"]["k_norm"] = norm((hk * hd,), pd)
     elif kind in ("latent", "window"):
         from ray_tpu.models.latent import PARAMS_KEY, latent_init
         layer[PARAMS_KEY[kind]] = latent_init(ks[0], cfg, kind)
@@ -406,10 +430,7 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
 
     def layer_axes(kind: str, dense: bool = False,
                    L=stacked) -> Dict[str, Any]:
-        layer = {"ln1": L("embed"), "ln2": L("embed")}
-        if cfg.sandwich_norm:
-            layer["ln1_post"] = L("embed")
-            layer["ln2_post"] = L("embed")
+        layer = {name: L("embed") for name in _layer_norms(cfg)}
         if kind == "full":
             layer["attn"] = {
                 "wq": L("embed", "heads", "kv"),
@@ -417,7 +438,7 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                 "wv": L("embed", "heads", "kv"),
                 "wo": L("heads", "kv", "embed"),
             }
-            if cfg.qk_norm:
+            if cfg.qk_norm or cfg.qk_norm_whole:
                 layer["attn"]["q_norm"] = L(None)
                 layer["attn"]["k_norm"] = L(None)
         elif kind in ("latent", "window"):
@@ -496,6 +517,8 @@ def _rope(x, positions, theta: float, rotary_dim: Optional[int] = None):
     """x: [B, S, H, D]; rotate pairs (d, d + R/2) of the first R =
     ``rotary_dim`` dims (all of them when None); the rest pass."""
     d = x.shape[-1]
+    if rotary_dim == 0:             # partial_rotary_factor 0: no rotation
+        return x
     if rotary_dim is not None and rotary_dim < d:
         return jnp.concatenate(
             [_rope(x[..., :rotary_dim], positions, theta),
@@ -535,8 +558,9 @@ def _feed_forward(cfg: TransformerConfig, layer, h):
 
 
 def _full_attention_mix(cfg: TransformerConfig, a, h, positions, attend):
-    """Softmax attention over the normed input ``h``: projections, per-head
-    q/k norm and output gate where configured, RoPE, ``attend``, ``wo``."""
+    """Softmax attention over the block's (normed) input ``h``: projections,
+    q/k norm (per head, or over the whole projection) and output gate where
+    configured, RoPE, ``attend``, ``wo``."""
     dt = cfg.dtype
     q = jnp.einsum("bse,ehd->bshd", h, a["wq"].astype(dt))
     k = jnp.einsum("bse,ehd->bshd", h, a["wk"].astype(dt))
@@ -545,6 +569,9 @@ def _full_attention_mix(cfg: TransformerConfig, a, h, positions, attend):
         q, gate = jnp.split(q, 2, axis=-1)
     if cfg.qk_norm:
         q, k = _norm(cfg, q, a["q_norm"]), _norm(cfg, k, a["k_norm"])
+    if cfg.qk_norm_whole:
+        q, k = (_norm(cfg, x.reshape(x.shape[:2] + (-1,)), w).reshape(x.shape)
+                for x, w in ((q, a["q_norm"]), (k, a["k_norm"])))
     q = _rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
     k = _rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
     o, kept = attend(q, k, v)
@@ -561,54 +588,84 @@ def _output_gate(o, gate):
     return o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
 
 
-def _gated_delta_mix(cfg: TransformerConfig, p, h, mesh=None,
-                     rules: LogicalRules = DEFAULT_RULES):
-    """The gated delta rule over the normed input ``h`` [B, S, E]: packed
-    projections, short causal convolution, the chunked rule, gated norm.
-    ``mesh``/``rules``: what ``h`` is laid out by (the rule's kernels run
-    per shard)."""
-    from ray_tpu.ops.gated_delta import causal_conv, gated_delta_rule_over
+def _gated_delta_mix(cfg: TransformerConfig, p, h, rule):
+    """The gated delta rule over the block's (normed) input ``h`` [B, S,
+    E]: packed projections, ``rule``, gated norm, output projection.
+    ``rule(u, ba, p) -> (o [B, S, Hv, dv], kept)`` is all that differs
+    between training, prefill and decode, as ``attend`` is for a softmax
+    layer: the short causal convolution of ``u`` [B, S, 2 kd + vd] (the
+    projection's [q | k | v] columns), ``_rule_operands`` and the rule over
+    them: the chunked rule from a zero state (``_whole_rule``, and prefill,
+    which keeps the final state and the last inputs), or one position on a
+    carried state against a carried tail (models/generate.py)."""
     dt, f32 = cfg.dtype, jnp.float32
     b, s, _ = h.shape
-    hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
-    dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
-    kd, vd = hk * dk, hv * dv
+    hv, dv = cfg.linear_value_heads, cfg.linear_value_dim
+    conv = p["conv"].shape[0]               # 2 kd + vd
     with jax.named_scope("rt.gdn.proj"):
         qkvz = h @ p["in_qkvz"].astype(dt)
         ba = (h @ p["in_ba"].astype(dt)).astype(f32)
-    qkv = causal_conv(qkvz[..., :2 * kd + vd], p["conv"])
-    z = qkvz[..., 2 * kd + vd:].reshape(b, s, hv, dv)
-    with jax.named_scope("rt.gdn.scan"):
-        q = qkv[..., :kd].reshape(b, s, hk, dk)
-        k = qkv[..., kd:2 * kd].reshape(b, s, hk, dk)
-        v = qkv[..., 2 * kd:].reshape(b, s, hv, dv)
-        beta = jax.nn.sigmoid(ba[..., :hv])
-        g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
-            ba[..., hv:] + p["dt_bias"].astype(f32))
-    # q, k to unit length inside; a key head serves hv / hk value heads
-    o = gated_delta_rule_over(mesh, rules, q, k, v, g, beta)
+    o, kept = rule(qkvz[..., :conv], ba, p)
+    z = qkvz[..., conv:].reshape(b, s, hv, dv)
     with jax.named_scope("rt.gdn.proj"):
         o = _rmsnorm(o, p["norm"]) * jax.nn.silu(z.astype(f32)).astype(dt)
-        return o.reshape(b, s, vd) @ p["out"].astype(dt)
+        return o.reshape(b, s, hv * dv) @ p["out"].astype(dt), kept
 
 
-def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend,
-                 mesh=None, rules: LogicalRules = DEFAULT_RULES):
+def _rule_operands(cfg: TransformerConfig, p, qkv, ba):
+    """The convolved [q | k | v] [B, S, 2 kd + vd] and the float32 [b | a]
+    [B, S, 2 Hv] -> the rule's (q, k [B, S, Hk, dk], v [B, S, Hv, dv], g,
+    beta [B, S, Hv] float32). q, k go to unit length inside the rule; a key
+    head serves Hv / Hk value heads."""
+    f32 = jnp.float32
+    b, s, _ = qkv.shape
+    hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
+    dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+    kd = hk * dk
+    q = qkv[..., :kd].reshape(b, s, hk, dk)
+    k = qkv[..., kd:2 * kd].reshape(b, s, hk, dk)
+    v = qkv[..., 2 * kd:].reshape(b, s, hv, dv)
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    if cfg.linear_beta_scale != 1.0:
+        beta = cfg.linear_beta_scale * beta
+    g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
+        ba[..., hv:] + p["dt_bias"].astype(f32))
+    return q, k, v, g, beta
+
+
+def _whole_rule(cfg: TransformerConfig, mesh=None,
+                rules: LogicalRules = DEFAULT_RULES):
+    """``_gated_delta_mix``'s ``rule`` over the sequence's own positions
+    from a zero state, nothing kept: the training forward's.
+    ``mesh``/``rules``: what the layer's input is laid out by (the rule's
+    kernels run per shard)."""
+    def rule(u, ba, p):
+        qkv = gated_delta.causal_conv(u, p["conv"])
+        with jax.named_scope("rt.gdn.scan"):
+            operands = _rule_operands(cfg, p, qkv, ba)
+        return gated_delta.gated_delta_rule_over(mesh, rules,
+                                                 *operands), None
+    return rule
+
+
+def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend):
     """One block: ``x + mixer(norm(x))``, then ``+ feed_forward(norm(.))``.
     The mixer is the layer's own: softmax attention where it holds
     ``attn``, the gated delta rule where it holds ``gdn``, latent attention
     where it holds ``mla`` or ``swa`` (models/latent.py); where the layer
     holds ``ln1_post`` and ``ln2_post`` each sublayer's output is normed
-    before it joins the residual (sandwich norm). ``attend`` is all that
-    differs between training, prefill and decode (models/generate.py):
-    ``attend(q, k, v) -> (o, kept)`` is what softmax attention does with
-    the rotated k and v, and what it keeps of them; ``attend(new) -> (keys,
-    key positions, kept)`` is what a latent layer's new cached entries
-    join (key positions None: the sequence's own, index i at position i),
-    and what is kept of them. ``mesh``/``rules`` are the gated delta
-    rule's, whose kernels run per shard. -> (x, kept, stats: the expert
+    before it joins the residual (sandwich norm), and where it holds no
+    ``ln1`` and ``ln2`` its input goes in as it is (``post_norm_only``).
+    ``attend`` is all that differs between training, prefill and decode
+    (models/generate.py): ``attend(q, k, v) -> (o, kept)`` is what softmax
+    attention does with the rotated k and v, and what it keeps of them;
+    ``attend(new) -> (keys, key positions, kept)`` is what a latent layer's
+    new cached entries join (key positions None: the sequence's own, index
+    i at position i), and what is kept of them; ``attend(u, ba, p) -> (o,
+    kept)`` is the gated delta rule's convolution and recurrence
+    (``_gated_delta_mix``'s ``rule``). -> (x, kept, stats: the expert
     layer's counters and a latent layer's selection, or None)."""
-    h = _norm(cfg, x, layer["ln1"])
+    h = _norm(cfg, x, layer["ln1"]) if "ln1" in layer else x
     taps = {}
     if "attn" in layer:
         # the gated variant's device time is found by this scope
@@ -617,14 +674,15 @@ def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend,
             o, kept = _full_attention_mix(cfg, layer["attn"], h, positions,
                                           attend)
     elif "gdn" in layer:
-        o, kept = _gated_delta_mix(cfg, layer["gdn"], h, mesh, rules), None
+        o, kept = _gated_delta_mix(cfg, layer["gdn"], h, attend)
     else:
         from ray_tpu.models.latent import latent_mix
         o, kept, taps = latent_mix(cfg, layer, h, positions, attend)
     if "ln1_post" in layer:
         o = _norm(cfg, o, layer["ln1_post"])
     x = x + o
-    y, stats = _feed_forward(cfg, layer, _norm(cfg, x, layer["ln2"]))
+    y, stats = _feed_forward(
+        cfg, layer, _norm(cfg, x, layer["ln2"]) if "ln2" in layer else x)
     if "ln2_post" in layer:
         y = _norm(cfg, y, layer["ln2_post"])
     if taps:
@@ -642,10 +700,11 @@ def _layer_bodies(cfg: TransformerConfig, mesh, rules: LogicalRules):
     def whole(new):             # a latent layer's keys: the sequence's own
         return new, None, None
 
+    attends = {"full": softmax, "latent": whole, "window": whole,
+               "linear": _whole_rule(cfg, mesh, rules)}
+
     def body_of(kind: str):
-        body = partial(_layer_apply, cfg, mesh=mesh, rules=rules,
-                       attend=whole if kind in ("latent", "window")
-                       else softmax)
+        body = partial(_layer_apply, cfg, attend=attends[kind])
         if cfg.remat:
             # what the delta rule's and flash's forward kernels name for
             # their backward kernels outlives the forward pass (flash names

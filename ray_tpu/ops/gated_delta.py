@@ -24,13 +24,31 @@ rounding compounds. The large matmuls take their operands in the inputs'
 dtype (bfloat16 in the model) and accumulate in float32.
 
 **Two forms, one a backend** (ops/attention.py's rule for flash: no option
-chooses). On a TPU, at head widths that are multiples of 128, the rule is
-two fused Pallas kernels with a ``jax.custom_vjp`` (ops/gated_delta_pallas:
-``rt_gdn_fwd``, ``rt_gdn_bwd``): a chunk's intermediates stay in VMEM, the
-state crosses chunks in VMEM scratch, the backward is a reverse scan of its
-own. Everywhere else (the CPU tests, odd widths) the jnp form below runs,
-batched matmuls and one ``lax.scan`` step a chunk with XLA's backward, which
-keeps one state a chunk; it is also the kernels' oracle.
+chooses). On a TPU the rule is two fused Pallas kernels with a
+``jax.custom_vjp`` (ops/gated_delta_pallas: ``rt_gdn_fwd``, ``rt_gdn_bwd``):
+a chunk's intermediates stay in VMEM, the state crosses chunks in VMEM
+scratch, the backward is a reverse scan of its own. They take head widths
+that are multiples of 128 as they come; other widths from 64 up (96 and 192)
+go in padded with zeros to the next multiple, which changes neither a head's
+length nor a product, and q keeps the scale of its own width. Everywhere
+else (the CPU tests, narrow heads) the jnp form below runs, batched matmuls
+and one ``lax.scan`` step a chunk with XLA's backward, which keeps one state
+a chunk; it is also the kernels' oracle.
+
+**Serving.** ``final_state=True`` also hands back the state after the last
+position, float32 ``[B, H, dk, dv]``: what a prefill leaves in the cache.
+From there ``gated_delta_step`` takes one position of the recurrence above
+on a carried state, elementwise in float32 under the scope ``rt.gdn.step``,
+and ``conv_step`` convolves one new column against the last ``K - 1``
+inputs (``rt.gdn.conv``); ``gated_delta_step_at`` and ``conv_step_at`` take
+them on one slot of the stacks a cache carries, on a TPU as Pallas kernels
+that write into the stack they read (``rt_gdn_step``,
+``rt_gdn_conv_step``). The carried state is PACKED (``pack_state``):
+``state_pack`` heads lie beside each other on the minor dimension, ``[B, H /
+r, dk, r dv]``, so that it is a multiple of the TPU's 128 lanes (two heads
+of 192 are 384) and the bytes a step moves are the state's own, not a
+third more of padding. A head's value columns stay together, so the step
+needs no fold: its sums over ``dk`` run down the sublanes.
 
 **What outlives the forward pass.** The forward kernel's four outputs, ``o``
 and what ``rt_gdn_bwd`` reads (each chunk's starting state, ``T`` and
@@ -80,6 +98,35 @@ def causal_conv(x, w):
         y = sum(padded[:, j:j + s].astype(jnp.float32) * w[:, j]
                 for j in range(width))
         return jax.nn.silu(y).astype(x.dtype)
+
+
+def conv_step(tail, x, w):
+    """``causal_conv`` of one new position against the inputs before it.
+    tail: [K-1, B, C], the last K - 1 inputs, oldest first; x: [B, C].
+    -> (y [B, C] in x's dtype, the tail with x in and its oldest out)."""
+    window = jnp.concatenate([tail.astype(x.dtype), x[None]], axis=0)
+    w = w.astype(jnp.float32)
+    y = sum(window[j].astype(jnp.float32) * w[:, j]
+            for j in range(w.shape[1]))
+    return jax.nn.silu(y).astype(x.dtype), window[1:]
+
+
+def conv_step_at(tails, slot, x, w):
+    """``conv_step`` on slot ``slot`` of the carried stack ``tails`` [slots,
+    K-1, B, C] (positions before rows: a tile of the TPU's then holds rows
+    and channels, and the K - 1 = 3 positions pad nothing) -> (y [B, C],
+    the stack with the slot's tail moved on). On a TPU a Pallas kernel
+    whose output IS the stack (``rt_gdn_conv_step``: it reads and writes
+    the slot's block and nothing else); elsewhere the slot is cut out,
+    stepped and written back."""
+    with jax.named_scope("rt.gdn.conv"):
+        if _on_tpu():
+            from ray_tpu.ops.gated_delta_pallas import conv_step_kernel
+            return conv_step_kernel(tails, slot, x, w)
+        y, tail = conv_step(
+            lax.dynamic_index_in_dim(tails, slot, 0, keepdims=False), x, w)
+        return y, lax.dynamic_update_slice(
+            tails, tail[None].astype(tails.dtype), (slot, 0, 0, 0))
 
 
 def _block_matmul(a, b):
@@ -134,18 +181,23 @@ def _mm(spec, a, b, dt):
                       preferred_element_type=jnp.float32)
 
 
-def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK):
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
+                     final_state: bool = False):
     """q, k: [B, S, Hk, dk] as the convolution left them: the rule takes
     each head's q and k to unit length in float32 (q then times dk^-0.5),
     as Gated DeltaNet does, and rounds them to v's dtype; v: [B, S, H, dv],
     each of the Hk key heads shared by H / Hk value heads in a row; g (log
-    decay, <= 0), beta: [B, S, H]. -> o [B, S, H, dv] in v's dtype. Any S:
-    the tail is padded with positions that leave the state alone."""
+    decay, <= 0), beta: [B, S, H]. -> o [B, S, H, dv] in v's dtype, and
+    with ``final_state`` (o, the state after position S - 1, float32 [B, H,
+    dk, dv]; no gradient flows through it from the kernels). Any S: the
+    tail is padded with positions that leave the state alone."""
     with jax.named_scope("rt.gdn.scan"):
         if _kernels_fit(q, v, chunk):
             from ray_tpu.ops.gated_delta_pallas import gated_delta_rule_kernels
-            return gated_delta_rule_kernels(q, k, v, g, beta, chunk)
-        return _chunked(q, k, v, g, beta, chunk)
+            return gated_delta_rule_kernels(q, k, v, g, beta, chunk,
+                                            final_state=final_state)
+        o, state = _chunked(q, k, v, g, beta, chunk)
+        return (o, state) if final_state else o
 
 
 def gated_delta_rule_over(mesh, rules: LogicalRules, q, k, v, g, beta):
@@ -173,11 +225,15 @@ def gated_delta_rule_over(mesh, rules: LogicalRules, q, k, v, g, beta):
                          out_specs=wide, check_vma=False)(q, k, v, g, beta)
 
 
+KERNEL_WIDTH_FROM = 64      # narrower heads would be mostly padding
+
+
 def _kernels_fit(q, v, chunk) -> bool:
-    """The Pallas kernels run on a TPU at head widths that fill its lanes;
+    """The Pallas kernels run on a TPU at head widths from KERNEL_WIDTH_FROM
+    up (those that are no multiple of 128 padded to the next one);
     everywhere else the jnp form below runs (ops/attention.py's rule for
     flash)."""
-    return (_on_tpu() and q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
+    return (_on_tpu() and min(q.shape[-1], v.shape[-1]) >= KERNEL_WIDTH_FROM
             and chunk % 16 == 0 and v.shape[2] % q.shape[2] == 0)
 
 
@@ -187,7 +243,103 @@ def unit(x):
     return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + EPS)
 
 
+def state_pack(hv: int, dv: int) -> int:
+    """Heads that lie beside each other on a carried state's minor
+    dimension: the fewest that make it a multiple of 128 lanes (1 where the
+    heads do not divide into such runs)."""
+    r = 128 // math.gcd(dv, 128)
+    return r if hv % r == 0 else 1
+
+
+def pack_state(state):
+    """[B, H, dk, dv] -> [B, H / r, dk, r dv], r = ``state_pack``."""
+    b, h, dk, dv = state.shape
+    r = state_pack(h, dv)
+    return jnp.swapaxes(state.reshape(b, h // r, r, dk, dv), 2, 3).reshape(
+        b, h // r, dk, r * dv)
+
+
+def unpack_state(packed, hv: int):
+    """``pack_state``'s inverse."""
+    b, groups, dk, wide = packed.shape
+    r = hv // groups
+    return jnp.swapaxes(packed.reshape(b, groups, dk, r, wide // r), 2,
+                        3).reshape(b, hv, dk, wide // r)
+
+
+def _beside(x, r: int, dv: int):
+    """x [B, H, ...] -> [B, H / r, ..., r dv]: each of a run's r heads'
+    values over its own dv lanes (a concatenation of broadcasts, which
+    fuses into what reads it; a reshape of the minor dimension would be a
+    copy on a TPU)."""
+    x = x.reshape((x.shape[0], x.shape[1] // r, r) + x.shape[2:])
+    return jnp.concatenate(
+        [jnp.broadcast_to(x[:, :, i, ..., None], x.shape[:2] + x.shape[3:]
+                          + (dv,)) for i in range(r)], axis=-1)
+
+
+def _step_operands(q, k, v):
+    """q, k [B, Hk, dk] as the convolution left them -> float32 at unit
+    length (q scaled), rounded to v's dtype as the chunked rule rounds
+    them, one copy a value head."""
+    dk, h, f32 = q.shape[-1], v.shape[1], jnp.float32
+    q = (unit(q) * dk ** -0.5).astype(v.dtype).astype(f32)
+    k = unit(k).astype(v.dtype).astype(f32)
+    if q.shape[1] != h:
+        q, k = (jnp.repeat(x, h // x.shape[1], axis=1) for x in (q, k))
+    return q, k
+
+
+def gated_delta_step_at(states, slot, q, k, v, g, beta):
+    """``gated_delta_step`` on slot ``slot`` of the carried stack ``states``
+    [slots, B, H / r, dk, r dv] -> (o [B, H, dv], the stack with the slot's
+    state moved on). On a TPU, where the packed state fills whole tiles, a
+    Pallas kernel whose output IS the stack (``rt_gdn_step``): a row's
+    state comes into VMEM once, is read for ``S^T k``, updated and read
+    for ``S^T q`` there, and goes back once. XLA's form reads the slot
+    for each of the two sums, and copied the whole stack around the update
+    of a slot it had just read (two copies of 1.48 GB a layer and step at
+    48 rows x 15 slots of [15, 96, 384]: the program did not fit). Elsewhere
+    the slot is cut out, stepped and written back."""
+    with jax.named_scope("rt.gdn.step"):
+        _, _, _, dk, wide = states.shape
+        if _on_tpu() and dk % 8 == 0 and wide % 128 == 0:
+            from ray_tpu.ops.gated_delta_pallas import step_kernel
+            return step_kernel(states, slot, *_step_operands(q, k, v), v, g,
+                               beta)
+        o, state = gated_delta_step(
+            lax.dynamic_index_in_dim(states, slot, 0, keepdims=False),
+            q, k, v, g, beta)
+        return o, lax.dynamic_update_slice(
+            states, state[None].astype(states.dtype), (slot, 0, 0, 0, 0))
+
+
+def gated_delta_step(state, q, k, v, g, beta):
+    """One position of the rule on a carried state. state: packed float32
+    [B, H / r, dk, r dv] (``pack_state``); q, k: [B, Hk, dk] as the
+    convolution left them (unit length taken here, as ``gated_delta_rule``
+    does); v: [B, H, dv]; g, beta: [B, H]. -> (o [B, H, dv] in v's dtype,
+    the state after the position). Elementwise products and sums over dk in
+    float32: an S = 1 step is bound by the state's bytes, and the MXU would
+    round the state to its operands' dtype."""
+    b, h, dv = v.shape
+    dt, f32 = v.dtype, jnp.float32
+    r = h // state.shape[1]
+    q, k = (_beside(x, r, dv) for x in _step_operands(q, k, v))
+    #                                                     [B, H/r, dk, r dv]
+    wide = (b, h // r, 1, r * dv)
+    decay = _beside(jnp.exp(g.astype(f32)), r, dv).reshape(wide)
+    beta = _beside(beta.astype(f32), r, dv).reshape(wide)
+    v = v.astype(f32).reshape(wide)
+    seen = jnp.sum(state * k, axis=2, keepdims=True)            # S^T k
+    fresh = beta * (v - decay * seen)
+    state = decay * state + k * fresh           # float32 whatever came in
+    o = jnp.sum(state * q, axis=2)
+    return o.reshape(b, h, dv).astype(dt), state
+
+
 def _chunked(q, k, v, g, beta, c):
+    """-> (o, the state after the last position)."""
     b, s, h, dv = v.shape
     dk = q.shape[-1]
     dt = v.dtype
@@ -232,7 +384,7 @@ def _chunked(q, k, v, g, beta, c):
     def by_chunk(x):        # chunk axis first, for the scan
         return jnp.moveaxis(x, 2, 0)
 
-    _, (states, fresh) = lax.scan(
+    last, (states, fresh) = lax.scan(
         step, jnp.zeros((b, h, dk, dv), f32),
         (by_chunk(w.astype(dt)), by_chunk(u), by_chunk(k_tail.astype(dt)),
          by_chunk(jnp.exp(g_last))))
@@ -243,4 +395,4 @@ def _chunked(q, k, v, g, beta, c):
             q.astype(f32) * jnp.exp(gsum)[..., None], states, dt) \
         + _mm("bhnij,bhnjv->bhniv", within, fresh, dt)
     o = jnp.moveaxis(o, 1, 3).reshape(b, n * c, h, dv)     # [B, S, H, dv]
-    return o[:, :s].astype(dt)
+    return o[:, :s].astype(dt), last

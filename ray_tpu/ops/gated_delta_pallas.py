@@ -10,7 +10,9 @@ independent of one another, so the scheduler overlaps them, and their ``[C,
 C]`` arrays lie two beside each other on the 128 lanes (C = 64), so the VPU
 works on full registers.
 
-Operands are read where the convolution left them: q, k ``[B, S, Hk*dk]``
+Operands are read where the convolution left them (head widths that are no
+multiple of 128 lanes padded to the next one first:
+``gated_delta_rule_kernels``): q, k ``[B, S, Hk*dk]``
 and v ``[B, S, Hv*dv]`` in blocks of ``(C, heads*d)`` at the group's column,
 a value head reading its key head's columns, so nothing is laid out as ``[B,
 H, n, C, d]`` in HBM, q and k are not repeated and their unit length is
@@ -50,6 +52,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.gated_delta import EPS, KEPT
+from ray_tpu.ops.gated_delta import _beside as _over_lanes
 
 BASE = 8        # side of the blocks the VPU inverts row by row: a sublane tile
 # Value heads a grid step. On a v5e at [2, 8192, 32 on 16 key heads, 128],
@@ -257,9 +260,11 @@ def _exp_over_lanes(x, n):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, gates_ref, o_ref, *rest, heads, rep,
-                dk, dv, save):
+                dk, dv, scale, save, final):
     if save:
         states_ref, t_ref, fresh_ref, state_ref = rest
+    elif final:
+        final_ref, state_ref = rest
     else:
         (state_ref,) = rest
     c = v_ref.shape[0]
@@ -276,7 +281,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, gates_ref, o_ref, *rest, heads, rep,
         qk, scores = {}, {}
         for j in sorted({i // rep for i in pack}):
             key = slice(j * dk, (j + 1) * dk)
-            q = (_unit(q_ref[:, key])[0] * dk ** -0.5).astype(dt)
+            q = (_unit(q_ref[:, key])[0] * scale).astype(dt)
             k = _unit(k_ref[:, key])[0].astype(dt)
             qk[j] = jnp.concatenate([q, k], axis=0)
             scores[j] = _dot_nt(qk[j], k)                    # [Q K^T; K K^T]
@@ -314,6 +319,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, gates_ref, o_ref, *rest, heads, rep,
                 states_ref[i] = state_dt
                 fresh_ref[:, val] = fresh_dt
 
+    if final:
+        @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+        def _last():
+            final_ref[...] = state_ref[...]
+
 
 def _gates(g, beta, heads, c):
     """g, beta [B, S, Hv] float32 -> [B, Hv/heads, n, 2 heads, C]: a head
@@ -350,10 +360,13 @@ _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _forward(q, k, v, g, beta, *, hk, hv, heads, c, save, interpret):
+def _forward(q, k, v, g, beta, *, hk, hv, heads, c, scale, save, interpret,
+             final=False):
     """q, k [B, S, Hk*dk], v [B, S, Hv*dv], S a multiple of c; g, beta [B,
-    S, Hv] float32. -> o like v, and with ``save`` what the backward reads:
-    (states, t, fresh)."""
+    S, Hv] float32; ``scale``: q's, on its unit length. -> o like v, and
+    with ``save`` what the backward reads: (states, t, fresh); with
+    ``final`` (and not ``save``) the state after the last chunk, float32
+    [B, Hv, dk, dv]."""
     b, s, _ = v.shape
     dk, dv, rep = q.shape[-1] // hk, v.shape[-1] // hv, hv // hk
     groups, n = hv // heads, s // c
@@ -367,9 +380,13 @@ def _forward(q, k, v, g, beta, *, hk, hv, heads, c, save, interpret):
             jax.ShapeDtypeStruct((b, groups, s, heads * c), v.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype)]
         out_specs += [states_spec, t_spec, val]
+    elif final:
+        out_shape.append(jax.ShapeDtypeStruct((b, hv, dk, dv), _F32))
+        out_specs.append(pl.BlockSpec((None, heads, dk, dv),
+                                      lambda b, h, n: (b, h, 0, 0)))
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, heads=heads, rep=rep, dk=dk, dv=dv,
-                          save=save),
+                          scale=scale, save=save, final=final),
         name="rt_gdn_fwd",   # rtcheck: allow-unminted-metric(a kernel's name in the device trace, not a metric)
         grid=(b, groups, n),
         in_specs=[key, key, val, gates],
@@ -379,7 +396,8 @@ def _forward(q, k, v, g, beta, *, hk, hv, heads, c, save, interpret):
         compiler_params=_PARAMS,
         interpret=interpret,
     )(q, k, v, _gates(g, beta, heads, c))
-    return (out[0], tuple(out[1:])) if save else out[0]
+    return (out[0], tuple(out[1:])) if save else \
+        (out[0], out[1]) if final else out[0]
 
 
 # ---------------------------------------------------------------------------
@@ -388,7 +406,7 @@ def _forward(q, k, v, g, beta, *, hk, hv, heads, c, save, interpret):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, gates_ref, states_ref, t_ref, fresh_ref,
                 do_ref, dq_ref, dk_ref, dv_ref, dgates_ref, dstate_ref, *,
-                heads, rep, dk, dv):
+                heads, rep, dk, dv, scale):
     c = v_ref.shape[0]
     dt = v_ref.dtype
 
@@ -409,7 +427,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gates_ref, states_ref, t_ref, fresh_ref,
             key = slice(j * dk, (j + 1) * dk)
             units[j] = _unit(q_ref[:, key]) + _unit(k_ref[:, key])
             qk[j] = jnp.concatenate([
-                (units[j][0] * dk ** -0.5).astype(dt),
+                (units[j][0] * scale).astype(dt),
                 units[j][2].astype(dt)], axis=0)             # [2C, dk]
             gram[j] = _dot_nt(qk[j], qk[j])                  # [2C, 2C]
         g_cols = [by_col[:, i:i + 1] for i in pack]
@@ -511,7 +529,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gates_ref, states_ref, t_ref, fresh_ref,
         key = slice(j * dk, (j + 1) * dk)
         # through ``_unit``: d x = (d y - y (d y . y)) / |x|
         for out_ref, dy, y, inverse in (
-                (dq_ref, dq_sum[j] * dk ** -0.5, q_unit, q_inverse),
+                (dq_ref, dq_sum[j] * scale, q_unit, q_inverse),
                 (dk_ref, dk_sum[j], k_unit, k_inverse)):
             along = jnp.sum(dy * y, axis=1, keepdims=True)
             out_ref[:, key] = (inverse * (dy - y * along)).astype(
@@ -530,7 +548,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gates_ref, states_ref, t_ref, fresh_ref,
     dgates_ref[...] = jnp.where(sublane < heads, summed, dgates)
 
 
-def _backward(q, k, v, g, beta, saved, do, *, hk, hv, heads, c, interpret):
+def _backward(q, k, v, g, beta, saved, do, *, hk, hv, heads, c, scale,
+              interpret):
     """-> (dq, dk, dv, dg, dbeta), shaped and typed as the operands."""
     b, s, _ = v.shape
     dk, dv, rep = q.shape[-1] // hk, v.shape[-1] // hv, hv // hk
@@ -538,7 +557,8 @@ def _backward(q, k, v, g, beta, saved, do, *, hk, hv, heads, c, interpret):
     key, val, gates, states_spec, t_spec = _specs(
         heads, rep, dk, dv, c, lambda i: n - 1 - i)
     dq, dk_, dv_, dgates = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads, rep=rep, dk=dk, dv=dv),
+        functools.partial(_bwd_kernel, heads=heads, rep=rep, dk=dk, dv=dv,
+                          scale=scale),
         name="rt_gdn_bwd",   # rtcheck: allow-unminted-metric(a kernel's name in the device trace, not a metric)
         grid=(b, groups, n),
         in_specs=[key, key, val, gates, states_spec, t_spec, val, val],
@@ -560,15 +580,15 @@ def _backward(q, k, v, g, beta, saved, do, *, hk, hv, heads, c, interpret):
             positions(dgates[..., heads:, :]))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _rule(q, k, v, g, beta, hk, hv, heads, c, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _rule(q, k, v, g, beta, hk, hv, heads, c, scale, interpret):
     return _forward(q, k, v, g, beta, hk=hk, hv=hv, heads=heads, c=c,
-                    save=False, interpret=interpret)
+                    scale=scale, save=False, interpret=interpret)
 
 
-def _rule_fwd(q, k, v, g, beta, hk, hv, heads, c, interpret):
+def _rule_fwd(q, k, v, g, beta, hk, hv, heads, c, scale, interpret):
     o, saved = _forward(q, k, v, g, beta, hk=hk, hv=hv, heads=heads, c=c,
-                        save=True, interpret=interpret)
+                        scale=scale, save=True, interpret=interpret)
     # All four of the kernel's outputs carry the name a remat'd layer's
     # policy keeps (models/transformer.py ``_stage_scan``): with one left
     # out the backward pass would run the whole kernel again for it (``o``
@@ -579,10 +599,10 @@ def _rule_fwd(q, k, v, g, beta, hk, hv, heads, c, interpret):
     return o, (q, k, v, g, beta, saved)
 
 
-def _rule_bwd(hk, hv, heads, c, interpret, res, do):
+def _rule_bwd(hk, hv, heads, c, scale, interpret, res, do):
     *operands, saved = res
     return _backward(*operands, saved, do, hk=hk, hv=hv, heads=heads, c=c,
-                     interpret=interpret)
+                     scale=scale, interpret=interpret)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
@@ -597,20 +617,185 @@ def heads_a_step(hk: int, hv: int) -> int:
     return fits[-1]
 
 
-def gated_delta_rule_kernels(q, k, v, g, beta, chunk, *, interpret=False):
+LANES = 128
+
+
+def gated_delta_rule_kernels(q, k, v, g, beta, chunk, *, interpret=False,
+                             final_state=False):
     """ops/gated_delta.py's ``gated_delta_rule`` by the kernels above. q, k
-    [B, S, Hk, dk], Hk dividing Hv. ``interpret`` runs the Pallas
-    interpreter: only tests pass it."""
+    [B, S, Hk, dk], Hk dividing Hv. A head width that is no multiple of
+    LANES goes in with zeros up to the next one (a head's columns then
+    start on a lane tile, as the kernels slice them): zeros add nothing to
+    a head's length, to a product with it, nor to the state's other rows
+    and columns, and q keeps the scale of its own width. Widths that are
+    multiples are read where the convolution left them, no copy made.
+    ``final_state``: also the state after position S - 1, float32 [B, Hv,
+    dk, dv], by the forward kernel alone (no gradient). ``interpret`` runs
+    the Pallas interpreter: only tests pass it."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2:]
+    wide_k, wide_v = -(-dk // LANES) * LANES, -(-dv // LANES) * LANES
     pad = -s % chunk
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-            for x in (q, k, v, g, beta))
+
+    def widen(x, wide=0):   # positions to whole chunks, a head to ``wide``
+        by = [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2)
+        if wide:
+            by[-1] = (0, wide - x.shape[-1])
+        return jnp.pad(x, by) if any(hi for _, hi in by) else x
+
+    q, k, v = widen(q, wide_k), widen(k, wide_k), widen(v, wide_v)
+    g, beta = widen(g), widen(beta)
     padded = s + pad
-    o = _rule(q.reshape(b, padded, hk * dk), k.reshape(b, padded, hk * dk),
-              v.reshape(b, padded, hv * dv), g.astype(_F32),
-              beta.astype(_F32), hk, hv, heads_a_step(hk, hv), chunk,
-              interpret)
-    return o.reshape(b, padded, hv, dv)[:, :s]
+    operands = (q.reshape(b, padded, hk * wide_k),
+                k.reshape(b, padded, hk * wide_k),
+                v.reshape(b, padded, hv * wide_v), g.astype(_F32),
+                beta.astype(_F32))
+    heads, scale = heads_a_step(hk, hv), dk ** -0.5
+    if final_state:
+        o, state = _forward(*operands, hk=hk, hv=hv, heads=heads, c=chunk,
+                            scale=scale, save=False, final=True,
+                            interpret=interpret)
+    else:
+        o = _rule(*operands, hk, hv, heads, chunk, scale, interpret)
+    o = o.reshape(b, padded, hv, wide_v)[:, :s, :, :dv]
+    return (o, state[:, :, :dk, :dv]) if final_state else o
+
+
+# ---------------------------------------------------------------------------
+# one position on a carried state (serving's decode step)
+# ---------------------------------------------------------------------------
+
+STEP_BLOCK_BYTES = 4 << 20      # of the state a grid step holds
+
+
+def _step_kernel(slot_ref, cols_ref, rows_ref, s_ref, o_ref, s_out_ref, *,
+                 groups, r, dv):
+    """One row's ``groups`` runs of r heads. cols_ref [dk, groups 2r]: a
+    run's k of each head, then its q of each, a key dim a sublane; rows_ref
+    [3, groups, r dv]: v, exp(g) and beta over their heads' lanes; s_ref,
+    s_out_ref [groups, dk, r dv]: the same block of the stack."""
+    del slot_ref
+    dk, wide = s_ref.shape[1:]
+    lane = lax.broadcasted_iota(jnp.int32, (dk, wide), 1)
+
+    def beside(first):      # r columns, each over its head's dv lanes
+        out = cols_ref[:, first + r - 1:first + r]
+        for i in range(r - 2, -1, -1):
+            out = jnp.where(lane < (i + 1) * dv,
+                            cols_ref[:, first + i:first + i + 1], out)
+        return out
+
+    for i in range(groups):
+        k, q = beside(2 * r * i), beside(2 * r * i + r)
+        v, decay, beta = (rows_ref[n, i:i + 1, :] for n in range(3))
+        state = s_ref[i].astype(_F32)
+        seen = jnp.sum(state * k, axis=0, keepdims=True)         # S^T k
+        fresh = beta * (v - decay * seen)
+        state = decay * state + k * fresh
+        s_out_ref[i] = state.astype(s_out_ref.dtype)
+        o_ref[i:i + 1, :] = jnp.sum(state * q, axis=0, keepdims=True)
+
+
+def _groups_a_step(total: int, group_bytes: int) -> int:
+    """Runs of heads a grid step: all of a row's where they fit in
+    STEP_BLOCK_BYTES, else the most that divide them into whole sublane
+    tiles of the per-run operands."""
+    if total * group_bytes <= STEP_BLOCK_BYTES:
+        return total
+    fits = [g for g in range(8, total, 8) if total % g == 0
+            and g * group_bytes <= STEP_BLOCK_BYTES]
+    return fits[-1] if fits else total
+
+
+def step_kernel(states, slot, q, k, v, g, beta, *, interpret=False):
+    """ops/gated_delta.py's ``gated_delta_step_at`` as a kernel. states
+    [slots, B, H / r, dk, r dv] float32; q, k [B, H, dk] float32 at unit
+    length (``_step_operands``); v [B, H, dv]; g, beta [B, H]. -> (o [B, H,
+    dv] in v's dtype, the stack: the kernel's output aliases ``states``
+    and a grid step reads and writes one row's block of slot ``slot``).
+    The small operands are laid out by XLA under the caller's scope."""
+    _, b, runs, dk, wide = states.shape
+    h, dv = v.shape[1:]
+    r = h // runs
+    groups = _groups_a_step(runs, dk * wide * states.dtype.itemsize)
+    cols = jnp.concatenate([x.reshape(b, runs, r, dk) for x in (k, q)],
+                           axis=2)                   # [B, runs, 2r, dk]
+    cols = jnp.swapaxes(
+        cols.reshape(b, runs // groups, groups * 2 * r, dk), 2, 3)
+    rows = jnp.stack([v.astype(_F32).reshape(b, runs, wide),
+                      _over_lanes(jnp.exp(g.astype(_F32)), r, dv),
+                      _over_lanes(beta.astype(_F32), r, dv)], axis=1)
+    block = (None, None, groups, dk, wide)
+    o, states = pl.pallas_call(
+        functools.partial(_step_kernel, groups=groups, r=r, dv=dv),
+        name="rt_gdn_step",   # rtcheck: allow-unminted-metric(a kernel's name in the device trace, not a metric)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, runs // groups),
+            in_specs=[
+                pl.BlockSpec((None, None, dk, groups * 2 * r),
+                             lambda i, j, slot: (i, j, 0, 0)),
+                pl.BlockSpec((None, 3, groups, wide),
+                             lambda i, j, slot: (i, 0, j, 0)),
+                pl.BlockSpec(block, lambda i, j, slot: (slot[0], i, j, 0,
+                                                        0))],
+            out_specs=[
+                pl.BlockSpec((None, groups, wide),
+                             lambda i, j, slot: (i, j, 0)),
+                pl.BlockSpec(block, lambda i, j, slot: (slot[0], i, j, 0,
+                                                        0))]),
+        out_shape=[jax.ShapeDtypeStruct((b, runs, wide), _F32),
+                   jax.ShapeDtypeStruct(states.shape, states.dtype)],
+        input_output_aliases={3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=10 * groups * dk * wide * 4 + (8 << 20)),
+        interpret=interpret,
+    )(jnp.asarray(slot, jnp.int32).reshape(1), cols, rows, states)
+    return o.reshape(b, h, dv).astype(v.dtype), states
+
+
+CONV_BLOCK = 2048       # channels a grid step, at most
+
+
+def _conv_step_kernel(slot_ref, x_ref, w_ref, t_ref, y_ref, t_out_ref):
+    """x_ref [B, c]: the new inputs; w_ref [K, c] float32; t_ref, t_out_ref
+    [K-1, B, c]: the same block of the stack, oldest position first."""
+    del slot_ref
+    kept = t_ref.shape[0]
+    x = x_ref[...]
+    y = x.astype(_F32) * w_ref[kept:kept + 1, :]
+    for j in range(kept):
+        y = y + t_ref[j].astype(_F32) * w_ref[j:j + 1, :]
+        t_out_ref[j] = t_ref[j + 1] if j + 1 < kept \
+            else x.astype(t_out_ref.dtype)
+    y_ref[...] = (y * jax.nn.sigmoid(y)).astype(y_ref.dtype)
+
+
+def conv_step_kernel(tails, slot, x, w, *, interpret=False):
+    """ops/gated_delta.py's ``conv_step_at`` as a kernel. tails [slots,
+    K-1, B, C]; x [B, C]; w [C, K]. -> (y [B, C] in x's dtype, the stack:
+    the kernel's output aliases ``tails``)."""
+    _, kept, b, c = tails.shape
+    fits = [n for n in range(128, min(c, CONV_BLOCK) + 1, 128) if c % n == 0]
+    wide = fits[-1] if fits else c
+    block = (None, kept, b, wide)
+    y, tails = pl.pallas_call(
+        _conv_step_kernel,
+        name="rt_gdn_conv_step",   # rtcheck: allow-unminted-metric(a kernel's name in the device trace, not a metric)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(c // wide,),
+            in_specs=[
+                pl.BlockSpec((b, wide), lambda i, slot: (0, i)),
+                pl.BlockSpec((kept + 1, wide), lambda i, slot: (0, i)),
+                pl.BlockSpec(block, lambda i, slot: (slot[0], 0, 0, i))],
+            out_specs=[
+                pl.BlockSpec((b, wide), lambda i, slot: (0, i)),
+                pl.BlockSpec(block, lambda i, slot: (slot[0], 0, 0, i))]),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(tails.shape, tails.dtype)],
+        input_output_aliases={3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(jnp.asarray(slot, jnp.int32).reshape(1), x, w.astype(_F32).T, tails)
+    return y, tails

@@ -145,6 +145,7 @@ class DeploymentHandle:
         # it. A generation bump (the controller noticed) lifts the
         # quarantine.
         self._suspects: Dict[Any, int] = {}
+        self._refreshing = False    # a background fetch of the table runs
         self._closed = False
         # Opt-in compiled fast path (serve.run(..., compile=True)): one
         # compiled one-step graph per replica; requests ride a persistent
@@ -161,11 +162,43 @@ class DeploymentHandle:
         return (DeploymentHandle, (self.name, self.method))
 
     def _refresh(self, force: bool = False):
-        import ray_tpu as rt
+        """Bring the routing table up to date. A table that is merely old
+        (over a second) still routes: ONE thread fetches the next one, off
+        the requests' path, and every request goes on with what the handle
+        has. Only a handle with no table, one told to fetch again
+        (``_ts`` 0: a replica was evicted, or the background fetch failed)
+        or ``force`` waits for the controller. (Until PR 51 every request
+        that found the table old made the round trip itself: after a call of
+        8 s all 48 callers of a closed loop did, at once, each for up to
+        0.1 s, and their requests reached the batcher too far apart for one
+        flush.)"""
         with self._lock:
             if not force and time.monotonic() - self._ts < 1.0 \
                     and self._replicas:
                 return
+            if not force and self._ts > 0.0 and self._replicas:
+                if not self._refreshing:
+                    self._refreshing = True
+                    threading.Thread(target=self._fetch_routing_quietly,
+                                     daemon=True,
+                                     name="serve-handle-refresh").start()
+                return
+        self._fetch_routing()
+
+    def _fetch_routing_quietly(self) -> None:
+        try:
+            self._fetch_routing()
+        except Exception:   # noqa: BLE001
+            # not lost: the next request fetches on its own path (``_ts`` 0)
+            # and raises what the controller said, as every request used to
+            with self._lock:
+                self._ts = 0.0
+        finally:
+            with self._lock:
+                self._refreshing = False
+
+    def _fetch_routing(self) -> None:
+        import ray_tpu as rt
         # The routing fetch is a controller round-trip (30s timeout) and
         # must NOT run under the handle lock: concurrent requests keep
         # routing on the previous table instead of convoying behind one
@@ -629,6 +662,10 @@ def _batch_state(key: str, window_s: float) -> dict:
             import collections
             st = _batch_states[key] = {
                 "lock": threading.Lock(), "pending": [],
+                # one flush at a time: ``running`` while ``fn`` has a
+                # batch, ``epoch`` counts the flushes begun, ``armed`` says
+                # that the assembling batch's timer is set
+                "running": False, "epoch": 0, "armed": False,
                 # Adaptive window state: current flush window plus the
                 # recent per-request latencies the controller law reads.
                 "window": window_s,
@@ -681,6 +718,17 @@ def batch(_fn=None, *, max_batch_size: int = 8,
     concurrent single calls coalesce into one list-call of the wrapped
     function — the TPU path to batched jitted forwards.
 
+    One batch at a time, as the reference's batch queue: a batch goes to
+    the function when it is full, or ``batch_wait_timeout_s`` after it
+    began to assemble: at its first arrival where the function was idle,
+    and where calls waited through a running batch, when that batch ENDS,
+    so that the callers it answers (a closed loop's re-send at once) join
+    the ones that waited. Until PR 51 every arrival set a timer of its
+    own, and a timer that fired while the function ran carved what had
+    arrived by then into a batch of its own behind it: three callers late
+    by more than the window were a call of three rows for as long as the
+    loop ran, whatever the call cost (8.4 s at 48 rows of a hybrid stack).
+
     With ``target_p99_ms`` set the flush window ADAPTS instead of staying
     fixed: it grows while observed p99 sits under the SLO target and
     halves on breach, so batch size tracks offered load without trading
@@ -690,14 +738,45 @@ def batch(_fn=None, *, max_batch_size: int = 8,
         import uuid
         state_key = uuid.uuid4().hex
 
+        def arm(st, delay: float) -> None:
+            """Under the state's lock: the assembling batch's one timer."""
+            if st["armed"]:
+                return
+            st["armed"] = True
+            timer = threading.Timer(delay, timed_flush, (st["epoch"],))
+            timer.daemon = True
+            timer.start()
+
+        def timed_flush(epoch: int) -> None:
+            st = _batch_state(state_key, batch_wait_timeout_s)
+            with st["lock"]:
+                if epoch != st["epoch"]:
+                    return      # that batch filled and went before its time
+                st["armed"] = False
+            flush()
+
         def flush():
             st = _batch_state(state_key, batch_wait_timeout_s)
             with st["lock"]:
-                batch_items = st["pending"][:]
-                st["pending"].clear()
+                if st["running"] or not st["pending"]:
+                    return      # the running batch's end sees to the rest
+                batch_items = st["pending"][:max_batch_size]
+                del st["pending"][:max_batch_size]
+                st["running"], st["armed"] = True, False
+                st["epoch"] += 1
                 window = st["window"]
-            if not batch_items:
-                return
+            try:
+                run(st, batch_items, window)
+            finally:
+                with st["lock"]:
+                    st["running"] = False
+                    if st["pending"]:
+                        # what waited through this batch assembles from
+                        # now: the callers just answered have the window
+                        arm(st, 0.0 if len(st["pending"]) >= max_batch_size
+                            else st["window"])
+
+        def run(st, batch_items, window):
             items = [it[0] for it in batch_items]
             self_obj = batch_items[0][2]
             waits = [it[4] for it in batch_items if it[4] is not None]
@@ -754,19 +833,16 @@ def batch(_fn=None, *, max_batch_size: int = 8,
             slot = {"event": threading.Event(), "result": None,
                     "error": None}
             st = _batch_state(state_key, batch_wait_timeout_s)
-            do_flush = False
             with st["lock"]:
                 st["pending"].append((item, slot, self_obj,
                                       time.monotonic(),
                                       _BatchWait() if events.enabled()
                                       else None))
-                if len(st["pending"]) >= max_batch_size:
-                    do_flush = True
-                window = st["window"]
-            if do_flush:
-                flush()
-            else:
-                threading.Timer(window, flush).start()
+                full = len(st["pending"]) >= max_batch_size
+                if not full and not st["running"]:
+                    arm(st, st["window"])   # the first arrival's, once
+            if full:
+                flush()         # nothing where a batch runs: its end will
             slot["event"].wait(timeout=120)
             if slot["error"] is not None:
                 raise slot["error"]

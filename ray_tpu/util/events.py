@@ -199,7 +199,12 @@ EVENT_KINDS: Dict[str, str] = {
                      "prefill_chunks, index_topk, keys_scored, "
                      "keys_attended, sparse_kernel_queries (of its queries, "
                      "those attended in rt_sparse_attend), moe_rows_here, "
-                     "moe_rows_dropped",
+                     "moe_rows_dropped, and of a stack with gated-delta-rule "
+                     "layers the cache's bytes by what holds them "
+                     "(cache_bytes_state: the float32 recurrent states, "
+                     "cache_bytes_tail: the convolutions' last inputs, "
+                     "cache_bytes_kv: the softmax layers' keys and values) "
+                     "and its slots by kind (linear_slots, full_slots)",
     "train.pump": "span: value = seconds of one synchronized report "
                   "round; attrs carry iteration/lag_s",
     # start-up

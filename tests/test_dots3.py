@@ -260,7 +260,7 @@ def test_a_decode_steps_buffer_is_the_most_any_routing_can_send():
 def test_generate_still_refuses_what_it_cannot_serve():
     cfg = program_config(layer_types=("latent", "full", "window", "window"))
     with pytest.raises(NotImplementedError, match="is not served"):
-        gen._refuse_recurrent(cfg)
+        gen._refuse_unserved(cfg)
     with pytest.raises(ValueError, match="not a multiple of the chunk"):
         gen.prefill_and_taps(None, jnp.zeros((1, 10), jnp.int32),
                              program_config(), 12, chunk=4)

@@ -299,3 +299,77 @@ def test_sparse_attend_compiles_for_v5e_at_the_cells_block():
     # no score and no row among the program's temporaries: the packed
     # cache (2 x 50.5 MB) and what packing it holds
     assert compiled.memory_analysis().temp_size_in_bytes < 320 << 20
+
+
+def test_a_hybrid_generate_keeps_one_state_on_v5e(monkeypatch):
+    """Mosaic accepts the gated delta rule's kernels at the Olmo-Hybrid
+    cell's widths (30 heads of 96 / 192: the forward kernel on operands
+    padded to 128 / 256 with its final state, the one-position kernel on
+    the packed state, the convolution's step), and the compiled
+    ``generate`` of two periods at the cell's 48 rows x (128 + 384) holds
+    ONE state stack: the step kernels' output is the stack they read, and
+    no instruction copies an array of its shape. XLA's form of the step
+    (cut the slot out, step, write it back) copies the whole stack twice a
+    layer and step: the guard bites on it."""
+    import re
+    from functools import partial
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import (TransformerConfig, generate_with_stats,
+                                transformer_init)
+    from ray_tpu.ops import gated_delta
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu in this install
+        pytest.skip(f"no TPU compiler here: {e!r}")
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = TransformerConfig(
+        vocab_size=12544, d_model=3840, n_layers=8, n_heads=30,
+        n_kv_heads=30, d_ff=11008, max_seq=512,
+        layer_types=("linear", "linear", "linear", "full"),
+        linear_key_heads=30, linear_value_heads=30, linear_key_dim=96,
+        linear_value_dim=192, linear_beta_scale=2.0, post_norm_only=True,
+        qk_norm_whole=True, partial_rotary_factor=0.0,
+        param_dtype=jnp.bfloat16, remat=False)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(partial(transformer_init, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    prompts = jax.ShapeDtypeStruct((48, 128), jnp.int32, sharding=one)
+
+    def compiled():
+        return jax.jit(partial(
+            generate_with_stats, cfg=cfg, max_new_tokens=384)).lower(
+                params, prompts).compile()
+
+    monkeypatch.setattr(gated_delta, "_on_tpu", lambda: True)
+    state = r"f32\[6,48,15,96,384\]"           # nothing padded: 3 x 128 lanes
+    copied = state + r"\S* copy\("
+    sound = compiled()
+    text = sound.as_text()
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    for name, shape in (("rt_gdn_fwd", "bf16[48,128,7680]"),     # 30 x 256
+                        ("rt_gdn_step", "f32[6,48,15,96,384]"),
+                        ("rt_gdn_conv_step", "bf16[6,3,48,11520]")):
+        calls = [ln for ln in kernels if name in ln]
+        assert calls and all(shape in ln for ln in calls), name
+    assert "f32[48,30,128,256]" in "".join(
+        ln for ln in kernels if "rt_gdn_fwd" in ln)         # the final state
+    assert re.search(state, text) and not re.search(copied, text)
+    stacks = 4 * 6 * 48 * 15 * 96 * 384 + 2 * 2 * 2 * 48 * 512 * 30 * 128 \
+        + 2 * 6 * 3 * 48 * 11520
+    assert sound.memory_analysis().temp_size_in_bytes < stacks + (3 << 30)
+    # the guard bites: off the kernels, the step copies the stack
+    monkeypatch.setattr(gated_delta, "gated_delta_step_at",
+                        lambda states, slot, *a: (lambda o, s: (
+                            o, jax.lax.dynamic_update_slice(
+                                states, s[None], (slot, 0, 0, 0, 0))))(
+                            *gated_delta.gated_delta_step(
+                                jax.lax.dynamic_index_in_dim(
+                                    states, slot, 0, keepdims=False), *a)))
+    assert re.search(copied, compiled().as_text())

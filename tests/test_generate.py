@@ -354,9 +354,15 @@ def _latent_dims():
     return LatentDims(heads=4, q_rank=16, kv_rank=16, nope=8, rope=8, v=8)
 
 
-# the three kinds of stack `generate` serves, through its one trunk and its
-# one cache: like softmax layers, the same looped, and a pattern by kind
+# the four kinds of stack `generate` serves, through its one trunk and its
+# one cache: like softmax layers, the same looped, a pattern by kind, and
+# a pattern of gated-delta-rule and softmax layers
 STACKS = {
+    "hybrid": dict(n_layers=4, layer_types=("linear", "full"),
+                   linear_key_heads=2, linear_value_heads=2,
+                   linear_key_dim=8, linear_value_dim=64,
+                   linear_beta_scale=2.0, post_norm_only=True,
+                   qk_norm_whole=True, partial_rotary_factor=0.0),
     "llama": dict(),
     "looped": dict(loop_steps=2, sandwich_norm=True),
     "by-kind": dict(n_layers=4, layer_types=("latent", "window"),
@@ -383,7 +389,8 @@ def test_every_path_runs_the_one_definition(monkeypatch, path, shared,
     last-position logits and decode_step's logits alike, and the three go
     on agreeing as they do untouched. The trunk over the cache is written
     once too: a stand-in in its place moves prefill and decode of a llama
-    stack, a looped stack and a stack by kind, and leaves training be."""
+    stack, a looped stack, a stack by kind and a stack of linear and
+    softmax layers, and leaves training be."""
     import sys
 
     from ray_tpu.models import transformer
@@ -474,7 +481,12 @@ def test_init_cache_is_zeros_of_cache_shapes(overrides):
     assert {k: v.shape for k, v in cache.items()} == shapes
     assert all(v.dtype == cfg.dtype and not np.asarray(v).any()
                for v in cache.values())
-    if "k" in shapes:       # a slot for every (loop step, layer)
+    if "state" in shapes:   # 2 periods of a linear and a softmax layer
+        assert sorted(shapes) == ["k", "state", "tail", "v"]
+        assert shapes["k"] == (2, 2, 12, cfg.kv_heads, cfg.head_dim)
+        assert shapes["state"] == (2, 2, 1, 8, 128)     # two heads a run
+        assert shapes["tail"] == (2, 3, 2, 2 * 16 + 128)
+    elif "k" in shapes:     # a slot for every (loop step, layer)
         assert shapes["k"] == shapes["v"] == (
             cache_slots(cfg), 2, 12, cfg.kv_heads, cfg.head_dim)
     else:                   # 2 periods of a latent and a window layer

@@ -79,7 +79,8 @@ def test_chunked_delta_rule_matches_the_recurrence_values_and_gradients():
     h = hidden()
 
     def system(p, x):
-        return transformer._gated_delta_mix(cfg, p, x)
+        return transformer._gated_delta_mix(
+            cfg, p, x, transformer._whole_rule(cfg))[0]
 
     def plain(p, x):
         with jax.default_matmul_precision("highest"):
@@ -244,7 +245,7 @@ def test_delta_rule_kernels_run_per_shard_under_a_mesh(kernels_interpreted,
     operands = delta_rule_operands(64, hk, 4, dk=128, dv=128, b=2)
     got = jax.jit(partial(gated_delta.gated_delta_rule_over, mesh,
                           DEFAULT_RULES))(*operands)
-    close(got, gated_delta._chunked(*operands, gated_delta.CHUNK))
+    close(got, gated_delta._chunked(*operands, gated_delta.CHUNK)[0])
 
 
 def test_off_the_tpu_the_delta_rule_is_the_jnp_form():
@@ -714,12 +715,39 @@ def test_make_lm_train_step_takes_the_hybrid_configuration():
     assert sorted(metrics) == ["grad_norm", "loss", "step"]
 
 
-def test_generate_refuses_recurrent_layers_in_words():
-    cfg = program_config()
+def test_generate_serves_the_pattern_and_refuses_it_beside_latent_layers():
+    """Since the cache holds a linear layer's state and tail beside keys
+    and values, this pattern (gated-delta-rule and gated softmax layers over
+    an expert layer) goes through the one trunk: greedy tokens are a chain
+    of decode steps'. What is still refused, in words: a linear layer
+    beside latent or window layers (tests/test_olmo_hybrid.py has the
+    rest)."""
+    import dataclasses
+    import importlib
+    from functools import partial
+
+    from ray_tpu.models.transformer import LatentDims
+    gen = importlib.import_module("ray_tpu.models.generate")
+    cfg = dataclasses.replace(program_config(), remat=False)
     params = transformer_init(jax.random.PRNGKey(0), cfg)
-    prompt = jnp.zeros((1, 4), jnp.int32)
+    prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
+                                cfg.vocab_size)
+    tokens = jax.jit(partial(generate, cfg=cfg, max_new_tokens=4))(params,
+                                                                   prompt)
+    logits, cache = gen.prefill(params, prompt, cfg, 12)
+    assert sorted(cache) == ["k", "state", "tail", "v"]
+    for j in range(4):
+        token = jnp.argmax(logits, -1).astype(jnp.int32)
+        np.testing.assert_array_equal(np.asarray(token),
+                                      np.asarray(tokens[:, j]))
+        logits, cache = gen.decode_step(params, token,
+                                        jnp.asarray(8 + j, jnp.int32), cache,
+                                        cfg)
+    dims = LatentDims(heads=2, q_rank=8, kv_rank=8, nope=8, rope=8, v=8)
+    beside = dataclasses.replace(
+        cfg, layer_types=("linear", "latent"), latent=dims)
     with pytest.raises(NotImplementedError, match="gated-delta-rule"):
-        generate(params, prompt, cfg, max_new_tokens=2)
+        generate(params, prompt, beside, max_new_tokens=2)
 
 
 def test_a_layer_pattern_is_checked_where_it_is_configured():
