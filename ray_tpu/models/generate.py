@@ -72,7 +72,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.latent import ring_positions, sparse_in_kernel
+from ray_tpu.models.latent import (NEVER, ring_positions, sparse_in_kernel,
+                                   window_in_kernel)
 from ray_tpu.models.transformer import (TransformerConfig, _attention,
                                         _head, _layer_apply,
                                         _over_loop_steps, _rule_operands)
@@ -396,11 +397,13 @@ def _write_chunk_at(cfg: TransformerConfig, start, chunk: int, mesh=None):
     the rotated K and V it ran on, so the cache matches the forward bit for
     bit; a latent layer writes the chunk's entries at their positions and
     attends to its whole slot (the mask leaves out what is not written
-    yet); a window layer attends to its ring as it stood and the chunk's
-    own entries, then writes the chunk's last `window_rows` into the
-    ring; a linear layer runs the chunked rule from a zero state (the whole
-    prompt too) and writes the state it ends in and the convolution's last
-    K - 1 inputs."""
+    yet); a window layer attends to keys in position order, the
+    ``window - 1`` positions before the chunk read out of its ring as it
+    stood and then the chunk's own entries (so that the band is one of
+    indices: models/latent.py ``_expanded``), then writes the chunk's last
+    `window_rows` into the ring; a linear layer runs the chunked rule from
+    a zero state (the whole prompt too) and writes the state it ends in and
+    the convolution's last K - 1 inputs."""
     def attend_at(kind, cache, slot):
         def full(q, k, v):
             return _attention(cfg, q, k, v, mesh), dict(
@@ -419,8 +422,13 @@ def _write_chunk_at(cfg: TransformerConfig, start, chunk: int, mesh=None):
         def window(new):
             rows = window_rows(cfg)
             ring = _slot_rows(cache["window"], slot)
-            keys = {"latent": jnp.concatenate([ring, new["latent"]], 1)}
-            kpos = jnp.concatenate([ring_positions(start - 1, rows),
+            # the window - 1 positions before the chunk, out of the ring,
+            # then the chunk's own: position order (those before position
+            # 0 do not exist)
+            before = start - (cfg.window - 1) + jnp.arange(cfg.window - 1)
+            keys = {"latent": jnp.concatenate(
+                [ring[:, before % rows], new["latent"]], 1)}
+            kpos = jnp.concatenate([jnp.where(before >= 0, before, NEVER),
                                     start + jnp.arange(chunk)])[None]
             tail = min(chunk, rows)
             at = (start + chunk - tail + jnp.arange(tail)) % rows
@@ -743,12 +751,13 @@ def call_span(cfg: TransformerConfig, rows: int, prompt: int,
         # of them scored by the indexer, index_topk of them attended to
         layers = rows * kind_slots(cfg)["latent"]
         k = cfg.index_topk if 0 < cfg.index_topk < prompt + new else 0
+        chunk = prefill_chunk(prompt)
         total = prompt + new - 1        # queries at positions 0 .. total-1
         causal = total * (total + 1) // 2
         few = min(total, k)             # positions with no more than k keys
         attrs.update(
             {"cache_bytes_" + name: n for name, n in nbytes.items()},
-            prefill_chunks=prompt // prefill_chunk(prompt),
+            prefill_chunks=prompt // chunk,
             index_topk=cfg.index_topk,
             keys_scored=layers * causal if k else 0,
             keys_attended=layers * (few * (few + 1) // 2 + (total - few) * k
@@ -756,6 +765,11 @@ def call_span(cfg: TransformerConfig, rows: int, prompt: int,
             # the prompt's queries, where a block of them over the whole
             # cache went through rt_sparse_attend (no decode step does)
             sparse_kernel_queries=layers * prompt if k and sparse_in_kernel(
-                cfg.latent_dims("latent"), k, prefill_chunk(prompt),
-                prompt + new) else 0)
+                cfg.latent_dims("latent"), k, chunk, prompt + new) else 0,
+            # and a window layer's, where a chunk of them went through the
+            # flash forward kernel with a window
+            window_kernel_queries=rows * kind_slots(cfg)["window"] * prompt
+            if "window" in shapes and window_in_kernel(
+                cfg.latent_dims("window"), cfg.window, chunk,
+                chunk + cfg.window - 1) else 0)
     return events.span("generate.call", **attrs)

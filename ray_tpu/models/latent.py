@@ -45,6 +45,22 @@ runs in the flash kernels, which take a value width of its own, or in query
 blocks that the backward pass makes again, so that a latent layer trains at
 8k and holds no block's scores across its backward.
 
+A window layer's expanded form is banded causal attention, and its bound is
+the layer's (``cfg.window``), never the mask's alone: ``attend`` hands a
+window layer's many queries their keys in position order, ending with the
+queries' own (a prefill chunk: the ``window - 1`` positions before it out of
+the ring, then its own entries; the training forward: the sequence), so
+that a query's window is a run of key indices. One algorithm at two sizes,
+by what the code sees of its input (``window_in_kernel``): on a TPU a
+prefill chunk goes through the flash forward kernel with that window
+(ops/flash.py: a block of 512 queries visits the two key blocks of 512 its
+band reaches, no score leaves VMEM, the keys before a prompt's position 0
+left out by a prefetched scalar); everywhere else (the CPU, the training
+forward, sizes the tiles do not divide) a block of queries takes the
+``block + window - 1`` keys its band reaches out of the ordered keys and
+masks those by position, the kernel's reference in the tests. A decode
+step's one query is absorbed, over the ring as it lies.
+
 The selection is a SET: ``select`` hands a query's ``index_topk`` key
 positions to a gather and a softmax that sums over them, and nothing reads
 their order. So no row of scores is sorted (XLA lowers ``lax.top_k`` on a
@@ -62,7 +78,9 @@ output), ``rt.dsa.index`` (the indexer's scores and the selection),
 ``rt.mla.sparse`` (the attention over the selection: the cache packed for
 the kernel once a layer and chunk, the query absorbed, the kernel's Mosaic
 call or the gather and the three passes, the values unabsorbed),
-``rt.mla.window`` (a window layer's attention), ``rt.mla.dense`` (a latent
+``rt.mla.window`` (a window layer's attention: the keys' expansion, the
+layouts and the Mosaic call ``rt_flash_fwd``, or the banded blocks; a decode
+step's absorbed attention over the ring), ``rt.mla.dense`` (a latent
 layer's attention over all keys, where there are no more than
 ``index_topk``).
 """
@@ -91,12 +109,15 @@ DENSE_QUERY_BLOCK = 512
 # A sequence over its own keys is filled up to a multiple of this for the
 # flash kernels: the larger of their default blocks (ops/flash.py).
 FLASH_MULTIPLE = 1024
+# What the sizes of a window layer's chunk are multiples of where it goes
+# through the flash forward kernel: a row of lanes, the least block.
+KERNEL_TILE = 128
 # Indexer heads scored at once: [B, group, block, keys] float32 is held.
 INDEX_HEAD_GROUP = 16
 # Keys a tile of the selection's compaction: one row of lanes.
 SELECT_TILE = 128
 LAYER_NORM_EPS = 1e-6
-_NEVER = jnp.iinfo(jnp.int32).max     # the position of a slot never written
+NEVER = jnp.iinfo(jnp.int32).max     # the position of a slot never written
 
 
 def _indexed(cfg: TransformerConfig, kind: str) -> bool:
@@ -201,6 +222,34 @@ def _flash_own(impl: str, s: int) -> bool:
                                and s >= FLASH_MULTIPLE)
 
 
+def window_in_kernel(dims: LatentDims, window: int, queries: int,
+                     keys: int) -> bool:
+    """Whether ``queries`` of a window layer over ``keys`` keys in position
+    order, the last ``queries`` of them the queries' own, run in the flash
+    forward kernel with a window: on a TPU, more than one query (a decode
+    step is absorbed), just the ``window - 1`` keys before the first query
+    that any query reaches (what a prefill chunk is handed; the training
+    forward's own keys stay in the ``jnp`` form), and sizes the kernel's
+    blocks and the chip's tiles divide. The ``jnp`` form otherwise."""
+    return _on_tpu() and window > 0 and queries > 1 \
+        and keys == queries + window - 1 \
+        and not any(n % KERNEL_TILE for n in (
+            queries, keys, dims.nope + dims.rope, dims.v))
+
+
+def _with_lead(keys, kpos, queries: int, lead: int):
+    """keys [B, T, ...] in position order, the last ``queries`` the
+    queries' own, and their positions [b, T] -> the same with just ``lead``
+    keys before the first query's: older ones cut off, the front filled
+    with keys that do not exist."""
+    extra = keys.shape[1] - queries - lead
+    if extra >= 0:
+        return keys[:, extra:], kpos[:, extra:]
+    fill = ((0, 0), (-extra, 0))
+    return jnp.pad(keys, fill + ((0, 0),) * (keys.ndim - 2)), \
+        jnp.pad(kpos, fill, constant_values=NEVER)
+
+
 def _expanded(dims, wukv, q_nope, q_rope, qpos, keys, kpos, window,
               own: str = ""):
     """Every key's k_nope and v from its latent; queries in blocks.
@@ -210,17 +259,30 @@ def _expanded(dims, wukv, q_nope, q_rope, qpos, keys, kpos, window,
     attention with k = [k_nope ; k_rope] and a value width of its own, so
     it runs in the flash kernels (ops/flash.py: no score leaves the chip's
     fast memory, causal blocks above the diagonal skipped) or, where they
-    do not run, in query blocks that a backward pass makes again."""
+    do not run, in query blocks that a backward pass makes again.
+    ``window``: the keys come in position order and end with the queries'
+    own (a key that does not exist holds position ``NEVER``), so the band
+    is one of indices: a prefill chunk's goes through the flash forward
+    kernel with that window (`window_in_kernel`), and everywhere else a
+    block of queries scores the ``block + window - 1`` keys its band
+    reaches, not all of them."""
     r = dims.kv_rank
+    s = q_nope.shape[1]
+    in_kernel = not own and window_in_kernel(dims, window, s, keys.shape[1])
+    if window and not in_kernel:
+        keys, kpos = _with_lead(keys, kpos, s, window - 1)
     kv = jnp.einsum("btr,rhd->bthd", keys[..., :r], wukv)
     k_nope, v, k_rope = kv[..., :dims.nope], kv[..., dims.nope:], \
         keys[..., r:]
     scale = 1.0 / math.sqrt(dims.nope + dims.rope)
-    s = q_nope.shape[1]
-    if own and not window and _flash_own(own, s):
+    if in_kernel or own and not window and _flash_own(own, s):
         q = jnp.concatenate([q_nope, q_rope], -1)
         k = jnp.concatenate([k_nope, jnp.broadcast_to(
             k_rope[:, :, None], k_nope.shape[:3] + (dims.rope,))], -1)
+        if in_kernel:       # the keys that do not exist lead the others
+            return flash_attention(
+                q, k, v, causal=True, scale=scale, window=window,
+                first_key=jnp.sum(kpos[0] == NEVER, dtype=jnp.int32))
         # up to a length the kernels' blocks divide (a multi-token-
         # prediction module runs S - 1 positions): the keys added lie
         # behind every query, the queries added are cut off again
@@ -228,15 +290,26 @@ def _expanded(dims, wukv, q_nope, q_rope, qpos, keys, kpos, window,
         q, k, v = (jnp.pad(a, fill) for a in (q, k, v))
         return flash_attention(q, k, v, causal=True, scale=scale)[:, :s]
 
-    def block(q_nope, q_rope, qpos):
+    def attend(q_nope, q_rope, qpos, k_nope, k_rope, v, kpos):
         scores = (jnp.einsum("bshd,bthd->bhst", q_nope, k_nope)
                   + jnp.einsum("bshd,btd->bhst", q_rope, k_rope)) * scale
         w = _softmax(scores, _mask(qpos, kpos, window)[:, None])
         return jnp.einsum("bhst,bthd->bshd", w.astype(v.dtype), v)
 
+    def block(q_nope, q_rope, qpos, *index):
+        shared = k_nope, k_rope, v, kpos
+        if window:      # the keys from window - 1 before the block's first
+            span = q_nope.shape[1] + window - 1
+            shared = [lax.dynamic_slice_in_dim(a, index[0][0, 0], span, 1)
+                      for a in shared]
+        return attend(q_nope, q_rope, qpos, *shared)
+
+    # a window's block finds its keys by its first query's index
+    index = (jnp.broadcast_to(jnp.arange(s), qpos.shape),) if window else ()
     # under a gradient a block's scores are made again, not kept
     return _over_query_blocks(jax.checkpoint(block) if own else block,
-                              DENSE_QUERY_BLOCK, q_nope, q_rope, qpos)
+                              DENSE_QUERY_BLOCK, q_nope, q_rope, qpos,
+                              *index)
 
 
 def _absorb(dims, wukv, q_nope):
@@ -491,4 +564,4 @@ def ring_positions(last, rows: int):
     was written to reads as a position no query reaches."""
     slot = jnp.arange(rows)
     held = last - (last - slot) % rows
-    return jnp.where(held >= 0, held, _NEVER)
+    return jnp.where(held >= 0, held, NEVER)

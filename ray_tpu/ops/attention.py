@@ -27,9 +27,11 @@ NEG_INF = -1e30
 
 def attention_reference(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
-                        segment_ids=None):
+                        segment_ids=None, window: Optional[int] = None,
+                        first_key=None):
     """Plain softmax attention. q,k,v: [B, S, H, D] (k/v may have fewer heads
-    for GQA — heads must divide evenly)."""
+    for GQA — heads must divide evenly). ``window``, ``first_key``: the
+    causal band of ops/flash.py ``flash_attention``, by key index."""
     b, sq, hq, d = q.shape
     _, sk, hk, _ = k.shape
     if hk != hq:
@@ -41,6 +43,10 @@ def attention_reference(q, k, v, *, causal: bool = True,
     mask = None
     if causal:
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
+        if window is not None:
+            mask &= ~jnp.tril(mask, k=sk - sq - window)
+            if first_key is not None:
+                mask &= jnp.arange(sk) >= first_key
     if segment_ids is not None:
         seg_q, seg_k = segment_ids
         seg_mask = seg_q[:, :, None] == seg_k[:, None, :]

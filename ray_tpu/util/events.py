@@ -198,7 +198,10 @@ EVENT_KINDS: Dict[str, str] = {
                      "a stack by kind cache_bytes_latent/_index/_window, "
                      "prefill_chunks, index_topk, keys_scored, "
                      "keys_attended, sparse_kernel_queries (of its queries, "
-                     "those attended in rt_sparse_attend), moe_rows_here, "
+                     "those attended in rt_sparse_attend), "
+                     "window_kernel_queries (of its window layers' prompt "
+                     "queries, those attended in rt_flash_fwd with a "
+                     "window), moe_rows_here, "
                      "moe_rows_dropped, and of a stack with gated-delta-rule "
                      "layers the cache's bytes by what holds them "
                      "(cache_bytes_state: the float32 recurrent states, "

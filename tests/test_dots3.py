@@ -109,30 +109,50 @@ def test_the_five_layer_stack_forward_against_the_reference():
     close(blocks["logits"], want["logits"], tol=1e-6)
 
 
-@pytest.mark.parametrize("chunk", [16, 32, 64])
-def test_chunked_prefill_then_decode_through_the_three_caches(chunk):
-    """Prefill in four, two and one chunks, then 8 decode steps: logits,
-    the cached latents, indexer keys and rings, and the selections against
-    the reference's full forward."""
-    cfg = program_config()
+@pytest.mark.parametrize("prompt,chunk", [
+    (64, 16), (64, 32), (64, 64),
+    # chunks under the ring's 16 rows: of the window's 8 earlier positions,
+    # and of fewer, so that a chunk's keys are mostly the ring's
+    (64, 8), (64, 4),
+    # a prompt shorter than the window of 9, in one chunk and in two: every
+    # position before the chunk's window does not exist
+    (8, 8), (8, 4),
+    (24, 24),       # the ring wraps inside the one chunk
+])
+def test_chunked_prefill_then_decode_through_the_three_caches(prompt, chunk):
+    """Prefill in chunks, then 8 decode steps: logits, the cached latents,
+    indexer keys and rings, the selections and the window layers' key
+    counts against the reference's full forward. A window layer's chunk
+    attends to keys in position order, the 8 before it out of the ring:
+    chunks below, at and above the ring's 16 rows, and a first chunk that
+    has no earlier keys."""
+    seq = prompt + DECODED
+    cfg = program_config(seq=seq)
     params = seeded(cfg)
-    tokens = tokens_of()
-    want = reference_details(params, TOY, tokens, keep_from=PROMPT - 1)
+    tokens = tokens_of(seq=seq)
+    want = reference_details(params, TOY, tokens, keep_from=prompt - 1,
+                             sizes=dict(ONE_BLOCK, positions=seq,
+                                        index_queries=seq, queries=seq))
     logits, cache, taps = jax.jit(partial(
-        gen.prefill_and_taps, cfg=cfg, max_len=SEQ, chunk=chunk))(
-            params, tokens[:, :PROMPT])
+        gen.prefill_and_taps, cfg=cfg, max_len=seq, chunk=chunk))(
+            params, tokens[:, :prompt])
+    assert app.window_keys_off(cfg, taps, prompt - 1) == 0
     step = jax.jit(partial(gen.decode_step_and_taps, cfg=cfg))
     got, picks = [logits], [app._selections(taps)]
     for j in range(DECODED):
-        logits, cache, taps = step(params, tokens[:, PROMPT + j],
-                                   jnp.asarray(PROMPT + j, jnp.int32), cache)
+        logits, cache, taps = step(params, tokens[:, prompt + j],
+                                   jnp.asarray(prompt + j, jnp.int32), cache)
+        assert app.window_keys_off(cfg, taps, prompt + j) == 0
         got.append(logits)
         picks.append(app._selections(taps))
     close(jnp.stack(got, 1), want["logits"])
-    view = app.cache_view(cfg, cache, SEQ)
+    view = app.cache_view(cfg, cache, seq)
     close(view["latent"], want["latent"])
     close(view["index"], want["index"])
-    close(view["window"], want["window"][:, :, SEQ - 16:])
+    close(view["window"], want["window"][:, :, max(0, seq - 16):])
+    if seq <= cfg.index_topk:       # no more keys than that: none selected
+        assert picks == [None] * (1 + DECODED)
+        return
     assert reference.selection_overlap(
         jnp.stack([s[0] for s in picks], 2),
         jnp.stack([s[1] for s in picks], 2),
@@ -174,28 +194,81 @@ def test_the_window_cache_is_a_ring_of_the_window_rounded_up():
     assert attrs["cache_bytes"] == 4 * (2 * 2 * SEQ * 40 + 3 * 2 * 16 * 40)
     assert attrs["cache_bytes_window"] == 4 * 3 * 2 * 16 * 40
     assert attrs["prefill_chunks"] == 1 and attrs["index_topk"] == 16
+    assert attrs["window_kernel_queries"] == 0
     total = SEQ - 1
     assert attrs["keys_scored"] == 2 * 2 * total * (total + 1) // 2
     assert attrs["keys_attended"] == 2 * 2 * (16 * 17 // 2
                                               + (total - 16) * 16)
 
 
-def test_absorbed_attention_is_expanded_attention():
+@pytest.mark.parametrize("window,s,t,start", [
+    (0, 5, 12, 7), (4, 5, 12, 7),
+    # in blocks of 4 queries, each over the 4 + window - 1 keys its band
+    # reaches: just the window - 1 keys before the first query (a prefill
+    # chunk's), none (the training forward's own keys), more than any
+    # query reaches, fewer
+    (4, 12, 15, 3), (4, 12, 12, 0), (9, 12, 23, 11), (9, 12, 14, 2),
+    (4, 12, 15, 0),         # a prompt's first chunk: 3 keys do not exist
+    (1, 12, 12, 0),         # a window of the query's own key
+])
+def test_absorbed_attention_is_expanded_attention(monkeypatch, window, s, t,
+                                                  start):
+    """The expanded form, banded by index under a window (keys in position
+    order, the last ``s`` the queries' own), against the absorbed form,
+    which masks every key by its position as the expanded form did: value
+    and, as the training forward takes it, gradient."""
+    monkeypatch.setattr(latent, "DENSE_QUERY_BLOCK", 4)
     dims = program_config().latent
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    b, s, t = 2, 5, 12
+    b = 2
     wukv = jax.random.normal(ks[0], (dims.kv_rank, dims.heads,
                                      dims.nope + dims.v))
     q_nope = jax.random.normal(ks[1], (b, s, dims.heads, dims.nope))
     q_rope = jax.random.normal(ks[2], (b, s, dims.heads, dims.rope))
     keys = jax.random.normal(ks[3], (b, t, dims.cached))
-    qpos = jnp.broadcast_to(jnp.arange(t - s, t), (b, s))
-    kpos = jnp.arange(t)[None]
-    for window in (0, 4):
-        close(latent._absorbed(dims, wukv, q_nope, q_rope, qpos, keys, kpos,
-                               window),
-              latent._expanded(dims, wukv, q_nope, q_rope, qpos, keys, kpos,
-                               window), tol=1e-5)
+    qpos = jnp.broadcast_to(start + jnp.arange(s), (b, s))
+    kpos = start - (t - s) + jnp.arange(t)
+    kpos = jnp.where(kpos >= 0, kpos, latent.NEVER)[None]
+
+    def out(form, q_nope, keys, **own):
+        return form(dims, wukv, q_nope, q_rope, qpos, keys, kpos, window,
+                    **own)
+
+    close(out(latent._expanded, q_nope, keys),
+          out(latent._absorbed, q_nope, keys), tol=1e-5)
+    grad = lambda form, **own: jax.grad(
+        lambda *a: jnp.sum(out(form, *a, **own) ** 2), (0, 1))(q_nope, keys)
+    for got, want in zip(grad(latent._expanded, own="reference"),
+                         grad(latent._absorbed)):
+        close(got, want, tol=1e-5)
+
+
+def test_where_a_windows_chunk_goes_through_the_flash_kernel(monkeypatch):
+    """``window_in_kernel`` reads the code's own view: a TPU, more than one
+    query, just the window's keys before them in order, sizes that tiles
+    divide; and ``generate.call`` counts the prompt's window-layer queries
+    that went that way."""
+    cfg = program_config(PUBLISHED, seq=32896)
+    dims = cfg.latent_dims("window")
+    chunk = (dims, 513, 2048, 2560)
+    assert not latent.window_in_kernel(*chunk)                  # the CPU
+    assert gen.call_span(cfg, 2, 32768, 128).attrs[
+        "window_kernel_queries"] == 0
+    monkeypatch.setattr(latent, "_on_tpu", lambda: True)
+    assert latent.window_in_kernel(*chunk)
+    assert not latent.window_in_kernel(dims, 513, 1, 513)       # a step
+    assert not latent.window_in_kernel(dims, 513, 2048, 2048)   # own keys
+    assert not latent.window_in_kernel(dims, 513, 2048, 2568)   # unordered
+    assert not latent.window_in_kernel(dims, 0, 2048, 2047)     # no window
+    assert not latent.window_in_kernel(dims, 500, 2048, 2547)   # off a tile
+    assert not latent.window_in_kernel(
+        program_config().latent_dims("window"), 513, 2048, 2560)    # widths
+    # three window layers' prompt queries of both rows; the decode steps
+    # keep the absorbed form over the ring
+    assert gen.call_span(cfg, 2, 32768, 128).attrs[
+        "window_kernel_queries"] == 2 * 3 * 32768
+    assert gen.call_span(cfg, 2, 32767, 128).attrs[     # chunks of 1,057
+        "window_kernel_queries"] == 0
 
 
 def test_with_no_more_keys_than_topk_a_full_layer_is_plain_latent_attention():

@@ -22,10 +22,23 @@ def _bhsd(x):  # [B, S, H, D] -> [B*H, S, D]
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
 
-@pytest.mark.parametrize("causal,sq,sk", [(True, 256, 256),
-                                          (False, 256, 256),
-                                          (True, 128, 256)])
-def test_flash_kernels_match_reference(causal, sq, sk):
+@pytest.mark.parametrize("causal,sq,sk,window,first", [
+    (True, 256, 256, None, None),
+    (False, 256, 256, None, None),
+    (True, 128, 256, None, None),
+    # a band, the queries the last of more keys: of one key, of few, of the
+    # dots3 cell's (512 keys before the first query, its window 513)
+    (True, 128, 256, 1, None),
+    (True, 128, 256, 5, None),
+    (True, 256, 768, 513, None),
+    (True, 256, 256, 200, None),         # the sequence's own keys
+    (True, 128, 256, 1000, None),        # wider than the keys: causal
+    # a prompt's first chunk: the keys before position 0 do not exist, a
+    # whole block of them and part of one
+    (True, 256, 768, 513, 512),
+    (True, 128, 256, 40, 100),
+])
+def test_flash_kernels_match_reference(causal, sq, sk, window, first):
     rng = np.random.default_rng(0)
     b, h, d = 1, 2, 128
     q = jnp.asarray(rng.normal(size=(b, sq, h, d)), jnp.float32) * 0.5
@@ -33,10 +46,11 @@ def test_flash_kernels_match_reference(causal, sq, sk):
     v = jnp.asarray(rng.normal(size=(b, sk, h, d)), jnp.float32) * 0.5
     g = jnp.asarray(rng.normal(size=(b, sq, h, d)), jnp.float32)
     kw = dict(causal=causal, scale=d ** -0.5, block_q=128, block_k=128,
-              interpret=True)
+              window=window, first=first, interpret=True)
 
     ref, vjp = jax.vjp(
-        lambda q, k, v: attention_reference(q, k, v, causal=causal), q, k, v)
+        lambda q, k, v: attention_reference(
+            q, k, v, causal=causal, window=window, first_key=first), q, k, v)
     out, lse = _flash_fwd(_bhsd(q), _bhsd(k), _bhsd(v), **kw)
     np.testing.assert_allclose(out, _bhsd(ref), atol=2e-5)
 
@@ -44,6 +58,63 @@ def test_flash_kernels_match_reference(causal, sq, sk):
                        **kw)
     for got, want in zip(grads, vjp(g)):
         np.testing.assert_allclose(got, _bhsd(want), atol=2e-4)
+    if first:       # a key that does not exist gets no gradient
+        assert not np.asarray(grads[1])[:, :first].any()
+        assert not np.asarray(grads[2])[:, :first].any()
+
+
+def test_a_band_visits_only_the_key_blocks_it_reaches():
+    """Which grid steps run, and which key block each fetches, at the dots3
+    cell's chunk (4 blocks of 512 queries over 5 of 512 keys, window 513):
+    a block of queries visits two key blocks, a step that does not run
+    fetches the block its neighbour holds, and in a prompt's first chunk
+    the key block before position 0 is never fetched."""
+    from ray_tpu.ops import flash
+
+    at = dict(block_q=512, block_k=512, q_offset=512, window=513)
+    for first, fetched in ((0, [[0, 1, 1, 1, 1], [1, 1, 2, 2, 2],
+                                [2, 2, 2, 3, 3], [3, 3, 3, 3, 4]]),
+                           (512, [[1, 1, 1, 1, 1], [1, 1, 2, 2, 2],
+                                  [2, 2, 2, 3, 3], [3, 3, 3, 3, 4]])):
+        for i in range(4):
+            span = flash._band_blocks(jnp.int32(i), first, **at)
+            assert [int(jnp.clip(j, *span)) for j in range(5)] == fetched[i]
+            runs = [j for j in range(5)
+                    if flash._reaches(i, j, first, **at)]
+            # the steps that run are those that fetch a block of their own
+            assert runs == sorted(set(fetched[i])) == [
+                j for j in range(5) if fetched[i][j] == j]
+
+
+def test_without_a_window_the_program_is_the_one_without_the_argument(
+        monkeypatch):
+    """``window=None`` traces, forward and gradient, the jaxpr that the
+    call without the argument traces: the cells that pass no window
+    compile what they compiled before there was one."""
+    from ray_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash, "generation", lambda: "v5e")
+    q = jax.ShapeDtypeStruct((2, 1024, 4, 192), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, 2048, 2, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((2, 2048, 2, 128), jnp.bfloat16)
+
+    def traced(**band):
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, causal=True, **band)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+        return str(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2)))(
+            q, k, v))
+
+    bare = traced()
+    assert traced(window=None, first_key=None) == bare
+    assert all(name in bare for name in ("rt_flash_fwd", "rt_flash_dkv",
+                                         "rt_flash_dq"))
+    assert traced(window=513) != bare
+    with pytest.raises(ValueError, match="a window is a causal band"):
+        flash_attention(q, k, v, causal=False, window=4)
+    with pytest.raises(ValueError, match="a window is a causal band"):
+        flash_attention(q, k, v, first_key=3)
 
 
 def test_flash_attention_raises_off_tpu():
@@ -177,7 +248,7 @@ def test_sharded_flash_splits_gqa_heads_like_the_reference(monkeypatch,
     monkeypatch.setattr(flash, "generation", lambda: "v5e")
     monkeypatch.setattr(
         flash, "_flash_bshd",
-        lambda q, k, v, *, causal, scale, block_q, block_k:
+        lambda q, k, v, *, causal, scale, block_q, block_k, window:
         attention_reference(q, k, v, causal=causal, scale=scale))
     mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("dp", "tp"))
     rng = np.random.default_rng(0)
@@ -299,6 +370,59 @@ def test_sparse_attend_compiles_for_v5e_at_the_cells_block():
     # no score and no row among the program's temporaries: the packed
     # cache (2 x 50.5 MB) and what packing it holds
     assert compiled.memory_analysis().temp_size_in_bytes < 320 << 20
+
+
+def test_a_windows_flash_compiles_for_v5e_at_the_cells_chunk(monkeypatch):
+    """Mosaic accepts the forward kernel with a window at the dots3 cell's
+    prefill chunk (2 rows x 64 heads of 2,048 queries over 2,560 keys in
+    position order, q and k 256 wide, v 128, window 513, the first key a
+    traced scalar), in blocks of 512 x 512, and no score leaves it; under a
+    gradient the rule is whole: three kernels, each with the band."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops import flash
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu in this install
+        pytest.skip(f"no TPU compiler here: {e!r}")
+    chip = SingleDeviceSharding(topo.devices[0])
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash, "generation", lambda: "v5e")
+    assert flash._default_blocks(2048, 2560, 256, True, 513) == (512, 512)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    def attend(q, k, v, first):
+        return flash_attention(q, k, v, causal=True, scale=256 ** -0.5,
+                               window=513, first_key=first)
+
+    b, s, t, h = 2, 2048, 2560, 64
+    operands = (shape((b, s, h, 256)), shape((b, t, h, 256)),
+                shape((b, t, h, 128)), shape((), jnp.int32))
+    compiled = jax.jit(attend).lower(*operands).compile()
+    hlo = compiled.as_text()
+    call, = [ln for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert "rt_flash_fwd" in call and "bf16[128,2048,128]" in call
+    assert not re.search(r"f32\[[\d,]*,(2560|2568)\]", hlo)     # no scores
+    # q, k, v laid out for the kernel, out and the log-sum-exp: no more
+    assert compiled.memory_analysis().temp_size_in_bytes < 700 << 20
+
+    def loss(q, k, v, first):
+        return jnp.sum(attend(q, k, v, first).astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *operands).compile().as_text()
+    calls = [ln for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sorted(re.search(r"rt_flash_[a-z]+", ln).group() for ln in calls) \
+        == ["rt_flash_dkv", "rt_flash_dq", "rt_flash_fwd"]
 
 
 def test_a_hybrid_generate_keeps_one_state_on_v5e(monkeypatch):

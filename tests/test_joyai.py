@@ -610,8 +610,8 @@ def test_who_keeps_is_read_from_the_kernels_shapes(
     found = equations(jax.make_jaxpr(rule)(like(d), like(d), like(d_v)).jaxpr)
     assert found["name"] == found[flash.KEPT] == (2 if keeps else 0)
     assert found["rt_flash_fwd"] == 1
-    out, (_, _, _, kept_out, lse) = jax.eval_shape(rule, like(d), like(d),
-                                                   like(d_v))
+    out, (_, _, _, kept_out, lse, _) = jax.eval_shape(rule, like(d), like(d),
+                                                      like(d_v))
     assert out.shape == kept_out.shape == (4, s, d_v)
     assert (lse.shape, lse.dtype) == ((4, s) if keeps else (4, s, 128),
                                       jnp.float32)
