@@ -88,6 +88,10 @@ RECURRENT_KINDS = ("full", "linear")
 # the cache's arrays over positions, [slots, B, T, ...]: what a decode
 # step's ``extent`` cuts
 BY_POSITION = ("k", "v", "latent", "index")
+# An expert layer's counters that a call sums, in the order of ``moe_rows``
+# (models/moe.py: pairs routed to held experts, those the buffer dropped,
+# the buffer's rows passed over outside the grouped matmuls).
+MOE_ROWS = ("rows_here", "rows_dropped", "rows_walked")
 
 
 def _refuse_unserved(cfg: TransformerConfig, mesh=None) -> None:
@@ -324,9 +328,9 @@ def _over_the_layers(cfg: TransformerConfig, params, x, positions, cache,
     slot)`` gives a layer its attention step (``transformer._layer_apply``'s
     ``attend`` of that kind), which hands back the cache with the slot
     written. -> (x, cache, exit distribution or None where there is no
-    loop, [rows routed to held experts, rows dropped] summed over the
-    layers, taps: the indexed layers' selections and the window layers' key
-    counts, ``lead`` stacked over the leading layers and ``periods`` a list
+    loop, the expert layers' ``MOE_ROWS`` summed over the layers, taps: the
+    indexed layers' selections and the window layers' key counts, ``lead``
+    stacked over the leading layers and ``periods`` a list
     over the period's positions, stacked over the periods).
 
     Only latent kinds have taps and only a stack of like ``"full"`` layers
@@ -344,8 +348,7 @@ def _over_the_layers(cfg: TransformerConfig, params, x, positions, cache,
                                        attend_at(kind, cache, slot))
         stats = stats or {}
         if "rows_here" in stats:
-            counts = counts + jnp.stack([stats["rows_here"],
-                                         stats["rows_dropped"]])
+            counts = counts + jnp.stack([stats[name] for name in MOE_ROWS])
         return (x, cache, counts), {k: v for k, v in stats.items()
                                     if k.startswith(("selected", "window"))}
 
@@ -372,7 +375,7 @@ def _over_the_layers(cfg: TransformerConfig, params, x, positions, cache,
 
     x, (cache, counts, taps), exits = _over_loop_steps(
         cfg, params, stack, x,
-        (cache, jnp.zeros((2,), jnp.int32),
+        (cache, jnp.zeros((len(MOE_ROWS),), jnp.int32),
          {"lead": None, "periods": [{} for _ in kinds]}))
     return x, cache, exits, counts, taps
 
@@ -520,10 +523,10 @@ def prefill_and_taps(params, tokens, cfg: TransformerConfig, max_len: int,
     """The prompt [B, S] (S <= max_len) through the trunk in chunks of
     ``chunk`` queries (S is a multiple; where None, `prefill_chunk` of S)
     -> (last-position logits [B, vocab], filled cache, taps of the last
-    chunk as `_over_the_layers` gives them, with ``moe_rows``: [rows routed
-    to held experts, rows dropped] over the whole prompt, and ``exits``:
-    the last position's exit distribution [B, loop_steps], None where
-    there is no loop)."""
+    chunk as `_over_the_layers` gives them, with ``moe_rows``: the expert
+    layers' ``MOE_ROWS`` over the whole prompt, and ``exits``: the last
+    position's exit distribution [B, loop_steps], None where there is no
+    loop)."""
     if cfg.pp_stages > 1:
         raise NotImplementedError("decode with pp_stages>1 is not supported")
     _refuse_unserved(cfg, mesh)
@@ -552,7 +555,8 @@ def prefill_and_taps(params, tokens, cfg: TransformerConfig, max_len: int,
         return (cache, counts + n), (x[:, -1], _last_exits(exits), taps)
 
     (cache, counts), last = lax.scan(
-        step, (init_cache(cfg, b, max_len), jnp.zeros((2,), jnp.int32)),
+        step, (init_cache(cfg, b, max_len),
+               jnp.zeros((len(MOE_ROWS),), jnp.int32)),
         jnp.arange(s // chunk))
     x, exits, taps = jax.tree.map(lambda a: a[-1], last)
     return (_head(params, x[:, None], cfg)[:, 0], cache,
@@ -644,8 +648,9 @@ def generate_and_cache(params, prompt, cfg: TransformerConfig, *,
     ``exit_steps_sum``, the sum over the generated tokens of the exit
     gate's expected loop step ``sum_t (t + 1) p_t``, and ``exit_tokens``,
     their count; and of a stack with expert layers, which must drop
-    nothing, ``moe_rows_here`` and ``moe_rows_dropped``, summed over the
-    call: a few numbers, accumulated in the decode loop's carry.
+    nothing, ``moe_rows_here``, ``moe_rows_dropped`` and
+    ``moe_rows_walked`` (``MOE_ROWS``), summed over the call: a few
+    numbers, accumulated in the decode loop's carry.
 
     The whole decode loop runs inside the caller's jit scope (wrap with
     jax.jit(partial(generate, ...)) or call under jit), one lax.scan for
@@ -685,7 +690,8 @@ def generate_and_cache(params, prompt, cfg: TransformerConfig, *,
             exit_steps_sum=steps_sum,
             exit_tokens=jnp.asarray(b * max_new_tokens, jnp.float32))
     if cfg.num_experts:
-        stats.update(moe_rows_here=rows[0], moe_rows_dropped=rows[1])
+        stats.update({"moe_" + name: rows[i]
+                      for i, name in enumerate(MOE_ROWS)})
     return jnp.transpose(jnp.concatenate(tokens), (1, 0)), stats, cache
 
 

@@ -922,7 +922,9 @@ def transformer_loss_and_stats(params, batch, cfg: TransformerConfig, *,
     cross-entropy, mean over non-final positions, and with a
     multi-token-prediction module ``loss_main + mtp_loss_weight x
     loss_mtp``, both among the counters. The expert layers' (the module's
-    among them), as scalars: ``moe_rows_here`` and ``moe_rows_dropped``
+    among them), as scalars: ``moe_rows_here``, ``moe_rows_dropped`` and
+    ``moe_rows_walked`` (the buffers' rows that the layers passed over
+    outside their grouped matmuls: the blocks that held a routed row)
     summed over the layers, ``moe_load_max`` and ``moe_load_mean`` the
     fullest held expert's rows and the mean, over layers and experts; of a
     router with a correction bias also ``moe_count_max_over_mean`` (the
@@ -950,6 +952,7 @@ def transformer_loss_and_stats(params, batch, cfg: TransformerConfig, *,
     out.update(
         moe_rows_here=sum(s["rows_here"].sum() for s in stacks),
         moe_rows_dropped=sum(s["rows_dropped"].sum() for s in stacks),
+        moe_rows_walked=sum(s["rows_walked"].sum() for s in stacks),
         moe_load_max=load.max(), moe_load_mean=load.mean())
     if "counts" in stacks[0]:
         counts = jnp.concatenate([s["counts"] for s in stacks])

@@ -182,8 +182,8 @@ EVENT_KINDS: Dict[str, str] = {
     "train.step": "span: value = seconds from a step's dispatch to its "
                   "metrics on the host, opened by the user's loop; attrs "
                   "carry the step's counters (the expert layers' "
-                  "moe_rows_here, moe_rows_dropped, moe_load_max, "
-                  "moe_load_mean)",
+                  "moe_rows_here, moe_rows_dropped, moe_rows_walked, "
+                  "moe_load_max, moe_load_mean)",
     "generate.call": "span: value = seconds of one compiled generate call, "
                      "dispatch to tokens on the host, opened by its caller "
                      "(models.generate.call_span); attrs carry rows/prompt/"
@@ -202,8 +202,9 @@ EVENT_KINDS: Dict[str, str] = {
                      "window_kernel_queries (of its window layers' prompt "
                      "queries, those attended in rt_flash_fwd with a "
                      "window), moe_rows_here, "
-                     "moe_rows_dropped, and of a stack with gated-delta-rule "
-                     "layers the cache's bytes by what holds them "
+                     "moe_rows_dropped, moe_rows_walked, and of a stack with "
+                     "gated-delta-rule layers the cache's bytes by what "
+                     "holds them "
                      "(cache_bytes_state: the float32 recurrent states, "
                      "cache_bytes_tail: the convolutions' last inputs, "
                      "cache_bytes_kv: the softmax layers' keys and values) "

@@ -175,6 +175,8 @@ def test_generate_serves_the_stack_and_counts_its_expert_rows():
     # the loop's last step runs the last served token through the stack
     # too: every position of prompt + served routed its pairs once
     assert int(stats["moe_rows_here"]) == want["moe_rows_here"]
+    # every block walked is a decode step's whole buffer or holds a row
+    assert int(stats["moe_rows_here"]) <= int(stats["moe_rows_walked"])
 
 
 def test_the_window_cache_is_a_ring_of_the_window_rounded_up():

@@ -497,3 +497,80 @@ def test_a_hybrid_generate_keeps_one_state_on_v5e(monkeypatch):
                                 jax.lax.dynamic_index_in_dim(
                                     states, slot, 0, keepdims=False), *a)))
     assert re.search(copied, compiled().as_text())
+
+
+# the three cells' expert layers and a decode step's of the dots3 cell:
+# (tokens, D, F, experts, held, top_k, scoring, under a gradient)
+EXPERT_LAYERS = {
+    "dots3_prefill_chunk": (4096, 5120, 1536, 256, 32, 8, "sigmoid", False),
+    "dots3_decode_step": (2, 5120, 1536, 256, 32, 8, "sigmoid", False),
+    "qwen3next_step": (16384, 2048, 512, 512, 32, 10, "softmax", True),
+}
+
+
+@pytest.mark.parametrize("which", sorted(EXPERT_LAYERS))
+def test_expert_layer_walk_compiles_for_v5e(which):
+    """The expert layer at the cells' own sizes, compiled for a v5e: no
+    gather, select, multiply, cast, add or scatter-add over the whole
+    [laid, D] buffer outside a loop's body (tests/test_moe_walk.py holds its
+    numbers, and this check, at a toy size on the CPU, where a scatter is a
+    loop of the compiler's own), a decode step's buffer passed over with no
+    loop, and every grouped matmul's kernel under ``rt.moe.experts`` by the
+    benchmark's own reading of the text: the loops take what the kernels
+    wrote as operands, which carry no scope."""
+    import os
+    import sys
+    from types import SimpleNamespace
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import moe
+    from test_moe_walk import passes_over
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if checkout not in sys.path:
+        sys.path.insert(0, checkout)
+    from benchmark import trace_scopes
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu in this install
+        pytest.skip(f"no TPU compiler here: {e!r}")
+    one = SingleDeviceSharding(topo.devices[0])
+    n, d, f, experts, held, top_k, scoring, grad = EXPERT_LAYERS[which]
+    cfg = SimpleNamespace(
+        expert_top_k=top_k, held=held, num_experts=experts, first_expert=0,
+        router_scoring=scoring, norm_topk_prob=True,
+        routed_scaling_factor=1.0)
+    pd = jnp.float32 if grad else jnp.bfloat16
+    like = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one)
+    p = {"router": like((d, experts), pd), "w1": like((held, d, f), pd),
+         "w3": like((held, d, f), pd), "w2": like((held, f, d), pd)}
+    if scoring == "sigmoid":
+        p["router_bias"] = like((experts,), pd)
+    h = like((1, n, d), jnp.bfloat16)
+
+    def loss(p, h):
+        y, stats = jax.checkpoint(lambda p, h: moe.moe_apply(cfg, p, h))(p, h)
+        return (y.astype(jnp.float32) ** 2).mean(), stats
+    fn = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True) if grad \
+        else lambda p, h: moe.moe_apply(cfg, p, h)
+    text = jax.jit(fn).lower(p, h).compile().as_text()
+
+    rows = moe.buffer_rows(cfg, n)
+    laid = -(-rows // moe.ROW_MULTIPLE) * moe.ROW_MULTIPLE
+    loops = [ln for ln in text.splitlines()
+             if " while(" in ln and "rt.moe.experts" in ln]
+    if n <= moe.FEW_TOKENS:
+        assert moe.walk_block(laid, d) == laid and not loops
+    else:
+        assert laid // moe.walk_block(laid, d) >= 16
+        assert len(loops) == (5 if grad else 2)     # forward twice: remat
+        assert passes_over(text, (laid, d)) == []
+    scopes = trace_scopes.scope_map(text)
+    kernels = [trace_scopes.INSTRUCTION.match(ln).group(1)
+               for ln in text.splitlines() if trace_scopes.INHERITS[0] in ln]
+    assert len(kernels) >= 3
+    assert {scopes.get(k) for k in kernels} == {"rt.moe.experts"}

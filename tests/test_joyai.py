@@ -271,8 +271,8 @@ def test_a_softmax_routed_step_is_the_step_it_was():
         np.testing.assert_allclose(np.asarray(got_leaf),
                                    np.asarray(want_leaf), atol=1e-6)
     assert set(metrics) == {"loss", "grad_norm", "step", "moe_rows_here",
-                            "moe_rows_dropped", "moe_load_max",
-                            "moe_load_mean"}
+                            "moe_rows_dropped", "moe_rows_walked",
+                            "moe_load_max", "moe_load_mean"}
     assert set(want_stats) == set(metrics) - {"loss", "grad_norm", "step"}
     # a sigmoid router of the same sizes counts and reports
     sigmoid = dataclasses.replace(cfg, router_scoring="sigmoid")

@@ -705,6 +705,7 @@ def test_make_lm_train_step_takes_the_hybrid_configuration():
         losses.append(float(metrics["loss"]))
     assert np.isfinite(losses).all() and losses[-1] < losses[0]
     assert int(metrics["moe_rows_here"]) == 4 * 4 * SEQ * 2   # all held
+    assert int(metrics["moe_rows_walked"]) == 4 * 4 * SEQ * 2  # so all walked
     assert int(metrics["moe_rows_dropped"]) == 0
     assert float(metrics["moe_load_max"]) >= float(metrics["moe_load_mean"])
     # the llama configuration's metrics carry no expert counters
