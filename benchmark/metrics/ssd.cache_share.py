@@ -1,0 +1,8 @@
+from _dots3 import reader_of
+
+_accepted = reader_of("state.cache_share")
+NEEDS = _accepted.NEEDS
+
+
+def read(record, cell):
+    return _accepted.read(record, cell)

@@ -2,8 +2,10 @@
 
 Flagship: decoder-only Transformer LM, one block whose layers are, by
 configuration: softmax attention (RMSNorm / RoPE, whole or partial / GQA;
-optionally per-head q/k norm, an output gate, any head width) or the gated
-delta rule (linear attention with a short causal convolution), over a SwiGLU
+optionally per-head q/k norm, an output gate, any head width), the gated
+delta rule (linear attention with a short causal convolution) or a
+state-space recurrence (Mamba-2's SSD), alone or beside softmax attention
+on one normed input, over a SwiGLU
 MLP or a top-k expert layer with no capacity per expert that holds a share
 of the experts, and a shared expert; the stack run once, or ``loop_steps``
 times over the one set of weights with sandwich norms and an exit gate; or
@@ -16,7 +18,9 @@ sublayers' inputs, on inputs and outputs, or on the outputs only.
 and values), the latent stacks (latents, indexer keys, window rings; the
 prompt in chunks) and patterns of gated-delta-rule and softmax layers (a
 float32 recurrent state and the convolution's last inputs beside the keys
-and values).
+and values); a layer may hold two mixers side by side, softmax attention
+and a linear one (the rule, or Mamba-2's state-space recurrence,
+ops/ssd.py), and then has a slot of both caches.
 Pure-functional params pytree with logical-axis
 annotations so one definition runs under any MeshSpec (dp/fsdp/tp/pp/sp/ep).
 Plus ResNet-50 (the north-star image benchmark, BASELINE.json) and an MLP.

@@ -8,12 +8,16 @@ One cache, one trunk, two makers of a layer's attention step.
   knows them: a softmax layer (kind ``"full"``) has a slot of ``k`` and of
   ``v`` [slots, B, T_max, KVH, D], a latent layer one of ``latent`` (and of
   the indexer's ``index``) over every position, a window layer one of
-  ``window``, a ring of `window_rows` positions, a gated-delta-rule layer
-  (kind ``"linear"``) one of ``state``, the recurrent state after the last
-  position in float32 whatever the compute dtype, packed so that its minor
-  dimension fills the TPU's lanes (ops/gated_delta.py ``pack_state``), and
-  one of ``tail``, the convolution's last K - 1 inputs: neither grows with
-  the positions, and a step rewrites the whole of both. A looped stack
+  ``window``, a ring of `window_rows` positions, a layer with a linear mixer
+  (kind ``"linear"``: the gated delta rule or, by ``cfg.linear_transition``,
+  a state-space recurrence) one of ``state``, the recurrent state after the
+  last position in float32 whatever the compute dtype (the rule's packed so
+  that its minor dimension fills the TPU's lanes, ops/gated_delta.py
+  ``pack_state``), and one of ``tail``, the convolution's last K - 1
+  inputs: neither grows with the positions, and a step rewrites the whole
+  of both. A layer of two mixers (kind ``"parallel"``: softmax attention and
+  the linear mixer side by side) has a slot of each of its mixers' arrays,
+  ``k``, ``v``, ``state`` and ``tail`` (`CACHES_OF`). A looped stack
   (``cfg.loop_steps`` passes over the one set of weights) has a slot for
   every (pass t, layer l), slot ``t * L + l``: the passes share weights, not
   activations, so a pass-t query sees pass-t keys only.
@@ -21,15 +25,17 @@ One cache, one trunk, two makers of a layer's attention step.
   dense layers and then the periods of ``cfg.kinds``, under
   ``transformer._over_loop_steps`` for the passes, each layer the training
   forward's own (``transformer._layer_apply``) and handed only its attention
-  step ``attend_at(kind, cache, slot)``. The cache is the CARRY of every
+  step ``attend_at(kind, cache, slots)``. The cache is the CARRY of every
   level (passes, periods, and the token loop around them), never a scanned
   input or output: a layer writes its slot and reads it back from the
   updated carry, so the token loop updates one buffer in place. Write first,
   read second: a read of the pre-update stack after the write would make
   XLA keep two buffers and copy the cache a token.
 - `_write_chunk_at` makes the attention step of a prefill chunk,
-  `_write_and_read_at` that of a decode step; each has the four kinds. A
-  linear layer's is the rule itself (``transformer._gated_delta_mix``'s
+  `_write_and_read_at` that of a decode step; each has the four kinds, and
+  a parallel layer's is the pair of its softmax and its linear part, the
+  second made from the cache the first wrote. A linear layer's is the rule
+  itself (``transformer._gated_delta_mix``'s
   ``rule``): the chunked rule from a zero state, whose final state and last
   inputs prefill writes, and one position of the recurrence on the carried
   state, read from its slot and written back to it, no second copy.
@@ -42,8 +48,9 @@ One cache, one trunk, two makers of a layer's attention step.
   stack that has either goes through in one chunk.
 
 Served: one stack of like softmax layers (looped or not), a pattern of
-latent and window layers, a pattern of softmax and linear layers. Not
-served: linear layers beside latent or window ones (`_refuse_unserved`).
+latent and window layers, a pattern of softmax, linear and parallel layers.
+Not served: a linear mixer beside latent or window layers, or under a mesh
+of several devices (`_refuse_unserved`).
 - `generate` runs the whole decode loop INSIDE jit via lax.scan: static
   shapes (cache padded to max length, attention masked by position), PRNG
   threaded through the scan, no host round trip a token. The token loop
@@ -76,15 +83,21 @@ from ray_tpu.models.latent import (NEVER, ring_positions, sparse_in_kernel,
                                    window_in_kernel)
 from ray_tpu.models.transformer import (TransformerConfig, _attention,
                                         _head, _layer_apply,
-                                        _over_loop_steps, _rule_operands)
-from ray_tpu.ops import gated_delta
+                                        _over_loop_steps, _rule_operands,
+                                        _state_space_operands)
+from ray_tpu.ops import gated_delta, ssd
 from ray_tpu.ops.attention import auto_path
 from ray_tpu.util import events
 
 LATENT_KINDS = ("latent", "window")
 
 
-RECURRENT_KINDS = ("full", "linear")
+RECURRENT_KINDS = ("full", "linear", "parallel")
+# The cache kinds a layer kind has a slot of: a kind's own but for a layer
+# of two mixers, which has one of each mixer's.
+CACHES_OF = {"full": ("full",), "linear": ("linear",),
+             "parallel": ("full", "linear"), "latent": ("latent",),
+             "window": ("window",)}
 # the cache's arrays over positions, [slots, B, T, ...]: what a decode
 # step's ``extent`` cuts
 BY_POSITION = ("k", "v", "latent", "index")
@@ -96,23 +109,26 @@ MOE_ROWS = ("rows_here", "rows_dropped", "rows_walked")
 
 def _refuse_unserved(cfg: TransformerConfig, mesh=None) -> None:
     kinds = set(cfg.layer_types)
-    if "linear" in kinds and mesh is not None and mesh.size > 1:
+    linear = kinds & {"linear", "parallel"}
+    if linear and mesh is not None and mesh.size > 1:
         raise NotImplementedError(
-            "generate serves gated-delta-rule layers on one device: the "
-            "rule's kernels cannot be partitioned, and the state's layout "
-            "over a mesh is not built (ROADMAP.md R8)")
+            "generate serves a linear mixer (the gated delta rule, a "
+            "state-space recurrence) on one device: the step's kernels "
+            "cannot be partitioned, and the state's layout over a mesh is "
+            "not built (ROADMAP.md R8)")
     if kinds <= set(LATENT_KINDS) or kinds <= set(RECURRENT_KINDS):
         return
-    if "linear" in kinds:
+    if linear:
         raise NotImplementedError(
-            "generate serves gated-delta-rule layers beside softmax layers "
-            f"only: this pattern {cfg.layer_types} has them beside latent "
-            "or window layers, whose prompt goes through in chunks of "
-            "queries, and the rule's prefill starts from a zero state "
+            "generate serves linear and parallel layers beside softmax "
+            f"layers only: this pattern {cfg.layer_types} has them beside "
+            "latent or window layers, whose prompt goes through in chunks "
+            "of queries, and a linear mixer's prefill starts from a zero "
+            "state: a chunked prefill over a carried state is not built "
             "(ROADMAP.md R8)")
     raise NotImplementedError(
         "generate serves one stack of like softmax-attention layers, a "
-        "pattern of softmax and gated-delta-rule layers, or a pattern of "
+        "pattern of softmax, linear and parallel layers, or a pattern of "
         f"latent and window layers; this pattern {cfg.layer_types} is not "
         "served")
 
@@ -128,17 +144,25 @@ def window_rows(cfg: TransformerConfig) -> int:
     return -(-cfg.window // _WRITE_ROWS) * _WRITE_ROWS
 
 
-def _lead_slots(cfg: TransformerConfig, kind: str) -> int:
-    """The kind's slots that the leading dense layers (of the period's
-    first kind) hold: its first."""
-    return cfg.first_dense_layers if cfg.kinds[0] == kind else 0
+def _layers_with(kinds, cache: str) -> int:
+    """How many layers of ``kinds`` have a slot of the cache kind."""
+    return sum(cache in CACHES_OF[kind] for kind in kinds)
+
+
+def _lead_slots(cfg: TransformerConfig, cache: str) -> int:
+    """The cache kind's slots that the leading dense layers (of the
+    period's first kind) hold: its first."""
+    return cfg.first_dense_layers * _layers_with(cfg.kinds[:1], cache)
 
 
 def kind_slots(cfg: TransformerConfig) -> Dict[str, int]:
-    """Cache slots by layer kind: a layer of a kind has one a loop step."""
-    return {kind: cfg.loop_steps * (
-        _lead_slots(cfg, kind) + cfg.periods * cfg.kinds.count(kind))
-        for kind in RECURRENT_KINDS + LATENT_KINDS}
+    """Cache slots by cache kind: a layer has one a loop step of each of
+    its `CACHES_OF` (a parallel layer one of ``"full"`` and one of
+    ``"linear"``)."""
+    return {cache: cfg.loop_steps * (
+        _lead_slots(cfg, cache)
+        + cfg.periods * _layers_with(cfg.kinds, cache))
+        for cache in ("full", "linear") + LATENT_KINDS}
 
 
 def _slots_of_pass(cfg: TransformerConfig, t):
@@ -148,12 +172,14 @@ def _slots_of_pass(cfg: TransformerConfig, t):
     return t * cfg.periods + jnp.arange(cfg.periods)
 
 
-def _slot(cfg: TransformerConfig, kind: str, period, j: int):
-    """The slot, among its kind's, of the layer at position ``j`` of
-    period ``period`` (as `_slots_of_pass` numbers them; the leading dense
-    layers hold their kind's first)."""
-    return _lead_slots(cfg, kind) + period * cfg.kinds.count(kind) \
-        + cfg.kinds[:j].count(kind)
+def _slots(cfg: TransformerConfig, period, j: int) -> Dict[str, Any]:
+    """{cache kind: the slot, among that kind's} of the layer at position
+    ``j`` of period ``period`` (as `_slots_of_pass` numbers them; the
+    leading dense layers hold their kinds' first)."""
+    return {cache: _lead_slots(cfg, cache)
+            + period * _layers_with(cfg.kinds, cache)
+            + _layers_with(cfg.kinds[:j], cache)
+            for cache in CACHES_OF[cfg.kinds[j]]}
 
 
 def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int
@@ -162,14 +188,20 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int
     a softmax layer holds the rotated keys and the values of every position
     (``k``, ``v``), a latent layer the latent and shared key of every
     position (``latent``) and the indexer's key (``index``), a window layer
-    a ring of `window_rows` positions (``window``); a linear layer holds no
-    positions: its packed state [slots, B, Hv / r, dk, r dv] (``state``:
-    r = ``gated_delta.state_pack`` heads beside each other on the minor
-    dimension, 2 x 192 = 384 = 3 x 128 lanes, so nothing is padded) and the
-    convolution's last K - 1 inputs, oldest first, [slots, K - 1, B, 2 kd
-    + vd] (``tail``: positions before rows, the only array here whose
-    second dimension is not the batch, so that a tile holds rows x channels
-    and the 3 positions pad nothing)."""
+    a ring of `window_rows` positions (``window``); a linear mixer holds no
+    positions: the rule's packed state [slots, B, Hv / r, dk, r dv]
+    (``state``: r = ``gated_delta.state_pack`` heads beside each other on
+    the minor dimension, 2 x 192 = 384 = 3 x 128 lanes, so nothing is
+    padded) and the convolution's last K - 1 inputs, oldest first, [slots,
+    K - 1, B, 2 kd + vd] (``tail``: positions before rows, the only array
+    here whose second dimension is not the batch, so that a tile holds rows
+    x channels and the 3 positions pad nothing). A state-space mixer's
+    (``linear_transition`` "ssd") are the same two arrays: ``state`` [slots,
+    B, H, N, P], a head's [state width, head width] as ``ops/ssd.py`` hands
+    it back (the transpose of the papers' [P, N]: P = 128 is the lanes,
+    nothing to pack), and ``tail`` over the H P + 2 G N channels of [x | B |
+    C]. A parallel layer has all four of ``k``, ``v``, ``state``,
+    ``tail``."""
     slots, shapes = kind_slots(cfg), {}
     if slots["full"]:
         shapes["k"] = shapes["v"] = (slots["full"], batch, max_len,
@@ -185,7 +217,8 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int
                             cfg.window_latent.cached)
     if slots["linear"]:
         hv, dv = cfg.linear_value_heads, cfg.linear_value_dim
-        r = gated_delta.state_pack(hv, dv)
+        r = 1 if cfg.linear_transition == "ssd" \
+            else gated_delta.state_pack(hv, dv)
         shapes["state"] = (slots["linear"], batch, hv // r,
                            cfg.linear_key_dim, r * dv)
         shapes["tail"] = (
@@ -325,7 +358,7 @@ def _over_the_layers(cfg: TransformerConfig, params, x, positions, cache,
     """The trunk over the CARRIED cache: every loop step's pass over the
     leading dense layers and then the periods of ``cfg.kinds`` (a plain
     stack's period is its one ``"full"`` layer). ``attend_at(kind, cache,
-    slot)`` gives a layer its attention step (``transformer._layer_apply``'s
+    slots)`` gives a layer its attention step (``transformer._layer_apply``'s
     ``attend`` of that kind), which hands back the cache with the slot
     written. -> (x, cache, exit distribution or None where there is no
     loop, the expert layers' ``MOE_ROWS`` summed over the layers, taps: the
@@ -343,9 +376,9 @@ def _over_the_layers(cfg: TransformerConfig, params, x, positions, cache,
     kinds = cfg.kinds
     periods = params["layers"] if cfg.layer_types else (params["layers"],)
 
-    def run(kind, slot, layer, x, cache, counts):
+    def run(kind, slots, layer, x, cache, counts):
         x, cache, stats = _layer_apply(cfg, layer, x, positions,
-                                       attend_at(kind, cache, slot))
+                                       attend_at(kind, cache, slots))
         stats = stats or {}
         if "rows_here" in stats:
             counts = counts + jnp.stack([stats[name] for name in MOE_ROWS])
@@ -357,7 +390,9 @@ def _over_the_layers(cfg: TransformerConfig, params, x, positions, cache,
         carry, lead_taps = (x, cache, counts), None
         if cfg.first_dense_layers:
             carry, lead_taps = lax.scan(
-                lambda carry, at: run(kinds[0], at[1], at[0], *carry),
+                lambda carry, at: run(
+                    kinds[0], dict.fromkeys(CACHES_OF[kinds[0]], at[1]),
+                    at[0], *carry),
                 carry,
                 (params["dense_layers"], jnp.arange(cfg.first_dense_layers)))
 
@@ -365,7 +400,7 @@ def _over_the_layers(cfg: TransformerConfig, params, x, positions, cache,
             layers, i = layers_and_index
             taps = []
             for j, (kind, layer) in enumerate(zip(kinds, layers)):
-                carry, tap = run(kind, _slot(cfg, kind, i, j), layer, *carry)
+                carry, tap = run(kind, _slots(cfg, i, j), layer, *carry)
                 taps.append(tap)
             return carry, taps
 
@@ -406,8 +441,16 @@ def _write_chunk_at(cfg: TransformerConfig, start, chunk: int, mesh=None):
     indices: models/latent.py ``_expanded``), then writes the chunk's last
     `window_rows` into the ring; a linear layer runs the chunked rule from
     a zero state (the whole prompt too) and writes the state it ends in and
-    the convolution's last K - 1 inputs."""
-    def attend_at(kind, cache, slot):
+    the convolution's last K - 1 inputs (a state-space mixer the chunked
+    scan of ops/ssd.py, the same way); a parallel layer's is the pair of
+    its softmax part and, from the cache that part wrote, its linear
+    part."""
+    def attend_at(kind, cache, slots):
+        if kind == "parallel":
+            return (attend_at("full", cache, slots),
+                    lambda cache: attend_at("linear", cache, slots))
+        slot = slots[kind]
+
         def full(q, k, v):
             return _attention(cfg, q, k, v, mesh), dict(
                 cache, k=_write_prompt(cache["k"], slot, k),
@@ -440,20 +483,35 @@ def _write_chunk_at(cfg: TransformerConfig, start, chunk: int, mesh=None):
                 cache["window"], ring[None, :, :, None, :],
                 (slot, 0, 0, 0, 0)))
 
+        def written(u, state):
+            """The cache with the chunk's final state and the
+            convolution's last K - 1 inputs in the slot."""
+            kept = cfg.linear_conv_kernel - 1       # zeros before position 0
+            tail = jnp.swapaxes(
+                jnp.pad(u, ((0, 0), (kept, 0), (0, 0)))[:, chunk:], 0, 1)
+            return dict(
+                cache, tail=_write_slot(cache["tail"], slot, tail),
+                state=_write_slot(cache["state"], slot, state))
+
         def linear(u, ba, p):
             qkv = gated_delta.causal_conv(u, p["conv"])
             with jax.named_scope("rt.gdn.scan"):
                 operands = _rule_operands(cfg, p, qkv, ba)
             o, state = gated_delta.gated_delta_rule(*operands,
                                                     final_state=True)
-            kept = cfg.linear_conv_kernel - 1       # zeros before position 0
-            tail = jnp.swapaxes(
-                jnp.pad(u, ((0, 0), (kept, 0), (0, 0)))[:, chunk:], 0, 1)
-            return o, dict(
-                cache, tail=_write_slot(cache["tail"], slot, tail),
-                state=_write_slot(cache["state"], slot,
-                                  gated_delta.pack_state(state)))
+            return o, written(u, gated_delta.pack_state(state))
 
+        def state_space(u, dt, p):
+            xbc = gated_delta.causal_conv(u, p["conv"], p["conv_bias"],
+                                          scope="rt.ssd.conv")
+            with jax.named_scope("rt.ssd.scan"):
+                y, state = ssd.ssd_scan(
+                    *_state_space_operands(cfg, p, xbc, dt),
+                    final_state=True)
+            return y, written(u, state)
+
+        if kind == "linear" and cfg.linear_transition == "ssd":
+            return state_space
         return {"full": full, "latent": latent, "window": window,
                 "linear": linear}[kind]
     return attend_at
@@ -468,9 +526,16 @@ def _write_and_read_at(cfg: TransformerConfig, pos, extent: int):
     column is convolved against the tail, one position of the rule taken on
     the state, and both written back where they were read, each by one
     operation on the stack (``gated_delta.conv_step_at``,
-    ``gated_delta_step_at``: on a TPU kernels whose output is the stack
-    they read)."""
-    def attend_at(kind, cache, slot):
+    ``gated_delta_step_at``, or ``ssd.ssd_step_at`` for a state-space
+    mixer: on a TPU kernels whose output is the stack they read). A
+    parallel layer's is the pair of the two, the second made from the
+    cache the first wrote."""
+    def attend_at(kind, cache, slots):
+        if kind == "parallel":
+            return (attend_at("full", cache, slots),
+                    lambda cache: attend_at("linear", cache, slots))
+        slot = slots[kind]
+
         def full(q, k, v):
             with jax.named_scope("rt.loop.cache"):
                 stack_k = _write_position(cache["k"], slot, pos, k)
@@ -507,6 +572,20 @@ def _write_and_read_at(cfg: TransformerConfig, pos, extent: int):
                                                         *operands)
             return o[:, None], dict(cache, tail=tails, state=states)
 
+        def state_space(u, dt, p):
+            xbc, tails = gated_delta.conv_step_at(
+                cache["tail"], slot, u[:, 0], p["conv"], p["conv_bias"],
+                scope="rt.ssd.conv")
+            with jax.named_scope("rt.ssd.step"):
+                x, b, c, g, dt, skip = _state_space_operands(
+                    cfg, p, xbc[:, None], dt)
+                y, states = ssd.ssd_step_at(
+                    cache["state"], slot, x[:, 0], b[:, 0], c[:, 0],
+                    g[:, 0], dt[:, 0], skip)
+            return y[:, None], dict(cache, tail=tails, state=states)
+
+        if kind == "linear" and cfg.linear_transition == "ssd":
+            return state_space
         return {"full": full, "latent": latent, "window": window,
                 "linear": linear}[kind]
     return attend_at
@@ -724,9 +803,10 @@ def call_span(cfg: TransformerConfig, rows: int, prompt: int,
     once it is fetched with the tokens. ``cache_positions_read`` is the sum
     over the call's decode steps of their segment's extent, what a slot's
     attention reads, ``cache_positions_needed`` that of ``pos + 1``, what
-    it has to. Of a stack with linear layers the cache's bytes by what
-    holds them (``cache_bytes_state``, ``_tail``, ``_kv``) and its slots by
-    kind."""
+    it has to. Of a stack with linear mixers the cache's bytes by what
+    holds them (``cache_bytes_state``, ``_tail``, ``_kv``), its slots by
+    cache kind and ``mixers_a_layer`` (2 where a layer holds softmax
+    attention and a linear mixer side by side)."""
     segments = _decode_segments(prompt, new)
     shapes = cache_shapes(cfg, rows, prompt + new)
     nbytes = {name: math.prod(shape)
@@ -751,7 +831,8 @@ def call_span(cfg: TransformerConfig, rows: int, prompt: int,
         attrs.update(
             cache_bytes_state=nbytes["state"], cache_bytes_tail=nbytes["tail"],
             cache_bytes_kv=nbytes.get("k", 0) + nbytes.get("v", 0),
-            linear_slots=slots["linear"], full_slots=slots["full"])
+            linear_slots=slots["linear"], full_slots=slots["full"],
+            mixers_a_layer=max(len(CACHES_OF[kind]) for kind in cfg.kinds))
     if by_kind:
         # a latent layer's queries, each over the keys up to its own: all
         # of them scored by the indexer, index_topk of them attended to

@@ -4,7 +4,9 @@ Qwen3-Next (a pattern of gated-delta-rule and gated full-attention layers
 over an expert layer without a capacity per expert, with a shared expert),
 the post-normed hybrid block of OLMo (the same rule with beta up to 2 beside
 plain softmax layers, a norm on each sublayer's output only, q and k normed
-over the whole projection, no rotation) or a looped stack (the layers run ``loop_steps`` times over one set of
+over the whole projection, no rotation), the parallel block of Falcon-H1
+(softmax attention and a state-space mixer, Mamba-2's SSD, read one normed
+input side by side and both join the residual) or a looped stack (the layers run ``loop_steps`` times over one set of
 weights, a norm on each sublayer's output, an exit gate after each pass).
 
 Design notes (TPU-first):
@@ -43,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops import flash, gated_delta
+from ray_tpu.ops import flash, gated_delta, ssd
 from ray_tpu.ops.attention import mha
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
@@ -104,7 +106,9 @@ class TransformerConfig:
     # by each expert's load (models/moe.py balance_bias)
     router_bias_update_rate: float = 0.001
     # Hybrid block. ``layer_types``: one period of "full" | "linear" |
-    # "latent" | "window", () = every layer full attention; after
+    # "parallel" (softmax attention and the linear mixer side by side on one
+    # normed input) | "latent" | "window", () = every layer full attention;
+    # after
     # ``first_dense_layers`` leading layers of the period's first kind, with
     # a dense MLP whatever ``num_experts`` says, n_layers is a multiple of
     # its length.
@@ -139,6 +143,11 @@ class TransformerConfig:
     linear_conv_kernel: int = 4
     linear_beta_scale: float = 1.0        # beta = scale x sigmoid(b): 2 lets
     #                                       a state's eigenvalue go negative
+    # The linear mixer's transition: "delta", the gated delta rule, or
+    # "ssd", Mamba-2's state-space recurrence (ops/ssd.py), its dual: the
+    # key heads are the groups that share B and C, ``linear_key_dim`` the
+    # state's width, ``linear_value_dim`` a head's.
+    linear_transition: str = "delta"
     # Looped stack: the n_layers run ``loop_steps`` times over the one set
     # of weights, the final norm and the exit gate (Linear(d_model -> 1),
     # sigmoid) after every pass. A position leaves the loop at the first
@@ -158,9 +167,14 @@ class TransformerConfig:
     mtp_loss_weight: float = 0.3
 
     def __post_init__(self):
-        if set(self.layer_types) - {"full", "linear", "latent", "window"}:
+        if set(self.layer_types) - {"full", "linear", "parallel", "latent",
+                                    "window"}:
             raise ValueError(f"layer_types {self.layer_types}: each is "
-                             "'full', 'linear', 'latent' or 'window'")
+                             "'full', 'linear', 'parallel', 'latent' or "
+                             "'window'")
+        if self.linear_transition not in ("delta", "ssd"):
+            raise ValueError(f"linear_transition {self.linear_transition!r}"
+                             ": 'delta' or 'ssd'")
         if self.layer_types and \
                 (self.n_layers - self.first_dense_layers) \
                 % len(self.layer_types):
@@ -192,10 +206,10 @@ class TransformerConfig:
         if self.attn_gate not in ("", "headwise"):
             raise ValueError(f"attn_gate {self.attn_gate!r}: '' or "
                              "'headwise'")
-        if "linear" in self.layer_types and not (self.linear_key_heads
-                                      and self.linear_value_heads):
-            raise ValueError("linear layers need linear_key_heads and "
-                             "linear_value_heads")
+        if {"linear", "parallel"} & set(self.layer_types) \
+                and not (self.linear_key_heads and self.linear_value_heads):
+            raise ValueError("linear and parallel layers need "
+                             "linear_key_heads and linear_value_heads")
         if self.layer_types and self.pp_stages > 1:
             raise ValueError("a layer pattern with pp_stages > 1 is not "
                              "supported")
@@ -295,6 +309,41 @@ def _layer_norms(cfg: TransformerConfig) -> Tuple[str, ...]:
     return before + after
 
 
+def _linear_mixer_init(key, cfg: TransformerConfig) -> Dict[str, Any]:
+    """The linear mixer of ``cfg.linear_transition`` under its own name:
+    ``{"gdn": ...}``, the gated delta rule's, or ``{"ssm": ...}``, the
+    state-space mixer's."""
+    gk = jax.random.split(key, 5)
+    init = jax.nn.initializers.normal(0.02)
+    d, pd, hv = cfg.d_model, cfg.param_dtype, cfg.linear_value_heads
+    kd = cfg.linear_key_heads * cfg.linear_key_dim
+    vd = hv * cfg.linear_value_dim
+    if cfg.linear_transition == "ssd":
+        return {"ssm": {
+            # flat, as published: [z | x | B | C | dt]
+            "in_proj": init(gk[0], (d, 2 * vd + 2 * kd + hv), pd),
+            "conv": init(gk[2], (vd + 2 * kd, cfg.linear_conv_kernel), pd),
+            "conv_bias": jnp.zeros((vd + 2 * kd,), pd),
+            "dt_bias": jnp.ones((hv,), pd),
+            "A_log": jnp.log(jax.random.uniform(
+                gk[3], (hv,), pd, minval=1.0, maxval=16.0)),
+            "D": jnp.ones((hv,), pd),
+            "norm": jnp.ones((vd,), pd),
+            "out": init(gk[4], (vd, d), pd),
+        }}
+    return {"gdn": {
+        # flat: [all q | all k | all v | all z] and [all b | all a]
+        "in_qkvz": init(gk[0], (d, 2 * kd + 2 * vd), pd),
+        "in_ba": init(gk[1], (d, 2 * hv), pd),
+        "conv": init(gk[2], (2 * kd + vd, cfg.linear_conv_kernel), pd),
+        "dt_bias": jnp.ones((hv,), pd),
+        "A_log": jnp.log(jax.random.uniform(
+            gk[3], (hv,), pd, minval=1e-3, maxval=16.0)),
+        "norm": jnp.ones((cfg.linear_value_dim,), pd),
+        "out": init(gk[4], (vd, d), pd),
+    }}
+
+
 def _layer_init(key, cfg: TransformerConfig, kind: str = "full",
                 dense: bool = False) -> Dict[str, Any]:
     """``dense``: a dense MLP whatever ``cfg.num_experts`` says."""
@@ -305,7 +354,7 @@ def _layer_init(key, cfg: TransformerConfig, kind: str = "full",
     pd = cfg.param_dtype
     norm = jnp.zeros if cfg.norm_plus_one else jnp.ones
     layer = {name: norm((d,), pd) for name in _layer_norms(cfg)}
-    if kind == "full":
+    if kind in ("full", "parallel"):
         gate = 2 if cfg.attn_output_gate else 1   # per head: query, gate
         layer["attn"] = {
             "wq": init(ks[0], (d, h, gate * hd), pd),
@@ -322,22 +371,11 @@ def _layer_init(key, cfg: TransformerConfig, kind: str = "full",
     elif kind in ("latent", "window"):
         from ray_tpu.models.latent import PARAMS_KEY, latent_init
         layer[PARAMS_KEY[kind]] = latent_init(ks[0], cfg, kind)
-    else:
-        gk = jax.random.split(ks[0], 5)
-        hv = cfg.linear_value_heads
-        kd = cfg.linear_key_heads * cfg.linear_key_dim
-        vd = hv * cfg.linear_value_dim
-        layer["gdn"] = {
-            # flat: [all q | all k | all v | all z] and [all b | all a]
-            "in_qkvz": init(gk[0], (d, 2 * kd + 2 * vd), pd),
-            "in_ba": init(gk[1], (d, 2 * hv), pd),
-            "conv": init(gk[2], (2 * kd + vd, cfg.linear_conv_kernel), pd),
-            "dt_bias": jnp.ones((hv,), pd),
-            "A_log": jnp.log(jax.random.uniform(
-                gk[3], (hv,), pd, minval=1e-3, maxval=16.0)),
-            "norm": jnp.ones((cfg.linear_value_dim,), pd),
-            "out": init(gk[4], (vd, d), pd),
-        }
+    if kind in ("linear", "parallel"):
+        # a parallel layer's from a key of its own: ks[0] is its wq's
+        layer.update(_linear_mixer_init(
+            jax.random.fold_in(key, 1) if kind == "parallel" else ks[0],
+            cfg))
     if cfg.num_experts and not dense:
         ek = jax.random.split(ks[4], 8)
         e, ef, sf = cfg.held, cfg.expert_ff_dim, cfg.shared_expert_ff
@@ -420,6 +458,62 @@ def transformer_init(key, cfg: TransformerConfig) -> Dict[str, Any]:
     return params
 
 
+def fold_multipliers(params, cfg: TransformerConfig, *, embedding=1.0,
+                     lm_head=1.0, attention_in=1.0, key=1.0,
+                     attention_out=1.0, ssm_in=1.0, ssm=(1.0,) * 5,
+                     ssm_out=1.0, mlp=(1.0, 1.0)) -> Dict[str, Any]:
+    """A parameter tree as published with muP multipliers (Falcon-H1's:
+    constants on the inputs and outputs of linear maps) -> the tree this
+    program runs, each constant folded into the matrix it scales, so that
+    the forward pass carries none: ``embedding`` into the embedding,
+    ``lm_head`` into the untied head; ``attention_in`` into wq, wk, wv,
+    ``key`` into wk besides, ``attention_out`` into wo; ``ssm_in`` and the
+    five ``ssm`` constants of the packed projection's segments [z | x | B |
+    C | dt] into its columns, ``ssm_out`` into the mixer's output
+    projection; ``mlp[0]`` into the gate's matrix, ``mlp[1]`` into the
+    down projection. Products in float32, rounded once to the parameter's
+    dtype."""
+    if cfg.tied_embeddings and (embedding != 1.0 or lm_head != 1.0):
+        raise ValueError("a tied embedding holds one matrix for two "
+                         "multipliers: untie it")
+    hv, vd = cfg.linear_value_heads, \
+        cfg.linear_value_heads * cfg.linear_value_dim
+    gn = cfg.linear_key_heads * cfg.linear_key_dim
+    columns = ssm_in * jnp.concatenate([
+        jnp.full((w,), m, jnp.float32)
+        for w, m in zip((vd, vd, gn, gn, hv), ssm)])
+
+    def scaled(a, m):
+        return (a.astype(jnp.float32) * m).astype(a.dtype)
+
+    def layer(stack):
+        stack = dict(stack)
+        if "attn" in stack:
+            a = stack["attn"]
+            stack["attn"] = dict(
+                a, wq=scaled(a["wq"], attention_in),
+                wk=scaled(a["wk"], attention_in * key),
+                wv=scaled(a["wv"], attention_in),
+                wo=scaled(a["wo"], attention_out))
+        if "ssm" in stack:
+            m = stack["ssm"]
+            stack["ssm"] = dict(m, in_proj=scaled(m["in_proj"], columns),
+                                out=scaled(m["out"], ssm_out))
+        if "mlp" in stack:
+            f = stack["mlp"]
+            stack["mlp"] = dict(f, w1=scaled(f["w1"], mlp[0]),
+                                w2=scaled(f["w2"], mlp[1]))
+        return stack
+
+    layers = params["layers"]
+    out = dict(params, embed=scaled(params["embed"], embedding),
+               layers=tuple(layer(stack) for stack in layers)
+               if cfg.layer_types else layer(layers))
+    if "lm_head" in params:
+        out["lm_head"] = scaled(params["lm_head"], lm_head)
+    return out
+
+
 def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     """Pytree mirroring params: per-leaf logical dim names (see
     parallel/sharding.py DEFAULT_RULES)."""
@@ -431,7 +525,7 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     def layer_axes(kind: str, dense: bool = False,
                    L=stacked) -> Dict[str, Any]:
         layer = {name: L("embed") for name in _layer_norms(cfg)}
-        if kind == "full":
+        if kind in ("full", "parallel"):
             layer["attn"] = {
                 "wq": L("embed", "heads", "kv"),
                 "wk": L("embed", "heads", "kv"),
@@ -444,14 +538,23 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         elif kind in ("latent", "window"):
             from ray_tpu.models.latent import PARAMS_KEY, latent_axes
             layer[PARAMS_KEY[kind]] = latent_axes(cfg, kind, L)
-        else:
+        if kind in ("linear", "parallel"):
             # the packed projections keep their columns whole: q, k, v and
             # z blocks of unequal width do not split over tp
-            layer["gdn"] = {
-                "in_qkvz": L("embed", None), "in_ba": L("embed", None),
-                "conv": L(None, None), "dt_bias": L(None), "A_log": L(None),
-                "norm": L(None), "out": L(None, "embed"),
-            }
+            if cfg.linear_transition == "ssd":
+                layer["ssm"] = {
+                    "in_proj": L("embed", None), "conv": L(None, None),
+                    "conv_bias": L(None), "dt_bias": L(None),
+                    "A_log": L(None), "D": L(None), "norm": L(None),
+                    "out": L(None, "embed"),
+                }
+            else:
+                layer["gdn"] = {
+                    "in_qkvz": L("embed", None), "in_ba": L("embed", None),
+                    "conv": L(None, None), "dt_bias": L(None),
+                    "A_log": L(None), "norm": L(None),
+                    "out": L(None, "embed"),
+                }
         if cfg.num_experts and not dense:
             layer["moe"] = {
                 "router": L("embed", None),
@@ -648,11 +751,69 @@ def _whole_rule(cfg: TransformerConfig, mesh=None,
     return rule
 
 
+def _state_space_mix(cfg: TransformerConfig, p, h, rule):
+    """Mamba-2's mixer over the block's (normed) input ``h`` [B, S, E]: the
+    packed projection [z | x | B | C | dt], ``rule``, the gate, a norm over
+    each group's share of the gated values, the output projection.
+    ``rule(u, dt, p) -> (y [B, S, H, P], kept)`` is all that differs
+    between training, prefill and decode, as for ``_gated_delta_mix``: the
+    convolution (with its bias) of ``u`` [B, S, H P + 2 G N], the
+    projection's [x | B | C] columns, ``_state_space_operands`` and the
+    recurrence over them (ops/ssd.py), skip included."""
+    dt, f32 = cfg.dtype, jnp.float32
+    b, s, _ = h.shape
+    hv, grp = cfg.linear_value_heads, cfg.linear_key_heads
+    vd = hv * cfg.linear_value_dim
+    conv = p["conv"].shape[0]               # H P + 2 G N
+    with jax.named_scope("rt.ssd.proj"):
+        zxbcdt = h @ p["in_proj"].astype(dt)
+    y, kept = rule(zxbcdt[..., vd:vd + conv],
+                   zxbcdt[..., vd + conv:].astype(f32), p)
+    with jax.named_scope("rt.ssd.proj"):
+        y = y.reshape(b, s, vd) * jax.nn.silu(
+            zxbcdt[..., :vd].astype(f32)).astype(dt)
+        y = _rmsnorm(y.reshape(b, s, grp, vd // grp),
+                     p["norm"].reshape(grp, vd // grp), cfg.norm_eps)
+        return y.reshape(b, s, vd) @ p["out"].astype(dt), kept
+
+
+def _state_space_operands(cfg: TransformerConfig, p, xbc, dt):
+    """The convolved [x | B | C] [B, S, H P + 2 G N] and the float32 step
+    size's pre-activation [B, S, H] -> ``ssd.ssd_scan``'s operands (x [B, S,
+    H, P], B, C [B, S, G, N], g, dt [B, S, H] float32, D [H])."""
+    f32 = jnp.float32
+    b, s, _ = xbc.shape
+    hv, grp = cfg.linear_value_heads, cfg.linear_key_heads
+    n, vd = cfg.linear_key_dim, hv * cfg.linear_value_dim
+    dt = jax.nn.softplus(dt + p["dt_bias"].astype(f32))
+    return (xbc[..., :vd].reshape(b, s, hv, cfg.linear_value_dim),
+            xbc[..., vd:vd + grp * n].reshape(b, s, grp, n),
+            xbc[..., vd + grp * n:].reshape(b, s, grp, n),
+            -jnp.exp(p["A_log"].astype(f32)) * dt, dt, p["D"])
+
+
+def _whole_scan(cfg: TransformerConfig):
+    """``_state_space_mix``'s ``rule`` over the sequence's own positions
+    from a zero state, nothing kept: the training forward's."""
+    def rule(u, dt, p):
+        xbc = gated_delta.causal_conv(u, p["conv"], p["conv_bias"],
+                                      scope="rt.ssd.conv")
+        with jax.named_scope("rt.ssd.scan"):
+            return ssd.ssd_scan(
+                *_state_space_operands(cfg, p, xbc, dt)), None
+    return rule
+
+
 def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend):
     """One block: ``x + mixer(norm(x))``, then ``+ feed_forward(norm(.))``.
     The mixer is the layer's own: softmax attention where it holds
-    ``attn``, the gated delta rule where it holds ``gdn``, latent attention
-    where it holds ``mla`` or ``swa`` (models/latent.py); where the layer
+    ``attn``, the gated delta rule where it holds ``gdn``, the state-space
+    mixer where it holds ``ssm``, latent attention where it holds ``mla`` or
+    ``swa`` (models/latent.py); a layer that holds ``attn`` AND a linear
+    mixer (kind ``"parallel"``) feeds both the one normed input and adds
+    both to the residual, and its ``attend`` is a pair: the softmax part,
+    and ``rule_after(kept) -> rule``, the linear mixer's part once the
+    softmax part has kept what it keeps (the cache it wrote). Where the layer
     holds ``ln1_post`` and ``ln2_post`` each sublayer's output is normed
     before it joins the residual (sandwich norm), and where it holds no
     ``ln1`` and ``ln2`` its input goes in as it is (``post_norm_only``).
@@ -666,16 +827,23 @@ def _layer_apply(cfg: TransformerConfig, layer, x, positions, attend):
     (``_gated_delta_mix``'s ``rule``). -> (x, kept, stats: the expert
     layer's counters and a latent layer's selection, or None)."""
     h = _norm(cfg, x, layer["ln1"]) if "ln1" in layer else x
-    taps = {}
+    taps, o = {}, 0
+    linear = next((name for name in ("gdn", "ssm") if name in layer), None)
     if "attn" in layer:
+        if linear:
+            attend, rule_after = attend
         # the gated variant's device time is found by this scope
         with jax.named_scope("rt.attn.gated") if cfg.attn_output_gate \
                 else contextlib.nullcontext():
             o, kept = _full_attention_mix(cfg, layer["attn"], h, positions,
                                           attend)
-    elif "gdn" in layer:
-        o, kept = _gated_delta_mix(cfg, layer["gdn"], h, attend)
-    else:
+        if linear:
+            attend = rule_after(kept)
+    if linear:
+        mix = _gated_delta_mix if linear == "gdn" else _state_space_mix
+        m, kept = mix(cfg, layer[linear], h, attend)
+        o = o + m
+    elif "attn" not in layer:
         from ray_tpu.models.latent import latent_mix
         o, kept, taps = latent_mix(cfg, layer, h, positions, attend)
     if "ln1_post" in layer:
@@ -700,8 +868,10 @@ def _layer_bodies(cfg: TransformerConfig, mesh, rules: LogicalRules):
     def whole(new):             # a latent layer's keys: the sequence's own
         return new, None, None
 
+    rule = _whole_scan(cfg) if cfg.linear_transition == "ssd" \
+        else _whole_rule(cfg, mesh, rules)
     attends = {"full": softmax, "latent": whole, "window": whole,
-               "linear": _whole_rule(cfg, mesh, rules)}
+               "linear": rule, "parallel": (softmax, lambda kept: rule)}
 
     def body_of(kind: str):
         body = partial(_layer_apply, cfg, attend=attends[kind])

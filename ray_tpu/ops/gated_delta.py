@@ -43,7 +43,13 @@ and ``conv_step`` convolves one new column against the last ``K - 1``
 inputs (``rt.gdn.conv``); ``gated_delta_step_at`` and ``conv_step_at`` take
 them on one slot of the stacks a cache carries, on a TPU as Pallas kernels
 that write into the stack they read (``rt_gdn_step``,
-``rt_gdn_conv_step``). The carried state is PACKED (``pack_state``):
+``rt_gdn_conv_step``). A state-space mixer (ops/ssd.py) carries the same two
+arrays and steps them with the same two kernels: its convolution is this
+one with a bias (``bias=``, under the scope ``rt.ssd.conv``), its state
+``[slots, B, H, N, P]`` (N the state width where the rule has ``dk``, P a
+head's width where it has ``dv``; P = 128 fills the lanes, so nothing is
+packed), and one position of it is this step without the delta correction
+(``step_kernel(delta=False)``, ``rt_ssd_step``). The carried state is PACKED (``pack_state``):
 ``state_pack`` heads lie beside each other on the minor dimension, ``[B, H /
 r, dk, r dv]``, so that it is a multiple of the TPU's 128 lanes (two heads
 of 192 are 384) and the bytes a step moves are the state's own, not a
@@ -86,21 +92,24 @@ BASE = 8            # side of the diagonal blocks inverted directly
 VPU_SIDE = 32       # blocks up to this side multiply on the VPU
 
 
-def causal_conv(x, w):
-    """Depthwise causal convolution, no bias, then SiLU.
-    x: [B, S, C]; w: [C, K] (``w[:, K-1]`` weighs the current position).
-    -> [B, S, C] in x's dtype."""
-    with jax.named_scope("rt.gdn.conv"):
+def causal_conv(x, w, bias=None, scope: str = "rt.gdn.conv"):
+    """Depthwise causal convolution, with ``bias`` [C] where one is given,
+    then SiLU. x: [B, S, C]; w: [C, K] (``w[:, K-1]`` weighs the current
+    position). -> [B, S, C] in x's dtype. ``scope``: the mixer's own name
+    for it in the device trace (a state-space mixer's is ``rt.ssd.conv``)."""
+    with jax.named_scope(scope):
         width = w.shape[1]
         s = x.shape[1]
         padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
         w = w.astype(jnp.float32)
         y = sum(padded[:, j:j + s].astype(jnp.float32) * w[:, j]
                 for j in range(width))
+        if bias is not None:
+            y = y + bias.astype(jnp.float32)
         return jax.nn.silu(y).astype(x.dtype)
 
 
-def conv_step(tail, x, w):
+def conv_step(tail, x, w, bias=None):
     """``causal_conv`` of one new position against the inputs before it.
     tail: [K-1, B, C], the last K - 1 inputs, oldest first; x: [B, C].
     -> (y [B, C] in x's dtype, the tail with x in and its oldest out)."""
@@ -108,10 +117,12 @@ def conv_step(tail, x, w):
     w = w.astype(jnp.float32)
     y = sum(window[j].astype(jnp.float32) * w[:, j]
             for j in range(w.shape[1]))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y).astype(x.dtype), window[1:]
 
 
-def conv_step_at(tails, slot, x, w):
+def conv_step_at(tails, slot, x, w, bias=None, scope: str = "rt.gdn.conv"):
     """``conv_step`` on slot ``slot`` of the carried stack ``tails`` [slots,
     K-1, B, C] (positions before rows: a tile of the TPU's then holds rows
     and channels, and the K - 1 = 3 positions pad nothing) -> (y [B, C],
@@ -119,12 +130,13 @@ def conv_step_at(tails, slot, x, w):
     whose output IS the stack (``rt_gdn_conv_step``: it reads and writes
     the slot's block and nothing else); elsewhere the slot is cut out,
     stepped and written back."""
-    with jax.named_scope("rt.gdn.conv"):
+    with jax.named_scope(scope):
         if _on_tpu():
             from ray_tpu.ops.gated_delta_pallas import conv_step_kernel
-            return conv_step_kernel(tails, slot, x, w)
+            return conv_step_kernel(tails, slot, x, w, bias)
         y, tail = conv_step(
-            lax.dynamic_index_in_dim(tails, slot, 0, keepdims=False), x, w)
+            lax.dynamic_index_in_dim(tails, slot, 0, keepdims=False), x, w,
+            bias)
         return y, lax.dynamic_update_slice(
             tails, tail[None].astype(tails.dtype), (slot, 0, 0, 0))
 

@@ -669,11 +669,13 @@ STEP_BLOCK_BYTES = 4 << 20      # of the state a grid step holds
 
 
 def _step_kernel(slot_ref, cols_ref, rows_ref, s_ref, o_ref, s_out_ref, *,
-                 groups, r, dv):
+                 groups, r, dv, delta):
     """One row's ``groups`` runs of r heads. cols_ref [dk, groups 2r]: a
     run's k of each head, then its q of each, a key dim a sublane; rows_ref
     [3, groups, r dv]: v, exp(g) and beta over their heads' lanes; s_ref,
-    s_out_ref [groups, dk, r dv]: the same block of the stack."""
+    s_out_ref [groups, dk, r dv]: the same block of the stack. ``delta``:
+    what is written is ``beta (v - S^T k)``, the delta rule's correction;
+    without it ``beta v`` (a state-space transition, beta its step size)."""
     del slot_ref
     dk, wide = s_ref.shape[1:]
     lane = lax.broadcasted_iota(jnp.int32, (dk, wide), 1)
@@ -689,8 +691,11 @@ def _step_kernel(slot_ref, cols_ref, rows_ref, s_ref, o_ref, s_out_ref, *,
         k, q = beside(2 * r * i), beside(2 * r * i + r)
         v, decay, beta = (rows_ref[n, i:i + 1, :] for n in range(3))
         state = s_ref[i].astype(_F32)
-        seen = jnp.sum(state * k, axis=0, keepdims=True)         # S^T k
-        fresh = beta * (v - decay * seen)
+        if delta:
+            seen = jnp.sum(state * k, axis=0, keepdims=True)     # S^T k
+            fresh = beta * (v - decay * seen)
+        else:
+            fresh = beta * v
         state = decay * state + k * fresh
         s_out_ref[i] = state.astype(s_out_ref.dtype)
         o_ref[i:i + 1, :] = jnp.sum(state * q, axis=0, keepdims=True)
@@ -707,13 +712,16 @@ def _groups_a_step(total: int, group_bytes: int) -> int:
     return fits[-1] if fits else total
 
 
-def step_kernel(states, slot, q, k, v, g, beta, *, interpret=False):
-    """ops/gated_delta.py's ``gated_delta_step_at`` as a kernel. states
-    [slots, B, H / r, dk, r dv] float32; q, k [B, H, dk] float32 at unit
-    length (``_step_operands``); v [B, H, dv]; g, beta [B, H]. -> (o [B, H,
-    dv] in v's dtype, the stack: the kernel's output aliases ``states``
-    and a grid step reads and writes one row's block of slot ``slot``).
-    The small operands are laid out by XLA under the caller's scope."""
+def step_kernel(states, slot, q, k, v, g, beta, *, delta=True,
+                interpret=False):
+    """ops/gated_delta.py's ``gated_delta_step_at`` as a kernel, and with
+    ``delta=False`` ops/ssd.py's ``ssd_step_at`` (``rt_ssd_step``: the same
+    step without the delta correction). states [slots, B, H / r, dk, r dv]
+    float32; q, k [B, H, dk] float32 (the rule's at unit length,
+    ``_step_operands``); v [B, H, dv]; g, beta [B, H]. -> (o [B, H, dv] in
+    v's dtype, the stack: the kernel's output aliases ``states`` and a grid
+    step reads and writes one row's block of slot ``slot``). The small
+    operands are laid out by XLA under the caller's scope."""
     _, b, runs, dk, wide = states.shape
     h, dv = v.shape[1:]
     r = h // runs
@@ -727,8 +735,9 @@ def step_kernel(states, slot, q, k, v, g, beta, *, interpret=False):
                       _over_lanes(beta.astype(_F32), r, dv)], axis=1)
     block = (None, None, groups, dk, wide)
     o, states = pl.pallas_call(
-        functools.partial(_step_kernel, groups=groups, r=r, dv=dv),
-        name="rt_gdn_step",   # rtcheck: allow-unminted-metric(a kernel's name in the device trace, not a metric)
+        functools.partial(_step_kernel, groups=groups, r=r, dv=dv,
+                          delta=delta),
+        name="rt_gdn_step" if delta else "rt_ssd_step",   # rtcheck: allow-unminted-metric(a kernel's name in the device trace, not a metric)
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, runs // groups),
             in_specs=[
@@ -758,12 +767,15 @@ CONV_BLOCK = 2048       # channels a grid step, at most
 
 
 def _conv_step_kernel(slot_ref, x_ref, w_ref, t_ref, y_ref, t_out_ref):
-    """x_ref [B, c]: the new inputs; w_ref [K, c] float32; t_ref, t_out_ref
-    [K-1, B, c]: the same block of the stack, oldest position first."""
+    """x_ref [B, c]: the new inputs; w_ref [K, c] float32, or [K + 1, c]
+    with the bias for its last row; t_ref, t_out_ref [K-1, B, c]: the same
+    block of the stack, oldest position first."""
     del slot_ref
     kept = t_ref.shape[0]
     x = x_ref[...]
     y = x.astype(_F32) * w_ref[kept:kept + 1, :]
+    if w_ref.shape[0] > kept + 1:
+        y = y + w_ref[kept + 1:kept + 2, :]
     for j in range(kept):
         y = y + t_ref[j].astype(_F32) * w_ref[j:j + 1, :]
         t_out_ref[j] = t_ref[j + 1] if j + 1 < kept \
@@ -771,11 +783,14 @@ def _conv_step_kernel(slot_ref, x_ref, w_ref, t_ref, y_ref, t_out_ref):
     y_ref[...] = (y * jax.nn.sigmoid(y)).astype(y_ref.dtype)
 
 
-def conv_step_kernel(tails, slot, x, w, *, interpret=False):
+def conv_step_kernel(tails, slot, x, w, bias=None, *, interpret=False):
     """ops/gated_delta.py's ``conv_step_at`` as a kernel. tails [slots,
-    K-1, B, C]; x [B, C]; w [C, K]. -> (y [B, C] in x's dtype, the stack:
-    the kernel's output aliases ``tails``)."""
+    K-1, B, C]; x [B, C]; w [C, K]; bias [C] or None. -> (y [B, C] in x's
+    dtype, the stack: the kernel's output aliases ``tails``)."""
     _, kept, b, c = tails.shape
+    taps = w.astype(_F32).T
+    if bias is not None:        # one more row of the taps' block
+        taps = jnp.concatenate([taps, bias.astype(_F32)[None]])
     fits = [n for n in range(128, min(c, CONV_BLOCK) + 1, 128) if c % n == 0]
     wide = fits[-1] if fits else c
     block = (None, kept, b, wide)
@@ -786,7 +801,7 @@ def conv_step_kernel(tails, slot, x, w, *, interpret=False):
             num_scalar_prefetch=1, grid=(c // wide,),
             in_specs=[
                 pl.BlockSpec((b, wide), lambda i, slot: (0, i)),
-                pl.BlockSpec((kept + 1, wide), lambda i, slot: (0, i)),
+                pl.BlockSpec((taps.shape[0], wide), lambda i, slot: (0, i)),
                 pl.BlockSpec(block, lambda i, slot: (slot[0], 0, 0, i))],
             out_specs=[
                 pl.BlockSpec((b, wide), lambda i, slot: (0, i)),
@@ -797,5 +812,5 @@ def conv_step_kernel(tails, slot, x, w, *, interpret=False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(jnp.asarray(slot, jnp.int32).reshape(1), x, w.astype(_F32).T, tails)
+    )(jnp.asarray(slot, jnp.int32).reshape(1), x, taps, tails)
     return y, tails

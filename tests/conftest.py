@@ -55,8 +55,30 @@ _CELL_THAT_IS_NO_LONGER_LAST = \
     "test_bench_zdots3.py::test_the_traffic_is_the_issues"
 
 
+# Two more, of the same kind: the olmo_hybrid family's own tests hold its
+# cell and its configuration to the LAST place of ``workloads`` and
+# ``configs``, its six metrics to the last six of ``per_layer``, and dots3's
+# cell (then its own) to the last places of the serving metrics' lists; PR 55
+# appended falconh1-serve-closed64-p128-n384 after them, as a cell-adding PR
+# has to, and may not edit tests/benchmark/test_bench_olmo_hybrid.py.
+# tests/benchmark/test_bench_falcon_h1.py::
+# test_the_cells_before_this_one_are_still_on_their_lists carries every one
+# of their assertions, with the places counted one before the new cell's:
+# only the ``[-1]`` is lost.
+_CELLS_THAT_ARE_NO_LONGER_LAST = tuple(
+    "test_bench_olmo_hybrid.py::" + name for name in (
+        "test_the_manifest_gained_entries_and_two_appended_names",
+        "test_dots3s_traffic_is_still_its_issues"))
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(_CELLS_THAT_ARE_NO_LONGER_LAST):
+            item.add_marker(pytest.mark.xfail(
+                reason="holds the olmo_hybrid cell to the last place of "
+                       "the manifest's lists; PR 55 appended a cell after "
+                       "it and may not edit the benchmark's own test",
+                strict=True))
         if item.nodeid.endswith(_CELL_THAT_IS_NO_LONGER_LAST):
             item.add_marker(pytest.mark.xfail(
                 reason="holds dots3's cell to the last place of the "

@@ -499,6 +499,73 @@ def test_a_hybrid_generate_keeps_one_state_on_v5e(monkeypatch):
     assert re.search(copied, compiled().as_text())
 
 
+def test_a_parallel_generate_keeps_one_state_on_v5e(monkeypatch):
+    """Mosaic accepts the state-space step at the Falcon-H1 cell's widths
+    (32 heads of [256, 128] float32: the delta rule's step kernel without
+    its correction on a block of 4 MiB a row, and the convolution's step
+    with a bias over 5,120 channels), and the compiled ``generate`` of two
+    parallel layers at the cell's 64 rows x (128 + 384) holds ONE state
+    stack beside its keys and values: no instruction copies an array of
+    the state's shape. (XLA's form of THIS step, cut the slot out, step,
+    write it back, compiles without such a copy too, where the delta
+    rule's did not: it reads its slot once, with no ``S^T k`` before the
+    update. The kernel is kept for the one pass over a row's state: on
+    the chip XLA's form takes 4.15 s of the cell's call under
+    ``rt.ssd.step`` where the kernel takes 2.92, PERF.md PR 55.)"""
+    import re
+    from functools import partial
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import (TransformerConfig, generate_with_stats,
+                                transformer_init)
+    from ray_tpu.ops import flash, gated_delta, ssd
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu in this install
+        pytest.skip(f"no TPU compiler here: {e!r}")
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = TransformerConfig(
+        vocab_size=4096, d_model=5120, n_layers=2, n_heads=20, n_kv_heads=4,
+        head_width=128, d_ff=21504, max_seq=512, rope_theta=1e11,
+        norm_eps=1e-5, layer_types=("parallel",), linear_transition="ssd",
+        linear_key_heads=2, linear_value_heads=32, linear_key_dim=256,
+        linear_value_dim=128, param_dtype=jnp.bfloat16, remat=False)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(partial(transformer_init, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    prompts = jax.ShapeDtypeStruct((64, 128), jnp.int32, sharding=one)
+
+    def compiled():
+        return jax.jit(partial(
+            generate_with_stats, cfg=cfg, max_new_tokens=384)).lower(
+                params, prompts).compile()
+
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash, "generation", lambda: "v5e")
+    monkeypatch.setattr(gated_delta, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
+    state = r"f32\[2,64,32,256,128\]"
+    copied = state + r"\S* copy\("
+    sound = compiled()
+    text = sound.as_text()
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    for name, shape in (("rt_ssd_step", "f32[2,64,32,256,128]"),
+                        ("rt_gdn_conv_step", "bf16[2,3,64,5120]")):
+        calls = [ln for ln in kernels if name in ln]
+        assert calls and all(shape in ln for ln in calls), name
+    assert not any("rt_gdn_step" in ln for ln in kernels)
+    assert re.search(state, text) and not re.search(copied, text)
+    stacks = 4 * 2 * 64 * 32 * 256 * 128 + 2 * 2 * 2 * 64 * 512 * 4 * 128 \
+        + 2 * 2 * 3 * 64 * 5120
+    assert sound.memory_analysis().temp_size_in_bytes < stacks + (3 << 30)
+
+
 # the three cells' expert layers and a decode step's of the dots3 cell:
 # (tokens, D, F, experts, held, top_k, scoring, under a gradient)
 EXPERT_LAYERS = {

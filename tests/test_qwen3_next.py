@@ -747,14 +747,16 @@ def test_generate_serves_the_pattern_and_refuses_it_beside_latent_layers():
     dims = LatentDims(heads=2, q_rank=8, kv_rank=8, nope=8, rope=8, v=8)
     beside = dataclasses.replace(
         cfg, layer_types=("linear", "latent"), latent=dims)
-    with pytest.raises(NotImplementedError, match="gated-delta-rule"):
+    with pytest.raises(NotImplementedError,
+                       match="linear and parallel layers beside softmax"):
         generate(params, prompt, beside, max_new_tokens=2)
 
 
 def test_a_layer_pattern_is_checked_where_it_is_configured():
     with pytest.raises(ValueError, match="multiple of the period"):
         program_config(n_layers=6)
-    with pytest.raises(ValueError, match="'full', 'linear', 'latent' or"):
+    with pytest.raises(ValueError,
+                       match="'full', 'linear', 'parallel', 'latent' or"):
         program_config(layer_types=("full", "banded"))
     with pytest.raises(ValueError, match="window layers need their widths"):
         program_config(layer_types=("full", "window"))
