@@ -34,7 +34,9 @@ input (``sparse_in_kernel``): where a block of queries fetches more rows
 than the cache holds, on a TPU (every prefill block of a long prompt), in
 the Pallas kernel ``rt_sparse_attend`` (ops/sparse_attend.py), which holds
 one batch row's cache in VMEM, fetches each selected row from there once
-and hands out the attended latents alone, the scores float32 all the way;
+(the next query's rows beside this query's products: 6.9 us a query of 128
+heads over 2,048 rows on a v5e) and hands out the attended latents alone,
+the scores float32 all the way;
 otherwise (a decode step, whose one query a row fetches a sixteenth of the
 cache; the CPU; small sizes) as a gather of the rows into a copy and three
 ``jnp`` passes over it, the kernel's reference in the tests. One algorithm,

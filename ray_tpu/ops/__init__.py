@@ -9,7 +9,8 @@ here. Public surface:
 - flash (Pallas): fused MXU flash-attention kernels for TPU.
 - sparse_attend (Pallas): latent attention of each query over the cached
   rows an indexer selected for it, the rows fetched once from a cache held
-  in VMEM (models/latent.py calls it for a prefill block).
+  in VMEM, a query's beside the products of the query before it
+  (models/latent.py calls it for a prefill block).
 - ring_attention: sequence parallelism over an ICI ring (shard_map +
   ppermute), blockwise-causal.
 - ulysses: all-to-all sequence parallelism (seq-sharded <-> head-sharded).

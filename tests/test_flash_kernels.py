@@ -336,7 +336,8 @@ def test_a_looped_generate_keeps_one_cache_layout_on_v5e(monkeypatch):
 def test_sparse_attend_compiles_for_v5e_at_the_cells_block():
     """Mosaic accepts the kernel at the dots3 cell's prefill block (2 x 128
     queries of 128 heads over 2,048 of 32,896 rows of 576) and grants it
-    the VMEM it asks for: one batch row's cache and the working set."""
+    the VMEM it asks for: one batch row's cache and the working set, a
+    slice of the next query's fetch unrolled into each slice of a query."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -367,6 +368,13 @@ def test_sparse_attend_compiles_for_v5e_at_the_cells_block():
     assert "bf16[2,128,2048,576]" not in hlo        # no copy of the rows
     asked = t * 384 * 4 + sparse_attend.WORK_VMEM_BYTES
     assert asked <= 100 << 20                       # of a core's 128 MiB
+    assert f'"size":"{asked}"' in call              # the limit it compiled to
+    # what the kernel declares is inside it: the cache, the two buffers of
+    # rows (one read while the other is filled), the scores and the value
+    # parts of the two halves (that a slice's halves and the blocks fit
+    # beside them is Mosaic's to refuse, above)
+    assert (t * 384 * 4 + 2 * topk * 384 * 4 + h * topk * 4
+            + 2 * topk * 256 * 2) < asked
     # no score and no row among the program's temporaries: the packed
     # cache (2 x 50.5 MB) and what packing it holds
     assert compiled.memory_analysis().temp_size_in_bytes < 320 << 20

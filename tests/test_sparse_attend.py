@@ -34,20 +34,26 @@ def jnp_form(q, keys, at, real, v, scale):
     return jnp.einsum("bshk,bskr->bshr", p.astype(rows.dtype), rows[..., :v])
 
 
-def inputs(seed):
+def inputs(seed, s=S):
     rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.standard_normal((B, S, H, V + ROPE)), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((B, s, H, V + ROPE)), jnp.bfloat16)
     keys = jnp.asarray(rng.standard_normal((B, T, V + ROPE)), jnp.bfloat16)
     at = np.stack([np.stack([np.sort(rng.choice(T, TOPK, replace=False))
-                             for _ in range(S)]) for _ in range(B)])
-    return rng, q, keys, at.astype(np.int32), np.ones((B, S, TOPK), bool)
+                             for _ in range(s)]) for _ in range(B)])
+    return rng, q, keys, at.astype(np.int32), np.ones((B, s, TOPK), bool)
 
 
-CASES = ["all_real", "few_keys", "rows_unlike", "ascending", "shuffled"]
+# The last five are what two buffers of rows, filled a query ahead, can get
+# wrong: the first query of a batch row has nothing fetched for it, the
+# last fetches for nobody, and which buffer a query reads goes by its index.
+CASES = ["all_real", "few_keys", "rows_unlike", "ascending", "shuffled",
+         "one_query", "odd_queries", "even_queries", "caches_unlike",
+         "last_query_one_key"]
+QUERIES = {"one_query": 1, "odd_queries": 5, "even_queries": 4}
 
 
 def selections(case):
-    rng, q, keys, at, real = inputs(CASES.index(case))
+    rng, q, keys, at, real = inputs(CASES.index(case), QUERIES.get(case, S))
     if case == "few_keys":      # a query with fewer keys than topk: the
         real[:, 0, 5:] = False  # slots it does not fill point at key 0
         real[1, 2, 1:] = False
@@ -57,6 +63,17 @@ def selections(case):
         assert not (at[0] == at[1]).all()
     elif case == "shuffled":
         at = rng.permuted(at, axis=-1)
+    elif case == "caches_unlike":
+        # row 0 selects out of its cache's first half and row 1 out of its
+        # second, whose rows are 64 x larger: a row of row 0's cache under
+        # row 1's first query, or the other way round, would show
+        keys = keys.at[1].multiply(64)
+        at = np.stack([[lo + np.sort(rng.choice(T // 2, TOPK, replace=False))
+                        for _ in range(S)] for lo in (0, T // 2)])
+        at = at.astype(np.int32)
+    elif case == "last_query_one_key":  # nothing real but the first slot
+        real[0, -1, 1:] = False
+        at = np.where(real, at, 0)
     return q, keys, jnp.asarray(at), jnp.asarray(real)
 
 
@@ -65,7 +82,7 @@ def test_kernel_is_the_jnp_form(case):
     q, keys, at, real = selections(case)
     got = kernel.sparse_attend(q, kernel.pack(keys, V), at, real, v=V,
                                scale=SCALE, interpret=True)
-    assert got.shape == (B, S, H, V) and got.dtype == keys.dtype
+    assert got.shape == at.shape[:2] + (H, V) and got.dtype == keys.dtype
     # float32 scores against the jnp form's bfloat16: within its rounding
     # of the float32 answer
     exact = jnp_form(*(a.astype(jnp.float32) for a in (q, keys)), at, real,
@@ -74,6 +91,21 @@ def test_kernel_is_the_jnp_form(case):
     ref_off = np.abs(np.asarray(jnp_form(q, keys, at, real, V, SCALE),
                                 np.float32) - exact).max()
     assert off <= max(ref_off, 2e-2), (off, ref_off)
+
+
+def test_a_block_is_its_queries_one_at_a_time():
+    """Nothing leaks from a query into its neighbour: a block's output is
+    bitwise that of the same kernel called a query at a time, where no row
+    is fetched ahead."""
+    q, keys, at, real = selections("few_keys")
+    packed = kernel.pack(keys, V)
+    run = lambda *a: kernel.sparse_attend(            # noqa: E731
+        *a, v=V, scale=SCALE, interpret=True)
+    block = run(q, packed, at, real)
+    alone = jnp.concatenate([run(q[:, j:j + 1], packed, at[:, j:j + 1],
+                                 real[:, j:j + 1]) for j in range(S)], 1)
+    np.testing.assert_array_equal(np.asarray(block, np.float32),
+                                  np.asarray(alone, np.float32))
 
 
 def test_the_selection_is_a_set():
