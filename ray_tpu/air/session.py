@@ -34,12 +34,18 @@ class _Session:
         self.reports = []           # consumed by the worker actor
         self.report_event = threading.Condition()
         self.iteration = 0
+        self._report_began: Optional[float] = None
         self.stop_requested = False
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None) -> None:
         self.iteration += 1
-        with events.span("train.report", iteration=self.iteration):
+        # a loop that reports once a step: its step period, as this rank
+        # sees it (absent on the first report)
+        began, last = time.perf_counter(), self._report_began
+        self._report_began = began
+        period = {} if last is None else {"period_s": began - last}
+        with events.span("train.report", iteration=self.iteration, **period):
             with self.report_event:
                 self.reports.append({"metrics": dict(metrics),
                                      "checkpoint": checkpoint,

@@ -517,14 +517,18 @@ class NodeDaemon:
             with self._lock:
                 avail = dict(self._avail)
                 demand = [dict(d) for d in self._pending_demand]
+            ring = _events.heartbeat_payload()
             try:
                 resp = cli.call("heartbeat", node_id=self.node_id,
                                 resources_available=avail,
-                                pending_demand=demand,
-                                events=_events.heartbeat_payload())
+                                pending_demand=demand, events=ring)
             except Exception:
+                _events.heartbeat_undelivered(ring)
                 time.sleep(float(config.get("health_check_period_s")))
                 continue
+            if not resp.get("ok", True):
+                # answered before the delta was read (a node it lost)
+                _events.heartbeat_undelivered(ring)
             epoch = resp.get("epoch")
             if resp.get("reregister") or (
                     epoch is not None and epoch != self._conductor_epoch):

@@ -1049,6 +1049,13 @@ class ClusterRuntime:
         # asynchronously (nothing on the submit/execute path performs a
         # synchronous conductor RPC).
         _events.configure(self.node_id, self.conductor_address)
+        # A pause of the driver or of the one process that owns chips (its
+        # environment carries them, tpu/topology.py) holds a caller or a
+        # chip back: those record their own pauses too (host.pause).
+        from ray_tpu.tpu import topology
+        if not getattr(self, "_is_worker", False) or \
+                topology.process_owns_chips():
+            _events.start_host_watch()
         _events.register_probe("object_plane", self.plane.metrics_probe)
         # Worker stdout/stderr -> this driver (log_monitor.py role). Only
         # true drivers subscribe: a worker echoing the channel into its own
@@ -2036,7 +2043,14 @@ def ring_timeline(ring: List[dict]) -> List[dict]:
         pid_, tid_ = e["node_id"][:8], e["pid"]
         ts_us = e["ts"] * 1e6
         attrs = e["attrs"] or {}
-        if "span" in attrs:
+        if kind == "host.watch":
+            # one a second a watched process: its counters as a counter
+            # track of the process's lane, not a slice over every other
+            out.append({"cat": "span", "name": kind, "ph": "C",
+                        "ts": ts_us, "dur": 0, "pid": pid_, "tid": tid_,
+                        "args": {k: v for k, v in attrs.items()
+                                 if k not in ("span", "parent")}})
+        elif "span" in attrs:
             # a span: ts is its start, value its seconds; slices of one
             # process nest as their parents do
             out.append({"cat": "span", "name": kind, "ph": "X",

@@ -666,6 +666,8 @@ def _batch_state(key: str, window_s: float) -> dict:
                 # batch, ``epoch`` counts the flushes begun, ``armed`` says
                 # that the assembling batch's timer is set
                 "running": False, "epoch": 0, "armed": False,
+                # perf_counter() as the last flush's fn returned
+                "returned": None,
                 # Adaptive window state: current flush window plus the
                 # recent per-request latencies the controller law reads.
                 "window": window_s,
@@ -738,24 +740,29 @@ def batch(_fn=None, *, max_batch_size: int = 8,
         import uuid
         state_key = uuid.uuid4().hex
 
-        def arm(st, delay: float) -> None:
-            """Under the state's lock: the assembling batch's one timer."""
+        def arm(st, delay: float, cause: str) -> None:
+            """Under the state's lock: the assembling batch's one timer.
+            ``cause`` is what its flush records, if it is the timer that
+            sends the batch: ``window`` (set at the batch's first arrival)
+            or ``after_running`` (set by the end of the batch it waited
+            through)."""
             if st["armed"]:
                 return
             st["armed"] = True
-            timer = threading.Timer(delay, timed_flush, (st["epoch"],))
+            timer = threading.Timer(delay, timed_flush,
+                                    (st["epoch"], cause))
             timer.daemon = True
             timer.start()
 
-        def timed_flush(epoch: int) -> None:
+        def timed_flush(epoch: int, cause: str) -> None:
             st = _batch_state(state_key, batch_wait_timeout_s)
             with st["lock"]:
                 if epoch != st["epoch"]:
                     return      # that batch filled and went before its time
                 st["armed"] = False
-            flush()
+            flush(cause)
 
-        def flush():
+        def flush(cause: str):
             st = _batch_state(state_key, batch_wait_timeout_s)
             with st["lock"]:
                 if st["running"] or not st["pending"]:
@@ -764,9 +771,9 @@ def batch(_fn=None, *, max_batch_size: int = 8,
                 del st["pending"][:max_batch_size]
                 st["running"], st["armed"] = True, False
                 st["epoch"] += 1
-                window = st["window"]
+                window, left = st["window"], len(st["pending"])
             try:
-                run(st, batch_items, window)
+                run(st, batch_items, window, cause, left)
             finally:
                 with st["lock"]:
                     st["running"] = False
@@ -774,9 +781,9 @@ def batch(_fn=None, *, max_batch_size: int = 8,
                         # what waited through this batch assembles from
                         # now: the callers just answered have the window
                         arm(st, 0.0 if len(st["pending"]) >= max_batch_size
-                            else st["window"])
+                            else st["window"], "after_running")
 
-        def run(st, batch_items, window):
+        def run(st, batch_items, window, cause, left_pending):
             items = [it[0] for it in batch_items]
             self_obj = batch_items[0][2]
             waits = [it[4] for it in batch_items if it[4] is not None]
@@ -786,12 +793,19 @@ def batch(_fn=None, *, max_batch_size: int = 8,
             # ``serve.batch.wait`` names it (``flush``).
             with events.span(
                     "serve.batch.flush", ctx=events.ROOT, rows=len(items),
-                    max_batch_size=max_batch_size,
-                    window_s=window) as flushed:
+                    max_batch_size=max_batch_size, window_s=window,
+                    cause=cause, left_pending=left_pending) as flushed:
                 fn_start = time.perf_counter()
                 if waits:
-                    flushed.set(oldest_wait_s=fn_start - min(
-                        w.started for w in waits))
+                    flushed.set(
+                        oldest_wait_s=fn_start - min(
+                            w.started for w in waits),
+                        newest_wait_s=fn_start - max(
+                            w.started for w in waits))
+                if st["returned"] is not None:
+                    # the gap as the replica saw it: the previous batch's
+                    # fn returned, this one's starts
+                    flushed.set(since_last_s=fn_start - st["returned"])
                 try:
                     outs = fn(self_obj, items) if self_obj is not None \
                         else fn(items)
@@ -799,6 +813,7 @@ def batch(_fn=None, *, max_batch_size: int = 8,
                     error = e
                     flushed.set(error=repr(e))
             returned, r0 = time.time(), time.perf_counter()
+            st["returned"] = r0
             if error is None and len(outs) != len(items):
                 error = ValueError(
                     f"@serve.batch fn returned {len(outs)} results "
@@ -840,9 +855,9 @@ def batch(_fn=None, *, max_batch_size: int = 8,
                                       else None))
                 full = len(st["pending"]) >= max_batch_size
                 if not full and not st["running"]:
-                    arm(st, st["window"])   # the first arrival's, once
+                    arm(st, st["window"], "window")   # the first arrival's
             if full:
-                flush()         # nothing where a batch runs: its end will
+                flush("full")   # nothing where a batch runs: its end will
             slot["event"].wait(timeout=120)
             if slot["error"] is not None:
                 raise slot["error"]

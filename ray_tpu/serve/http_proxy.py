@@ -183,6 +183,7 @@ class HTTPProxy:
                 "serve proxy failed to start within 10s")
         _live_proxies.add(self)
         events.register_probe("serve.proxy", self._probe)
+        events.start_host_watch()   # a pause here holds every reply back
 
     def _probe(self) -> dict:
         queued = sum(st["queued"] for st in self._adm.values())

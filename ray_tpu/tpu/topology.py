@@ -307,6 +307,12 @@ def chip_visibility_env(chips: Sequence[int], chips_on_host: int
     return env
 
 
+def process_owns_chips() -> bool:
+    """Whether this process is the one a daemon spawned with chips: its
+    environment carries ``chip_visibility_env``'s list."""
+    return bool(os.environ.get("TPU_VISIBLE_CHIPS"))
+
+
 def default_compile_cache_dir() -> str:
     """Where chip-owning processes keep jax's persistent compilation
     cache when JAX_COMPILATION_CACHE_DIR is not set (where it is, jax

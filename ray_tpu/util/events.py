@@ -54,14 +54,21 @@ On top of the ring:
   in-flight, cache sizes) sampled once per flush instead of per call;
 - a slow-op watchdog (``watch_begin``/``watch_end``) reports any
   task/pull/RPC outliving ``slow_op_threshold_s`` to the conductor as
-  a structured cluster event carrying the surrounding ring context.
+  a structured cluster event carrying the surrounding ring context;
+- ``start_host_watch`` records the process's own pauses (``host.pause``
+  with whose pause it was, ``gc.pause``, one ``host.watch`` a second) in
+  the processes where a pause holds a chip or a reply back: a stalled
+  step or call finds its owner in the ring of any run, traced or not.
 """
 
 from __future__ import annotations
 
+import collections
 import contextvars
+import gc
 import itertools
 import os
+import resource
 import sys
 import threading
 import time
@@ -170,7 +177,15 @@ EVENT_KINDS: Dict[str, str] = {
     "serve.retry": "value = attempt ordinal",
     "serve.drain": "value = drained ongoing count",
     "serve.batch.flush": "span: value = seconds in the batched fn; attrs "
-                         "carry rows/max_batch_size/window_s/oldest_wait_s",
+                         "carry rows/max_batch_size/window_s/oldest_wait_s, "
+                         "newest_wait_s (their difference: how far apart the "
+                         "batch's callers arrived), cause (full: the arrival "
+                         "that filled it; window: the assembling batch's "
+                         "timer; after_running: armed by the end of the batch "
+                         "it waited through), left_pending (calls that stayed "
+                         "queued as it went) and since_last_s (from the "
+                         "previous flush's fn returning to this one's "
+                         "starting, on this batcher; absent on the first)",
     # trainer gang
     "train.fit": "span: value = seconds of one fit(); mints the ident",
     "train.backend.start": "span: value = seconds in BackendExecutor.start",
@@ -178,7 +193,9 @@ EVENT_KINDS: Dict[str, str] = {
                         "creation",
     "train.loop": "span: value = seconds of the user's loop on one rank",
     "train.report": "span: value = seconds in session.report; attrs carry "
-                    "iteration",
+                    "iteration and period_s (seconds since this rank's "
+                    "previous report began; absent on the first: a loop that "
+                    "reports once a step reads its step period here)",
     "train.step": "span: value = seconds from a step's dispatch to its "
                   "metrics on the host, opened by the user's loop; attrs "
                   "carry the step's counters (the expert layers' "
@@ -219,6 +236,31 @@ EVENT_KINDS: Dict[str, str] = {
                     "attrs carry chips",
     "worker.boot": "span: value = seconds from main()'s first line to "
                    "register_worker acknowledged",
+    # the process itself (start_host_watch: a chip-owning worker, the
+    # serve proxy's process, the driver); ident = pid:<pid>, no parent
+    "host.pause": "span: value = seconds the process's 10 ms ticker was "
+                  "woken late (20 ms or more), ts = the wake it asked for; "
+                  "attrs say whose pause it was: cpu_s (the process's CPU "
+                  "seconds over it: about value = a thread of this process "
+                  "held the interpreter or a core all along, about 0 = the "
+                  "process did not run), gc_s (seconds of garbage collection "
+                  "that ended inside it), and, deltas since a read at most a "
+                  "second before, runq_s (the ticker thread's run-queue "
+                  "delay: about value = no core for it; left out on a "
+                  "kernel without /proc schedstat), majflt and nivcsw "
+                  "(getrusage); skipped / skipped_s: pauses over 20 records "
+                  "a second that were summed into this one and not recorded",
+    "host.watch": "span: value = seconds it covers (one a second from the "
+                  "same ticker, so a window without host.pause reads 0 and "
+                  "not nothing); attrs carry ticks, late (wakes 20 ms or "
+                  "more late), pause_max_s, own_cpu_s (the ticker thread's "
+                  "own CPU seconds: what the watch costs) and, where the "
+                  "process had already brought a jax backend up, the first "
+                  "local device's bytes_in_use, largest_free_block_bytes "
+                  "and num_allocs (memory_stats())",
+    "gc.pause": "span: value = seconds of one garbage collection, recorded "
+                "for every generation-2 collection and any of 5 ms or more; "
+                "attrs carry generation and collected",
     # infrastructure
     "fault.fired": "value unused; ident = site, attrs carry action",
     "lock.cycle": "value unused; attrs carry the lock cycle",
@@ -750,6 +792,178 @@ def _fold_metrics(evs: List[tuple], dropped: int) -> None:
 
 
 # ----------------------------------------------------------------------
+# the process's own pauses
+# ----------------------------------------------------------------------
+# A stall of a step or a call has an owner: the device, or the host. The
+# host's part is seen from inside: a thread that asks to be woken every
+# TICK_S and is woken PAUSE_S or more late was kept from running, by the
+# interpreter lock, by the scheduler or by a stop of the whole process, and
+# so was every other thread of the process. What it reads beside its two
+# clocks says which.
+TICK_S = 0.010
+PAUSE_S = 0.020
+PAUSES_A_SECOND = 20        # recorded; the rest is summed into the next
+GC_PAUSE_S = 0.005
+
+_watcher: Optional[threading.Thread] = None
+_gc_seconds = 0.0           # of every collection this process ended
+_gc_began = 0.0
+# Collections the hook found worth a ``gc.pause``: (start by time.time(),
+# seconds, generation, collected), for the ticker's next wake to record.
+_gc_found: collections.deque = collections.deque(maxlen=1024)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks``: a collection holds the interpreter, so it is a
+    ``host.pause`` with ``cpu_s`` about ``value``; this names it. The
+    collector runs in whichever thread reaches its threshold, also one that
+    holds ``_lock`` inside ``drain()`` or ``snapshot()``, so nothing here
+    takes a lock: the hook appends, the ticker thread records."""
+    global _gc_seconds, _gc_began
+    if phase == "start":
+        _gc_began = time.perf_counter()
+        return
+    took = time.perf_counter() - _gc_began
+    _gc_seconds += took
+    if info.get("generation") == 2 or took >= GC_PAUSE_S:
+        _gc_found.append((time.time() - took, took, info.get("generation"),
+                          info.get("collected")))
+
+
+def _record_collections() -> None:
+    ident = f"pid:{os.getpid()}"
+    while _gc_found:
+        start, took, generation, collected = _gc_found.popleft()
+        span_record("gc.pause", start, took, ident=ident,
+                    generation=generation, collected=collected)
+
+
+def _thread_runq_s() -> Optional[float]:
+    """This thread's seconds on a run queue, waiting for a core; None on a
+    kernel that keeps no schedstat (``runq_s`` is then left out)."""
+    try:
+        with open("/proc/thread-self/schedstat") as f:
+            return int(f.read().split()[1]) * 1e-9
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def _device_memory() -> dict:
+    """The first local device's allocator counters, in a process that has
+    already imported jax and brought a backend up (never for this). No
+    import and no lock of jax's is taken here: an import from this thread
+    beside the main thread's ``import jax`` hands one of them a half-made
+    module, and ``jax.local_devices()`` waits on the lock that a backend's
+    start-up holds for seconds, which would read as a pause."""
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    try:
+        backend = getattr(bridge, "_default_backend", None)
+        if backend is None:
+            return {}
+        stats = backend.local_devices()[0].memory_stats() or {}
+    except Exception:       # not this jax's layout, or not on this device
+        return {}
+    return {k: stats[k] for k in ("bytes_in_use", "largest_free_block_bytes",
+                                  "num_allocs") if k in stats}
+
+
+class _HostWatch:
+    """The ticker's books. ``woke(asked, now)`` is one wake, by
+    ``time.perf_counter()``; once a second it stores ``host.watch`` and
+    reads what costs a system call."""
+
+    def __init__(self, now: float):
+        self.ident = f"pid:{os.getpid()}"
+        self.cpu = time.process_time()
+        self.gc_s = _gc_seconds
+        self.skipped, self.skipped_s = 0, 0.0
+        self.own_cpu = time.thread_time()
+        self._open_second(now)
+
+    def _open_second(self, now: float) -> None:
+        self.second, self.second_ts = now, time.time()
+        self.ticks = self.late = 0
+        self.pause_max = 0.0
+        self.recorded = 0
+        self._read_slow()
+
+    def _read_slow(self) -> None:
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        self.runq_s, self.majflt, self.nivcsw = \
+            _thread_runq_s(), ru.ru_majflt, ru.ru_nivcsw
+
+    def woke(self, asked: float, now: float) -> None:
+        cpu, gc_s = time.process_time(), _gc_seconds
+        late = now - asked
+        self.ticks += 1
+        if late >= PAUSE_S:
+            self.late += 1
+            self.pause_max = max(self.pause_max, late)
+            if self.recorded >= PAUSES_A_SECOND:
+                self.skipped += 1
+                self.skipped_s += late
+            else:
+                self.recorded += 1
+                before = self.runq_s, self.majflt, self.nivcsw
+                self._read_slow()
+                attrs = {"cpu_s": cpu - self.cpu, "gc_s": gc_s - self.gc_s,
+                         "majflt": self.majflt - before[1],
+                         "nivcsw": self.nivcsw - before[2]}
+                if self.runq_s is not None and before[0] is not None:
+                    attrs["runq_s"] = self.runq_s - before[0]
+                if self.skipped:
+                    attrs.update(skipped=self.skipped,
+                                 skipped_s=self.skipped_s)
+                    self.skipped, self.skipped_s = 0, 0.0
+                span_record("host.pause", time.time() - late, late,
+                            ident=self.ident, **attrs)
+        self.cpu, self.gc_s = cpu, gc_s
+        if now - self.second >= 1.0:
+            own = time.thread_time()
+            span_record("host.watch", self.second_ts, now - self.second,
+                        ident=self.ident, ticks=self.ticks, late=self.late,
+                        pause_max_s=self.pause_max,
+                        own_cpu_s=own - self.own_cpu, **_device_memory())
+            self.own_cpu = own
+            self._open_second(now)
+
+
+def _host_watch_loop() -> None:
+    gc.callbacks.append(_on_gc)
+    try:
+        last = time.perf_counter()
+        watch = _HostWatch(last)
+        while not _flush_stop.is_set() and enabled():
+            time.sleep(TICK_S)      # a third cheaper a wake than Event.wait
+            watch.woke(last + TICK_S, time.perf_counter())
+            if _gc_found:
+                _record_collections()
+            # read again: what ``woke`` took (the second's system calls, a
+            # wait for ``_lock`` behind a long drain) is no lateness of the
+            # next wake
+            last = time.perf_counter()
+    finally:
+        gc.callbacks.remove(_on_gc)
+        _record_collections()
+
+
+def start_host_watch() -> None:
+    """Record this process's pauses (``host.pause``, ``gc.pause``) and one
+    ``host.watch`` a second, from now until the process's flusher stops. For
+    the processes in which a pause holds a chip or a reply back: a worker
+    spawned with chips, the serve proxy's process, the driver. A process
+    with no flusher, or with ``events_enabled`` false, gets no thread."""
+    global _watcher
+    if not enabled() or _flusher is None or not _flusher.is_alive():
+        return
+    with _flusher_lock:
+        if _watcher is None or not _watcher.is_alive():
+            _watcher = threading.Thread(target=_host_watch_loop, daemon=True,
+                                        name="events-host-watch")
+            _watcher.start()
+
+
+# ----------------------------------------------------------------------
 # shipping
 # ----------------------------------------------------------------------
 def configure(node_id, conductor_address: str,
@@ -794,6 +1008,27 @@ def heartbeat_payload() -> Optional[dict]:
     return {"pid": os.getpid(), "events": evs, "dropped": dropped}
 
 
+def _park(evs: List[tuple], dropped: int) -> None:
+    """A drained delta whose RPC failed waits here for the next ship."""
+    global _unshipped, _unshipped_dropped
+    with _ship_lock:
+        keep = max(64, _cap or 16384)
+        merged = evs + _unshipped
+        _unshipped = merged[-keep:]
+        _unshipped_dropped += dropped + max(0, len(merged) - keep)
+
+
+def heartbeat_undelivered(payload: Optional[dict]) -> None:
+    """The heartbeat that carried ``heartbeat_payload()``'s delta failed,
+    or the conductor answered it without reading it: keep the delta for
+    the next one (it was drained, so nothing else still holds it).
+    At-least-once, as ``flush_now``'s parking is: a heartbeat that arrived
+    and whose reply was lost is sent again, and the conductor then holds
+    those records twice (a span's id tells a reader that minds)."""
+    if payload:
+        _park(list(payload["events"]), payload["dropped"])
+
+
 def flush_now() -> None:
     """One flush pass: ship the ring delta to the conductor, fold
     metrics, sample probes."""
@@ -819,11 +1054,7 @@ def flush_now() -> None:
             cli.call("push_ring_events", node_id=_node_hex, pid=os.getpid(),
                      events=evs, dropped=dropped)
         except Exception:
-            with _ship_lock:
-                keep = max(64, _cap or 16384)
-                merged = evs + _unshipped
-                _unshipped = merged[-keep:]
-                _unshipped_dropped += dropped + max(0, len(merged) - keep)
+            _park(evs, dropped)
             raise
     _sample_probes()
     _check_slow_ops(cli)
@@ -868,3 +1099,4 @@ def reset_for_tests() -> None:
         _watch_reported.clear()
         _watch_next = 0
     _scan_reported.clear()
+    _gc_found.clear()

@@ -110,6 +110,25 @@ def test_flush_failure_reships_drained_delta(monkeypatch):
         events.reset_for_tests()
 
 
+def test_a_failed_heartbeat_keeps_its_delta_for_the_next():
+    """The daemon's heartbeat drains the ring into its payload; where that
+    RPC fails (or is answered unread) the delta rides the next one."""
+    events.reset_for_tests()
+    try:
+        events.emit("test.hb", "a")
+        payload = events.heartbeat_payload()
+        assert [e[2] for e in payload["events"]] == ["a"]
+        assert events.heartbeat_payload() is None       # drained
+        events.heartbeat_undelivered(payload)
+        events.heartbeat_undelivered(None)              # nothing was sent
+        events.emit("test.hb", "b")
+        again = events.heartbeat_payload()
+        assert [e[2] for e in again["events"]] == ["a", "b"]
+        assert events.heartbeat_payload() is None
+    finally:
+        events.reset_for_tests()
+
+
 def test_fold_metrics_counts_batched_hits():
     """inline.hit/miss events carry a batch count in ``value``; a bare
     emit (value 0) must still count as one."""

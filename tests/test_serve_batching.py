@@ -87,6 +87,62 @@ def test_one_batch_at_a_time_and_none_over_the_size():
     assert sorted(i for c in model.calls for i in c) == list(range(20))
 
 
+# (rows, call_s, [(caller, it arrives at, seconds)]) and, for the flush of
+# ``caller 2``: its cause and its counters. The window is 0.1 s; caller 0
+# always goes first and alone, so that every case has a flush before it.
+ARRIVALS = {
+    # 1 arrives 0.1 s after caller 0's answer, 2 follows 0.04 s behind and
+    # does not fill the batch: 1's timer sends both
+    "window": (3, 0.02, [(0, 0.0), (1, 0.25), (2, 0.29)],
+               {"rows": 2, "left_pending": 0, "oldest_wait_s": 0.1,
+                "newest_wait_s": 0.06, "since_last_s": 0.23}),
+    # the same arrivals into batches of two: 2 fills it
+    "full": (2, 0.02, [(0, 0.0), (1, 0.25), (2, 0.29)],
+             {"rows": 2, "left_pending": 0, "oldest_wait_s": 0.04,
+              "newest_wait_s": 0.0, "since_last_s": 0.17}),
+    # a call of 0.3 s began at 0.1 (caller 0's window); 1, 2, 3 arrive
+    # inside it: its end sends 1 and 2 at once and leaves 3
+    "after_running": (2, 0.3, [(0, 0.0), (1, 0.2), (2, 0.25), (3, 0.3)],
+                      {"rows": 2, "left_pending": 1, "oldest_wait_s": 0.2,
+                       "newest_wait_s": 0.15, "since_last_s": 0.0}),
+}
+
+
+@pytest.mark.parametrize("cause", sorted(ARRIVALS))
+def test_a_flush_says_why_it_went_when_it_did(cause):
+    from ray_tpu.util import events
+    rows, call_s, arrivals, expected = ARRIVALS[cause]
+    events.reset_for_tests()
+    try:
+        model = Model(rows=rows, window_s=0.1, call_s=call_s)
+        t0 = time.monotonic()
+
+        def caller(c, at):
+            time.sleep(max(0.0, t0 + at - time.monotonic()))
+            model.ask(c)
+        threads = [threading.Thread(target=caller, args=a) for a in arrivals]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        flushes = [ev[4] for ev in events.snapshot()
+                   if ev[1] == "serve.batch.flush"]
+    finally:
+        events.reset_for_tests()
+    assert model.calls[:2] == [[0], [1, 2]]
+    first, flush = flushes[0], flushes[1]
+    assert first["cause"] == "window" and first["rows"] == 1
+    assert "since_last_s" not in first and first["left_pending"] == 0
+    assert first["oldest_wait_s"] == pytest.approx(0.1, abs=0.05)
+    assert first["newest_wait_s"] == first["oldest_wait_s"]
+    assert flush["cause"] == cause
+    for key, value in expected.items():
+        assert flush[key] == pytest.approx(value, abs=0.06), (key, flush)
+    if cause == "after_running":        # 3 goes alone, a window later
+        assert flushes[2]["cause"] == "after_running"
+        assert flushes[2]["since_last_s"] == pytest.approx(0.1, abs=0.06)
+
+
 def test_an_error_fails_its_batch_and_not_the_next():
     calls = []
 
