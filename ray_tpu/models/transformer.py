@@ -743,7 +743,7 @@ def _whole_rule(cfg: TransformerConfig, mesh=None,
     ``mesh``/``rules``: what the layer's input is laid out by (the rule's
     kernels run per shard)."""
     def rule(u, ba, p):
-        qkv = gated_delta.causal_conv(u, p["conv"])
+        qkv = gated_delta.causal_conv_over(mesh, rules, u, p["conv"])
         with jax.named_scope("rt.gdn.scan"):
             operands = _rule_operands(cfg, p, qkv, ba)
         return gated_delta.gated_delta_rule_over(mesh, rules,
@@ -792,12 +792,16 @@ def _state_space_operands(cfg: TransformerConfig, p, xbc, dt):
             -jnp.exp(p["A_log"].astype(f32)) * dt, dt, p["D"])
 
 
-def _whole_scan(cfg: TransformerConfig):
+def _whole_scan(cfg: TransformerConfig, mesh=None,
+                rules: LogicalRules = DEFAULT_RULES):
     """``_state_space_mix``'s ``rule`` over the sequence's own positions
-    from a zero state, nothing kept: the training forward's."""
+    from a zero state, nothing kept: the training forward's.
+    ``mesh``/``rules``: as ``_whole_rule``'s (the convolution's kernels run
+    per shard)."""
     def rule(u, dt, p):
-        xbc = gated_delta.causal_conv(u, p["conv"], p["conv_bias"],
-                                      scope="rt.ssd.conv")
+        xbc = gated_delta.causal_conv_over(mesh, rules, u, p["conv"],
+                                           p["conv_bias"],
+                                           scope="rt.ssd.conv")
         with jax.named_scope("rt.ssd.scan"):
             return ssd.ssd_scan(
                 *_state_space_operands(cfg, p, xbc, dt)), None
@@ -868,8 +872,8 @@ def _layer_bodies(cfg: TransformerConfig, mesh, rules: LogicalRules):
     def whole(new):             # a latent layer's keys: the sequence's own
         return new, None, None
 
-    rule = _whole_scan(cfg) if cfg.linear_transition == "ssd" \
-        else _whole_rule(cfg, mesh, rules)
+    rule = (_whole_scan if cfg.linear_transition == "ssd"
+            else _whole_rule)(cfg, mesh, rules)
     attends = {"full": softmax, "latent": whole, "window": whole,
                "linear": rule, "parallel": (softmax, lambda kept: rule)}
 

@@ -35,6 +35,18 @@ else (the CPU tests, narrow heads) the jnp form below runs, batched matmuls
 and one ``lax.scan`` step a chunk with XLA's backward, which keeps one state
 a chunk; it is also the kernels' oracle.
 
+**The convolution** in front of the rule (``causal_conv``: K shifted
+products summed in float32, a bias where there is one, SiLU) has the same
+two forms: on a TPU a Pallas pair where the sequence fills a row block
+(``conv_kernels_fit``: a multiple of ``CONV_ROWS`` positions, channels a
+multiple of 128; ops/gated_delta_pallas ``rt_gdn_conv_fwd``,
+``rt_gdn_conv_bwd``, a ``jax.custom_vjp``), one read of x and one write of
+y forward, x and dy read and dx written backward with the taps' and the
+bias's gradients summed in the same pass, nothing kept between them;
+everywhere else, a served prompt of 128 positions among it, the jnp form,
+which is also the pair's oracle. Under a mesh of several devices
+``causal_conv_over`` runs the pair per shard of the batch.
+
 **Serving.** ``final_state=True`` also hands back the state after the last
 position, float32 ``[B, H, dk, dv]``: what a prefill leaves in the cache.
 From there ``gated_delta_step`` takes one position of the recurrence above
@@ -74,6 +86,7 @@ finds their device time.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -98,6 +111,9 @@ def causal_conv(x, w, bias=None, scope: str = "rt.gdn.conv"):
     position). -> [B, S, C] in x's dtype. ``scope``: the mixer's own name
     for it in the device trace (a state-space mixer's is ``rt.ssd.conv``)."""
     with jax.named_scope(scope):
+        if conv_kernels_fit(x, w):
+            from ray_tpu.ops.gated_delta_pallas import causal_conv_kernels
+            return causal_conv_kernels(x, w, bias)
         width = w.shape[1]
         s = x.shape[1]
         padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
@@ -107,6 +123,18 @@ def causal_conv(x, w, bias=None, scope: str = "rt.gdn.conv"):
         if bias is not None:
             y = y + bias.astype(jnp.float32)
         return jax.nn.silu(y).astype(x.dtype)
+
+
+CONV_ROWS = 512     # positions the sequence's kernels take a multiple of
+
+
+def conv_kernels_fit(x, w) -> bool:
+    """The Pallas pair runs on a TPU where the sequence fills its row
+    blocks and the channels the lanes (and the K - 1 positions a tap looks
+    back lie in one float32 tile of 8 rows); a shorter sequence (a served
+    prompt of 128 positions), and every other shape, runs the jnp form."""
+    return (_on_tpu() and x.shape[1] % CONV_ROWS == 0
+            and x.shape[2] % 128 == 0 and w.shape[1] <= 8)
 
 
 def conv_step(tail, x, w, bias=None):
@@ -212,6 +240,13 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
         return (o, state) if final_state else o
 
 
+def _on_one_device(mesh) -> bool:
+    """What is traced runs on one device: no mesh, a mesh of one, or the
+    inside of a shard_map already."""
+    return mesh is None or mesh.size == 1 \
+        or bool(jax.sharding.get_abstract_mesh().manual_axes)
+
+
 def gated_delta_rule_over(mesh, rules: LogicalRules, q, k, v, g, beta):
     """``gated_delta_rule`` of operands laid out by ``rules`` over ``mesh``.
     A Mosaic call cannot be partitioned by GSPMD, so where the kernels run
@@ -220,8 +255,7 @@ def gated_delta_rule_over(mesh, rules: LogicalRules, q, k, v, g, beta):
     both), as ops/flash.py does; the jnp form is GSPMD's to partition."""
     rule = gated_delta_rule     # as it is named now: the benchmark's sweep
     #                             plants faults on the name
-    if mesh is None or mesh.size == 1 or not _kernels_fit(q, v, CHUNK) \
-            or jax.sharding.get_abstract_mesh().manual_axes:
+    if _on_one_device(mesh) or not _kernels_fit(q, v, CHUNK):
         return rule(q, k, v, g, beta)
     head_shards = math.prod(
         mesh.shape[a] for ax in rules.spec(("heads",), mesh)
@@ -235,6 +269,23 @@ def gated_delta_rule_over(mesh, rules: LogicalRules, q, k, v, g, beta):
     return jax.shard_map(rule, mesh=mesh, in_specs=(wide, wide, wide, gate,
                                                     gate),
                          out_specs=wide, check_vma=False)(q, k, v, g, beta)
+
+
+def causal_conv_over(mesh, rules: LogicalRules, x, w, bias=None,
+                     scope: str = "rt.gdn.conv"):
+    """``causal_conv`` of an input laid out by ``rules`` over ``mesh``:
+    where its kernels run under a mesh of several devices they run per
+    shard of the batch inside shard_map, as the rule's do (the channels are
+    whole on every device, the packed projection's columns being kept so:
+    models/transformer.py ``transformer_logical_axes``); the jnp form is
+    GSPMD's to partition."""
+    conv = functools.partial(causal_conv, scope=scope)
+    if _on_one_device(mesh) or not conv_kernels_fit(x, w):
+        return conv(x, w, bias)
+    rows = rules.spec(("batch", None, None), mesh)
+    whole = jax.sharding.PartitionSpec()
+    return jax.shard_map(conv, mesh=mesh, in_specs=(rows, whole, whole),
+                         out_specs=rows, check_vma=False)(x, w, bias)
 
 
 KERNEL_WIDTH_FROM = 64      # narrower heads would be mostly padding

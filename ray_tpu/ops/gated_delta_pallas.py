@@ -37,6 +37,11 @@ blocks exact on the VPU and its merges float32 products at the highest
 precision (``_product``), g's running sum, ``exp`` and the gates float32,
 the state float32; the large products take operands in the inputs' dtype
 and accumulate in float32.
+
+The short causal convolution in front of the rule has kernels of its own at
+the end of this file: one position on a carried tail (``rt_gdn_conv_step``)
+and a whole sequence, forward and backward (``rt_gdn_conv_fwd``,
+``rt_gdn_conv_bwd``).
 """
 
 from __future__ import annotations
@@ -783,14 +788,21 @@ def _conv_step_kernel(slot_ref, x_ref, w_ref, t_ref, y_ref, t_out_ref):
     y_ref[...] = (y * jax.nn.sigmoid(y)).astype(y_ref.dtype)
 
 
+def _taps(w, bias):
+    """w [C, K], bias [C] or None -> [K, C] float32, or [K + 1, C] with the
+    bias for a last row: the convolution kernels' one block of weights."""
+    taps = w.astype(_F32).T
+    if bias is None:
+        return taps
+    return jnp.concatenate([taps, bias.astype(_F32)[None]])
+
+
 def conv_step_kernel(tails, slot, x, w, bias=None, *, interpret=False):
     """ops/gated_delta.py's ``conv_step_at`` as a kernel. tails [slots,
     K-1, B, C]; x [B, C]; w [C, K]; bias [C] or None. -> (y [B, C] in x's
     dtype, the stack: the kernel's output aliases ``tails``)."""
     _, kept, b, c = tails.shape
-    taps = w.astype(_F32).T
-    if bias is not None:        # one more row of the taps' block
-        taps = jnp.concatenate([taps, bias.astype(_F32)[None]])
+    taps = _taps(w, bias)
     fits = [n for n in range(128, min(c, CONV_BLOCK) + 1, 128) if c % n == 0]
     wide = fits[-1] if fits else c
     block = (None, kept, b, wide)
@@ -814,3 +826,259 @@ def conv_step_kernel(tails, slot, x, w, bias=None, *, interpret=False):
         interpret=interpret,
     )(jnp.asarray(slot, jnp.int32).reshape(1), x, taps, tails)
     return y, tails
+
+
+# ---------------------------------------------------------------------------
+# the convolution over a whole sequence (training, a prompt of a block or
+# more): one pass forward, one pass backward
+# ---------------------------------------------------------------------------
+
+# A grid step's block, on a v5e at [2, 8192, 8192] bfloat16, K = 4, forward /
+# backward alone (my chip run, PR 59; the jnp form 1.99 / 8.21 ms; the bytes
+# are 0.66 / 0.98 ms at 819 GB/s): rows 256 1.29 / 1.87 ms, 512 1.07 / 1.67,
+# 1024 0.97 / 1.63, 2048 0.91 / 1.60 (fewer steps, fewer halos); at 512 rows
+# 256 lanes 1.34 / 1.96, 1024 lanes 1.02 / 1.95, a tile of 16 rows 1.11 /
+# 1.81, of 64 1.11 / 1.88. Both are bound by the vector ALUs' work, the
+# backward at 48 operations a float32 register of x (PERF.md, PR 59).
+CONV_ROWS_MOST = 2048   # positions a grid step, at most
+CONV_LANES = 512        # channels a grid step
+CONV_TILE = 32          # rows an inner step: two bfloat16 tiles
+HALO = 16               # rows of the views beside a block (a bfloat16 tile)
+_ROWS = 8               # a float32 tile: how far a shift reaches, at most
+_LOG2_E = 1.4426950408889634
+
+
+def _sigmoid(pre):
+    """1 / (1 + e^-pre), float32: the EUP's reciprocal and two Newton steps
+    (its 8 good bits to 16, to float32's last place). Mosaic's own ``divf``
+    spends as much again on what ``1 + e^-pre`` cannot be: zero, negative,
+    a NaN. The power of two is held at 2^115, so that the sum stays finite."""
+    d = 1.0 + jnp.exp2(jnp.minimum(pre * -_LOG2_E, 115.0))
+    r = pl.reciprocal(d, approx=True)
+    r = r * (2.0 - d * r)
+    return r * (2.0 - d * r)
+
+
+def _shifted(ext, taps):
+    """ext [_ROWS + n, c]: n rows behind the _ROWS before them -> [rows
+    t - (K-1) + j of those n's t, for j = 0 .. K-1]."""
+    return [ext[_ROWS:] if j == taps - 1 else
+            pltpu.roll(ext, taps - 1 - j, axis=0)[_ROWS:]
+            for j in range(taps)]
+
+
+def _tap(w_ref, j, n):
+    """Row j of w_ref [K or K + 1, _ROWS, c] (the taps, then the bias where
+    there is one, float32, each over its sublanes) as [n, c]: read where it
+    is used, so that no tap waits in registers."""
+    return jnp.concatenate([w_ref[j]] * (n // _ROWS), axis=0)
+
+
+def _pre_activation(parts, w_ref):
+    """The taps' sum in the jnp form's order: j = 0 .. K-1, then the bias."""
+    n = parts[0].shape[0]
+    y = parts[0] * _tap(w_ref, 0, n)
+    for j in range(1, len(parts)):
+        y = y + parts[j] * _tap(w_ref, j, n)
+    return y + _tap(w_ref, len(parts), n) if w_ref.shape[0] > len(parts) \
+        else y
+
+
+def _rows_before(before_ref):
+    """before_ref [HALO, c], the rows of x that end where the block starts
+    -> the last _ROWS of them, float32; zeros at a sequence's start, where
+    the view holds other rows."""
+    return jnp.where(pl.program_id(2) == 0, 0.0,
+                     before_ref[HALO - _ROWS:, :].astype(_F32))
+
+
+def _conv_fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, taps, tile):
+    """x_ref, y_ref [R, c]; before_ref: ``_rows_before``'s; w_ref:
+    ``_tap``'s."""
+    before = _rows_before(before_ref)
+
+    def step(n, before):
+        at = pl.multiple_of(n * tile, tile)
+        x = x_ref[pl.ds(at, tile), :].astype(_F32)
+        pre = _pre_activation(
+            _shifted(jnp.concatenate([before, x], axis=0), taps), w_ref)
+        y_ref[pl.ds(at, tile), :] = (pre * _sigmoid(pre)).astype(y_ref.dtype)
+        return x[tile - _ROWS:]
+
+    lax.fori_loop(0, x_ref.shape[0] // tile, step, before)
+
+
+def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref,
+                     w_ref, dx_ref, dw_ref, sums_ref, *, taps, tile):
+    """x_ref, dy_ref, dx_ref [R, c]; before_ref, after_ref, dy_after_ref
+    [HALO, c]: x's rows before the block, x's and dy's after it (past a
+    sequence's end: no gradient comes from there); dw_ref [K + 1, c]:
+    the taps' gradients and the bias's (its row is there with or without
+    one), written once a channel block; sums_ref [K + 1, _ROWS, c]: their
+    sums so far, a sublane a partial sum. The rows go last to first, so
+    that a tile's dx finds the gate's gradient of the rows after it made."""
+    rows = x_ref.shape[0]
+    first = (pl.program_id(1) == 0) & (pl.program_id(2) == 0)
+    last = (pl.program_id(1) == pl.num_programs(1) - 1) \
+        & (pl.program_id(2) == pl.num_programs(2) - 1)
+
+    @pl.when(first)
+    def _init():
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    def gate(ext, dy):
+        """ext [_ROWS + n, c] float32: n rows of x behind the _ROWS before
+        them; dy [n, c] -> (d loss / d pre-activation [n, c] float32, the
+        shifted x's it was made of)."""
+        parts = _shifted(ext, taps)
+        pre = _pre_activation(parts, w_ref)
+        sig = _sigmoid(pre)
+        return dy.astype(_F32) * (sig * (1.0 + pre * (1.0 - sig))), parts
+
+    def fold(x):        # [n, c] -> [_ROWS, c]: partial sums, a sublane each
+        return functools.reduce(
+            jnp.add, [x[r:r + _ROWS] for r in range(0, x.shape[0], _ROWS)])
+
+    def tile_of(ext, at, after):
+        """One tile's sums and dx, from its rows of x behind the _ROWS
+        before them -> its first rows' gate gradient."""
+        g, parts = gate(ext, dy_ref[pl.ds(at, tile), :])
+        for j, part in enumerate(parts):
+            sums_ref[j] = sums_ref[j] + fold(g * part)
+        sums_ref[taps] = sums_ref[taps] + fold(g)
+        both = jnp.concatenate([g, after], axis=0)      # [tile + _ROWS, c]
+        dx = g * _tap(w_ref, taps - 1, tile)
+        for j in range(taps - 2, -1, -1):
+            dx = dx + pltpu.roll(both, tile + _ROWS - (taps - 1 - j),
+                                 axis=0)[:tile] * _tap(w_ref, j, tile)
+        dx_ref[pl.ds(at, tile), :] = dx.astype(dx_ref.dtype)
+        return g[:_ROWS]
+
+    # the gate's gradient of the rows after the block, from its last rows on
+    after, _ = gate(jnp.concatenate([
+        x_ref[rows - HALO:, :].astype(_F32)[HALO - _ROWS:],
+        after_ref[...].astype(_F32)[:_ROWS]], axis=0),
+        dy_after_ref[...][:_ROWS])
+    after = jnp.where(pl.program_id(2) == pl.num_programs(2) - 1, 0.0, after)
+
+    def step(n, after):
+        at = pl.multiple_of(rows - (n + 1) * tile, tile)
+        ext = x_ref[pl.ds(at - HALO, HALO + tile), :].astype(_F32)
+        return tile_of(ext[HALO - _ROWS:], at, after)
+
+    after = lax.fori_loop(0, rows // tile - 1, step, after)
+    tile_of(jnp.concatenate([_rows_before(before_ref),
+                             x_ref[:tile, :].astype(_F32)], axis=0), 0, after)
+
+    @pl.when(last)
+    def _write():
+        for j in range(taps + 1):
+            dw_ref[j:j + 1, :] = jnp.sum(sums_ref[j], axis=0, keepdims=True)
+
+
+def _conv_specs(rows, lanes, blocks, taps):
+    """BlockSpecs over the grid (channel block, row of the batch, row
+    block): a block [rows, lanes] of a [B, S, C] array, the HALO rows before
+    it and the HALO rows after it (held inside the sequence at its ends,
+    where the kernels do not read them), and the channel block's weights
+    (``_tap``'s layout of ``taps`` [n, C])."""
+    per = rows // HALO
+    weights = pl.BlockSpec((taps.shape[0], _ROWS, lanes),
+                           lambda c, b, i: (0, 0, c))
+    block = pl.BlockSpec((None, rows, lanes), lambda c, b, i: (b, i, c))
+    before = pl.BlockSpec(
+        (None, HALO, lanes),
+        lambda c, b, i: (b, jnp.maximum(i * per - 1, 0), c))
+    after = pl.BlockSpec(
+        (None, HALO, lanes),
+        lambda c, b, i: (b, jnp.minimum((i + 1) * per, blocks * per - 1), c))
+    return block, before, after, weights
+
+
+_CONV_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary", "arbitrary"))
+
+
+def _conv_block(s: int, c: int):
+    """(rows, lanes) of a grid step for S = s positions (a multiple of
+    CONV_ROWS) of c channels (a multiple of 128): the most rows up to
+    CONV_ROWS_MOST that divide the sequence."""
+    rows = CONV_ROWS_MOST
+    while s % rows:
+        rows //= 2
+    return rows, CONV_LANES if c % CONV_LANES == 0 else 128
+
+
+def _over_sublanes(taps):
+    """[n, C] -> [n, _ROWS, C]: ``_tap``'s layout."""
+    return jnp.broadcast_to(taps[:, None, :],
+                            (taps.shape[0], _ROWS, taps.shape[1]))
+
+
+def _conv_forward(x, taps, width, interpret):
+    b, s, c = x.shape
+    rows, lanes = _conv_block(s, c)
+    block, before, _, weights = _conv_specs(rows, lanes, s // rows, taps)
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, taps=width, tile=CONV_TILE),
+        name="rt_gdn_conv_fwd",   # rtcheck: allow-unminted-metric(a kernel's name in the device trace, not a metric)
+        grid=(c // lanes, b, s // rows),
+        in_specs=[block, before, weights],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=_CONV_PARAMS,
+        interpret=interpret,
+    )(x, x, _over_sublanes(taps))
+
+
+def _conv_backward(x, taps, dy, width, interpret):
+    """-> (dx like x, dtaps like taps: where they hold no bias its row of
+    the kernel's sums is dropped)."""
+    b, s, c = x.shape
+    rows, lanes = _conv_block(s, c)
+    block, before, after, weights = _conv_specs(rows, lanes, s // rows,
+                                                 taps)
+    sums = pl.BlockSpec((width + 1, lanes), lambda c, b, i: (0, c))
+    dx, dtaps = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, taps=width, tile=CONV_TILE),
+        name="rt_gdn_conv_bwd",   # rtcheck: allow-unminted-metric(a kernel's name in the device trace, not a metric)
+        grid=(c // lanes, b, s // rows),
+        in_specs=[block, before, after, block, after, weights],
+        out_specs=[block, sums],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((width + 1, c), _F32)],
+        scratch_shapes=[pltpu.VMEM((width + 1, _ROWS, lanes), _F32)],
+        compiler_params=_CONV_PARAMS,
+        interpret=interpret,
+    )(x, x, x, dy, dy, _over_sublanes(taps))
+    return dx, dtaps[:taps.shape[0]]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _conv(x, taps, width, interpret):
+    """x [B, S, C], S a multiple of CONV_ROWS, C of 128; taps [K, C]
+    float32, or [K + 1, C] with the bias last -> silu(the convolution),
+    like x."""
+    return _conv_forward(x, taps, width, interpret)
+
+
+def _conv_fwd(x, taps, width, interpret):
+    # nothing of the forward is kept: the backward makes the pre-activation
+    # again from x, which the layer's remat makes again with the projection
+    return _conv_forward(x, taps, width, interpret), (x, taps)
+
+
+def _conv_bwd(width, interpret, res, dy):
+    return _conv_backward(*res, dy, width, interpret)
+
+
+_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def causal_conv_kernels(x, w, bias=None, *, interpret=False):
+    """ops/gated_delta.py's ``causal_conv`` (inside its scope) by the two
+    kernels above, for what ``conv_kernels_fit`` takes: x [B, S, C], S a
+    multiple of CONV_ROWS and C of 128; w [C, K]; bias [C] or None. The
+    taps go in as ``conv_step_kernel``'s, float32 with the bias for a last
+    row; their gradient comes back through the same lines."""
+    return _conv(x, _taps(w, bias), w.shape[1], interpret)

@@ -233,6 +233,62 @@ def test_delta_rule_kernels_compile_for_v5e_per_shard(monkeypatch):
     assert "all-gather" not in hlo and "all-to-all" not in hlo
 
 
+@pytest.mark.parametrize("bias", [False, True], ids=["gdn", "ssd"])
+def test_convolution_kernels_compile_for_v5e_per_shard(monkeypatch, bias):
+    """Mosaic accepts the sequence convolution's forward and backward
+    kernels at the Qwen3-Next cell's shape (2 x 8192 positions of 8192
+    channels, bfloat16, K = 4; with a bias: a state-space mixer's) within
+    the chip's VMEM, and under a 4-chip mesh each chip runs them on its own
+    rows of the batch; a served prompt of 128 positions compiles to no
+    Mosaic call (tests/test_gdn_conv_kernel.py holds their numbers, through
+    the interpreter)."""
+    from jax.experimental import topologies
+    from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+
+    from ray_tpu.ops import gated_delta
+    from ray_tpu.parallel.sharding import DEFAULT_RULES
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu in this install
+        pytest.skip(f"no TPU compiler here: {e!r}")
+    monkeypatch.setattr(gated_delta, "_on_tpu", lambda: True)
+
+    def kernel_calls(mesh, b, s, at):
+        def like(shape, spec):
+            return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                        sharding=at(spec))
+        operands = (like((b, s, 8192), P("dp")), like((8192, 4), P())) \
+            + ((like((8192,), P()),) if bias else ())
+
+        def loss(*a):
+            out = gated_delta.causal_conv_over(mesh, DEFAULT_RULES, *a)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+        hlo = jax.jit(jax.grad(loss, argnums=tuple(range(len(operands))))
+                      ).lower(*operands).compile().as_text()
+        return hlo, [ln for ln in hlo.splitlines()
+                     if 'custom_call_target="tpu_custom_call"' in ln]
+
+    one = SingleDeviceSharding(topo.devices[0])
+    _, calls = kernel_calls(None, 2, 8192, lambda spec: one)
+    assert [name for ln in calls for name in
+            ("rt_gdn_conv_fwd", "rt_gdn_conv_bwd") if name in ln] == [
+                "rt_gdn_conv_fwd", "rt_gdn_conv_bwd"]
+    assert all("bf16[2,8192,8192]" in ln for ln in calls)
+    _, calls = kernel_calls(None, 48, 128, lambda spec: one)
+    assert not calls
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4, 1),
+                ("dcn_dp", "dp", "tp"))
+    hlo, calls = kernel_calls(mesh, 8, 1024,
+                              lambda spec: NamedSharding(mesh, spec))
+    assert len(calls) == 2
+    assert all("bf16[2,1024,8192]" in ln for ln in calls)   # a chip's rows
+    assert "all-gather" not in hlo and "all-to-all" not in hlo
+
+
 @pytest.mark.parametrize("kv_heads", [4, 2, 1])
 def test_sharded_flash_splits_gqa_heads_like_the_reference(monkeypatch,
                                                            kv_heads):

@@ -364,6 +364,10 @@ def test_gated_attentions_flash_forward_runs_once_a_period(
     assert found["rt_flash_dkv"] == found["rt_flash_dq"] == 1
     assert found["rt_gdn_fwd"] == forwards * GDN_LAYERS
     assert found[flash.KEPT] >= 2
+    # the convolution's pair at this length: nothing of its forward is
+    # kept, so the layer's backward runs it again under either policy
+    assert found["rt_gdn_conv_fwd"] == 2 * GDN_LAYERS
+    assert found["rt_gdn_conv_bwd"] == GDN_LAYERS
 
 
 @pytest.mark.parametrize("mesh_shape", [None, (2, 2)])
